@@ -8,12 +8,17 @@ cache (``jax_compilation_cache_dir``) that converts all of those
 recompiles into a disk read; this module is the ONE place that owns
 wiring it:
 
-* :func:`configure` — resolve the cache dir (CLI arg > ``$VIT_COMPILE_
-  CACHE_DIR``), apply the min-entry-size / min-compile-time knobs, and
-  nest entries under a **versioned salt** derived from the package
-  version + a caller-supplied config fingerprint, so entries written by
-  an older package or a different model config can never resurrect old
-  numerics — a salt change simply lands in an empty subdirectory.
+* :func:`configure` — the one rule for where the cache lives, called by
+  every entry point before its first jit. If ``JAX_COMPILATION_CACHE_DIR``
+  is set the cache is exactly there and this code sets no directory at
+  all (whoever runs the program — a launcher, a benchmark harness —
+  places the cache from outside and finds it again next time). If it is
+  unset the cache is ON at ``--compile-cache-dir``, or by default at
+  ``<checkout>/.jax_compile_cache``: a fixed path next to the package,
+  never the cwd, a temp name, a pid or the time, because the path is
+  what the next process must find. jax's own cache key already hashes
+  the program, the compile options, the jax/jaxlib/libtpu versions and
+  the device kind, so entries need no further salting.
 * :data:`STATS` — hit/miss/saved-seconds counters fed by
   ``jax.monitoring`` events, so "did the cache actually work" is
   assertable from instrumentation instead of wall clocks, and surfaced
@@ -23,9 +28,9 @@ wiring it:
   ``time_to_first_step`` / ``time_to_first_batch`` run-log fields
   (honest restart latency includes interpreter + import + backend init,
   not just the compile the caller happens to time).
-* :func:`warn_if_uncached` — one warning per process when an inference
-  entry point runs on a non-CPU backend with no cache configured;
-  silent multi-minute warmups were the failure mode.
+* :func:`warn_if_uncached` — one warning per process when a library
+  caller reaches inference on a non-CPU backend without having called
+  :func:`configure`; silent multi-minute warmups were the failure mode.
 
 ``tools/coldstart_bench.py`` measures the end-to-end effect in fresh
 subprocesses; ``runs/coldstart_r8/`` carries the committed numbers and
@@ -44,14 +49,12 @@ import warnings
 from pathlib import Path
 from typing import Any, Dict, Optional
 
-from . import __version__
-
-# CLI-less configuration axis; the CLI flag (--compile-cache-dir) wins.
-ENV_CACHE_DIR = "VIT_COMPILE_CACHE_DIR"
-ENV_MIN_COMPILE_SECS = "VIT_COMPILE_CACHE_MIN_COMPILE_SECS"
-ENV_MIN_ENTRY_BYTES = "VIT_COMPILE_CACHE_MIN_ENTRY_BYTES"
-# What `--compile-cache-dir` with no value means; .gitignore'd.
-DEFAULT_CACHE_DIR = ".jax_compile_cache"
+# jax's own variable: when set, it alone decides where the cache lives.
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# Where the cache lives otherwise: next to the package (the checkout
+# root for a source tree), whatever the cwd. .gitignore'd.
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parent.parent / \
+    ".jax_compile_cache"
 
 # jax.monitoring event names the persistent cache emits (jax/_src/
 # compiler.py). One *request* per XLA module that consults the cache;
@@ -102,7 +105,6 @@ class CacheStats:
         self.hits = 0
         self.saved_secs = 0.0
         self.cache_dir: Optional[str] = None
-        self.salt: Optional[str] = None
 
     @property
     def misses(self) -> int:
@@ -138,7 +140,6 @@ class CacheStats:
         with self._lock:
             return {
                 "cache_dir": self.cache_dir,
-                "salt": self.salt,
                 "requests": self.requests,
                 "hits": self.hits,
                 "misses": self.requests - self.hits,
@@ -169,7 +170,7 @@ def config_fingerprint(*objs: Any, **parts: Any) -> str:
     Dataclasses (e.g. :class:`..configs.ViTConfig`) are serialized via
     ``asdict``; everything else must be JSON-serializable. Keyword parts
     are sorted, so call-site ordering cannot change the digest. Used
-    both for the cache-key salt and the warmup-manifest identity check.
+    for the warmup-manifest and model-meta identity checks.
     """
     def canon(o):
         if dataclasses.is_dataclass(o) and not isinstance(o, type):
@@ -183,19 +184,6 @@ def config_fingerprint(*objs: Any, **parts: Any) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()
 
 
-def cache_salt(fingerprint: str = "") -> str:
-    """Versioned subdirectory name stale entries can never escape into:
-    bump the package version OR change the config fingerprint and the
-    cache starts empty (old entries persist but are never consulted)."""
-    tag = fingerprint[:12] if fingerprint else "any"
-    return f"v{__version__}-{tag}"
-
-
-def resolve_cache_dir(cli_value: Optional[str]) -> Optional[str]:
-    """CLI flag > $VIT_COMPILE_CACHE_DIR > disabled (None)."""
-    return cli_value or os.environ.get(ENV_CACHE_DIR) or None
-
-
 _ATOMIC_PUT_LOCK = threading.Lock()
 _atomic_put_installed = False
 
@@ -203,8 +191,8 @@ _atomic_put_installed = False
 def _install_atomic_cache_writes() -> None:
     """Harden jax's persistent-cache writes to temp + ``os.replace``.
 
-    jax 0.4.x's ``LRUCache.put`` writes the serialized executable with
-    a bare ``write_bytes`` — a worker SIGKILLed mid-write (preemption,
+    jax's ``LRUCache.put`` writes the serialized executable with a bare
+    ``write_bytes`` — a worker SIGKILLed mid-write (preemption,
     the elastic fault-injection harness, an OOM kill) leaves a
     TRUNCATED ``-cache`` file at the final path, and the next process
     to hit that key feeds torn bytes into XLA executable
@@ -277,103 +265,86 @@ def _install_atomic_cache_writes() -> None:
         LRUCache.put = atomic_put
 
 
-def configure(cache_dir: Optional[str] = None, *,
-              fingerprint: str = "",
-              min_entry_size_bytes: Optional[int] = None,
-              min_compile_time_secs: Optional[float] = None
-              ) -> Optional[Path]:
-    """Point jax's persistent compilation cache at ``cache_dir/<salt>``.
+def configure(cache_dir: Optional[str] = None) -> Optional[Path]:
+    """Turn jax's persistent compilation cache on and say where it is.
 
-    Returns the resolved (salted) directory, or None when no directory
-    is configured anywhere — in which case this is a no-op apart from
-    installing the instrumentation listeners (so a cache configured via
-    jax's own ``JAX_COMPILATION_CACHE_DIR`` still gets counted).
+    ``JAX_COMPILATION_CACHE_DIR`` set: the cache is where jax put it when
+    it read the variable at import, and ``cache_dir`` is ignored; no
+    code path sets another directory. Unset: ``cache_dir`` (the
+    ``--compile-cache-dir`` flag) or :data:`DEFAULT_CACHE_DIR`.
 
-    The min-compile-time knob defaults to 0 (jax's default of 1s would
-    silently skip every sub-second CPU compile — exactly the entries
-    the tests and the CPU cold-start bench rely on); real TPU
-    deployments can raise it via the env knobs to keep trivial modules
-    out of the cache.
+    Returns the directory in force (also ``STATS.cache_dir``).
+
+    The min-compile-time/entry-size thresholds are zeroed: jax's default
+    of 1 s would silently skip every sub-second compile — exactly the
+    entries the CPU tests and the cold-start harness look for.
     """
     import jax
 
     _install_listeners()
     _install_atomic_cache_writes()
-    raw = resolve_cache_dir(cache_dir)
-    if raw is None:
-        return None
-    if min_entry_size_bytes is None:
-        min_entry_size_bytes = int(os.environ.get(ENV_MIN_ENTRY_BYTES, 0))
-    if min_compile_time_secs is None:
-        min_compile_time_secs = float(
-            os.environ.get(ENV_MIN_COMPILE_SECS, 0.0))
-    salt = cache_salt(fingerprint)
-    root = Path(raw).expanduser()
-    if root.exists() and not root.is_dir():
-        # Catch the misparse symptom early with a diagnosis, not a
-        # NotADirectoryError from mkdir: the classic cause is a
-        # positional (an image path) landing in --compile-cache-dir.
-        raise ValueError(
-            f"compile cache dir {raw!r} is an existing file, not a "
-            "directory — was a positional argument (e.g. an image "
-            "path) swallowed by --compile-cache-dir?")
-    resolved = root / salt
-    resolved.mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", str(resolved))
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                      int(min_entry_size_bytes))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_time_secs))
-    try:
-        # A cache already initialized (an earlier compile in this
-        # process) holds the OLD dir; reset so the new config takes.
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:  # noqa: BLE001 — jax-version drift; lazy init
-        pass           # covers the common configure-before-first-compile
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not os.environ.get(ENV_CACHE_DIR):
+        resolved = Path(cache_dir).expanduser() if cache_dir \
+            else DEFAULT_CACHE_DIR
+        if resolved.exists() and not resolved.is_dir():
+            # Catch the misparse symptom early with a diagnosis, not a
+            # NotADirectoryError from mkdir: the classic cause is a
+            # positional (an image path) landing in --compile-cache-dir.
+            raise ValueError(
+                f"compile cache dir {str(resolved)!r} is an existing "
+                "file, not a directory — was a positional argument (e.g. "
+                "an image path) swallowed by --compile-cache-dir?")
+        resolved.mkdir(parents=True, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != str(resolved):
+            jax.config.update("jax_compilation_cache_dir", str(resolved))
+            # A cache already initialized (an earlier compile in this
+            # process) holds the OLD dir; reset so the new one takes.
+            from jax.experimental.compilation_cache import (
+                compilation_cache)
+            compilation_cache.reset_cache()
+    in_force = jax.config.jax_compilation_cache_dir
     with STATS._lock:
-        STATS.cache_dir = str(resolved)
-        STATS.salt = salt
-    return resolved
+        STATS.cache_dir = in_force
+    return Path(in_force) if in_force else None
 
 
 def add_cache_cli(parser) -> None:
     """The shared ``--compile-cache-dir`` axis (train/serve/predict/
-    probe). The value is REQUIRED — an optional-value flag placed ahead
-    of a positional (predict's image paths) silently swallows one, the
-    same greedy-nargs footgun ``--classes-file`` exists to kill.
-    Omitted entirely falls back to ``$VIT_COMPILE_CACHE_DIR``."""
+    probe/batch_infer). The value is REQUIRED — an optional-value flag
+    placed ahead of a positional (predict's image paths) silently
+    swallows one, the same greedy-nargs footgun ``--classes-file``
+    exists to kill."""
     parser.add_argument(
         "--compile-cache-dir", default=None, metavar="DIR",
-        help="persistent XLA compilation cache directory, e.g. "
-             "./" + DEFAULT_CACHE_DIR + " (restarts skip recompiles: "
-             "preemption recovery becomes checkpoint gap + cache hit); "
-             f"default ${ENV_CACHE_DIR} or disabled. Entries are salted "
-             "by package version + model-config fingerprint, so config "
-             "changes can never resurrect stale executables")
+        help="persistent XLA compilation cache directory (restarts skip "
+             "recompiles: preemption recovery becomes checkpoint gap + "
+             f"cache hit). Ignored when ${ENV_CACHE_DIR} is set — the "
+             "cache is then exactly there; default "
+             f"{DEFAULT_CACHE_DIR}")
 
 
 def warn_if_uncached(context: str) -> None:
     """Warn ONCE per process when a non-CPU backend runs without a
-    persistent compilation cache — the silent multi-minute-warmup
-    failure mode this subsystem exists to kill."""
+    persistent compilation cache — a library caller that never called
+    :func:`configure` — the silent multi-minute-warmup failure mode this
+    subsystem exists to kill."""
     global _warned_uncached
     if _warned_uncached:
         return
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend at all: nothing to warm
-        return
-    if backend == "cpu":
-        return
     if jax.config.jax_compilation_cache_dir:
+        return
+    backend = jax.default_backend()
+    if backend == "cpu":
         return
     _warned_uncached = True
     warnings.warn(
         f"[{context}] no persistent compilation cache is configured on "
         f"the '{backend}' backend: every process start re-pays full XLA "
-        f"compilation (multi-second stalls per shape). Pass "
-        f"--compile-cache-dir or set ${ENV_CACHE_DIR}.", stacklevel=2)
+        f"compilation (multi-second stalls per shape). Call "
+        f"compile_cache.configure() or set ${ENV_CACHE_DIR}.",
+        stacklevel=2)

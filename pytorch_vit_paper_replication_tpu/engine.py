@@ -292,6 +292,36 @@ def evaluate(
                                            "count": 0., "skipped": 0.}
 
 
+def _report_first_step(train_step, state, batch, cache: Dict) -> None:
+    """Once per process, after the first step's barrier: what the device
+    boundary actually did, printed instead of trusted. The persistent
+    cache's hit/miss counts; on a TPU the Mosaic calls found in the
+    lowered step with their per-shard operand rows (the kernel dispatch
+    reads ``jax.default_backend()`` and falls back silently) — costs one
+    more lowering, so it is skipped elsewhere, where there is no Mosaic
+    to find; and the memory each local device holds, where the backend
+    reports it."""
+    from .ops.partition import mosaic_calls
+
+    lines = [f"compile cache: {cache['hits']} hits, {cache['misses']} "
+             f"misses ({cache['cache_dir']})"]
+    if jax.default_backend() == "tpu" and hasattr(train_step, "lower"):
+        calls = mosaic_calls(train_step.lower(state, batch).as_text())
+        rows = sorted({shape[0] for _, shape in calls})
+        lines.append(f"train step: {len(calls)} Mosaic kernel calls, "
+                     f"operand rows {rows}")
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    if all("bytes_in_use" in s for s in stats):
+        # (peak_bytes_in_use adds nothing here: on the v5e it reads the
+        # same as bytes_in_use — the step's temporaries are not in it.)
+        gib = " ".join(f"{s['bytes_in_use'] / 2**30:.2f}" for s in stats)
+        lines.append(f"device memory in use: {gib} GiB (limit "
+                     f"{stats[0].get('bytes_limit', 0) / 2**30:.2f} GiB "
+                     "per device)")
+    # vitlint: hot-path-ok(once per process, with the first-step barrier)
+    print("\n".join(lines))
+
+
 def train(
     state: TrainState,
     train_batches: Callable[[], Iterable[Batch]],
@@ -450,6 +480,8 @@ def train(
                         print(f"time_to_first_step: "
                               f"{time_to_first_step:.2f}s (process start "
                               f"-> first train step applied)")
+                        _report_first_step(train_step, state, batch,
+                                           cache_stats.snapshot())
                 total = _accumulate(total, metrics)
                 steps += 1
                 global_step += 1

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import ViTConfig
+from ..ops import partition
 from ..ops.attention import dot_product_attention
 from ..ops.dropout import Dropout
 
@@ -249,6 +250,11 @@ class MLPBlock(nn.Module):
     The fused core kernel composes: it computes the hidden-sliced partial
     locally and the psum stays outside (full-block fusion is skipped —
     the residual must follow the psum).
+
+    On a mesh (GSPMD, no ``tp_axis``) XLA cannot split the kernels, so
+    they shard_map themselves (:mod:`..ops.partition`). A mesh with a
+    model axis takes the same hidden-sliced core-kernel form, the psum
+    then inside :func:`..ops.fused_mlp.fused_mlp`.
     """
 
     config: ViTConfig
@@ -260,8 +266,11 @@ class MLPBlock(nn.Module):
         cfg = self.config
         fused = _mlp_fused(cfg)
         dt = _dtype(cfg)
+        part = partition.current()
+        hidden_sliced = self.tp_axis is not None or (
+            part is not None and part.size(part.model_axis) > 1)
 
-        if fused and self.include_residual and self.tp_axis is None:
+        if fused and self.include_residual and not hidden_sliced:
             # One kernel for the whole half-block, INCLUDING the
             # residual add.
             from ..ops.fused_mlp import fused_ln_mlp_residual

@@ -1,7 +1,9 @@
 """ctypes bridge to the native JPEG fast path (jpeg_loader.cc).
 
-Compiles the C++ source on demand with g++ (``-O2 -shared -fPIC -ljpeg``)
-into a cached shared object next to the source, then exposes:
+Compiles the C++ source on demand with g++ (``-O3 -shared -fPIC -ljpeg``)
+into a shared object next to the source, named after a hash of that
+source — so the only binary that can ever load is one built from the
+``jpeg_loader.cc`` sitting beside it — then exposes:
 
 * :func:`available` — True when the toolchain + libjpeg exist and the
   library compiled; every consumer must branch on this and fall back to
@@ -18,6 +20,7 @@ default), so DataLoader threads decode truly in parallel.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -27,7 +30,6 @@ from typing import Optional
 import numpy as np
 
 _SRC = Path(__file__).parent / "jpeg_loader.cc"
-_SO = Path(__file__).parent / "_jpeg_loader.so"
 _MODES = {"squash": 0, "shorter_crop": 1}
 
 _lock = threading.Lock()
@@ -35,11 +37,23 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _compile() -> bool:
+def _so_path() -> Optional[Path]:
+    """Where the object built from the current source lives:
+    ``_jpeg_loader.<sha256 of jpeg_loader.cc>.so``. A binary from an
+    edited, older or foreign source has another name and is never
+    opened (mtimes say nothing after a checkout or a copy)."""
+    try:
+        digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    except OSError:
+        return None
+    return _SRC.with_name(f"_jpeg_loader.{digest}.so")
+
+
+def _compile(so: Path) -> bool:
     # Build to a process-unique temp name and rename into place: rename is
     # atomic on POSIX, so concurrent first-use compiles (multi-host runs
     # over a shared checkout) never dlopen a half-written file.
-    tmp = _SO.with_name(f".{_SO.name}.{os.getpid()}.tmp")
+    tmp = so.with_name(f".{so.name}.{os.getpid()}.tmp")
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", str(tmp), str(_SRC),
            "-ljpeg"]
     try:
@@ -47,12 +61,15 @@ def _compile() -> bool:
                               timeout=120)
         if proc.returncode != 0 or not tmp.is_file():
             return False
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
     except (OSError, subprocess.TimeoutExpired):
         return False
     finally:
         tmp.unlink(missing_ok=True)
-    return _SO.is_file()
+    for old in so.parent.glob("_jpeg_loader*.so"):
+        if old != so:  # objects of earlier sources: never loadable again
+            old.unlink(missing_ok=True)
+    return so.is_file()
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -63,19 +80,10 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("PSR_TPU_NO_NATIVE"):
             return None
-        try:
-            stale = (not _SO.is_file()
-                     or (_SRC.is_file()
-                         and _SO.stat().st_mtime < _SRC.stat().st_mtime))
-        except OSError:
-            stale = True
-        if stale and not _compile():
+        so = _so_path()
+        if so is None or (not so.is_file() and not _compile(so)):
             return None
-        lib = _open(_SO)
-        if lib is None and _SRC.is_file() and _compile():
-            # Stale/foreign .so (e.g. an older ABI from a previous
-            # version): one rebuild attempt before giving up.
-            lib = _open(_SO)
+        lib = _open(so)
         if lib is None:
             return None
         lib.psr_decode_jpeg.restype = ctypes.c_int

@@ -23,15 +23,17 @@ function so the execution path can be swapped without touching model code:
                  kernel on this hardware, only memory does.
 
 Sequence parallelism rides on top of the dispatch rather than on ``impl``:
-entering :func:`sequence_parallel` (done by ``parallel.api``'s step builders
-whenever the mesh's 'seq' axis is >1) makes every eligible attention call
-route through ring attention (:mod:`..parallel.ring_attention`) via
+tracing under :func:`.partition.on_mesh` (done by ``parallel.api``'s step
+builders) with a mesh whose 'seq' axis is >1 makes every eligible attention
+call route through ring attention (:mod:`..parallel.ring_attention`) via
 ``jax.shard_map`` — tokens stay sharded over the ring, K/V rotate over ICI.
-Model code never changes; that is the point.
+Model code never changes; that is the point. The same context makes the
+flash kernel run per shard on a data/model mesh (XLA will not partition a
+Mosaic call by itself).
 
 Masks run natively on both single-device paths (in-kernel on flash since
 round 4 — broadcast dims stream unmaterialized). The one remaining
-fallback is explicit: an active :func:`sequence_parallel` context that
+fallback is explicit: an active sequence-parallel mesh that
 cannot be honored (mask or non-divisible shapes) warns once and uses the
 XLA path, which is always numerically correct (under GSPMD it simply
 all-gathers K/V). Attention
@@ -47,15 +49,14 @@ softmax accumulation.
 
 from __future__ import annotations
 
-import contextlib
 import functools
-import threading
 import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from . import partition
 from .quant import PROBS_DTYPES, dequantize_probs, quantize_probs
 
 # auto-dispatch: switch to the Pallas kernel when the XLA path would
@@ -74,45 +75,13 @@ _FLASH_MIN_SEQ = 512  # Pallas kernel's own tiling floor
 _SOFTMAX_SHIFT = 16.0
 _SOFTMAX_CLAMP = 80.0
 
-# --- sequence-parallel context --------------------------------------------
-
-_SP = threading.local()
-
-
-@contextlib.contextmanager
-def sequence_parallel(mesh, *, data_axis: str = "data",
-                      seq_axis: str = "seq", model_axis: str = "model",
-                      sp_impl: str = "ring"):
-    """Route attention through sequence parallelism while active.
-
-    Entered at trace time by ``parallel.api.make_parallel_train_step`` /
-    ``make_parallel_eval_step`` when ``mesh.shape[seq_axis] > 1``; the
-    traced program then carries the shard_map'd SP attention permanently,
-    so the context only needs to surround tracing, not every call.
-
-    ``sp_impl``: ``"ring"`` (K/V rotate over neighbor ICI, O(T·T_local)
-    memory) or ``"ulysses"`` (two all_to_alls re-shard tokens→heads,
-    local full-sequence attention — needs heads divisible by the seq
-    axis; see ``parallel/ulysses.py`` for the trade-off table).
-    """
-    if sp_impl not in ("ring", "ulysses"):
-        raise ValueError(f"unknown sp_impl {sp_impl!r}")
-    prev = getattr(_SP, "ctx", None)
-    _SP.ctx = (mesh, data_axis, seq_axis, model_axis, sp_impl)
-    try:
-        yield
-    finally:
-        _SP.ctx = prev
-
-
-def _sp_context():
-    ctx = getattr(_SP, "ctx", None)
-    if ctx is None:
+def _sp_partition():
+    """The active :class:`.partition.Partition` when its seq axis is >1
+    (attention must then run sequence-parallel), else None."""
+    part = partition.current()
+    if part is None or part.size(part.seq_axis) <= 1:
         return None
-    mesh = ctx[0]
-    if mesh.shape.get(ctx[2], 1) <= 1:
-        return None
-    return ctx
+    return part
 
 
 @functools.lru_cache(maxsize=None)
@@ -120,10 +89,10 @@ def _warn_once(msg: str) -> None:
     warnings.warn(msg, stacklevel=3)
 
 
-def _sp_attention(q, k, v, ctx, *, dropout_rate=0.0, dropout_rng=None,
+def _sp_attention(q, k, v, part, *, dropout_rate=0.0, dropout_rng=None,
                   deterministic=True):
     """Dispatch to ring or Ulysses attention over the seq axis
-    (shard_map'd, per the context's sp_impl).
+    (shard_map'd, per the partition's sp_impl).
 
     Batch is sharded over the data axis and heads over the model axis (a
     size-1 axis is a no-op), so the same call serves dp x tp x sp meshes.
@@ -134,11 +103,12 @@ def _sp_attention(q, k, v, ctx, *, dropout_rate=0.0, dropout_rng=None,
     from ..parallel.ring_attention import make_ring_attention
     from ..parallel.ulysses import make_ulysses_attention
 
-    mesh, data_axis, seq_axis, model_axis, sp_impl = ctx
-    make = (make_ulysses_attention if sp_impl == "ulysses"
+    mesh = part.mesh
+    make = (make_ulysses_attention if part.sp_impl == "ulysses"
             else make_ring_attention)
-    head_axis = model_axis if model_axis in mesh.axis_names else None
-    fn = make(mesh, seq_axis, data_axis=data_axis,
+    head_axis = (part.model_axis if part.model_axis in mesh.axis_names
+                 else None)
+    fn = make(mesh, part.seq_axis, data_axis=part.data_axis,
               head_axis=head_axis,
               dropout_rate=dropout_rate,
               dropout_rng=dropout_rng,
@@ -336,10 +306,16 @@ def _xla_attention(q, k, v, *, dropout_rate: float, dropout_rng,
 
 def _flash_ok(q) -> bool:
     """auto-mode: use the Pallas kernel only when the XLA path's
-    materialized logits would not fit comfortably (and shapes qualify)."""
+    materialized logits would not fit comfortably (and shapes qualify).
+    Under a mesh the logits are split over batch and heads, so it is the
+    per-device share that is weighed."""
     if jax.default_backend() != "tpu":
         return False
     b, t, h, dh = q.shape
+    part = partition.current()
+    if part is not None:
+        b = -(-b // part.size(part.data_axis))
+        h = -(-h // part.size(part.model_axis))
     if t < _FLASH_MIN_SEQ or dh not in (32, 64, 128, 256):
         return False
     logits_bytes = b * h * t * t * jnp.dtype(q.dtype).itemsize
@@ -406,8 +382,8 @@ def dot_product_attention(
     retains the classic ``finfo.min``-fill behavior there — a uniform
     softmax with nonzero grads — so don't combine "exact" with
     fully-masked rows expecting zeros. The one remaining
-    fallback (warns once per process): an active :func:`sequence_parallel`
-    context with a mask or shapes not divisible by the mesh axes uses the
+    fallback (warns once per process): an active sequence-parallel
+    mesh with a mask or shapes not divisible by the mesh axes uses the
     XLA path, which GSPMD keeps correct by gathering K/V instead of
     ring-rotating them. Attention dropout rides the ring natively.
     """
@@ -420,28 +396,27 @@ def dot_product_attention(
         raise ValueError(f"unknown residual_dtype {residual_dtype!r}; "
                          f"expected one of {PROBS_DTYPES}")
 
-    sp = _sp_context()
+    sp = _sp_partition()
     if sp is not None:
-        mesh, data_axis, seq_axis, model_axis, sp_impl = sp
         b, t, h = q.shape[0], q.shape[1], q.shape[2]
-        seq_size = mesh.shape[seq_axis]
-        if model_axis in mesh.axis_names and not heads_already_local:
+        seq_size = sp.size(sp.seq_axis)
+        if not heads_already_local:
             # Under GSPMD-TP the traced h is global and must be divided
             # down to the per-shard head count; manual-TP callers hold
             # local heads already and say so via heads_already_local.
-            h = max(1, h // mesh.shape[model_axis])
+            h = max(1, h // sp.size(sp.model_axis))
         if mask is not None:
             _warn_once(
                 "sequence_parallel: attention masks are not supported by "
                 "ring/ulysses attention; using the (gathered) XLA path "
                 "instead")
-        elif t % seq_size or b % mesh.shape.get(data_axis, 1):
+        elif t % seq_size or b % sp.size(sp.data_axis):
             _warn_once(
                 f"sequence_parallel: shape (batch={b}, tokens={t}) not "
-                f"divisible by mesh axes {dict(mesh.shape)}; using the "
+                f"divisible by mesh axes {dict(sp.mesh.shape)}; using the "
                 "(gathered) XLA path instead. Hint: pool='gap' removes the "
                 "odd CLS token from the sequence length")
-        elif sp_impl == "ulysses" and h % seq_size:
+        elif sp.sp_impl == "ulysses" and h % seq_size:
             _warn_once(
                 f"sequence_parallel: sp_impl='ulysses' needs heads ({h}) "
                 f"divisible by the seq axis ({seq_size}); using the "

@@ -97,6 +97,31 @@ def derive_positional_seed(dropout_rng: jax.Array) -> jax.Array:
         jax.random.bits(dropout_rng, (1,), jnp.uint32), jnp.int32)
 
 
+def positional_dropout_seed(name: str, rate: float,
+                            rng: Optional[jax.Array], deterministic: bool):
+    """``(threshold, int32[1] seed)`` for a kernel that draws
+    :func:`positional_keep_u8` masks; threshold 0 (dropout off) carries a
+    dummy seed. ``name`` is the caller, for the error."""
+    threshold = 0
+    if not deterministic and rate > 0.0:
+        threshold = _threshold(rate)
+    if not threshold:
+        return 0, jnp.zeros((1,), jnp.int32)
+    if rng is None:
+        raise ValueError(f"{name} dropout needs dropout_rng")
+    return threshold, derive_positional_seed(rng)
+
+
+def positional_meta(seed: jax.Array, n0: int = 0, shard0=0, n1: int = 0,
+                    shard1=0) -> jax.Array:
+    """The kernels' scalar-prefetch triple ``[seed, offset0, offset1]``:
+    where this shard's slice of two mask coordinates starts (its shard
+    index x the slice's size; 0, 0 on one device), so a kernel that runs
+    per shard still hashes GLOBAL coordinates."""
+    return jnp.stack([seed[0], jnp.int32(n0) * shard0,
+                      jnp.int32(n1) * shard1]).astype(jnp.int32)
+
+
 def quantized_rate(rate: float) -> float:
     """The effective drop probability after uint8 quantization."""
     if rate == 1.0:

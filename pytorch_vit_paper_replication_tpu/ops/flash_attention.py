@@ -43,6 +43,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from . import partition
+from .dropout import positional_dropout_seed, positional_meta
 
 # 256x256 measured fastest on v5e at every length >= 1024 (1.8x the
 # 128x128 fwd+bwd step at t=8192 and t=4096, neutral at 577); larger
@@ -84,6 +88,20 @@ def _keep_mask(seed, bh, row0, col0, shape, threshold):
     row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
     return positional_keep_u8(seed, bh, row, col, threshold)
+
+
+def _global_bh(meta_ref, heads):
+    """The GLOBAL batch·head index of this program, the ``bh`` the mask
+    hash is keyed on. ``meta_ref`` is the scalar-prefetch triple
+    ``[seed, batch0, head0]`` and ``heads`` the static ``(local,
+    global)`` head counts: under a mesh this shard holds batch rows from
+    ``batch0`` and heads from ``head0``, so shards never share a mask
+    and an element's mask is the one ring attention gives it. On one
+    device (offsets 0, local == global) this is ``program_id(0)``."""
+    h_local, h_total = heads
+    bh = pl.program_id(0)
+    return ((meta_ref[1] + jax.lax.div(bh, jnp.int32(h_local))) * h_total
+            + meta_ref[2] + jax.lax.rem(bh, jnp.int32(h_local)))
 
 
 # --------------------------------------------------------------------------
@@ -182,8 +200,8 @@ def _mask_block_cols(mask_ref, mask_info, qi, block_q, block_k):
 # Forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale,
-                block_k, kv_len, threshold, mask_info):
+def _fwd_kernel(meta_ref, q_ref, k_ref, v_ref, *rest, scale,
+                block_k, kv_len, threshold, mask_info, heads):
     """One (batch·head, q-block) program: online-softmax over K/V blocks."""
     if mask_info is not None:
         mask_ref, o_ref, lse_ref = rest
@@ -193,7 +211,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale,
     block_q, head_dim = q.shape
     padded_kv = k_ref.shape[1]
     num_kv = padded_kv // block_k
-    bh = pl.program_id(0)
+    bh = _global_bh(meta_ref, heads)
     qi = pl.program_id(1)
 
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
@@ -233,7 +251,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, *rest, scale,
         # to softmax(S), not to exp(S) pre-normalization.
         l_new = l * correction + jnp.sum(p, axis=-1, keepdims=True)
         if threshold:
-            keep = _keep_mask(seed_ref[0], bh, qi * block_q, ki * block_k,
+            keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
                               (block_q, block_k), threshold)
             p = jnp.where(keep, p, 0.0)
         acc_new = acc * correction + jnp.dot(
@@ -269,8 +287,8 @@ def _pad_to_false(x, axis, multiple):
     return jnp.pad(x, widths, constant_values=False)
 
 
-def _fwd(q, k, v, seed, mask3, mask_info, *, h, scale, block_q, block_k,
-         threshold, interpret):
+def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
+         block_k, threshold, interpret):
     bh, q_len, head_dim = q.shape
     kv_len = k.shape[1]
     qp = _pad_to(q, 1, block_q)
@@ -280,7 +298,7 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, h, scale, block_q, block_k,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                kv_len=kv_len, threshold=threshold,
-                               mask_info=mask_info)
+                               mask_info=mask_info, heads=heads)
     in_specs = [
         pl.BlockSpec((1, block_q, head_dim), lambda b, i, *_: (b, i, 0)),
         pl.BlockSpec((1, kp.shape[1], head_dim), lambda b, i, *_: (b, 0, 0)),
@@ -289,8 +307,8 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, h, scale, block_q, block_k,
     operands = [qp, kp, vp]
     if mask_info is not None:
         mask3 = _pad_mask(mask3, mask_info, block_q, block_k)
-        in_specs.append(_mask_spec_rows(mask_info, h, mask3.shape[2],
-                                        block_q))
+        in_specs.append(_mask_spec_rows(mask_info, heads[0],
+                                        mask3.shape[2], block_q))
         operands.append(mask3)
     out, lse = pl.pallas_call(
         kernel,
@@ -317,8 +335,9 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, h, scale, block_q, block_k,
 # Backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   *rest, scale, block_k, kv_len, threshold, mask_info):
+def _bwd_dq_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   *rest, scale, block_k, kv_len, threshold, mask_info,
+                   heads):
     if mask_info is not None:
         mask_ref, dq_ref = rest
     else:
@@ -329,7 +348,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     delta = delta_ref[0, 0][:, None]   # [Bq, 1]
     block_q, head_dim = q.shape
     num_kv = k_ref.shape[1] // block_k
-    bh = pl.program_id(0)
+    bh = _global_bh(meta_ref, heads)
     qi = pl.program_id(1)
     inv_keep = 256.0 / (256.0 - threshold)
 
@@ -352,7 +371,7 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if threshold:
             # dS = P ⊙ (M/keep ⊙ dP − delta): the mask enters through dP;
             # delta = rowsum(dO⊙O) already carries the forward's dropout.
-            keep = _keep_mask(seed_ref[0], bh, qi * block_q, ki * block_k,
+            keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
                               (block_q, block_k), threshold)
             dp = jnp.where(keep, dp * inv_keep, 0.0)
         ds = p * (dp - delta) * scale
@@ -363,9 +382,9 @@ def _bwd_dq_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *rest, scale, block_q, q_len, threshold,
-                    mask_info):
+                    mask_info, heads):
     if mask_info is not None:
         mask_ref, dk_ref, dv_ref = rest
     else:
@@ -374,7 +393,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     v = v_ref[0].astype(jnp.float32)
     block_k, head_dim = k.shape
     num_q = q_ref.shape[1] // block_q
-    bh = pl.program_id(0)
+    bh = _global_bh(meta_ref, heads)
     ki = pl.program_id(1)
     inv_keep = 256.0 / (256.0 - threshold)
 
@@ -395,7 +414,7 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                       block_k)
             p = jnp.where(attend, p, 0.0)
         if threshold:
-            keep = _keep_mask(seed_ref[0], bh, qi * block_q, ki * block_k,
+            keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
                               (block_q, block_k), threshold)
             p_dropped = jnp.where(keep, p * inv_keep, 0.0)
         else:
@@ -428,25 +447,26 @@ def _bwd_dkv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, seed, mask3, threshold, block_q, block_k, interpret,
-           mask_info, h):
+           mask_info, heads):
     scale = q.shape[-1] ** -0.5
-    out, _ = _fwd(q, k, v, seed, mask3, mask_info, h=h, scale=scale,
+    out, _ = _fwd(q, k, v, seed, mask3, mask_info, heads=heads, scale=scale,
                   block_q=block_q, block_k=block_k, threshold=threshold,
                   interpret=interpret)
     return out
 
 
 def _flash_fwd(q, k, v, seed, mask3, threshold, block_q, block_k,
-               interpret, mask_info, h):
+               interpret, mask_info, heads):
     scale = q.shape[-1] ** -0.5
-    out, lse = _fwd(q, k, v, seed, mask3, mask_info, h=h, scale=scale,
+    out, lse = _fwd(q, k, v, seed, mask3, mask_info, heads=heads,
+                    scale=scale,
                     block_q=block_q, block_k=block_k, threshold=threshold,
                     interpret=interpret)
     return out, (q, k, v, seed, mask3, out, lse)
 
 
-def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, h, res,
-               do):
+def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
+               res, do):
     q, k, v, seed, mask3, out, lse = res
     scale = q.shape[-1] ** -0.5
     bh, q_len, head_dim = q.shape
@@ -476,16 +496,16 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, h, res,
     mask_operands = []
     if mask_info is not None:
         mask3 = _pad_mask(mask3, mask_info, block_q, block_k)
-        dq_in_specs.append(_mask_spec_rows(mask_info, h, mask3.shape[2],
-                                           block_q))
-        dkv_extra_specs.append(_mask_spec_cols(mask_info, h,
+        dq_in_specs.append(_mask_spec_rows(mask_info, heads[0],
+                                           mask3.shape[2], block_q))
+        dkv_extra_specs.append(_mask_spec_cols(mask_info, heads[0],
                                                mask3.shape[1], block_k))
         mask_operands.append(mask3)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           kv_len=kv_len, threshold=threshold,
-                          mask_info=mask_info),
+                          mask_info=mask_info, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, padded_q // block_q),
@@ -503,7 +523,7 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, h, res,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           q_len=q_len, threshold=threshold,
-                          mask_info=mask_info),
+                          mask_info=mask_info, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, padded_kv // block_k),
@@ -553,27 +573,53 @@ def flash_attention(q, k, v, *, mask=None, dropout_rate: float = 0.0,
     ``interpret``: run the Pallas interpreter instead of Mosaic (default:
     auto — True off-TPU, so a forced ``impl="flash"`` works everywhere
     and the CPU suite exercises the identical kernel code).
+
+    Traced under a mesh (:func:`.partition.on_mesh`) the call runs per
+    shard: batch split over the data axis, heads over the model axis,
+    each shard attending over the full sequence (a sequence-sharded mesh
+    never reaches this function — the dispatcher routes it through
+    ring/Ulysses).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    b, t, h, d = q.shape
-    threshold = 0
-    if not deterministic and dropout_rate > 0.0:
-        from .dropout import _threshold
-        threshold = _threshold(dropout_rate)
-    if threshold:
-        if dropout_rng is None:
-            raise ValueError("flash_attention dropout needs dropout_rng")
-        from .dropout import derive_positional_seed
-        seed = derive_positional_seed(dropout_rng)
-    else:
-        seed = jnp.zeros((1,), jnp.int32)
-    mask3, mask_info = _normalize_mask(mask, b, h, t, k.shape[1])
-    # Round clamped block sizes up to a multiple of 8 — Mosaic rejects
-    # non-tile-aligned blocks for f32/bf16 on real TPUs (reachable when
-    # impl="flash" is forced at short unaligned sequence lengths).
-    bq = min(block_q, max(8, -(-t // 8) * 8))
-    bk = min(block_k, max(8, -(-k.shape[1] // 8) * 8))
-    out = _flash(_fold_heads(q), _fold_heads(k), _fold_heads(v), seed,
-                 mask3, threshold, bq, bk, interpret, mask_info, h)
-    return _unfold_heads(out, b, h)
+    threshold, seed = positional_dropout_seed(
+        "flash_attention", dropout_rate, dropout_rng, deterministic)
+    h_total = q.shape[2]
+
+    def local(q, k, v, seed, mask, batch0=0, head0=0):
+        b, t, h, _ = q.shape
+        meta = positional_meta(seed, b, batch0, h, head0)
+        mask3, mask_info = _normalize_mask(mask, b, h, t, k.shape[1])
+        # Round clamped block sizes up to a multiple of 8 — Mosaic rejects
+        # non-tile-aligned blocks for f32/bf16 on real TPUs (reachable when
+        # impl="flash" is forced at short unaligned sequence lengths).
+        bq = min(block_q, max(8, -(-t // 8) * 8))
+        bk = min(block_k, max(8, -(-k.shape[1] // 8) * 8))
+        out = _flash(_fold_heads(q), _fold_heads(k), _fold_heads(v), meta,
+                     mask3, threshold, bq, bk, interpret, mask_info,
+                     (h, h_total))
+        return _unfold_heads(out, b, h)
+
+    part = partition.current()
+    if part is None:
+        return local(q, k, v, seed, mask)
+    data, model = part.axis(part.data_axis), part.axis(part.model_axis)
+    if q.shape[0] % part.size(data) or h_total % part.size(model):
+        raise ValueError(
+            f"flash_attention: batch {q.shape[0]} / heads {h_total} not "
+            f"divisible by the mesh's {part.data_axis!r}/"
+            f"{part.model_axis!r} axes ({part.size(data)}/"
+            f"{part.size(model)})")
+    spec = P(data, None, model, None)
+    if mask is not None:
+        while mask.ndim < 4:
+            mask = mask[None]
+    # A broadcast (size-1) batch/head dim of the mask stays replicated.
+    mask_spec = None if mask is None else P(
+        data if mask.shape[0] > 1 else None,
+        model if mask.shape[1] > 1 else None, None, None)
+    return part.shard_map(
+        lambda q, k, v, seed, mask: local(
+            q, k, v, seed, mask, part.index((data,)), part.index((model,))),
+        in_specs=(spec, spec, spec, P(), mask_spec), out_specs=spec,
+    )(q, k, v, seed, mask)

@@ -60,7 +60,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dropout import positional_keep_u8
+from jax.sharding import PartitionSpec as P
+
+from . import partition
+from .dropout import (positional_dropout_seed, positional_keep_u8,
+                      positional_meta)
 
 DEFAULT_BLOCK_ROWS = 256
 _SQRT_HALF = math.sqrt(0.5)
@@ -99,20 +103,31 @@ def _gelu_grad(h):
     return cdf + h * phi
 
 
-def _keep_mask(seed, row0, shape, threshold):
-    """Dropout keep mask for one [block_rows, F] hidden tile, keyed on the
-    GLOBAL (flattened-row, hidden-column) coordinates so every kernel
-    (fwd, bwd) regenerates the identical mask."""
-    row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+def _keep_mask(meta_ref, block_row0, shape, threshold, *, tag=0):
+    """Dropout keep mask for one [block_rows, F] tile, keyed on the
+    GLOBAL (flattened-row, column) coordinates so every kernel (fwd,
+    bwd) regenerates the identical mask. ``meta_ref`` is the scalar-
+    prefetch triple ``[seed, row0, col0]``: under a mesh each shard's
+    rows and hidden columns start at its own offset
+    (:func:`.dropout.positional_meta`), so
+    no two shards draw the same mask and the mask of an element does
+    not depend on how the batch was split. ``tag`` decorrelates the two
+    dropout sites that share one seed (0 = hidden, 1 = output; the
+    output is never column-sharded, so its column offset is 0)."""
+    row = (meta_ref[1] + block_row0
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return positional_keep_u8(seed, jnp.int32(0), row, col, threshold)
+    if tag == 0:
+        col = col + meta_ref[2]
+    return positional_keep_u8(meta_ref[0], jnp.int32(tag), row, col,
+                              threshold)
 
 
 # --------------------------------------------------------------------------
 # Forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(seed_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
+def _fwd_kernel(meta_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
                 h_ref=None, *, threshold, block_rows):
     """Forward: hidden tile never leaves VMEM. With an ``h_ref`` output
     (training variant) the pre-activation is additionally written in the
@@ -135,7 +150,7 @@ def _fwd_kernel(seed_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
         h_ref[...] = h.astype(h_ref.dtype)
     g = _gelu_exact(h)
     if threshold:
-        keep = _keep_mask(seed_ref[0], pl.program_id(0) * block_rows,
+        keep = _keep_mask(meta_ref, pl.program_id(0) * block_rows,
                           g.shape, threshold)
         g = jnp.where(keep, g * (256.0 / (256.0 - threshold)), 0.0)
     out = jax.lax.dot(g.astype(x.dtype), w2_ref[...],
@@ -148,7 +163,7 @@ def _fwd_kernel(seed_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
 # Backward (saved-h residual; dW accumulated across the sequential grid)
 # --------------------------------------------------------------------------
 
-def _bwd_kernel(seed_ref, x_ref, h_ref, w1_ref, w2_ref, do_ref,
+def _bwd_kernel(meta_ref, x_ref, h_ref, w1_ref, w2_ref, do_ref,
                 dx_ref, dw1_ref, db1_ref, dw2_ref, db2_ref, *,
                 threshold, block_rows):
     i = pl.program_id(0)
@@ -169,7 +184,7 @@ def _bwd_kernel(seed_ref, x_ref, h_ref, w1_ref, w2_ref, do_ref,
     h = h_ref[...].astype(jnp.float32)
     g = _gelu_exact(h)
     if threshold:
-        keep = _keep_mask(seed_ref[0], i * block_rows, g.shape, threshold)
+        keep = _keep_mask(meta_ref, i * block_rows, g.shape, threshold)
         inv_keep = 256.0 / (256.0 - threshold)
         g_drop = jnp.where(keep, g * inv_keep, 0.0)
     else:
@@ -207,9 +222,12 @@ def _bwd_kernel(seed_ref, x_ref, h_ref, w1_ref, w2_ref, do_ref,
 def _compiler_params(interpret):
     if interpret:
         return None
-    # The bwd kernel holds both weight matrices plus two f32 grad
-    # accumulators in VMEM (~28 MB for ViT-B, ~67 MB for ViT-H); raise the
-    # compiler's default cap. v5e/v6e have 128 MiB of VMEM per core.
+    # The bwd kernel holds both weight matrices (bf16) plus their two f32
+    # grad accumulators in VMEM — 27 MiB at ViT-B's 768x3072, 75 MiB at
+    # ViT-H's 1280x5120, before the row blocks — so raise the compiler's
+    # default cap. A v5e core has 128 MiB of VMEM (jax's own
+    # pallas.tpu.get_tpu_info table, "TPU v5 lite"); the v5e compiler
+    # accepts this limit for the B/16, L/16 and H/14 steps.
     return pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
         vmem_limit_bytes=100 * 1024 * 1024,
@@ -337,7 +355,7 @@ def _ln(x32, gamma_ref, beta_ref, eps):
     return xhat, rstd, y
 
 
-def _lnmlp_fwd_kernel(seed_ref, x_ref, gamma_ref, beta_ref, w1_ref, b1_ref,
+def _lnmlp_fwd_kernel(meta_ref, x_ref, gamma_ref, beta_ref, w1_ref, b1_ref,
                       w2_ref, b2_ref, o_ref, h_ref=None, *, threshold,
                       block_rows, eps):
     x32 = x_ref[...].astype(jnp.float32)
@@ -351,21 +369,18 @@ def _lnmlp_fwd_kernel(seed_ref, x_ref, gamma_ref, beta_ref, w1_ref, b1_ref,
     row0 = pl.program_id(0) * block_rows
     if threshold:
         inv_keep = 256.0 / (256.0 - threshold)
-        keep = _keep_mask(seed_ref[0], row0, g.shape, threshold)
+        keep = _keep_mask(meta_ref, row0, g.shape, threshold)
         g = jnp.where(keep, g * inv_keep, 0.0)
     f = jax.lax.dot(g.astype(x_ref.dtype), w2_ref[...],
                     preferred_element_type=jnp.float32)
     f = f + b2_ref[...].astype(jnp.float32)
     if threshold:
-        row = row0 + jax.lax.broadcasted_iota(jnp.int32, f.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, f.shape, 1)
-        keep2 = positional_keep_u8(seed_ref[0], jnp.int32(1), row, col,
-                                   threshold)
+        keep2 = _keep_mask(meta_ref, row0, f.shape, threshold, tag=1)
         f = jnp.where(keep2, f * inv_keep, 0.0)
     o_ref[...] = (x32 + f).astype(o_ref.dtype)
 
 
-def _lnmlp_bwd_kernel(seed_ref, x_ref, h_ref, gamma_ref, beta_ref,
+def _lnmlp_bwd_kernel(meta_ref, x_ref, h_ref, gamma_ref, beta_ref,
                       w1_ref, w2_ref, do_ref, dx_ref, dgamma_ref,
                       dbeta_ref, dw1_ref, db1_ref, dw2_ref, db2_ref, *,
                       threshold, block_rows, eps):
@@ -388,10 +403,7 @@ def _lnmlp_bwd_kernel(seed_ref, x_ref, h_ref, gamma_ref, beta_ref,
     # Output dropout enters through the fc2 cotangent.
     if threshold:
         inv_keep = 256.0 / (256.0 - threshold)
-        row = row0 + jax.lax.broadcasted_iota(jnp.int32, do32.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, do32.shape, 1)
-        keep2 = positional_keep_u8(seed_ref[0], jnp.int32(1), row, col,
-                                   threshold)
+        keep2 = _keep_mask(meta_ref, row0, do32.shape, threshold, tag=1)
         df = jnp.where(keep2, do32 * inv_keep, 0.0)
     else:
         df = do32
@@ -400,7 +412,7 @@ def _lnmlp_bwd_kernel(seed_ref, x_ref, h_ref, gamma_ref, beta_ref,
     h = h_ref[...].astype(jnp.float32)
     g = _gelu_exact(h)
     if threshold:
-        keep = _keep_mask(seed_ref[0], row0, g.shape, threshold)
+        keep = _keep_mask(meta_ref, row0, g.shape, threshold)
         g_drop = jnp.where(keep, g * inv_keep, 0.0)
     else:
         g_drop = g
@@ -540,6 +552,35 @@ def _lnmlp_bwd(threshold, block_rows, eps, interpret, res, do):
 _lnmlp.defvjp(_lnmlp_fwd, _lnmlp_bwd)
 
 
+def _flat_rows(x, block_rows):
+    """``[..., D]`` -> ``([N_padded, D], N, block)``: rows flattened and
+    padded up to a whole number of row blocks."""
+    x2 = x.reshape(-1, x.shape[-1])
+    n = x2.shape[0]
+    block = min(block_rows, max(16, -(-n // 16) * 16))
+    pad = (-n) % block
+    if pad:
+        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
+    return x2, n, block
+
+
+def _row_sharding(part, x):
+    """How the active mesh splits the rows of ``x``: batch over the data
+    axis and — for ``[B, T, D]`` whose tokens divide — tokens over the
+    seq axis (the MLP is per-token). -> ``(axes, PartitionSpec)``."""
+    data = part.axis(part.data_axis)
+    seq = part.axis(part.seq_axis)
+    if x.ndim != 3 or x.shape[1] % part.size(seq):
+        seq = None
+    if x.shape[0] % part.size(data):
+        raise ValueError(
+            f"leading dim {x.shape[0]} of the fused-MLP input is not "
+            f"divisible by the mesh's {part.data_axis!r} axis "
+            f"({part.size(data)})")
+    lead = (data, seq) if x.ndim == 3 else (data,) + (None,) * (x.ndim - 2)
+    return (data, seq), P(*lead, None)
+
+
 def fused_ln_mlp_residual(x: jax.Array, gamma: jax.Array, beta: jax.Array,
                           w1: jax.Array, b1: jax.Array, w2: jax.Array,
                           b2: jax.Array, *, eps: float = 1e-6,
@@ -557,38 +598,36 @@ def fused_ln_mlp_residual(x: jax.Array, gamma: jax.Array, beta: jax.Array,
     reference's single ``mlp_dropout`` rate (``models/vit.py:120-126``).
     Requires ``w2``'s output dim to equal ``x``'s feature dim (the
     residual add).
+
+    Traced under a mesh (:func:`.partition.on_mesh`) the call runs per
+    shard of the rows with every weight replicated; a mesh that slices
+    the hidden dim (tensor parallelism) wants :func:`fused_mlp`, whose
+    partial sums can be reduced before the residual.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    *lead, d = x.shape
-    if w2.shape[1] != d:
+    if w2.shape[1] != x.shape[-1]:
         raise ValueError(
             f"residual form needs fc2 out dim == input dim, got "
-            f"{w2.shape[1]} != {d}")
-    threshold = 0
-    if not deterministic and dropout_rate > 0.0:
-        from .dropout import _threshold
-        threshold = _threshold(dropout_rate)
-    if threshold:
-        if dropout_rng is None:
-            raise ValueError("fused_ln_mlp_residual dropout needs "
-                             "dropout_rng")
-        from .dropout import derive_positional_seed
-        seed = derive_positional_seed(dropout_rng)
-    else:
-        seed = jnp.zeros((1,), jnp.int32)
+            f"{w2.shape[1]} != {x.shape[-1]}")
+    threshold, seed = positional_dropout_seed(
+        "fused_ln_mlp_residual", dropout_rate, dropout_rng, deterministic)
 
-    x2 = x.reshape(-1, d)
-    n = x2.shape[0]
-    block = min(block_rows, max(16, -(-n // 16) * 16))
-    pad = (-n) % block
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    out = _lnmlp(x2, gamma, beta, w1, b1, w2, b2, seed, threshold, block,
-                 eps, interpret)
-    if pad:
-        out = out[:n]
-    return out.reshape(x.shape)
+    def local(x, gamma, beta, w1, b1, w2, b2, seed, row_shard=0):
+        x2, n, block = _flat_rows(x, block_rows)
+        meta = positional_meta(seed, n, row_shard)
+        out = _lnmlp(x2, gamma, beta, w1, b1, w2, b2, meta, threshold,
+                     block, eps, interpret)
+        return out[:n].reshape(x.shape)
+
+    part = partition.current()
+    if part is None:
+        return local(x, gamma, beta, w1, b1, w2, b2, seed)
+    rows, x_spec = _row_sharding(part, x)
+    return part.shard_map(
+        lambda *a: local(*a, row_shard=part.index(rows)),
+        in_specs=(x_spec,) + (P(),) * 7, out_specs=x_spec,
+    )(x, gamma, beta, w1, b1, w2, b2, seed)
 
 
 def fused_mlp(x: jax.Array, w1: jax.Array, b1: jax.Array, w2: jax.Array,
@@ -612,30 +651,45 @@ def fused_mlp(x: jax.Array, w1: jax.Array, b1: jax.Array, w2: jax.Array,
 
     Returns:
       ``[..., D_out]``, in ``x.dtype``.
+
+    Traced under a mesh (:func:`.partition.on_mesh`) the call runs per
+    shard: rows split like :func:`fused_ln_mlp_residual`'s, and when the
+    mesh has a model axis the hidden dim is sliced over it (``w1``
+    columns / ``w2`` rows, the layout ``parallel.sharding.TP_RULES``
+    places them in) with fc2's partial sums ``psum``'d before ``b2`` is
+    added once — Megatron wiring, the same form
+    :class:`..models.vit.MLPBlock`'s manual ``tp_axis`` spells by hand.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    *lead, d = x.shape
-    d_out = w2.shape[1]
-    threshold = 0
-    if not deterministic and dropout_rate > 0.0:
-        from .dropout import _threshold
-        threshold = _threshold(dropout_rate)
-    if threshold:
-        if dropout_rng is None:
-            raise ValueError("fused_mlp dropout needs dropout_rng")
-        from .dropout import derive_positional_seed
-        seed = derive_positional_seed(dropout_rng)
-    else:
-        seed = jnp.zeros((1,), jnp.int32)
+    threshold, seed = positional_dropout_seed(
+        "fused_mlp", dropout_rate, dropout_rng, deterministic)
 
-    x2 = x.reshape(-1, d)
-    n = x2.shape[0]
-    block = min(block_rows, max(16, -(-n // 16) * 16))
-    pad = (-n) % block
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    out = _fused(x2, w1, b1, w2, b2, seed, threshold, block, interpret)
-    if pad:
-        out = out[:n]
-    return out.reshape(*lead, d_out)
+    def local(x, w1, b1, w2, b2, seed, row_shard=0, col_shard=0):
+        x2, n, block = _flat_rows(x, block_rows)
+        meta = positional_meta(seed, n, row_shard, w1.shape[1], col_shard)
+        out = _fused(x2, w1, b1, w2, b2, meta, threshold, block, interpret)
+        return out[:n].reshape(*x.shape[:-1], w2.shape[1])
+
+    part = partition.current()
+    if part is None:
+        return local(x, w1, b1, w2, b2, seed)
+    rows, x_spec = _row_sharding(part, x)
+    model = part.axis(part.model_axis)
+    if w1.shape[1] % part.size(model):
+        raise ValueError(
+            f"mlp hidden dim {w1.shape[1]} is not divisible by the mesh's "
+            f"{part.model_axis!r} axis ({part.size(model)})")
+
+    def shard(x, w1, b1, w2, b2, seed):
+        if model is None:
+            return local(x, w1, b1, w2, b2, seed, part.index(rows))
+        partial = local(x, w1, b1, w2, jnp.zeros_like(b2), seed,
+                        part.index(rows), jax.lax.axis_index(model))
+        return jax.lax.psum(partial, model) + b2
+
+    return part.shard_map(
+        shard,
+        in_specs=(x_spec, P(None, model), P(model), P(model, None), P(),
+                  P()),
+        out_specs=x_spec)(x, w1, b1, w2, b2, seed)

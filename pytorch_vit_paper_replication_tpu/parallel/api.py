@@ -11,36 +11,21 @@ Usage (the whole data+tensor-parallel story, scaling-book style)::
 
 GSPMD inserts the gradient psum over 'data' and the TP collectives over
 'model'; nothing in the model or engine code changes — the payoff of pure
-step functions (SURVEY.md §7).
+step functions (SURVEY.md §7). The one thing GSPMD will not split is a
+Mosaic (Pallas) custom call, so the steps are traced under
+``ops.partition.on_mesh`` and the kernels shard_map themselves.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine import TrainState, make_eval_step, make_train_step
-from ..ops.attention import sequence_parallel
+from ..ops.partition import traced_on_mesh
 from .sharding import pspec_for_path, shard_tree
-
-
-def _with_seq_parallel(jitted, mesh: Mesh, sp_impl: str = "ring"):
-    """Run `jitted` under the sequence-parallel attention context when the
-    mesh has a 'seq' axis >1, so the trace routes attention through ring
-    or Ulysses SP (ops.attention.sequence_parallel). No-op wrapper
-    otherwise."""
-    if mesh.shape.get("seq", 1) <= 1:
-        return jitted
-
-    @functools.wraps(jitted)
-    def call(*args, **kwargs):
-        with sequence_parallel(mesh, sp_impl=sp_impl):
-            return jitted(*args, **kwargs)
-
-    return call
 
 
 def state_shardings(state: TrainState, mesh: Mesh) -> TrainState:
@@ -98,7 +83,7 @@ def make_parallel_train_step(state: TrainState, mesh: Mesh, *,
                      in_shardings=(st_sh, None),
                      out_shardings=(st_sh, None),
                      donate_argnums=0)
-    return _with_seq_parallel(jitted, mesh, sp_impl)
+    return traced_on_mesh(jitted, mesh, sp_impl=sp_impl)
 
 
 def make_parallel_eval_step(state: TrainState, mesh: Mesh, *,
@@ -106,4 +91,4 @@ def make_parallel_eval_step(state: TrainState, mesh: Mesh, *,
     step = make_eval_step()
     st_sh = state_shardings(state, mesh)
     jitted = jax.jit(step, in_shardings=(st_sh, None), out_shardings=None)
-    return _with_seq_parallel(jitted, mesh, sp_impl)
+    return traced_on_mesh(jitted, mesh, sp_impl=sp_impl)

@@ -22,9 +22,8 @@ a SIGKILLed worker took the whole job with it. Now:
   as an independent single-process JAX instance and sums gradients
   across workers through a TCP :class:`AllReduceServer` in the
   supervisor — genuinely multi-process data parallelism that runs on
-  any host (the jax-0.4.x CPU backend cannot execute cross-process XLA
-  computations, so this is also what the 2-process CPU evidence runs
-  and tier-1 tests exercise).
+  any host (this is what the 2-process CPU evidence runs and tier-1
+  tests exercise).
 
 Correctness core: a checkpoint written at ``dp=N`` restores onto a
 ``dp=N-1`` mesh bit-faithfully — :meth:`..checkpoint.Checkpointer.restore`
@@ -435,9 +434,9 @@ def make_host_collective_train_step(
         return new_state, metrics
 
     local_fn = jax.jit(_local)
-    # NO donate_argnums on the apply jit, deliberately: on jax 0.4.x
-    # CPU, a DESERIALIZED (persistent-compile-cache-hit) executable
-    # with donated inputs corrupts the heap when run against
+    # NO donate_argnums on the apply jit, deliberately: on the CPU
+    # backend a DESERIALIZED (persistent-compile-cache-hit) executable
+    # with donated inputs was seen to corrupt the heap when run against
     # orbax-restored arrays ("corrupted double-linked list"/SIGSEGV a
     # couple of steps after resume) — exactly the restore-through-the-
     # cache path every elastic recovery takes. Found by the
@@ -653,18 +652,23 @@ def read_loss_trajectory(rendezvous: str | Path
 # --------------------------------------------------------------------------
 
 def worker_cache_dir(argv: Sequence[str],
-                     env: Optional[dict] = None) -> Optional[Path]:
-    """The persistent compile-cache ROOT the workers will use, parsed
-    from their argv (``--compile-cache-dir``) or the env fallback —
-    the supervisor needs it for poisoned-cache quarantine."""
+                     env: Optional[dict] = None) -> Path:
+    """The persistent compile-cache directory the workers will use —
+    ``compile_cache.configure``'s rule (jax's variable, else
+    ``--compile-cache-dir``, else the in-checkout default) read off
+    their argv and environment; the supervisor needs it for
+    poisoned-cache quarantine."""
+    from ..compile_cache import DEFAULT_CACHE_DIR, ENV_CACHE_DIR
+
+    raw = (env if env is not None else os.environ).get(ENV_CACHE_DIR)
+    if raw:
+        return Path(raw)
     for i, arg in enumerate(argv):
         if arg == "--compile-cache-dir" and i + 1 < len(argv):
             return Path(argv[i + 1])
         if arg.startswith("--compile-cache-dir="):
             return Path(arg.split("=", 1)[1])
-    raw = (env if env is not None else os.environ).get(
-        "VIT_COMPILE_CACHE_DIR")
-    return Path(raw) if raw else None
+    return DEFAULT_CACHE_DIR
 
 
 def strip_elastic_args(argv: Sequence[str]) -> List[str]:
@@ -954,7 +958,6 @@ class ElasticSupervisor:
             self._stuck_restores = 0
             self._last_loss_restore_step = restore_step
         if (self._stuck_restores < self.quarantine_after
-                or self._cache_dir is None
                 or not self._cache_dir.exists()):
             return
         dest = self._cache_dir.with_name(

@@ -30,17 +30,15 @@ AXES = ("data", "model", "seq", "pipe")
 
 def make_mesh(config: Optional[MeshConfig] = None,
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
-    """Build a 3-axis ('data','model','seq') mesh over the given devices."""
+    """Build the ('data','model','seq','pipe') mesh over the given devices."""
     config = config or MeshConfig()
     devices = list(devices) if devices is not None else jax.devices()
     shape = config.axis_sizes(len(devices))
-    try:
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=np.asarray(devices))
-    except Exception:
-        # create_device_mesh can reject virtual/host platforms; plain
-        # reshape preserves semantics (just not physical-torus locality).
-        dev_array = np.asarray(devices).reshape(shape)
+    # On a TPU this orders the devices along the physical torus (a 2x2
+    # v5e host comes back 0,1,3,2); elsewhere it is a plain reshape. A
+    # shape it cannot map is an error, not a silent loss of that order.
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=np.asarray(devices))
     return Mesh(dev_array, AXES)
 
 

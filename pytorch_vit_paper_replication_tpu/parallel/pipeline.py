@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.partition import on_mesh
+
 BLOCKS_KEY = "encoder_blocks"  # sharding rule lives in sharding.pspec_for_path
 
 
@@ -285,20 +287,23 @@ def make_pipeline_apply(cfg, mesh: Mesh, *, num_microbatches: int,
         stacked_specs = jax.tree_util.tree_map_with_path(
             lambda p, leaf: pspec_for_path(p, leaf),
             {BLOCKS_KEY: stacked})[BLOCKS_KEY]
-        if dropout_rng is not None:
-            fn = jax.shard_map(
-                lambda s, xx, r: encoder(s, xx, train, r),
-                mesh=mesh,
-                in_specs=(stacked_specs, x_spec, P()),
-                out_specs=x_spec, check_vma=False)
-            x = fn(stacked, x, dropout_rng)
-        else:
-            fn = jax.shard_map(
-                lambda s, xx: encoder(s, xx, train, None),
-                mesh=mesh,
-                in_specs=(stacked_specs, x_spec),
-                out_specs=x_spec, check_vma=False)
-            x = fn(stacked, x)
+        # Inside this shard_map every array is already local: the Pallas
+        # kernels must run as they are, not shard_map themselves again.
+        with on_mesh(None):
+            if dropout_rng is not None:
+                fn = jax.shard_map(
+                    lambda s, xx, r: encoder(s, xx, train, r),
+                    mesh=mesh,
+                    in_specs=(stacked_specs, x_spec, P()),
+                    out_specs=x_spec, check_vma=False)
+                x = fn(stacked, x, dropout_rng)
+            else:
+                fn = jax.shard_map(
+                    lambda s, xx: encoder(s, xx, train, None),
+                    mesh=mesh,
+                    in_specs=(stacked_specs, x_spec),
+                    out_specs=x_spec, check_vma=False)
+                x = fn(stacked, x)
 
         return apply_tail(cfg, params, x)
 

@@ -13,7 +13,7 @@ SURVEY.md §5); this module is what makes long-context a first-class
 capability of the TPU build. Three ways in: (1) training — build the step
 via ``parallel.api.make_parallel_train_step`` on a mesh whose 'seq' axis is
 >1 and every model attention call routes here automatically
-(``ops.attention.sequence_parallel``); (2) :func:`make_ring_attention` for
+(``ops.partition.on_mesh``); (2) :func:`make_ring_attention` for
 a standalone global-array op; (3) :func:`ring_self_attention` inside your
 own ``shard_map``.
 """

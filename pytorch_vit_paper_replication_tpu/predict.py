@@ -46,22 +46,13 @@ def main(argv=None):
                         "the checkpoint's transform.json when present, "
                         "else the reference predict default: normalized)")
     p.add_argument("--plot-dir", type=str, default=None)
-    from .compile_cache import add_cache_cli, config_fingerprint, configure
+    from .compile_cache import add_cache_cli, configure
     add_cache_cli(p)
     args = p.parse_args(argv)
 
     # Before the first jit: directory prediction compiles one forward
-    # per bucket rung — all cache hits on the second invocation. The
-    # salt uses the RESOLVED image size (transform.json over the flag),
-    # so explicit and implicit launches of the same checkpoint share
-    # one cache subdirectory.
-    from .predictions import resolve_transform_spec
-    configure(args.compile_cache_dir,
-              fingerprint=config_fingerprint(
-                  preset=args.preset,
-                  image_size=resolve_transform_spec(
-                      args.checkpoint,
-                      image_size=args.image_size)["image_size"]))
+    # per bucket rung — all cache hits on the second invocation.
+    configure(args.compile_cache_dir)
 
     from .predictions import load_class_names
     classes = (load_class_names(args.classes_file) if args.classes_file

@@ -227,10 +227,7 @@ def resolve_transform_spec(checkpoint: str | Path, *,
     """The checkpoint's preprocessing identity WITHOUT loading params:
     the recorded ``transform.json`` (next to the export, or its parent
     run dir) over the reference predict defaults (224px, normalize ON),
-    explicit overrides last. Cheap enough to call before
-    ``compile_cache.configure()``, so cache salts are built from the
-    RESOLVED image size — two replicas of the same checkpoint share
-    entries whether or not one passed ``--image-size`` explicitly."""
+    explicit overrides last."""
     import json
 
     ckpt = Path(checkpoint)

@@ -165,15 +165,12 @@ def main(argv=None) -> Dict[str, float]:
     p.add_argument("--lr", type=float, default=1e-2)
     p.add_argument("--weight-decay", type=float, default=0.0)
     p.add_argument("--no-normalize", action="store_true")
-    from .compile_cache import add_cache_cli, config_fingerprint, configure
+    from .compile_cache import add_cache_cli, configure
     add_cache_cli(p)
     args = p.parse_args(argv)
     # The probe re-pays the frozen-backbone forward compile every
     # invocation; with a cache, only the first run compiles.
-    configure(args.compile_cache_dir,
-              fingerprint=config_fingerprint(preset=args.preset,
-                                             image_size=args.image_size,
-                                             probe=True))
+    configure(args.compile_cache_dir)
     if args.checkpoint and not args.num_classes:
         p.error("--num-classes is required with --checkpoint (it sizes the "
                 "saved head in the restore template)")

@@ -35,7 +35,7 @@ the workload the north star actually names — serving. The pieces:
 * :mod:`.offline` — :class:`OfflineEngine`: the *throughput* half
   (ROADMAP 4b) — sweep a whole packed dataset through the same
   bucketed forward sharded over every local device, double-buffered
-  prefetch with donated inputs, an atomic resumable progress
+  prefetch, an atomic resumable progress
   manifest, and ``.npy``/JSONL sinks ("embed 10⁶ images overnight";
   CLI: ``tools/batch_infer.py``, gate: ``batch_infer_ok``).
 * :mod:`.fleet` — the multi-replica serving fleet (ISSUE 10): a

@@ -439,7 +439,7 @@ def main(argv=None):
                         "background, smallest rung first — requests for "
                         "already-warm rungs are servable immediately)")
     add_engine_args(p)
-    from ..compile_cache import add_cache_cli, configure, warn_if_uncached
+    from ..compile_cache import add_cache_cli, configure
     add_cache_cli(p)
     args = p.parse_args(argv)
     if args.ship_to:
@@ -465,24 +465,10 @@ def main(argv=None):
     class_names = (load_class_names(args.classes_file)
                    if args.classes_file else args.classes)
 
-    # Cache before the first compile; salt by the serving identity so a
-    # preset/size change can't resurrect another model's executables.
-    # The RESOLVED image size (transform.json over the flag) keeps
-    # replicas of one checkpoint in one cache subdirectory whether or
-    # not they passed --image-size explicitly.
-    from ..compile_cache import config_fingerprint
-    from ..predictions import resolve_transform_spec
-    cache_dir = configure(args.compile_cache_dir,
-                          fingerprint=config_fingerprint(
-                              preset=args.preset,
-                              image_size=resolve_transform_spec(
-                                  args.checkpoint,
-                                  image_size=args.image_size)
-                              ["image_size"]))
-    if cache_dir is not None:
-        print(f"[serve] compile cache: {cache_dir}", file=sys.stderr)
-    else:
-        warn_if_uncached("serve")
+    # Cache before the first compile: every replica of a checkpoint, and
+    # every restart, then shares the rung executables.
+    print(f"[serve] compile cache: {configure(args.compile_cache_dir)}",
+          file=sys.stderr)
 
     def log_rung(bucket, seconds):
         print(f"[serve] warmup: bucket {bucket} compiled in "

@@ -216,8 +216,6 @@ class InferenceEngine:
                  search_index=None,
                  search_k_max: int = 100,
                  model_tier: Optional[str] = None):
-        import jax
-
         from ..data.transforms import eval_transform
 
         self.model = model
@@ -234,15 +232,10 @@ class InferenceEngine:
                                     if model_tier else None)
         self.buckets = tuple(sorted(set(int(b) for b in buckets)))
         self.stats = stats if stats is not None else ServeStats()
-        # Donating the activations buffer lets XLA reuse the request
-        # batch's HBM for the forward's workspace; params (arg 0) are
-        # shared across batches and must NOT be donated. CPU backends
-        # don't implement donation and would warn once per bucket shape.
-        donate = (1,) if jax.default_backend() != "cpu" else ()
         self._params = params
         # The fused multi-head forward (see module docstring): ONE
         # program per rung serving every head a request may tag.
-        self._fwd, self.heads = self._make_forward(model, params, donate)
+        self._fwd, self.heads = self._make_forward(model, params)
         # AOT-compiled executables per rung (written by warmup, read by
         # the single batcher worker thread; dict writes are atomic).
         self._compiled: Dict[int, Any] = {}
@@ -324,8 +317,10 @@ class InferenceEngine:
 
     # ---------------------------------------------------------- device
     @staticmethod
-    def _make_forward(model, params, donate):
-        """Build the fused multi-head jitted forward.
+    def _make_forward(model, params):
+        """Build the fused multi-head jitted forward. Nothing is donated:
+        params are shared across batches, and no head's output has the
+        request batch's shape for XLA to alias it to.
 
         For a ViT-shaped (model, params) — a ``.config`` plus the
         ``{"backbone", "head"}`` param split — the program runs the
@@ -355,7 +350,7 @@ class InferenceEngine:
                 return {"probs": jax.nn.softmax(
                     model.apply({"params": p}, x).astype(jnp.float32),
                     axis=-1)}
-            return jax.jit(fwd_probs, donate_argnums=donate), ("probs",)
+            return jax.jit(fwd_probs), ("probs",)
 
         import flax.linen as nn
 
@@ -379,7 +374,7 @@ class InferenceEngine:
                         logits.astype(jnp.float32), axis=-1),
                     "features": pooled.astype(jnp.float32),
                     "tokens": tokens.astype(jnp.float32)}
-        return jax.jit(fused, donate_argnums=donate), HEADS
+        return jax.jit(fused), HEADS
 
     def _device_forward(self, padded: np.ndarray, mask: np.ndarray,
                         heads: Optional[Sequence[str]] = None

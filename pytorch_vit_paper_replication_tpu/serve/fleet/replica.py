@@ -87,6 +87,13 @@ def replica_env(devices: Sequence[int],
     on the name); on CPU hosts they are inert and the partition is
     advisory. ``VIT_REPLICA_DEVICES`` rides along for diagnostics —
     a replica's stderr tail names its partition.
+
+    On a 2x2 v5e host (libtpu 0.0.34) this is all a ONE-chip child
+    needs: it sees exactly that chip (as device id 0), and children on
+    different chips run side by side — ``chip_smoke.py`` starts its
+    one-chip phases this way. A partition of several chips also needs
+    ``TPU_CHIPS_PER_PROCESS_BOUNDS`` (chips 0,1 came up with ``1,2,1``
+    and were refused with ``2,1,1``), which nothing sets yet.
     """
     env = dict(base if base is not None else os.environ)
     csv = ",".join(str(int(d)) for d in devices)
@@ -453,8 +460,13 @@ class ReplicaManager:
                     self._spawn(rid, require_supervise=True)
             elif addr is not None:
                 snap = self._poll_stats(addr)
+                # A failed rung compile leaves the server answering on
+                # the jit path — on a chip that is a replica that cannot
+                # run its program, not a healthy one.
+                warm_failed = bool(snap is not None and (
+                    snap.get("warmup") or {}).get("error"))
                 with self._lock:
-                    if snap is not None:
+                    if snap is not None and not warm_failed:
                         rep.last_ok_mono = time.monotonic()
                         rep.up = True
                         rep.cur_backoff_s = 0.0
@@ -465,7 +477,7 @@ class ReplicaManager:
                             (snap.get("warm_rungs") or [])))
                         rep.fingerprint = snap.get(
                             "checkpoint_fingerprint")
-                    elif (rep.last_ok_mono is None
+                    elif (warm_failed or rep.last_ok_mono is None
                           or time.monotonic() - rep.last_ok_mono
                           > self.stale_after_s):
                         rep.up = False

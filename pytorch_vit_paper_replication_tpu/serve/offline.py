@@ -15,8 +15,7 @@ jitted forward, but
 * **double-buffered**: dispatch is async — batch N+1's host→device
   copy and forward are issued while batch N still computes, with a
   bounded in-flight window (``prefetch``) so host memory stays O(few
-  batches). Input buffers are donated off-CPU, so XLA reuses the
-  transfer pages as forward workspace exactly like the online engine;
+  batches);
 * **resumable**: an atomic progress manifest (``progress.json``,
   temp-file + ``os.replace`` — the PR 4 warmup-manifest discipline)
   records the record offset + output-row count after every flushed
@@ -350,16 +349,6 @@ class OfflineEngine:
         elif head == "logits":
             apply_params = params
 
-            # The probs expression below MINUS the softmax — the
-            # pre-softmax classifier activations, bit-exact (test-
-            # asserted): softmax(logits head) == probs head. This is
-            # the distillation dataset (train.py --distill-from) and
-            # calibration/hard-example-mining feed (ROADMAP 4).
-            def fn(p, x):
-                return model.apply({"params": p}, x).astype(jnp.float32)
-        elif head == "logits":
-            apply_params = params
-
             # The probs program with the final softmax dropped: the
             # float32 cast happens BEFORE softmax in the probs fn, so
             # these rows are bit-identical to the tensor the probs
@@ -390,12 +379,13 @@ class OfflineEngine:
         # N-D path and gets its row_shape pinned in the manifest.
         self.out_shape = tuple(int(d) for d in out.shape[1:])
 
-        # Donating the input batch lets XLA reuse its HBM as forward
-        # workspace; params (arg 0) are shared across batches and must
-        # NOT be donated. CPU backends don't implement donation and
-        # would warn once per shape — same gate as the online engine.
-        donate = (1,) if jax.default_backend() != "cpu" else ()
-        self._fwd = jax.jit(fn, donate_argnums=donate)
+        # No donation: no output has the input batch's shape, so XLA
+        # has nothing to alias it to ("Some donated buffers were not
+        # usable" on every rung when it was asked). Traced on the mesh
+        # so the Pallas kernels run per shard of the batch.
+        from ..ops.partition import traced_on_mesh
+        self._fwd = traced_on_mesh(jax.jit(fn), self.mesh,
+                                   data_axis="batch")
         # Params placed ONCE, replicated over the mesh — every per-chunk
         # dispatch reuses the same committed buffers.
         self._params = jax.device_put(apply_params, replicated)

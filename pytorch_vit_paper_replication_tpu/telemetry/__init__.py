@@ -76,7 +76,8 @@ serve_bench A/B; ``tools/trace_demo.py`` regenerates it.
 
 from .chrome_trace import (to_chrome_trace, validate_chrome_trace,
                            write_chrome_trace)
-from .flops import V5E_PEAK_TFLOPS, analytic_mfu, train_step_flops_per_image
+from .flops import (CHIP_PEAKS, analytic_mfu, peak_bf16_tflops,
+                    train_step_flops_per_image)
 from .profiling import (ProfileController, parse_profile_steps,
                         sample_device_memory)
 from .registry import (HELP_TEXT, INSTRUMENTS, TelemetryRegistry,
@@ -88,11 +89,11 @@ from .tracing import (TraceContext, Tracer, configure_tracer,
 from .watchdog import Watchdog, memory_report
 
 __all__ = [
-    "FrameSink", "HELP_TEXT", "INSTRUMENTS", "ProfileController",
-    "ROW_KEYS", "StepTelemetry", "TelemetryRegistry",
-    "TelemetryShipper", "TraceContext", "Tracer", "V5E_PEAK_TFLOPS",
-    "Watchdog", "analytic_mfu", "configure_tracer", "get_registry",
-    "get_tracer", "memory_report", "parse_profile_steps",
+    "CHIP_PEAKS", "FrameSink", "HELP_TEXT", "INSTRUMENTS",
+    "ProfileController", "ROW_KEYS", "StepTelemetry", "TelemetryRegistry",
+    "TelemetryShipper", "TraceContext", "Tracer", "Watchdog",
+    "analytic_mfu", "configure_tracer", "get_registry", "get_tracer",
+    "memory_report", "parse_profile_steps", "peak_bf16_tflops",
     "render_prometheus", "sample_device_memory", "start_metrics_http",
     "to_chrome_trace", "trace_sample", "train_step_flops_per_image",
     "validate_chrome_trace", "write_chrome_trace",

@@ -16,9 +16,21 @@ hardware FLOPs.
 
 from __future__ import annotations
 
-# bf16 dense peak of the deployment chip (TPU v5e datasheet) — the MFU
-# denominator everywhere in this repo.
-V5E_PEAK_TFLOPS = 197.0
+from typing import Optional
+
+# Published per-chip peaks, keyed by jax's ``device_kind`` — the ONE
+# table every utilization in this repo divides by. Source: Google Cloud
+# documentation, "TPU v5e". A kind that is not here has no peak: its
+# MFU is not reported, never computed against another chip's number.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"bf16_tflops": 197.0, "int8_tops": 393.0,
+                    "hbm_gb_per_s": 819.0, "hbm_gb": 16.0},
+}
+
+
+def peak_bf16_tflops(device_kind: str) -> Optional[float]:
+    """bf16 dense peak of one chip of this kind; None when unknown."""
+    return CHIP_PEAKS.get(device_kind, {}).get("bf16_tflops")
 
 
 def train_step_flops_per_image(cfg) -> float:
@@ -43,6 +55,7 @@ def train_step_flops_per_image(cfg) -> float:
 
 
 def analytic_mfu(images_per_sec_per_chip: float, flops_per_image: float,
-                 peak_tflops: float = V5E_PEAK_TFLOPS) -> float:
-    """Model-FLOPs utilization from a per-chip image rate."""
+                 peak_tflops: float) -> float:
+    """Model-FLOPs utilization from a per-chip image rate and that
+    chip's peak (:func:`peak_bf16_tflops`)."""
     return images_per_sec_per_chip * flops_per_image / 1e12 / peak_tflops

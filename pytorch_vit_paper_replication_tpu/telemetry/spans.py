@@ -43,7 +43,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
-from .flops import V5E_PEAK_TFLOPS, analytic_mfu
+from .flops import analytic_mfu
 from .registry import TelemetryRegistry, get_registry
 
 # Every key a telemetry JSONL row may carry beyond the declared
@@ -76,8 +76,13 @@ class StepTelemetry:
         timing (defaults to ``sample_every``); the engine asks via
         :meth:`should_block`.
       flops_per_image: analytic train-step FLOPs (``telemetry.flops``);
-        enables the ``tel_mfu`` gauge. None = gauge omitted (TinyVGG).
-      n_chips: MFU/per-chip denominator; default ``jax.device_count()``.
+        with ``peak_tflops`` enables the ``tel_mfu`` gauge. None = gauge
+        omitted (TinyVGG).
+      peak_tflops: one chip's peak (``flops.peak_bf16_tflops`` of the
+        mesh's ``device_kind``); None — a kind with no published peak,
+        the CPU included — omits the gauge too.
+      n_chips: MFU/per-chip denominator: the size of the mesh the step
+        runs on (not the devices the process can see).
       watchdog: optional :class:`.watchdog.Watchdog`; every recorded
         step and span beats it (progress of ANY kind resets the stall
         deadline — a long eval pass is not a hang).
@@ -97,8 +102,8 @@ class StepTelemetry:
                  sample_every: int = 32,
                  block_every: Optional[int] = None,
                  flops_per_image: Optional[float] = None,
-                 peak_tflops: float = V5E_PEAK_TFLOPS,
-                 n_chips: Optional[int] = None,
+                 peak_tflops: Optional[float] = None,
+                 n_chips: int = 1,
                  watchdog=None,
                  profiler=None,
                  sample_memory: bool = True):
@@ -115,12 +120,6 @@ class StepTelemetry:
         if jsonl_path is not None:
             from ..metrics import MetricsLogger
             self._logger = MetricsLogger(jsonl_path)
-        if n_chips is None:
-            try:
-                import jax
-                n_chips = jax.device_count()
-            except Exception:  # noqa: BLE001 — registry-only use, no jax
-                n_chips = 1
         self.n_chips = max(1, int(n_chips))
         self._total_steps = 0
         # Live-throughput window: images/time since the last sampled row.
@@ -213,7 +212,7 @@ class StepTelemetry:
                 # this is the honest per-step figure (window wall /
                 # steps) dashboards should plot.
                 row["tel_step_amortized_s"] = round(self._last_amortized, 6)
-            if self.flops_per_image:
+            if self.flops_per_image and self.peak_tflops:
                 mfu = analytic_mfu(ips / self.n_chips,
                                    self.flops_per_image, self.peak_tflops)
                 reg.gauge("tel_mfu", round(mfu, 4))
@@ -292,7 +291,7 @@ class StepTelemetry:
             "tel_ckpt_s_sum": round(self._ep_ckpt, 3),
             "tel_eval_s_sum": round(self._ep_eval, 3),
         }
-        if self.flops_per_image:
+        if self.flops_per_image and self.peak_tflops:
             summary["tel_mfu"] = round(
                 analytic_mfu(ips / self.n_chips, self.flops_per_image,
                              self.peak_tflops), 4)

@@ -10,10 +10,7 @@ import pytest
 # main explicitly.
 from pytorch_vit_paper_replication_tpu.train import main as train_main
 
-from conftest import requires_shard_map
 
-
-@requires_shard_map
 def test_cli_synthetic_seq_parallel(devices, tmp_path):
     """--mesh-seq 2: the whole CLI path trains with ring attention (gap
     pooling for an even token count) on a data=4 x seq=2 mesh."""

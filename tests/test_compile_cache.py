@@ -1,6 +1,7 @@
-"""Cold-start subsystem tests (ISSUE 4): persistent-compile-cache
-config/salt/fingerprint, cache-hit INSTRUMENTATION across fresh
-subprocesses (no wall clocks), warmup-manifest contracts, AOT warmup
+"""Cold-start subsystem tests (ISSUE 4, placement rule ISSUE 21): where
+the persistent compile cache lives (jax's variable, else the flag, else
+the checkout), cache-hit INSTRUMENTATION across fresh subprocesses (no
+wall clocks), config fingerprints, warmup-manifest contracts, AOT warmup
 observability, the cached-restart bit-identity extension of the serve
 round trip, the coldstart bench harness, and the bench compact-gates
 line-length bound."""
@@ -51,35 +52,77 @@ def test_config_fingerprint_sensitive_to_config(tiny_config):
         tiny_config.replace(num_layers=3))
 
 
-def test_cache_salt_versioned_and_fingerprinted():
-    from pytorch_vit_paper_replication_tpu import __version__
-
-    s1 = compile_cache.cache_salt("abcdef0123456789")
-    s2 = compile_cache.cache_salt("ffff")
-    assert s1.startswith(f"v{__version__}-") and s1 != s2
-    assert compile_cache.cache_salt("") == f"v{__version__}-any"
-
-
-def test_configure_nests_under_salt(tmp_path):
-    fp = compile_cache.config_fingerprint(model="x")
-    resolved = compile_cache.configure(str(tmp_path / "cc"), fingerprint=fp)
-    assert resolved == tmp_path / "cc" / compile_cache.cache_salt(fp)
-    assert resolved.is_dir()
-    # a different fingerprint lands in a DIFFERENT (empty) subdir: stale
-    # entries can never be consulted by a changed config
-    other = compile_cache.configure(
-        str(tmp_path / "cc"),
-        fingerprint=compile_cache.config_fingerprint(model="y"))
-    assert other != resolved
+# ------------------------------------------------- where the cache lives
+_WHERE = """
+import json, sys
+from pytorch_vit_paper_replication_tpu import compile_cache as C
+returned = C.configure(sys.argv[1] if len(sys.argv) > 1 else None)
+import jax
+print(json.dumps({"jax": jax.config.jax_compilation_cache_dir,
+                  "stats": C.STATS.cache_dir, "returned": str(returned)}))
+"""
 
 
-def test_resolve_cache_dir_env_fallback(monkeypatch):
-    monkeypatch.delenv(compile_cache.ENV_CACHE_DIR, raising=False)
-    assert compile_cache.resolve_cache_dir(None) is None
-    assert compile_cache.resolve_cache_dir("/x") == "/x"
-    monkeypatch.setenv(compile_cache.ENV_CACHE_DIR, "/from_env")
-    assert compile_cache.resolve_cache_dir(None) == "/from_env"
-    assert compile_cache.resolve_cache_dir("/cli_wins") == "/cli_wins"
+def _where(tmp_path, *flag, env_dir=None) -> dict:
+    """configure() in a fresh process started from an unrelated cwd."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO))
+    env.pop("XLA_FLAGS", None)
+    env.pop(compile_cache.ENV_CACHE_DIR, None)
+    if env_dir is not None:
+        env[compile_cache.ENV_CACHE_DIR] = str(env_dir)
+    out = subprocess.run([sys.executable, "-c", _WHERE, *flag],
+                         capture_output=True, text=True, timeout=120,
+                         env=env, cwd=tmp_path)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_jax_variable_places_the_cache_whatever_the_flag(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is exactly there and no
+    code sets another directory — flag or no flag."""
+    outside = tmp_path / "placed_from_outside"
+    for flag in ([], [str(tmp_path / "from_flag")]):
+        got = _where(tmp_path, *flag, env_dir=outside)
+        assert got == {"jax": str(outside), "stats": str(outside),
+                       "returned": str(outside)}
+    assert not (tmp_path / "from_flag").exists()
+
+
+def test_default_cache_is_the_checkout_from_any_cwd(tmp_path):
+    """Variable unset, no flag: <checkout>/.jax_compile_cache, anchored
+    to the package and not the cwd — every component of the path is the
+    checkout's own, none a temp name, a pid or the time."""
+    got = _where(tmp_path)
+    want = REPO / ".jax_compile_cache"
+    assert got == {"jax": str(want), "stats": str(want),
+                   "returned": str(want)}
+    assert compile_cache.DEFAULT_CACHE_DIR == want
+    assert Path(got["jax"]).parts == REPO.parts + (".jax_compile_cache",)
+    assert not (tmp_path / ".jax_compile_cache").exists()
+
+
+def test_flag_places_the_cache_when_the_variable_is_unset(tmp_path):
+    got = _where(tmp_path, str(tmp_path / "cc"))
+    assert got["jax"] == got["stats"] == str(tmp_path / "cc")
+    assert (tmp_path / "cc").is_dir()
+    assert not list((tmp_path / "cc").iterdir())  # no salted subdir
+
+
+def test_only_configure_sets_a_cache_directory():
+    """train/serve/predict/probe/batch_infer (and everything else) go
+    through configure(): no other source line touches jax's setting."""
+    offenders = [
+        str(f.relative_to(REPO))
+        for root in ("pytorch_vit_paper_replication_tpu", "tools")
+        for f in (REPO / root).rglob("*.py")
+        if "jax_compilation_cache_dir" in f.read_text()
+        and f.name != "compile_cache.py"]
+    offenders += [f for f in ("bench.py", "chip_smoke.py")
+                  if "jax_compilation_cache_dir" in (REPO / f).read_text()]
+    assert offenders == []
+    assert "VIT_COMPILE_CACHE_DIR" not in (
+        REPO / "pytorch_vit_paper_replication_tpu" / "compile_cache.py"
+    ).read_text()
 
 
 def test_seconds_since_process_start_positive_and_monotonic():
@@ -97,7 +140,7 @@ def test_warn_if_uncached_fires_once_on_tpu(monkeypatch):
     old = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", None)
     try:
-        with pytest.warns(UserWarning, match="compile-cache-dir"):
+        with pytest.warns(UserWarning, match="JAX_COMPILATION_CACHE_DIR"):
             compile_cache.warn_if_uncached("test")
         # second call: silent (warn ONCE per process)
         compile_cache.warn_if_uncached("test")
@@ -109,7 +152,7 @@ def test_no_warn_on_cpu_backend(monkeypatch, recwarn):
     monkeypatch.setattr(compile_cache, "_warned_uncached", False)
     compile_cache.warn_if_uncached("test")  # backend here IS cpu
     assert not [w for w in recwarn.list
-                if "compile-cache-dir" in str(w.message)]
+                if "JAX_COMPILATION_CACHE_DIR" in str(w.message)]
 
 
 # --------------------------------------- cross-process hit instrumentation
@@ -118,39 +161,40 @@ import json, sys
 sys.path.insert(0, {repo!r})
 import jax, jax.numpy as jnp
 from pytorch_vit_paper_replication_tpu import compile_cache as C
-C.configure(sys.argv[1], fingerprint=sys.argv[2])
+C.configure(sys.argv[1])
 f = jax.jit(lambda x: (x @ x.T).sum())
 f(jnp.ones((128, 128))).block_until_ready()
 print(json.dumps(C.STATS.snapshot()))
 """
 
 
-def _run_child(script_path, cache_dir, fingerprint) -> dict:
+def _run_child(script_path, cache_dir) -> dict:
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
-        [sys.executable, str(script_path), str(cache_dir), fingerprint],
+        [sys.executable, str(script_path), str(cache_dir)],
         capture_output=True, text=True, timeout=120, env=env)
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def test_second_process_hits_cache_and_salt_invalidates(tmp_path):
+def test_second_process_hits_cache(tmp_path):
     """The satellite's contract, asserted via instrumentation (hit/miss
-    counters), not wall clock: an identical fingerprint in a FRESH
-    process hits every entry; a changed salt starts cold."""
+    counters), not wall clock: the same program in a FRESH process hits
+    every entry; another directory starts cold."""
     script = tmp_path / "child.py"
     script.write_text(_CHILD.format(repo=str(REPO)))
-    cold = _run_child(script, tmp_path / "cc", "fp_a")
+    cold = _run_child(script, tmp_path / "cc")
     assert cold["hits"] == 0 and cold["requests"] >= 1
-    warm = _run_child(script, tmp_path / "cc", "fp_a")
+    assert cold["cache_dir"] == str(tmp_path / "cc")
+    warm = _run_child(script, tmp_path / "cc")
     assert warm["requests"] >= 1
     assert warm["hits"] == warm["requests"] and warm["misses"] == 0
     # saved = stored compile time - retrieval time: can be slightly
     # NEGATIVE for sub-ms modules, so only assert it was recorded.
     assert isinstance(warm["compile_time_saved_s"], float)
-    salted = _run_child(script, tmp_path / "cc", "fp_B")
-    assert salted["hits"] == 0  # stale entries not resurrected
+    elsewhere = _run_child(script, tmp_path / "cc2")
+    assert elsewhere["hits"] == 0
 
 
 # ------------------------------------------------------ warmup manifest
@@ -257,8 +301,7 @@ def test_cached_restart_engine_bit_identical_and_observable(
     from pytorch_vit_paper_replication_tpu.serve import InferenceEngine
 
     ckpt, model, params = tiny_ckpt
-    fp = compile_cache.config_fingerprint(model.config, image_size=32)
-    compile_cache.configure(str(tmp_path / "cache"), fingerprint=fp)
+    compile_cache.configure(str(tmp_path / "cache"))
     assert load_warmup_manifest(ckpt) is None
     with InferenceEngine.from_checkpoint(
             ckpt, preset="ViT-Ti/16", num_classes=3, buckets=(1, 2),
@@ -530,6 +573,5 @@ def test_train_cli_logs_time_to_first_step(tmp_path):
     ttfs = [r for r in records if "time_to_first_step" in r]
     assert len(ttfs) == 1 and ttfs[0]["epoch"] == 1
     assert ttfs[0]["time_to_first_step"] > 0
-    # the salted cache dir exists and received entries
-    salted = list((tmp_path / "cache").iterdir())
-    assert len(salted) == 1
+    # the cache dir named by the flag received the entries, unsalted
+    assert list((tmp_path / "cache").glob("*-cache"))

@@ -1,8 +1,8 @@
 """Elastic preemption-tolerant training (ISSUE 11, parallel/elastic.py).
 
-Covers the pieces that don't need a multi-process jax cluster (which
-jax 0.4.x cannot run on CPU — those paths are exercised by the host
-backend, which IS multi-process at the gradient level):
+Covers the pieces that don't need a multi-process jax cluster (those
+paths are exercised by the host backend, which IS multi-process at the
+gradient level):
 
 * resharded restore — a checkpoint written at dp=4 restored onto a
   dp=2 virtual-device mesh, bit-faithful params and IDENTICAL next-step
@@ -503,9 +503,12 @@ def test_worker_cache_dir_parsing():
     assert worker_cache_dir(["--compile-cache-dir", "/a"], {}) == \
         Path("/a")
     assert worker_cache_dir(["--compile-cache-dir=/b"], {}) == Path("/b")
-    assert worker_cache_dir([], {"VIT_COMPILE_CACHE_DIR": "/c"}) == \
+    # compile_cache.configure's rule: jax's variable beats the flag,
+    # and with neither the cache is the in-checkout default.
+    assert worker_cache_dir(["--compile-cache-dir", "/a"],
+                            {"JAX_COMPILATION_CACHE_DIR": "/c"}) == \
         Path("/c")
-    assert worker_cache_dir([], {}) is None
+    assert worker_cache_dir([], {}) == REPO / ".jax_compile_cache"
 
 
 def test_atomic_cache_put_never_leaves_torn_entry(tmp_path,
@@ -697,9 +700,9 @@ def test_elastic_e2e_kill_mid_epoch_matches_reference(tmp_path):
 @pytest.mark.slow
 def test_restore_cache_hit_roundtrips_survive(tmp_path):
     """Regression for the recovery-path crash the fault-injection runs
-    surfaced: on jax 0.4.x CPU, a DESERIALIZED persistent-cache
-    executable with donated inputs heap-corrupts when run against
-    orbax-restored arrays (SIGSEGV ~1 step after resume, every
+    surfaced: on the CPU backend a DESERIALIZED persistent-cache
+    executable with donated inputs was seen to heap-corrupt when run
+    against orbax-restored arrays (SIGSEGV ~1 step after resume, every
     respawned generation). The host-collective apply jit is
     donation-free for exactly this reason — three consecutive
     save -> restore -> cache-HIT -> train round-trips must survive."""
@@ -716,7 +719,7 @@ from pytorch_vit_paper_replication_tpu.checkpoint import Checkpointer
 from pytorch_vit_paper_replication_tpu.parallel.elastic import (
     make_host_collective_train_step)
 
-configure({str(tmp_path / "cache")!r}, fingerprint="rt")
+configure({str(tmp_path / "cache")!r})
 cfg = PRESETS["ViT-Ti/16"](num_classes=10, image_size=32,
                            dtype="float32", attn_dropout=0.0,
                            mlp_dropout=0.0, embedding_dropout=0.0)

@@ -249,6 +249,34 @@ def test_router_routes_and_answers_stats_metrics(tmp_path):
         assert "vit_replica_up_r0 1" in metrics
 
 
+def test_replica_with_failed_warmup_is_not_up(tmp_path, monkeypatch):
+    """A serve replica whose rung compile failed keeps answering ::stats
+    from the jit path; its snapshot carries warmup.error, and the health
+    round takes it out of the fleet at once instead of calling a server
+    that cannot run its program healthy."""
+    manager, _, _ = _mk_fleet(tmp_path)
+    with manager:
+        manager.start()
+        assert manager.wait_ready(20.0)
+        real_poll = manager._poll_stats
+        sick = manager.address_of("r0")
+
+        def poll(addr):
+            snap = real_poll(addr)
+            if snap is not None and addr == sick:
+                snap["warmup"] = {"done": False,
+                                  "error": "XlaRuntimeError: boom"}
+            return snap
+
+        monkeypatch.setattr(manager, "_poll_stats", poll)
+        # The health thread may be mid-round on a snapshot taken before
+        # the patch: two rounds later every verdict is the patched one.
+        time.sleep(3 * manager.health_interval_s)
+        manager.poll_once()
+        assert not manager.view("r0").up
+        assert manager.view("r1").up
+
+
 def test_router_rung_affinity_steers_to_warm_replica(tmp_path):
     manager, router, _ = _mk_fleet(
         tmp_path, warm_by_rid={"r0": "1", "r1": "8"})

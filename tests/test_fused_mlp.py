@@ -19,7 +19,6 @@ from pytorch_vit_paper_replication_tpu.ops.dropout import (
 from pytorch_vit_paper_replication_tpu.ops.fused_mlp import (
     fused_ln_mlp_residual, fused_mlp)
 
-from conftest import requires_shard_map
 
 D, F = 64, 256
 
@@ -203,7 +202,6 @@ def test_mlp_impl_grad_parity(rng):
                                    err_msg=str(ka))
 
 
-@requires_shard_map
 def test_mlp_impl_manual_tp_core_mode(rng):
     """Under a tp_axis (shard_map manual TP) the fused path uses the core
     kernel with the psum outside — forward must still match xla."""
@@ -313,3 +311,49 @@ def test_fused_under_gspmd_mesh_train_step(devices, rng):
     loss_x, head_x = run("xla")
     np.testing.assert_allclose(loss_f, loss_x, rtol=1e-4)
     np.testing.assert_allclose(head_f, head_x, rtol=1e-3)
+
+
+@pytest.mark.parametrize("dp,tp", [(4, 1), (2, 2)])
+def test_kernels_on_a_mesh_draw_the_unsharded_dropout_masks(devices, rng,
+                                                            dp, tp):
+    """Traced under a mesh the kernels run per shard (XLA cannot split a
+    Mosaic call). Each shard restarts its grid at row 0 with the same
+    replicated seed, so its global row/column offset must enter the mask
+    hash: shards then draw different masks — in fact exactly the masks
+    the unsharded call draws, so values AND grads agree with dropout ON
+    however the batch (dp) and the hidden dim (tp) are split."""
+    from pytorch_vit_paper_replication_tpu.configs import MeshConfig
+    from pytorch_vit_paper_replication_tpu.ops import on_mesh
+    from pytorch_vit_paper_replication_tpu.parallel.mesh import make_mesh
+
+    p = _params(rng)
+    p["x"] = jax.random.normal(rng, (8, 6, D), jnp.float32)
+    kw = dict(dropout_rate=0.25, dropout_rng=jax.random.key(7),
+              deterministic=False)
+
+    def full(x, gamma, beta, w1, b1, w2, b2):
+        return (fused_ln_mlp_residual(x, gamma, beta, w1, b1, w2, b2,
+                                      **kw) ** 2).sum()
+
+    def core(x, w1, b1, w2, b2):
+        return (fused_mlp(x, w1, b1, w2, b2, **kw) ** 2).sum()
+
+    full_args = [p[k] for k in ("x", "gamma", "beta", "w1", "b1", "w2", "b2")]
+    core_args = [p[k] for k in ("x", "w1", "b1", "w2", "b2")]
+    mesh = make_mesh(MeshConfig(data=dp, model=tp), devices[:dp * tp])
+    for fn, args in ((full, full_args), (core, core_args)):
+        grad = jax.value_and_grad(fn, argnums=tuple(range(len(args))))
+        want = grad(*args)
+        with on_mesh(mesh):
+            got = jax.jit(grad)(*args)
+        for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            # f32 sums over shards in another order; a different mask
+            # would move these by O(1).
+            np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-3)
+
+    # And directly: the same rows on every shard still come out different.
+    same = jnp.broadcast_to(p["x"][:1], p["x"].shape)
+    with on_mesh(mesh):
+        out = jax.jit(lambda x: fused_mlp(x, *core_args[1:], **kw))(same)
+    per_shard = np.asarray(out).reshape(dp, -1)
+    assert not np.allclose(per_shard[0], per_shard[1])

@@ -15,11 +15,6 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from conftest import requires_multiprocess_cpu
-
-# jax 0.4.x: "Multiprocess computations aren't implemented on the CPU
-# backend" — a known environment gap, reported as SKIPPED, not FAILED.
-pytestmark = requires_multiprocess_cpu
 
 WORKER = Path(__file__).with_name("multihost_worker.py")
 

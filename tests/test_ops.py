@@ -419,3 +419,33 @@ def test_flash_mask_key_broadcast_dim():
     rows = np.asarray(mask)[:, 0, :, 0]  # [B, Tq] True = row attends
     np.testing.assert_allclose(out[rows], ref[rows], **TOL)
     np.testing.assert_array_equal(out[~rows], 0.0)
+
+
+def test_flash_on_a_mesh_draws_the_unsharded_dropout_masks(devices):
+    """Under a dp x tp mesh the flash kernel runs per shard of batch and
+    heads; its mask hash is keyed on the GLOBAL batch·head index (the
+    shard's offsets ride the scalar prefetch), so no two shards share a
+    mask and the result — values and grads, key-padding mask included —
+    is the unsharded call's."""
+    from pytorch_vit_paper_replication_tpu.configs import MeshConfig
+    from pytorch_vit_paper_replication_tpu.ops import on_mesh
+    from pytorch_vit_paper_replication_tpu.parallel.mesh import make_mesh
+
+    ks = jax.random.split(jax.random.key(0), 4)
+    q, k, v = (jax.random.normal(kk, (4, 24, 4, 32)) for kk in ks[:3])
+    mask = jax.random.bernoulli(ks[3], 0.8, (4, 1, 1, 24))
+
+    def loss(q, k, v, mask):
+        return (flash_attention(
+            q, k, v, mask=mask, dropout_rate=0.25,
+            dropout_rng=jax.random.key(3), deterministic=False,
+            block_q=8, block_k=8) ** 2).sum()
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    for m, dp, tp in ((None, 4, 1), (mask, 2, 2)):
+        want = grad(q, k, v, m)
+        mesh = make_mesh(MeshConfig(data=dp, model=tp), devices[:dp * tp])
+        with on_mesh(mesh):
+            got = jax.jit(grad)(q, k, v, m)
+        for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            np.testing.assert_allclose(g, w, rtol=2e-4, atol=5e-3)

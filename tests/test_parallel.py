@@ -15,8 +15,6 @@ from pytorch_vit_paper_replication_tpu.data import synthetic_batch
 from pytorch_vit_paper_replication_tpu.models import ViT
 from pytorch_vit_paper_replication_tpu.optim import make_optimizer
 
-from conftest import requires_shard_map
-
 
 def _make_state(cfg, total_steps=10, seed=0):
     model = ViT(cfg)
@@ -104,7 +102,6 @@ def test_data_parallel_matches_single_device(tiny_config, devices):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5)
 
 
-@requires_shard_map
 def test_tensor_parallel_matches_single_device(tiny_config, devices):
     """dp=4 x tp=2: same numerics, params physically sharded over 'model'."""
     batch = jax.tree.map(jnp.asarray, synthetic_batch(
@@ -132,7 +129,6 @@ def test_tensor_parallel_matches_single_device(tiny_config, devices):
     np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5)
 
 
-@requires_shard_map
 def test_ring_attention_exact(devices):
     """Ring attention over the 'seq' axis equals full attention."""
     mesh = parallel.make_mesh(MeshConfig(data=1, model=1, seq=8))
@@ -146,7 +142,6 @@ def test_ring_attention_exact(devices):
                                rtol=2e-2, atol=2e-2)
 
 
-@requires_shard_map
 def test_ring_attention_with_dp(devices):
     """SP composes with DP on a 2x1x4 mesh."""
     mesh = parallel.make_mesh(MeshConfig(data=2, model=1, seq=4))
@@ -181,7 +176,6 @@ def test_ragged_eval_batch_padded_dp(tiny_config, devices):
     np.testing.assert_allclose(float(m1["correct"]), float(m8["correct"]))
 
 
-@requires_shard_map
 def test_ring_attention_gradient(devices):
     """ppermute/scan are differentiable; the ring backward must equal the
     full-attention backward (VERDICT r1: ring had no gradient coverage)."""
@@ -248,10 +242,9 @@ def test_fused_mlp_train_step_on_dp_tp_mesh(tiny_config, devices):
     assert int(state_f.step) == 1
 
 
-@requires_shard_map
 def test_seq_parallel_train_step_matches_single_device(devices):
     """A full ViT train step on a data=2 x seq=4 mesh routes attention
-    through the ring (ops.attention.sequence_parallel) and produces the
+    through the ring (ops.partition.on_mesh) and produces the
     same loss and parameter update as one device."""
     cfg = _gap_config()
     batch = jax.tree.map(jnp.asarray, synthetic_batch(
@@ -275,7 +268,6 @@ def test_seq_parallel_train_step_matches_single_device(devices):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5)
 
 
-@requires_shard_map
 def test_seq_parallel_composes_with_tp(devices):
     """dp=2 x tp=2 x sp=2: heads shard over 'model' inside the ring
     shard_map, tokens over 'seq' — one step, same numerics."""
@@ -294,7 +286,6 @@ def test_seq_parallel_composes_with_tp(devices):
         float(m1["loss_sum"]), float(m3["loss_sum"]), rtol=1e-4)
 
 
-@requires_shard_map
 def test_seq_parallel_eval_step(devices):
     """Eval also routes through the ring and stays example-exact."""
     cfg = _gap_config()
@@ -370,7 +361,6 @@ def _recover_ring_mask(mesh, b, h, t, rate, rng):
     return weights > 0.0, weights
 
 
-@requires_shard_map
 def test_ring_dropout_mask_statistics(devices):
     """In-ring dropout drops at the quantized rate with exact unbiased
     survivor rescale, and masks differ across (example, head)."""
@@ -385,7 +375,6 @@ def test_ring_dropout_mask_statistics(devices):
     assert (mask[0, 0] != mask[1, 0]).mean() > 0.1   # examples differ
 
 
-@requires_shard_map
 def test_ring_dropout_matches_masked_reference_and_grads(devices):
     """EXACT fwd+bwd check: recover the ring's own mask (a pure function
     of (seed, example·head, global row/col) — independent of q/k/v), build
@@ -427,19 +416,19 @@ def test_ring_dropout_matches_masked_reference_and_grads(devices):
                                    atol=2e-4, err_msg=f"d{name}")
 
 
-@requires_shard_map
 def test_sequence_parallel_dispatch_runs_dropout_in_ring(devices):
-    """attn dropout no longer forces the sequence_parallel fallback: under
+    """attn dropout no longer forces the sequence-parallel fallback: under
     the context the call must go through the ring (different rngs give
     different outputs; deterministic matches the no-dropout ring)."""
     from pytorch_vit_paper_replication_tpu.ops.attention import (
-        dot_product_attention, sequence_parallel)
+        dot_product_attention)
+    from pytorch_vit_paper_replication_tpu.ops import on_mesh
 
     mesh = parallel.make_mesh(MeshConfig(data=2, model=1, seq=4))
     b, t, h, d = 2, 32, 2, 16
     ks = jax.random.split(jax.random.key(2), 3)
     q, k, v = (jax.random.normal(kk, (b, t, h, d)) for kk in ks)
-    with sequence_parallel(mesh):
+    with on_mesh(mesh):
         a1 = dot_product_attention(q, k, v, dropout_rate=0.3,
                                    dropout_rng=jax.random.key(1),
                                    deterministic=False)
@@ -454,7 +443,6 @@ def test_sequence_parallel_dispatch_runs_dropout_in_ring(devices):
                                atol=2e-2)
 
 
-@requires_shard_map
 def test_ring_and_flash_dropout_masks_identical(devices):
     """The positional-hash mask is THE same function in both accelerated
     paths (ops.dropout.positional_keep_u8): for equal (seed, example·head,

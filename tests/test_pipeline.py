@@ -16,7 +16,6 @@ from pytorch_vit_paper_replication_tpu.data import synthetic_batch
 from pytorch_vit_paper_replication_tpu.models import ViT
 from pytorch_vit_paper_replication_tpu.optim import make_optimizer
 
-from conftest import requires_shard_map
 
 # Dropout off: the exact-parity tests compare against the standard model,
 # and pipeline dropout draws DIFFERENT (equally valid) masks by design —
@@ -47,7 +46,6 @@ def test_stack_unstack_roundtrip():
                                       np.asarray(fb[path]))
 
 
-@requires_shard_map
 def test_pipeline_forward_matches_standard(devices):
     """dp=2 x pipe=4, M=2 microbatches: deterministic pipelined logits
     equal the per-layer model's (same modules, same params, staged)."""
@@ -63,7 +61,6 @@ def test_pipeline_forward_matches_standard(devices):
                                rtol=1e-4, atol=1e-5)
 
 
-@requires_shard_map
 def test_pipeline_train_step_matches_standard(devices):
     """THREE full optimizer steps through the GPipe schedule (grads flow
     through scan + ppermute + psum) equal the single-device trajectory —
@@ -139,7 +136,6 @@ def test_pipeline_decay_mask_matches_standard_rule():
         assert bool(np.asarray(a).all()) == bool(b), jax.tree_util.keystr(pa)
 
 
-@requires_shard_map
 def test_pipeline_dropout_trains_and_varies(devices):
     """Dropout through the pipeline: masks differ across steps (rng folds
     step), loss stays finite and decreases over a few steps of overfitting
@@ -184,7 +180,6 @@ def test_validate_pipeline_rejects_bad_configs(devices):
         parallel.validate_pipeline(CFG, mesh_tp4, 2, 8)
 
 
-@requires_shard_map
 def test_pipeline_with_tensor_parallel_matches_standard(devices):
     """dp=2 × tp=2 × pp=2 (all three axes at once): manual Megatron psums
     inside the GPipe stages. Biases are perturbed PER-CHANNEL — a uniform
@@ -238,7 +233,6 @@ def test_pipeline_with_tensor_parallel_matches_standard(devices):
             atol=atol, err_msg=key)
 
 
-@requires_shard_map
 def test_cli_pipeline_end_to_end(devices, tmp_path):
     """--mesh-pipe 4 through train.main, incl. a RAGGED eval set (9
     images, batch 8: the final batch must pad to dp*microbatches, not
@@ -273,7 +267,6 @@ def test_cli_pipeline_end_to_end(devices, tmp_path):
     assert parallel.pipeline.BLOCKS_KEY not in exported
 
 
-@requires_shard_map
 def test_pipeline_composes_with_grad_accum(devices):
     """--grad-accum through the pipeline: K micro-steps through the GPipe
     schedule average into one optimizer update, equal to the standard
@@ -313,7 +306,6 @@ def test_pipeline_composes_with_grad_accum(devices):
             atol=atol, err_msg=key)
 
 
-@requires_shard_map
 def test_pipeline_composes_with_nan_guard(devices):
     """nan_guard through the pipeline: a poisoned batch is skipped (no
     param change, skipped=1), a clean batch still applies."""
@@ -342,7 +334,6 @@ def test_pipeline_composes_with_nan_guard(devices):
     assert float(m["skipped"]) == 0.0
 
 
-@requires_shard_map
 def test_cli_pipeline_resume_and_eval_only(devices, tmp_path):
     """Pipeline runs share the generic checkpoint machinery: a pipeline
     training run resumes from its (pipeline-layout) checkpoint, and

@@ -243,7 +243,7 @@ def test_batcher_drain_rejects_flushes_and_reports():
         mb.submit(np.zeros(2, np.float32))
     assert exc.value.retry_after_s > 0
     assert isinstance(exc.value, QueueFullError)  # one backpressure
-    #                                               taxonomy fleet-wide
+    #                                               set of classes fleet-wide
     assert mb.stats.snapshot()["counters"]["rejected_draining"] == 1
     # Draining gates ADMISSION, not dispatch: the queue still flushes.
     assert mb.run_once() == 3

@@ -12,10 +12,6 @@ import pytest
 from pytorch_vit_paper_replication_tpu import parallel
 from pytorch_vit_paper_replication_tpu.configs import MeshConfig
 
-from conftest import requires_shard_map
-
-pytestmark = requires_shard_map
-
 
 def _qkv(seed, b, t, h, d):
     ks = jax.random.split(jax.random.key(seed), 3)
@@ -106,24 +102,25 @@ def test_ulysses_rejects_indivisible_heads(devices):
 
 
 def test_dispatch_ulysses_and_head_fallback(devices):
-    """sequence_parallel(sp_impl='ulysses') routes through the all-to-all
+    """on_mesh(sp_impl='ulysses') routes through the all-to-all
     path when heads divide, and warns+falls back to the gathered XLA path
     when they don't — never a crash mid-model."""
     import warnings
 
     from pytorch_vit_paper_replication_tpu.ops.attention import (
-        dot_product_attention, sequence_parallel)
+        dot_product_attention)
+    from pytorch_vit_paper_replication_tpu.ops import on_mesh
 
     mesh = parallel.make_mesh(MeshConfig(data=2, model=1, seq=4))
     q, k, v = _qkv(5, 2, 32, 4, 16)
     ref = jax.nn.dot_product_attention(q, k, v)
-    with sequence_parallel(mesh, sp_impl="ulysses"):
+    with on_mesh(mesh, sp_impl="ulysses"):
         out = dot_product_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-2, atol=2e-2)
 
     qs, ks_, vs = _qkv(6, 2, 32, 2, 16)  # h=2 not divisible by 4
-    with sequence_parallel(mesh, sp_impl="ulysses"):
+    with on_mesh(mesh, sp_impl="ulysses"):
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
             out2 = dot_product_attention(qs, ks_, vs)

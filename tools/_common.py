@@ -24,12 +24,18 @@ def cpu_child_env() -> dict:
     * the parent test harness's 8-virtual-device ``XLA_FLAGS`` is
       dropped — it slows children ~8x and measures a topology no
       deployment restarts into,
+    * ``JAX_COMPILATION_CACHE_DIR`` is dropped — a cache placed from
+      outside is for the chip's programs; the harnesses hand their CPU
+      children a ``--compile-cache-dir`` of their own (cold/warm A/Bs
+      need one nobody else has warmed), which the variable would
+      override,
     * the repo root rides ``PYTHONPATH`` so children import the
       package without an install.
     """
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env["PYTHONPATH"] = (str(REPO) + os.pathsep + env["PYTHONPATH"]
                          if env.get("PYTHONPATH") else str(REPO))
     return env

@@ -423,7 +423,7 @@ def main(argv=None) -> dict:
                    help="run the committed-evidence kill+resume proof "
                         "into --out instead of a real job")
     from pytorch_vit_paper_replication_tpu.compile_cache import (
-        add_cache_cli, config_fingerprint, configure)
+        add_cache_cli, configure)
     add_cache_cli(p)
     args = p.parse_args(argv)
 
@@ -449,16 +449,7 @@ def main(argv=None) -> dict:
 
     if not args.pack or not args.checkpoint:
         raise SystemExit("PACK_DIR and --checkpoint are required")
-    # Before the first jit: the salt uses the RESOLVED image size
-    # (transform.json over the flag) — same discipline as predict.py.
-    from pytorch_vit_paper_replication_tpu.predictions import (
-        resolve_transform_spec)
-    configure(args.compile_cache_dir,
-              fingerprint=config_fingerprint(
-                  preset=args.preset, head=args.head,
-                  image_size=resolve_transform_spec(
-                      args.checkpoint,
-                      image_size=args.image_size)["image_size"]))
+    configure(args.compile_cache_dir)  # before the first jit
     return run_job(args)
 
 
