@@ -24,6 +24,13 @@ wiring it:
   assertable from instrumentation instead of wall clocks, and surfaced
   through the train run's :class:`..metrics.MetricsLogger` JSONL
   (first-epoch line) and the serve ``::stats`` line protocol.
+  Since PR 24 it also keeps what each program's first call cost by
+  stage — ``trace`` (Python -> jaxpr), ``lower`` (jaxpr -> MLIR module),
+  ``backend`` (compile on a miss; read + deserialise on a hit) and
+  ``cache_read`` (the read alone, part of ``backend``) — under the
+  program's ``fun_name`` and on the process's clock, so that
+  ``time_to_first_step`` comes with its split
+  (:meth:`CacheStats.stage_seconds`, ``snapshot()["programs"]``).
 * :func:`seconds_since_process_start` — the denominator for the
   ``time_to_first_step`` / ``time_to_first_batch`` run-log fields
   (honest restart latency includes interpreter + import + backend init,
@@ -39,6 +46,7 @@ subprocesses; ``runs/coldstart_r8/`` carries the committed numbers and
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import json
@@ -62,6 +70,23 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parent.parent / \
 _EVENT_REQUESTS = "/jax/compilation_cache/compile_requests_use_cache"
 _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _EVENT_SAVED_SECS = "/jax/compilation_cache/compile_time_saved_sec"
+# Duration events of a program's first call (jax/_src/dispatch.py,
+# pxla.py, compiler.py), event -> (stage, registry counter). The first
+# three carry the program's ``fun_name``; the cache read does not, and
+# is emitted just before the ``backend`` event of the program it read.
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("trace", "compile_trace_seconds_total"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("lower", "compile_lower_seconds_total"),
+    "/jax/core/compile/backend_compile_duration":
+        ("backend", "compile_backend_seconds_total"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("cache_read", "compile_cache_read_seconds_total"),
+}
+STAGE_NAMES = tuple(stage for stage, _ in _STAGES.values())
+# Bounds: a serve process compiles for as long as it lives.
+MAX_STAGE_EVENTS = 8192
 
 _IMPORT_WALL_TIME = time.time()
 
@@ -105,6 +130,10 @@ class CacheStats:
         self.hits = 0
         self.saved_secs = 0.0
         self.cache_dir: Optional[str] = None
+        # (stage, fun_name, seconds, end: seconds since process start)
+        self._events: collections.deque = collections.deque(
+            maxlen=MAX_STAGE_EVENTS)
+        self._unnamed_read = threading.local()
 
     @property
     def misses(self) -> int:
@@ -135,16 +164,93 @@ class CacheStats:
             from .telemetry.registry import get_registry
             get_registry().count("compile_cache_saved_seconds_total",
                                  float(duration))
+        elif event in _STAGES:
+            stage, counter = _STAGES[event]
+            self._on_stage(stage, kw.get("fun_name"), float(duration))
+            from .telemetry.registry import get_registry
+            get_registry().count(counter, float(duration))
+
+    def _on_stage(self, stage: str, fun_name: Optional[str],
+                  seconds: float) -> None:
+        """Keep one stage of one program's first call. ``trace`` names
+        the function bare and ``lower``/``backend`` as ``jit(<name>)``:
+        one key for both. A cache read has no name until the
+        ``backend`` event that follows it on the same thread."""
+        name = fun_name or "(unnamed)"
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
+        end = seconds_since_process_start()
+        if stage == "cache_read":
+            self._unnamed_read.event = (seconds, end)
+            return
+        read = getattr(self._unnamed_read, "event", None)
+        with self._lock:
+            if stage == "backend" and read is not None:
+                self._unnamed_read.event = None
+                self._events.append(("cache_read", name, *read))
+            self._events.append((stage, name, seconds, end))
+
+    def _kept(self, until_s: Optional[float]) -> list:
+        with self._lock:
+            events = list(self._events)
+        return [e for e in events if until_s is None or e[3] <= until_s]
+
+    def stage_seconds(self, until_s: Optional[float] = None
+                      ) -> Dict[str, float]:
+        """Seconds this process has spent in each stage, over the kept
+        events that ended before ``until_s`` (seconds since process
+        start; None = all). The union of the events' intervals, not
+        their sum: a function traced while another is being traced (a
+        jitted ``jnp`` helper inside the step) reports its own event
+        inside the outer one's, and counts once."""
+        from .telemetry.device_trace import covered
+
+        spans: Dict[str, list] = {stage: [] for stage in STAGE_NAMES}
+        for stage, _, seconds, end in self._kept(until_s):
+            spans[stage].append((end - seconds, end))
+        return {stage: covered(ivs) for stage, ivs in spans.items()}
+
+    def programs(self, until_s: Optional[float] = None
+                 ) -> Dict[str, Dict[str, float]]:
+        """Per ``fun_name``: ``count`` (first calls: backend events) and
+        the seconds of each stage, over the same events as
+        :meth:`stage_seconds` (here plain sums: a program's own cost)."""
+        out: Dict[str, Dict[str, float]] = {}
+        for stage, name, seconds, _ in self._kept(until_s):
+            row = out.setdefault(
+                name, {"count": 0, **dict.fromkeys(STAGE_NAMES, 0.0)})
+            row[stage] += seconds
+            row["count"] += stage == "backend"
+        return out
+
+    def programs_line(self, until_s: Optional[float] = None,
+                      top: int = 6) -> str:
+        """:meth:`programs` on one line, costliest first: what
+        ``engine._report_first_step`` prints beside the hits/misses and
+        the benchmark as its ``[programs]`` line."""
+        cost = ("trace", "lower", "backend")
+        rows = sorted(self.programs(until_s).items(),
+                      key=lambda kv: -sum(kv[1][s] for s in cost))
+        shown = [f"{name} x{r['count']} trace {r['trace']:.2f} lower "
+                 f"{r['lower']:.2f} backend {r['backend']:.2f} (cache "
+                 f"read {r['cache_read']:.2f})" for name, r in rows[:top]]
+        if rows[top:]:
+            shown.append(f"{len(rows) - top} others " + " ".join(
+                f"{s} {sum(r[s] for _, r in rows[top:]):.2f}"
+                for s in cost))
+        return "; ".join(shown)
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
-            return {
+            snap = {
                 "cache_dir": self.cache_dir,
                 "requests": self.requests,
                 "hits": self.hits,
                 "misses": self.requests - self.hits,
                 "compile_time_saved_s": round(self.saved_secs, 3),
             }
+        snap["programs"] = self.programs()
+        return snap
 
 
 STATS = CacheStats()

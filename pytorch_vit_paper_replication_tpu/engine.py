@@ -172,41 +172,46 @@ def make_train_step(label_smoothing: float = 0.0, nan_guard: bool = False,
             logits = state.apply_fn(
                 {"params": params}, batch["image"], True,
                 rngs={"dropout": dropout_rng})
-            if distill_alpha is not None:
-                loss = distill_loss(
-                    logits, batch["teacher_logits"], batch["label"],
-                    t=distill_t, alpha=distill_alpha,
-                    label_smoothing=label_smoothing)
-            else:
-                loss = cross_entropy_loss(logits, batch["label"],
-                                          label_smoothing)
+            with jax.named_scope("loss"):
+                if distill_alpha is not None:
+                    loss = distill_loss(
+                        logits, batch["teacher_logits"], batch["label"],
+                        t=distill_t, alpha=distill_alpha,
+                        label_smoothing=label_smoothing)
+                else:
+                    loss = cross_entropy_loss(logits, batch["label"],
+                                              label_smoothing)
             return loss, logits
 
+        # The scopes below and the modules' own names are what
+        # telemetry/device_trace.py sums a captured step by.
         (loss, logits), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        updates, opt_state = state.tx.update(grads, state.opt_state,
-                                             state.params)
-        params = optax.apply_updates(state.params, updates)
-        metrics = _metrics(loss, logits, batch["label"])
-        if distill_alpha is not None:
-            metrics["teacher_agree"] = jnp.sum(
-                jnp.argmax(logits, axis=-1) ==
-                jnp.argmax(batch["teacher_logits"], axis=-1)
-            ).astype(jnp.float32)
-        metrics["grad_norm"] = optax.global_norm(grads)
-        if nan_guard:
-            # A single scalar catches every nonfinite leaf: any NaN/inf
-            # gradient makes the global norm nonfinite.
-            ok = jnp.isfinite(loss) & jnp.isfinite(metrics["grad_norm"])
-            keep = lambda new, old: jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o), new, old)
-            params = keep(params, state.params)
-            opt_state = keep(opt_state, state.opt_state)
-            # where(), not multiply: loss_sum/grad_norm are NaN on a
-            # skipped step and NaN * 0 = NaN would poison the epoch sums.
-            metrics = {k: jnp.where(ok, v, jnp.zeros_like(v))
-                       for k, v in metrics.items()}
-            metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = state.tx.update(grads, state.opt_state,
+                                                 state.params)
+            params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("metrics"):
+            metrics = _metrics(loss, logits, batch["label"])
+            if distill_alpha is not None:
+                metrics["teacher_agree"] = jnp.sum(
+                    jnp.argmax(logits, axis=-1) ==
+                    jnp.argmax(batch["teacher_logits"], axis=-1)
+                ).astype(jnp.float32)
+            metrics["grad_norm"] = optax.global_norm(grads)
+            if nan_guard:
+                # A single scalar catches every nonfinite leaf: any NaN/inf
+                # gradient makes the global norm nonfinite.
+                ok = jnp.isfinite(loss) & jnp.isfinite(metrics["grad_norm"])
+                keep = lambda new, old: jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o), new, old)
+                params = keep(params, state.params)
+                opt_state = keep(opt_state, state.opt_state)
+                # where(), not multiply: loss_sum/grad_norm are NaN on a
+                # skipped step and NaN * 0 = NaN would poison the epoch sums.
+                metrics = {k: jnp.where(ok, v, jnp.zeros_like(v))
+                           for k, v in metrics.items()}
+                metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
         new_state = state.replace(step=state.step + 1, params=params,
                                   opt_state=opt_state)
         return new_state, metrics
@@ -292,10 +297,11 @@ def evaluate(
                                            "count": 0., "skipped": 0.}
 
 
-def _report_first_step(train_step, state, batch, cache: Dict) -> None:
+def _report_first_step(train_step, state, batch, stats) -> None:
     """Once per process, after the first step's barrier: what the device
     boundary actually did, printed instead of trusted. The persistent
-    cache's hit/miss counts; on a TPU the Mosaic calls found in the
+    cache's hit/miss counts and what the first calls cost by stage (the
+    split of ``time_to_first_step``); on a TPU the Mosaic calls found in the
     lowered step with their per-shard operand rows (the kernel dispatch
     reads ``jax.default_backend()`` and falls back silently) — costs one
     more lowering, so it is skipped elsewhere, where there is no Mosaic
@@ -303,8 +309,13 @@ def _report_first_step(train_step, state, batch, cache: Dict) -> None:
     reports it."""
     from .ops.partition import mosaic_calls
 
+    cache = stats.snapshot()
     lines = [f"compile cache: {cache['hits']} hits, {cache['misses']} "
              f"misses ({cache['cache_dir']})"]
+    # What the first calls cost, by stage, costliest program first (the
+    # train step), then every other program's together.
+    lines.append("[programs] seconds in first calls (backend holds the "
+                 f"cache read): {stats.programs_line(top=1)}")
     if jax.default_backend() == "tpu" and hasattr(train_step, "lower"):
         calls = mosaic_calls(train_step.lower(state, batch).as_text())
         rows = sorted({shape[0] for _, shape in calls})
@@ -333,7 +344,6 @@ def train(
     logger=None,
     checkpointer=None,
     verbose: bool = True,
-    profile_dir: Optional[str] = None,
     start_epoch: int = 0,
     checkpoint_every_steps: int = 0,
     checkpoint_every_epochs: int = 1,
@@ -418,7 +428,6 @@ def train(
 
     from .compile_cache import STATS as cache_stats
     from .compile_cache import seconds_since_process_start
-    from .metrics import profile_trace
 
     global_step = int(jax.device_get(state.step))
     time_to_first_step = None
@@ -429,78 +438,76 @@ def train(
         total = None
         steps = 0
         epoch_no = start_epoch + epoch + 1
-        # Trace the first epoch when asked (SURVEY.md §5 'tracing': the
-        # jax.profiler subsystem the reference lacks, behind a flag).
-        with profile_trace(profile_dir or "",
-                           enabled=profile_dir is not None and epoch == 0):
-            batches = iter(train_batches())
-            while True:
-                # Data-wait span: host time blocked on the batch
-                # iterator — the loader's share of the step, separated
-                # from the device's (the clock calls cost ~100 ns; the
-                # telemetry overhead gate holds the whole path < 2%).
-                t_wait = time.perf_counter()
-                try:
-                    batch = next(batches)
-                except StopIteration:
-                    break
-                t_step = time.perf_counter()
-                data_wait = t_step - t_wait
+        batches = iter(train_batches())
+        while True:
+            # Data-wait span: host time blocked on the batch
+            # iterator — the loader's share of the step, separated
+            # from the device's (the clock calls cost ~100 ns; the
+            # telemetry overhead gate holds the whole path < 2%).
+            t_wait = time.perf_counter()
+            try:
+                batch = next(batches)
+            except StopIteration:
+                break
+            t_step = time.perf_counter()
+            data_wait = t_step - t_wait
+            if telemetry is not None:
+                # Pre-step hook: opens an armed profiler capture
+                # window BEFORE dispatch (after it, the window
+                # would miss this step's XLA ops). A None-check
+                # when no profiler is wired.
+                telemetry.step_begin(global_step + 1)
+            state, metrics = train_step(state, batch)
+            blocked = False
+            if telemetry is not None and telemetry.should_block():
+                # Sampled honesty barrier: async dispatch returns
+                # before the device finishes, so unsampled step
+                # walls measure dispatch; barriering every N-th
+                # step re-pins the host timeline to the device at
+                # amortized-negligible cost.
+                # vitlint: hot-path-ok(sampled honesty barrier, every telemetry.block_every steps)
+                jax.block_until_ready(metrics["loss_sum"])
+                blocked = True
+            if time_to_first_step is None:
+                # The cold-start headline: process start -> first
+                # optimizer update applied. The one-off barrier makes
+                # it honest (async dispatch would otherwise report
+                # trace time, not compile+execute time); on a resume
+                # it measures THIS restart's latency — exactly the
+                # number preemption recovery pays on top of the
+                # checkpoint gap.
+                # vitlint: hot-path-ok(one-off time-to-first-step barrier, first step only)
+                jax.block_until_ready(metrics["loss_sum"])
+                blocked = True
+                time_to_first_step = seconds_since_process_start()
                 if telemetry is not None:
-                    # Pre-step hook: opens an armed profiler capture
-                    # window BEFORE dispatch (after it, the window
-                    # would miss this step's XLA ops). A None-check
-                    # when no profiler is wired.
-                    telemetry.step_begin(global_step + 1)
-                state, metrics = train_step(state, batch)
-                blocked = False
-                if telemetry is not None and telemetry.should_block():
-                    # Sampled honesty barrier: async dispatch returns
-                    # before the device finishes, so unsampled step
-                    # walls measure dispatch; barriering every N-th
-                    # step re-pins the host timeline to the device at
-                    # amortized-negligible cost.
-                    # vitlint: hot-path-ok(sampled honesty barrier, every telemetry.block_every steps)
-                    jax.block_until_ready(metrics["loss_sum"])
-                    blocked = True
-                if time_to_first_step is None:
-                    # The cold-start headline: process start -> first
-                    # optimizer update applied. The one-off barrier makes
-                    # it honest (async dispatch would otherwise report
-                    # trace time, not compile+execute time); on a resume
-                    # it measures THIS restart's latency — exactly the
-                    # number preemption recovery pays on top of the
-                    # checkpoint gap.
-                    # vitlint: hot-path-ok(one-off time-to-first-step barrier, first step only)
-                    jax.block_until_ready(metrics["loss_sum"])
-                    blocked = True
-                    time_to_first_step = seconds_since_process_start()
-                    if verbose:
-                        # vitlint: hot-path-ok(once per process, with the first-step barrier)
-                        print(f"time_to_first_step: "
-                              f"{time_to_first_step:.2f}s (process start "
-                              f"-> first train step applied)")
-                        _report_first_step(train_step, state, batch,
-                                           cache_stats.snapshot())
-                total = _accumulate(total, metrics)
-                steps += 1
-                global_step += 1
+                    telemetry.first_step(train_step, state, batch)
+                if verbose:
+                    # vitlint: hot-path-ok(once per process, with the first-step barrier)
+                    print(f"time_to_first_step: "
+                          f"{time_to_first_step:.2f}s (process start "
+                          f"-> first train step applied)")
+                    _report_first_step(train_step, state, batch,
+                                       cache_stats)
+            total = _accumulate(total, metrics)
+            steps += 1
+            global_step += 1
+            if telemetry is not None:
+                telemetry.step(
+                    data_wait_s=data_wait,
+                    exec_s=time.perf_counter() - t_step,
+                    images=int(batch["label"].shape[0]),
+                    step=global_step, epoch=epoch_no, blocked=blocked)
+            if (checkpoint_every_steps and checkpointer is not None
+                    and global_step % checkpoint_every_steps == 0):
+                t_ck = time.perf_counter()
+                checkpointer.save(state)
                 if telemetry is not None:
-                    telemetry.step(
-                        data_wait_s=data_wait,
-                        exec_s=time.perf_counter() - t_step,
-                        images=int(batch["label"].shape[0]),
-                        step=global_step, epoch=epoch_no, blocked=blocked)
-                if (checkpoint_every_steps and checkpointer is not None
-                        and global_step % checkpoint_every_steps == 0):
-                    t_ck = time.perf_counter()
-                    checkpointer.save(state)
-                    if telemetry is not None:
-                        telemetry.span("checkpoint",
-                                       time.perf_counter() - t_ck)
-                if stop_check is not None and stop_check(global_step):
-                    stop_requested = True
-                    break
+                    telemetry.span("checkpoint",
+                                   time.perf_counter() - t_ck)
+            if stop_check is not None and stop_check(global_step):
+                stop_requested = True
+                break
         if stop_requested:
             # Clean mid-epoch yield (elastic re-formation): no partial-
             # epoch eval/log rows, no epoch-end save — the caller

@@ -7,14 +7,13 @@ results dict (SURVEY.md §5 'metrics'). This module upgrades that to:
   history,
 * TensorBoard scalars (``tensorboardX``) when a ``tb_dir`` is given,
 * throughput (images/sec and per-chip), step timing,
-* a :class:`Timer` for images/sec accounting that excludes compilation,
-* :func:`profile_trace` — ``jax.profiler`` wrapper (the tracing subsystem
-  the reference lacks entirely).
+* a :class:`Timer` for images/sec accounting that excludes compilation.
+
+Profiler captures are :mod:`.telemetry.profiling`'s (``--profile-steps``).
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import math
 import time
@@ -135,24 +134,6 @@ class Timer:
                                 n_chips: Optional[int] = None) -> float:
         n = n_chips or jax.device_count()
         return self.images_per_sec / max(1, n)
-
-
-@contextlib.contextmanager
-def profile_trace(log_dir: str | Path, enabled: bool = True):
-    """Capture a jax.profiler trace around the enclosed steps.
-
-    View with TensorBoard or xprof. The flagged-off path is free — this is
-    the 'tracing/profiling behind a flag' subsystem from SURVEY.md §5.
-    """
-    if not enabled:
-        yield
-        return
-    log_dir = str(log_dir)
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def block_until_ready(tree: Any) -> Any:

@@ -322,6 +322,10 @@ def _flash_ok(q) -> bool:
     return 3 * logits_bytes > _FLASH_MEMORY_BYTES
 
 
+# One scope for every implementation (XLA, flash, ring, ulysses), so that a
+# device trace splits `msa` into norm / qkv / attn_core / out and what is
+# left: the slices and transposes between them (telemetry/device_trace.py).
+@jax.named_scope("attn_core")
 def dot_product_attention(
     q: jax.Array,
     k: jax.Array,

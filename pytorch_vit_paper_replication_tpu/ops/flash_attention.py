@@ -312,6 +312,7 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
         operands.append(mask3)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -506,6 +507,7 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           kv_len=kv_len, threshold=threshold,
                           mask_info=mask_info, heads=heads),
+        name="flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, padded_q // block_q),
@@ -524,6 +526,7 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           q_len=q_len, threshold=threshold,
                           mask_info=mask_info, heads=heads),
+        name="flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, padded_kv // block_k),

@@ -253,6 +253,7 @@ def _fused_call(x, w1, b1, w2, b2, seed, threshold, block_rows, interpret,
             jax.ShapeDtypeStruct((n, f), SAVED_H_DTYPE or x.dtype))
     res = pl.pallas_call(
         kernel,
+        name="mlp_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block_rows,),
@@ -295,6 +296,7 @@ def _fused_bwd(threshold, block_rows, interpret, res, do):
     row_spec = pl.BlockSpec((block_rows, d), lambda i, *_: (i, 0))
     dx, dw1, db1, dw2, db2 = pl.pallas_call(
         kernel,
+        name="mlp_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block_rows,),
@@ -472,6 +474,7 @@ def _lnmlp_call(x, gamma, beta, w1, b1, w2, b2, seed, threshold, block_rows,
             jax.ShapeDtypeStruct((n, f), SAVED_H_DTYPE or x.dtype))
     res = pl.pallas_call(
         kernel,
+        name="lnmlp_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block_rows,),
@@ -512,6 +515,7 @@ def _lnmlp_bwd(threshold, block_rows, eps, interpret, res, do):
     vec_d = pl.BlockSpec((1, d), const)
     dx, dgamma, dbeta, dw1, db1, dw2, db2 = pl.pallas_call(
         kernel,
+        name="lnmlp_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // block_rows,),

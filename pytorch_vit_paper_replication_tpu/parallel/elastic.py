@@ -693,7 +693,7 @@ def strip_elastic_args(argv: Sequence[str]) -> List[str]:
 # garbage, so the supervisor suffixes these flags' values with .w<slot>.
 _PER_WORKER_PATH_FLAGS = ("--metrics-jsonl", "--telemetry-jsonl",
                           "--postmortem", "--tensorboard-dir", "--plot",
-                          "--profile-dir", "--profile-trace-dir")
+                          "--profile-trace-dir")
 
 
 def _suffix_path(value: str, slot: int) -> str:

@@ -18,9 +18,25 @@ through one thread-safe :class:`.registry.TelemetryRegistry` —
   all-thread stacks + memory + the last-N events instead of freezing
   silently (and the same dump on SIGTERM for preemption forensics),
 * :mod:`.profiling` — :class:`ProfileController`, on-demand
-  ``jax.profiler`` capture windows (``--profile-steps``, SIGUSR2, or
-  a step-time anomaly) plus device-memory watermark gauges sampled on
-  the honesty-barrier cadence,
+  ``jax.profiler`` capture windows (``--profile-steps A:B``, SIGUSR2,
+  or a step-time anomaly) plus device-memory watermark gauges sampled
+  on the honesty-barrier cadence. A capture is of the device alone
+  (the host tracer slows the feed it records: PERF.md, PR 22); the
+  host's side is :class:`StepTelemetry` 's own spans, handed over
+  while a capture is active and set on the trace's clock by an anchor
+  program,
+* :mod:`.device_trace` — the reader: when a window closes (and as
+  ``python -m ...telemetry.device_trace <capture>``) the captured
+  step's device time by layer (``patch_embed``, ``msa_norm``,
+  ``msa_qkv``, ``attn_core``, ``msa_out``, ``msa_glue``,
+  ``block_glue``, the Pallas kernels by their ``name=``, ``mlp_xla``,
+  ``final_norm_head``, ``loss``, ``metrics``, ``optimizer``,
+  ``collective``, ``other``) and by phase (forward / backward /
+  recompute / optimizer), printed, written to
+  ``<capture>/device_time.json`` and published as one
+  ``profiler_device_time`` event; an op's layer is its jax name stack
+  (module names and ``named_scope`` s), joined in from the step
+  program's optimized HLO because the v5e profile keeps no path,
 * :mod:`.chrome_trace` — the span/event stream as Chrome trace-event
   JSON, so engine spans render in Perfetto next to XLA captures,
 * :mod:`.tracing` — request-scoped DISTRIBUTED tracing (ISSUE 20):

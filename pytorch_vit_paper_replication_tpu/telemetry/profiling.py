@@ -25,6 +25,22 @@ drills below the span level, without the cost of always-on tracing:
   the trace that explains it. All ``jax.profiler`` calls are fenced:
   a profiling failure degrades to a counted error, never a dead run.
 
+  A capture is of the **device alone** (host and Python tracers off):
+  on the v5e the host tracer records every chunk of the runtime's
+  host-side layout change of an input batch, the feed then outlasts
+  the step, and the chip reads 27-41% idle where it is 0.04% idle
+  (PERF.md, PR 22) — a capture that misreports the run it captures.
+  The host's side comes from the program's own spans instead: while a
+  capture is active :class:`..spans.StepTelemetry` hands over the
+  intervals it already measures (``data_wait``, ``step_exec``,
+  ``checkpoint``, ``eval``), and an anchor — a tiny named program run
+  right after the capture starts, whose end both clocks see — sets
+  them on the trace's clock. When the window closes the capture is
+  read back (:mod:`.device_trace`): ``<capture>/device_time.json``,
+  one ``profiler_device_time`` event, the gauges
+  ``profiler_last_step_device_ms`` / ``profiler_last_idle_pct`` and,
+  for a verbose trainer, the layer x phase table on standard output.
+
 * :func:`sample_device_memory` — peak/live device-byte watermarks:
   live bytes via ``jax.live_arrays()`` (every backend) plus per-device
   ``memory_stats()`` (``bytes_in_use`` / ``peak_bytes_in_use`` /
@@ -44,11 +60,14 @@ windows disarmed — under the same <2% gate.
 
 from __future__ import annotations
 
+import gzip
+import json
 import signal
 import statistics
+import time
 from collections import deque
 from pathlib import Path
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 from .registry import TelemetryRegistry, get_registry
 
@@ -138,7 +157,10 @@ class ProfileController:
         is bounded no matter how flappy the anomaly signal gets.
       check_every: anomaly-check cadence in steps (keeps the median
         computation off the per-step path).
+      verbose: print the device-time table when a capture closes.
     """
+
+    ANCHOR = "profiler_clock_anchor"
 
     def __init__(self, trace_dir: str | Path, *,
                  registry: Optional[TelemetryRegistry] = None,
@@ -149,7 +171,8 @@ class ProfileController:
                  warmup_steps: int = 3,
                  signal_steps: int = 16,
                  max_captures: int = 8,
-                 check_every: int = 16):
+                 check_every: int = 16,
+                 verbose: bool = False):
         self.trace_dir = Path(trace_dir)
         self.registry = registry if registry is not None else get_registry()
         self.auto = bool(auto)
@@ -171,7 +194,51 @@ class ProfileController:
         self._baseline_p50: Optional[float] = None
         self._steps_seen = 0
         self.last_capture_path: Optional[str] = None
+        self.verbose = bool(verbose)
+        # Host spans of the active capture, (name, start_ns, end_ns) on
+        # time.perf_counter_ns, and the anchor's end on that clock.
+        self._spans: list = []
+        self._anchor_host_ns: Optional[int] = None
+        self._anchor = self._make_anchor()
+        # () -> optimized HLO text of the step program, and what
+        # device_trace.parse_scopes made of it (once per process).
+        self._program_text: Optional[Callable[[], str]] = None
+        self._program: Optional[dict] = None
+        self.last_device_time: Optional[dict] = None
         self.registry.gauge("profiler_capture_active", 0)
+
+    def _make_anchor(self):
+        """The clock anchor, compiled now and not inside a window."""
+        try:
+            import jax
+            import jax.numpy as jnp
+
+            def anchor(x):
+                return x + 1
+            anchor.__name__ = self.ANCHOR
+            fn, x = jax.jit(anchor), jnp.zeros((), jnp.int32)
+            jax.block_until_ready(fn(x))
+            return lambda: jax.block_until_ready(fn(x))
+        except Exception as e:  # noqa: BLE001 — jax absent/uninitialized
+            self.registry.event("profiler_error", error=f"anchor: {e}")
+            return None
+
+    @property
+    def active(self) -> bool:
+        """True while a capture window is open."""
+        return self._active is not None
+
+    def add_span(self, name: str, end_ns: int, seconds: float) -> None:
+        """A host interval that ended at ``end_ns`` (perf_counter_ns)
+        and lasted ``seconds``; kept only while a capture is active."""
+        if self._active is not None:
+            self._spans.append((name, end_ns - int(seconds * 1e9), end_ns))
+
+    def set_program(self, hlo_text: Callable[[], str]) -> None:
+        """How to get the step program's optimized HLO text, from which
+        a capture's ops get their module paths. Called (one cache hit)
+        when the first capture closes, never on the step path."""
+        self._program_text = hlo_text
 
     # ------------------------------------------------------------ arming
     def arm(self, start_step: int, n_steps: Optional[int] = None,
@@ -243,13 +310,23 @@ class ProfileController:
         try:
             import jax
             path.mkdir(parents=True, exist_ok=True)
-            jax.profiler.start_trace(str(path))
+            options = jax.profiler.ProfileOptions()
+            options.host_tracer_level = 0      # the device alone: see
+            options.python_tracer_level = 0    # the module docstring
+            jax.profiler.start_trace(str(path), profiler_options=options)
         except Exception as e:  # noqa: BLE001 — profiling must never
             # take the training step down with it.
             self.registry.count("profiler_capture_errors_total")
             self.registry.event("profiler_error", error=f"{e}")
             return False
         self._active = (end, path)
+        self._spans, self._anchor_host_ns = [], None
+        try:
+            if self._anchor is not None:
+                self._anchor()
+                self._anchor_host_ns = time.perf_counter_ns()
+        except Exception as e:  # noqa: BLE001 — spans go unplaced
+            self.registry.event("profiler_error", error=f"anchor: {e}")
         self._captures += 1
         self.registry.count("profiler_captures_total")
         self.registry.gauge("profiler_capture_active", 1)
@@ -309,6 +386,51 @@ class ProfileController:
         self.registry.gauge("profiler_last_capture_path", str(path))
         self.registry.event("profiler_capture_stop", step=step,
                             path=str(path))
+        try:
+            self._read_back(path)
+        except Exception as e:  # noqa: BLE001 — as every profiler call
+            self.registry.count("profiler_capture_errors_total")
+            self.registry.event("profiler_error", error=f"read back: {e}")
+
+    def _read_back(self, path: Path) -> None:
+        """The closed capture as device time by layer and phase."""
+        from . import device_trace
+
+        def scopes():
+            # One compile of the step (a cache hit), once per process,
+            # and only for a capture that has a device plane.
+            if self._program is None and self._program_text is not None:
+                self._program = device_trace.parse_scopes(
+                    self._program_text())
+            return (self._program or {}).get("scopes")
+
+        trace = device_trace.load(path, scopes)
+        program = dict(self._program or {"module": "", "scopes": {}})
+        anchor_ns = device_trace.module_end_ns(trace, f"jit_{self.ANCHOR}")
+        program["host_spans"] = [] if None in (
+            anchor_ns, self._anchor_host_ns) else device_trace.shift_spans(
+            self._spans, self._anchor_host_ns, anchor_ns)
+        self._spans = []
+        result = device_trace.reduce(
+            trace, module_prefix=program["module"] or "jit_train_step",
+            host_spans=program["host_spans"])
+        with gzip.open(path / device_trace.PROGRAM_FILE, "wt") as f:
+            json.dump(program, f, separators=(",", ":"))
+        (path / device_trace.TABLE_FILE).write_text(
+            json.dumps(result, indent=1))
+        self.last_device_time = result
+        if "rows" in result:
+            self.registry.gauge("profiler_last_step_device_ms",
+                                round(result["step_ms"], 4))
+            self.registry.gauge("profiler_last_idle_pct",
+                                round(result["idle_pct"], 4))
+        self.registry.event(
+            "profiler_device_time", path=str(path),
+            **{k: result[k] for k in ("reason", "steps", "chips", "step_ms",
+                                      "busy_ms", "idle_pct", "rows",
+                                      "idle_gaps") if k in result})
+        if self.verbose:
+            print(device_trace.format_table(result), flush=True)
 
     # ------------------------------------------------------------ cleanup
     def close(self) -> None:

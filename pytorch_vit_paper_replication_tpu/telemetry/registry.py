@@ -76,6 +76,9 @@ INSTRUMENTS: Dict[str, str] = {
     "profiler_capture_active": "gauge",
     "profiler_last_capture_path": "gauge",   # string gauge: snapshot/
     # postmortem only — the Prometheus renderer skips non-numerics
+    # The last closed capture, read back (telemetry/device_trace.py).
+    "profiler_last_step_device_ms": "gauge",
+    "profiler_last_idle_pct": "gauge",
     "mem_live_bytes": "gauge",
     "mem_live_bytes_peak": "gauge",
     "mem_live_arrays": "gauge",
@@ -104,6 +107,11 @@ INSTRUMENTS: Dict[str, str] = {
     "compile_cache_requests_total": "counter",
     "compile_cache_hits_total": "counter",
     "compile_cache_saved_seconds_total": "counter",
+    # What programs' first calls cost, by stage (CacheStats._on_stage).
+    "compile_trace_seconds_total": "counter",
+    "compile_lower_seconds_total": "counter",
+    "compile_backend_seconds_total": "counter",
+    "compile_cache_read_seconds_total": "counter",
     # Serving fleet (serve/fleet/): the router's routing/admission
     # instruments, the rolling checkpoint hot-swap, and replica
     # membership. Per-replica replica_up_<rid> gauges are published
@@ -302,6 +310,19 @@ HELP_TEXT: Dict[str, str] = {
                                 "persistent compile cache",
     "compile_cache_saved_seconds_total": "Compile seconds saved by "
                                          "persistent-cache hits",
+    "compile_trace_seconds_total": "Seconds tracing Python to jaxprs "
+                                   "(programs' first calls)",
+    "compile_lower_seconds_total": "Seconds lowering jaxprs to MLIR "
+                                   "modules",
+    "compile_backend_seconds_total": "Seconds in the backend: compile "
+                                     "on a cache miss, read + "
+                                     "deserialise on a hit",
+    "compile_cache_read_seconds_total": "Seconds reading persistent-"
+                                        "cache entries (part of backend)",
+    "profiler_last_step_device_ms": "Device ms per step in the last "
+                                    "closed capture",
+    "profiler_last_idle_pct": "Device idle share of the last closed "
+                              "capture's window, percent",
     "fleet_route_requests_total": "Client request lines the fleet "
                                   "router dispatched",
     "fleet_route_retries_total": "Re-dispatches after a replica died "

@@ -155,11 +155,39 @@ class StepTelemetry:
             self.profiler.maybe_start(
                 step if step is not None else self._total_steps + 1)
 
+    def first_step(self, train_step, state, batch) -> None:
+        """Once, after the first applied step: tell the profiler how to
+        get the step program's optimized HLO (from shapes and layouts
+        alone — no array is kept), which is where a capture's ops get
+        their module paths (:mod:`.device_trace`)."""
+        if self.profiler is None or not hasattr(train_step, "lower"):
+            return
+        import jax
+
+        try:
+            avals = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=getattr(a, "sharding", None)),
+                (state, batch))
+        except (AttributeError, TypeError):
+            return      # a leaf that is no array: captures go unnamed
+        self.profiler.set_program(
+            lambda: train_step.lower(*avals).compile().as_text())
+
     def step(self, *, data_wait_s: float, exec_s: float, images: int,
              step: Optional[int] = None, epoch: Optional[int] = None,
              blocked: bool = False) -> None:
         """Record one completed train step's spans."""
         reg = self.registry
+        if self.profiler is not None and self.profiler.active:
+            # The same two intervals, on the host's clock, for the open
+            # capture to set beside the device's (before on_step_end,
+            # which may close it).
+            now = time.perf_counter_ns()
+            self.profiler.add_span("step_exec", now, exec_s)
+            self.profiler.add_span("data_wait", now - int(exec_s * 1e9),
+                                   data_wait_s)
         total = data_wait_s + exec_s
         self._total_steps += 1
         self._ep_steps += 1
@@ -244,6 +272,8 @@ class StepTelemetry:
             self._ep_ckpt += seconds
         else:
             self._ep_eval += seconds
+        if self.profiler is not None and self.profiler.active:
+            self.profiler.add_span(name, time.perf_counter_ns(), seconds)
         self.registry.observe(key, seconds)
         self.registry.event("span", span=name,
                             seconds=round(seconds, 6))

@@ -353,8 +353,6 @@ def build_parser() -> argparse.ArgumentParser:
                      help="write TensorBoard scalars here")
     out.add_argument("--plot", type=str, default=None,
                      help="save loss curves PNG here")
-    out.add_argument("--profile-dir", type=str, default=None,
-                     help="capture a jax.profiler trace of epoch 1")
 
     obs = p.add_argument_group("observability (telemetry/)")
     obs.add_argument("--telemetry-jsonl", type=str, default=None,
@@ -384,11 +382,14 @@ def build_parser() -> argparse.ArgumentParser:
                           "--telemetry-jsonl, else ./postmortem.txt)")
     obs.add_argument("--profile-steps", type=str, default=None,
                      metavar="A:B",
-                     help="capture a jax.profiler trace of global "
-                          "steps A..B (inclusive) into the run's "
-                          "profile dir — open in Perfetto/TensorBoard "
-                          "next to the engine-span chrome trace "
-                          "(tools/trace_report.py --format chrome). "
+                     help="capture the device trace of global steps "
+                          "A..B (inclusive) into the run's profile "
+                          "dir, and when the window closes print and "
+                          "write (device_time.json) the step's device "
+                          "time by module and by forward / backward / "
+                          "optimizer (telemetry/device_trace.py). The "
+                          "capture is of the device alone: the host "
+                          "tracer slows the feed it records. "
                           "A running trainer can also be captured "
                           "without flags: SIGUSR2 arms a window over "
                           "the next steps")
@@ -1006,7 +1007,8 @@ def main(argv=None) -> dict:
             trace_dir = args.profile_trace_dir or str(run_dir / "profiles")
             profiler = ProfileController(
                 trace_dir, steps=profile_window,
-                auto=args.profile_auto, auto_pct=args.profile_auto_pct)
+                auto=args.profile_auto, auto_pct=args.profile_auto_pct,
+                verbose=True)
             profiler.install_sigusr2()
             obs_stack.callback(profiler.close)
             if args.profile_steps or args.profile_auto:
@@ -1134,7 +1136,6 @@ def main(argv=None) -> dict:
                               or elastic_ctx.is_primary
                               or args.elastic_backend == "jax"
                               else None),
-                profile_dir=args.profile_dir,
                 start_epoch=done_epochs,
                 checkpoint_every_steps=args.checkpoint_every_steps,
                 checkpoint_every_epochs=args.checkpoint_every_epochs,

@@ -131,6 +131,110 @@ def test_seconds_since_process_start_positive_and_monotonic():
     assert 0 < a <= b
 
 
+# ------------------------------ what a program's first call cost (PR 24)
+def test_first_call_leaves_its_stages_under_the_programs_name():
+    import jax
+    import jax.numpy as jnp
+
+    compile_cache._install_listeners()
+
+    def pr24_stage_probe(x):
+        return jnp.tanh(x) * 3
+
+    before = compile_cache.seconds_since_process_start()
+    jax.block_until_ready(jax.jit(pr24_stage_probe)(jnp.ones(5)))
+    row = compile_cache.STATS.snapshot()["programs"]["pr24_stage_probe"]
+    assert row["count"] == 1
+    assert row["trace"] > 0 and row["lower"] > 0 and row["backend"] > 0
+    mine = [e for e in compile_cache.STATS._kept(None)
+            if e[1] == "pr24_stage_probe"]
+    assert [e[0] for e in mine][:3] == ["trace", "lower", "backend"]
+    assert all(e[3] >= before for e in mine)
+    assert "pr24_stage_probe x1 trace" in \
+        compile_cache.STATS.programs_line(top=10_000)
+    from pytorch_vit_paper_replication_tpu.telemetry import get_registry
+    counters = get_registry().snapshot()["counters"]
+    assert counters["compile_trace_seconds_total"] > 0
+    assert counters["compile_backend_seconds_total"] > 0
+
+
+def _stats_with(monkeypatch, events):
+    """A CacheStats fed ``(stage, fun_name, seconds, end)`` by hand."""
+    stats = compile_cache.CacheStats()
+    for stage, fun_name, seconds, end in events:
+        monkeypatch.setattr(compile_cache, "seconds_since_process_start",
+                            lambda end=end: end)
+        stats._on_stage(stage, fun_name, seconds)
+    return stats
+
+
+def test_stage_seconds_counts_nested_traces_once_and_stops_at_until_s(
+        monkeypatch):
+    stats = _stats_with(monkeypatch, [
+        ("trace", "multiply", 0.5, 11.0),      # traced inside train_step's
+        ("trace", "train_step", 3.0, 12.0),    # trace: 9.0 .. 12.0
+        ("lower", "jit(train_step)", 1.0, 13.0),
+        ("cache_read", None, 1.5, 14.5),       # named by what follows
+        ("backend", "jit(train_step)", 2.0, 15.0),
+        ("trace", "eval_step", 4.0, 40.0),     # after the window opened
+        ("lower", "jit(eval_step)", 1.0, 41.0),
+        ("backend", "jit(eval_step)", 9.0, 50.0)])
+    assert stats.stage_seconds(until_s=20.0) == {
+        "trace": 3.0, "lower": 1.0, "backend": 2.0, "cache_read": 1.5}
+    everything = stats.stage_seconds()
+    assert everything["trace"] == 7.0 and everything["backend"] == 11.0
+    programs = stats.programs(until_s=20.0)
+    assert set(programs) == {"multiply", "train_step"}
+    assert programs["train_step"] == {"count": 1, "trace": 3.0,
+                                      "lower": 1.0, "backend": 2.0,
+                                      "cache_read": 1.5}
+    assert stats.programs()["eval_step"]["backend"] == 9.0
+    line = stats.programs_line(until_s=20.0, top=1)
+    assert line.startswith("train_step x1 trace 3.00 lower 1.00 backend "
+                           "2.00 (cache read 1.50); 1 others trace 0.50")
+
+
+def test_kept_stage_events_are_bounded(monkeypatch):
+    monkeypatch.setattr(compile_cache, "MAX_STAGE_EVENTS", 16)
+    stats = _stats_with(monkeypatch, [
+        ("backend", f"jit(f{i})", 1.0, float(i)) for i in range(40)])
+    assert len(stats._kept(None)) == 16
+    assert stats.stage_seconds()["backend"] == 16.0
+    assert set(stats.programs()) == {f"f{i}" for i in range(24, 40)}
+
+
+def test_setup_metrics_read_the_stages_up_to_the_window_with_a_second_of_slack(
+        monkeypatch, capsys):
+    """The benchmark's clock and the program's both count from process
+    start but may differ by up to a second (``btime`` is whole seconds):
+    the three set-up metrics allow that second and no more."""
+    from benchmark.metrics import (setup_backend_s, setup_cache_read_s,
+                                   setup_trace_lower_s)
+
+    stats = _stats_with(monkeypatch, [
+        ("trace", "train_step", 3.0, 20.0),
+        ("lower", "jit(train_step)", 1.0, 21.0),
+        ("cache_read", None, 0.25, 21.5),
+        ("backend", "jit(train_step)", 2.0, 30.9),   # 0.9 s "late"
+        ("backend", "jit(eval_step)", 7.0, 31.5)])   # after the window
+    monkeypatch.setattr(compile_cache, "STATS", stats)
+    assert setup_trace_lower_s.read({"setup_s": 30.0}) == 4.0
+    assert capsys.readouterr().out == "", "a rehearsal prints no time"
+    obs = {"setup_s": 30.0, "peak": {"bf16_tflops": 197.0}}
+    assert setup_trace_lower_s.read(obs) == 4.0
+    assert setup_backend_s.read(obs) == 2.0
+    assert setup_cache_read_s.read(obs) == 0.25
+    out = capsys.readouterr().out
+    assert out.startswith("[programs] ") and "train_step x1" in out \
+        and "eval_step" not in out
+    # a program from before the counters reports nothing, and raises
+    # nothing (the benchmark's files are laid over the parent commit too)
+    monkeypatch.setattr(compile_cache, "STATS", object())
+    assert setup_trace_lower_s.read(obs) is None
+    assert setup_backend_s.read(obs) is None
+    assert setup_cache_read_s.read(obs) is None
+
+
 def test_warn_if_uncached_fires_once_on_tpu(monkeypatch):
     import jax
 

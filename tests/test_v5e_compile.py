@@ -24,6 +24,7 @@ from pytorch_vit_paper_replication_tpu.configs import TrainConfig, ViTConfig
 from pytorch_vit_paper_replication_tpu.models import ViT
 from pytorch_vit_paper_replication_tpu.ops.partition import mosaic_calls
 from pytorch_vit_paper_replication_tpu.optim import make_optimizer
+from pytorch_vit_paper_replication_tpu.telemetry import device_trace
 
 
 @pytest.fixture(scope="module")
@@ -69,24 +70,57 @@ def _lower_train_step(devices, cfg, *, dp, tp, batch):
         state, example)
 
 
-def test_dp4_train_step_compiles_with_per_shard_mosaic_calls(
-        v5e_2x2, monkeypatch):
+@pytest.fixture(scope="module")
+def dp4_step(v5e_2x2):
     """ViT-B/16's width, two layers, global batch 1024 on a dp=4 mesh:
-    the step lowers with one fused fwd and one bwd Mosaic call per layer,
-    each over the PER-SHARD rows (256 images x 197 tokens), and the
-    v5e compiler takes it."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    lowered = _lower_train_step(
-        v5e_2x2, ViTConfig(num_layers=2, num_classes=3), dp=4, tp=1,
-        batch=1024)
-    calls = mosaic_calls(lowered.as_text())
-    assert sorted(name for name, _ in calls) == [
-        "_lnmlp_bwd_kernel"] * 2 + ["_lnmlp_fwd_kernel"] * 2
-    assert {shape for _, shape in calls} == {(256 * 197, 768)}
+    the lowered step's text and, from the file's one compile, the
+    optimized HLO's."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = _lower_train_step(
+            v5e_2x2, ViTConfig(num_layers=2, num_classes=3), dp=4, tp=1,
+            batch=1024)
+        return lowered.as_text(), lowered.compile().as_text()
 
-    hlo = lowered.compile().as_text()
+
+def test_dp4_train_step_compiles_with_per_shard_mosaic_calls(dp4_step):
+    """The step lowers with one fused fwd and one bwd Mosaic call per
+    layer, each over the PER-SHARD rows (256 images x 197 tokens), and
+    the v5e compiler takes it."""
+    lowered_text, hlo = dp4_step
+    calls = mosaic_calls(lowered_text)
+    assert sorted(name for name, _ in calls) == [
+        "lnmlp_bwd"] * 2 + ["lnmlp_fwd"] * 2
+    assert {shape for _, shape in calls} == {(256 * 197, 768)}
     assert hlo.count('custom_call_target="tpu_custom_call"') == 4
     assert "all-reduce" in hlo   # the gradient sum over 'data'
+
+
+def test_compiled_step_keeps_the_scopes_and_the_kernels_names(dp4_step):
+    """What telemetry/device_trace.py joins a captured op to: the
+    optimized HLO's ``op_name`` s hold the modules' names, the
+    ``named_scope`` s of the attention core and of the train step, and
+    the kernels' ``name=`` — metadata of the same four Mosaic calls."""
+    lowered_text, hlo = dp4_step
+    assert "_kernel" not in "".join(n for n, _ in mosaic_calls(lowered_text))
+    program = device_trace.parse_scopes(hlo)
+    assert program["module"] == "jit_train_step"
+    paths = set(program["scopes"].values())
+    for scope in ("jvp(loss)", "/optimizer/", "/metrics/", "patch_embedding",
+                  "/msa/norm/", "/msa/qkv/", "/msa/attn_core/", "/msa/out/",
+                  "transpose(jvp(ViT))/backbone/encoder_block_1/msa/"
+                  "attn_core/", "/encoder_norm/", "/head/"):
+        assert any(scope in path for path in paths), scope
+    kernels = sorted(
+        device_trace.kernel_name({"name": name, "scope": scope})
+        for name, scope in program["scopes"].items()
+        if name.startswith("lnmlp") and "pallas_call" in scope)
+    assert kernels == ["lnmlp_bwd"] * 2 + ["lnmlp_fwd"] * 2
+    # nearly every path of an instruction (the program's arguments are
+    # named after the state's leaves) has a layer of the table
+    layers = [device_trace.classify(path)[0] for path in paths
+              if path.startswith("jit(")]
+    assert layers.count("other") < 0.05 * len(layers)
 
 
 def test_dp2_tp2_step_lowers_hidden_sliced_mlp_and_per_shard_flash(
@@ -100,11 +134,11 @@ def test_dp2_tp2_step_lowers_hidden_sliced_mlp_and_per_shard_flash(
                     attention_impl="flash", attn_dropout=0.1)
     calls = mosaic_calls(_lower_train_step(
         v5e_2x2, cfg, dp=2, tp=2, batch=64).as_text())
-    # MLP: the core kernels (not _lnmlp_*), over the data shard's 32
+    # MLP: the core kernels (not lnmlp_*), over the data shard's 32
     # images x 577 tokens padded up to whole 256-row blocks.
     mlp_rows = -(-32 * 577 // 256) * 256
     # Flash: q folded to [images x local heads, tokens padded, head_dim].
     q = (32 * 6, 768, 64)
     assert sorted(calls) == sorted([
-        ("_fwd_kernel", (mlp_rows, 768)), ("_bwd_kernel", (mlp_rows, 768)),
-        ("_fwd_kernel", q), ("_bwd_dq_kernel", q), ("_bwd_dkv_kernel", q)])
+        ("mlp_fwd", (mlp_rows, 768)), ("mlp_bwd", (mlp_rows, 768)),
+        ("flash_fwd", q), ("flash_bwd_dq", q), ("flash_bwd_dkv", q)])
