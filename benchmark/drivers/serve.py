@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 
-from ..lib import clock, harness, reference_vit, schedule
+from ..lib import clock, harness, kernels, reference_vit, schedule
 
 PRE_ROLL_S = 1.0            # uncounted traffic before the window opens
 TRACE_SECONDS = 3.0         # what a ``--trace 1`` run captures
@@ -116,7 +116,6 @@ def replay(engine, arrivals: dict, images: np.ndarray, *, timeout_s: float,
 def run(cell: dict, config: dict, args) -> dict:
     import jax
 
-    from pytorch_vit_paper_replication_tpu.ops.partition import mosaic_calls
     from pytorch_vit_paper_replication_tpu.telemetry import tracing
 
     p = cell["serve"]
@@ -199,10 +198,10 @@ def run(cell: dict, config: dict, args) -> dict:
         # Mosaic calls per rung, read from each rung's lowered program.
         x_s = lambda b: jax.ShapeDtypeStruct(
             (b, cfg.image_size, cfg.image_size, 3), np.float32)
-        per_rung = {b: len(mosaic_calls(
-            engine._fwd.lower(params, x_s(b)).as_text()))
+        per_rung = {b: kernels.kernel_counts(
+            engine._fwd.lower(params, x_s(b)).as_text())
             for b in engine.buckets}
-        expect = p["expect_mosaic_calls"]
+        expect = p["expect_kernels"]
         rung_bytes = {b: harness.program_bytes(c)
                       for b, c in engine._compiled.items()}
 
@@ -228,8 +227,9 @@ def run(cell: dict, config: dict, args) -> dict:
     checks = {
         "every_request_accounted": int(in_win.sum())
         == int(answered.sum()) + failed,
-        "mosaic_calls": args.rehearsal
-        or all(n == expect for n in per_rung.values()),
+        "mosaic_calls": args.rehearsal or all(
+            kernels.check_kernels(found, expect)[0]
+            for found in per_rung.values()),
         "reference": err <= reference_vit.TOLERANCE,
         "no_compile_in_window": w["misses_close"] == w["misses_open"],
     }
@@ -240,8 +240,9 @@ def run(cell: dict, config: dict, args) -> dict:
             f"{k} {v:.1f}" for k, v in phases), flush=True)
     print(f"[serve] offered {p['traffic']['rate_rps']} rps | in window "
           f"{int(in_win.sum())} scheduled, {int(answered.sum())} answered "
-          f"(latency samples), {failed} failed | mosaic calls per rung "
-          f"{per_rung} (expected {expect}) | reference error {err:.4f} "
+          f"(latency samples), {failed} failed | mosaic kernels per "
+          f"rung {per_rung} (the cell names {expect}; others are not "
+          f"judged) | reference error {err:.4f} "
           f"(tolerance {reference_vit.TOLERANCE}) | cache misses at open "
           f"{w['misses_open']} at close {w['misses_close']} | rung "
           f"programs {({b: round(v / 2**30, 3) for b, v in rung_bytes.items()})}"

@@ -1,5 +1,5 @@
 """From a profiler trace (``.xplane.pb``) to busy/idle, step, kernel and
-collective times.
+collective times, and to the step's device time by layer and phase.
 
 Two stages, so that the arithmetic can be checked without a chip:
 ``load`` turns the file into plain Python (planes -> lines -> events),
@@ -14,9 +14,11 @@ which ``load`` keeps the collectives), and
 ``XLA Ops`` one event per HLO op executed by the core, named by the
 whole HLO instruction (``%mlp.36 = (bf16[50432,768]{...}, ...)
 custom-call(...), custom_call_target="tpu_custom_call", ...``): ``load``
-keeps its name, opcode and output shape. A Pallas kernel is a
+keeps its name, opcode and output shape, and joins in the scope path
+that the profile does not keep (``lib/scopes.py``). A Pallas kernel is a
 ``custom-call`` whose target is ``tpu_custom_call`` (other custom calls
-are XLA's own). The host's threads are not read: the benchmark captures
+are XLA's own); its ``kernel`` is the name the program gave it. The
+host's threads are not read: the benchmark captures
 the device alone and adds its own host spans as a ``/host:CPU`` plane,
 set on the trace's clock by an anchor program (``harness.Capture``).
 """
@@ -24,10 +26,13 @@ set on the trace's clock by an anchor program (``harness.Capture``).
 from __future__ import annotations
 
 import bisect
+import functools
 import gzip
 import json
 import re
 from pathlib import Path
+
+from . import kernels, scopes
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 MODULE_LINE = "XLA Modules"
@@ -36,10 +41,8 @@ ASYNC_LINE = "Async XLA Ops"    # start-to-done spans of asynchronous ops
 HOST_PLANE = "/host:CPU"
 
 # HLO opcodes of cross-chip collectives (with their async halves).
-_COLLECTIVE = re.compile(
-    r"^(all-reduce|all-gather|reduce-scatter|all-to-all|"
-    r"collective-permute|collective-broadcast|ragged-all-to-all)"
-    r"(-start|-done)?$")
+_COLLECTIVE = scopes._COLLECTIVE
+PHASES = ("forward", "backward", "recompute", "optimizer")
 _LAYOUT = re.compile(r"\{[^{}]*\}|/\*.*?\*/")
 
 
@@ -72,10 +75,17 @@ def find_xplane(trace_dir) -> Path:
     return files[-1]
 
 
-def load(path) -> dict:
+def load(path, scopes_by_name=None) -> dict:
     """Plain-Python view of an xplane file: of every device plane the
-    module line, the op line (parsed) and the collectives in flight."""
+    module line, the op line (parsed) and the collectives in flight.
+    ``scopes_by_name`` is ``{instruction: op_name}`` of the traced
+    program (``scopes.parse_scopes(compiled.as_text())["scopes"]``):
+    every op gets its ``scope`` from it, and a Mosaic call its
+    ``kernel``. Without it an op has no scope (its layer is ``other``)
+    and a kernel is named by its instruction."""
     from jax.profiler import ProfileData
+
+    scopes_by_name = scopes_by_name or {}
 
     path = Path(path)
     if path.suffix == ".gz":
@@ -103,6 +113,9 @@ def load(path) -> dict:
                     if line.name == ASYNC_LINE and \
                             op_class(row) != "collective":
                         continue        # copies and slices in flight
+                    row["scope"] = scopes_by_name.get(row["name"], "")
+                    if row["mosaic"]:
+                        row["kernel"] = scopes.kernel_name(row)
                 events.append(row)
             if events:
                 lines.append({"name": line.name, "events": events})
@@ -224,6 +237,50 @@ def _line(plane, name):
     return []
 
 
+@functools.lru_cache(maxsize=None)
+def _row_of(scope: str, op: str, name: str, kernel: str) -> tuple:
+    """``(layer, phase)`` of an op: ``scopes.classify``, asked once for
+    the instruction and not once for each step and chip it ran in. Only
+    an MLP half-block kernel is a layer of its own."""
+    return scopes.classify(
+        scope, op=op, name=name,
+        kernel=kernel if kernel in kernels.MLP_KERNELS else "")
+
+
+def _add(sums: dict, key, i: int, n: int, ns: int) -> None:
+    """``sums[key][i] += ns``, where ``sums[key]`` has one slot for each
+    of the ``n`` steps (a step without the key counts as 0)."""
+    sums.setdefault(key, [0] * n)[i] += ns
+
+
+def _mean_of_dicts(dicts) -> dict:
+    """Per key the mean over ``dicts`` (the chips), a chip without the
+    key counting as 0."""
+    return {k: sum(d.get(k, 0.0) for d in dicts) / len(dicts)
+            for k in sorted(set().union(*dicts))}
+
+
+def layer_ms(rows_ms: dict, *layers) -> float:
+    """Milliseconds per step in ``layers``, every phase, of a ``rows_ms``
+    table; a layer that no op ran under counts as 0."""
+    return sum(sum(rows_ms.get(layer, {}).values()) for layer in layers)
+
+
+def format_rows(reduced: dict) -> str:
+    """One line: each layer's milliseconds per step by phase, largest
+    first, and their sum against the step's busy time."""
+    rows = reduced.get("rows_ms") or {}
+    parts = []
+    for layer in sorted(rows, key=lambda k: -layer_ms(rows, k)):
+        by = " ".join(f"{p[:3]} {rows[layer][p]:.3f}" for p in PHASES
+                      if p in rows[layer])
+        parts.append(f"{layer} {layer_ms(rows, layer):.3f} ({by})")
+    total = layer_ms(rows, *rows)
+    return ("device ms per step by layer (phase): " + " | ".join(parts)
+            + f" | rows' sum {total:.3f} of busy "
+            f"{reduced.get('busy_ms', 0.0):.3f} ms")
+
+
 def _default_window(per_chip, all_iv, module_prefix):
     """From the start of the second execution of the step program to the
     end of the last, on the chip where that is widest (the first may
@@ -251,13 +308,21 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
     line (``jit_train_step``); its executions delimit the steps. Per
     chip, over the complete executions: the median duration, and per
     execution the op time inside it by class (Mosaic calls also by
-    their result type, so that a metric can pick one kernel's), and the
-    part of the collectives (their ops, and their start-to-done spans
-    where the trace has them) during which no other op runs on that
-    chip. Busy time is the union of all op intervals (modules where a
-    plane has no op line) inside the traced window, which is
-    ``window_ns`` or else ``_default_window``. Values are means over
-    chips of per-chip medians."""
+    their kernel's name), the part of the collectives (their ops, and
+    their start-to-done spans where the trace has them) during which no
+    other op runs on that chip, and ``rows_ms[layer][phase]``: every
+    op's own duration under the layer and phase of its scope
+    (``scopes.classify``). One rule holds for every kernel, present or
+    to come: an MLP half-block kernel (by name, ``kernels.MLP_KERNELS``)
+    is a row of its own; **every other op, XLA or Mosaic, counts under
+    the layer its scope names**, so ``attn_core`` is whatever ran under
+    that scope. The collectives' exposed part is the row ``collective``,
+    so that the rows of a step sum to its ``busy_ms`` (to within ops
+    that overlap). ``xla_by_phase_ms`` is the same sum over the ops that
+    are neither Mosaic calls nor collectives. Busy time is the union of
+    all op intervals (modules where a plane has no op line) inside the
+    traced window, which is ``window_ns`` or else ``_default_window``.
+    Values are means over chips of per-chip medians."""
     devs = [p for p in trace["planes"] if DEVICE_PLANE.match(p["name"])]
     if not devs:
         return {"chips": 0, "busy_s": 0.0, "window_s": 0.0,
@@ -292,37 +357,49 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
             row["step_ms"] = _median(m["dur_ns"] for m in steps) / 1e6
             acc = {"mosaic": [], "collective": [], "xla": [],
                    "collective_exposed": [], "busy": [], "mosaic_calls": []}
-            kernels = []        # per step: {result type: ns in Mosaic calls}
+            # Per key a list with one sum (ns) per step.
+            rows, by_kernel, xla_phase = {}, {}, {}
             ops_sorted = sorted(chip["ops"] + chip["async"],
                                 key=lambda e: e["start_ns"])
             starts = [e["start_ns"] for e in ops_sorted]
-            for m in steps:
+            for i, m in enumerate(steps):
                 s0, s1 = _iv(m)
                 a = bisect.bisect_left(starts, s0)
                 b = bisect.bisect_left(starts, s1)
-                inside = ops_sorted[a:b]
                 by = {"mosaic": [], "collective": [], "xla": []}
-                kernels.append({})
-                for e in inside:
-                    by[op_class(e)].append(_iv(e))
-                    if e.get("mosaic"):
-                        kernels[-1][e["out"]] = kernels[-1].get(
-                            e["out"], 0) + e["dur_ns"]
+                for e in ops_sorted[a:b]:
+                    cls = op_class(e)
+                    by[cls].append(_iv(e))
+                    if cls == "collective":
+                        continue
+                    kernel = e["kernel"] if cls == "mosaic" else ""
+                    key = _row_of(e.get("scope", ""), e.get("op", ""),
+                                  e["name"], kernel)
+                    _add(rows, key, i, len(steps), e["dur_ns"])
+                    if cls == "mosaic":
+                        _add(by_kernel, kernel, i, len(steps), e["dur_ns"])
+                    else:
+                        _add(xla_phase, key[1], i, len(steps), e["dur_ns"])
                 compute = by["mosaic"] + by["xla"]
                 coll = union_ns(by["collective"])
+                exposed = coll - overlap_ns(by["collective"], compute)
+                if by["collective"]:
+                    _add(rows, ("collective", "forward"), i, len(steps),
+                         exposed)
                 acc["mosaic"].append(sum(e - s for s, e in by["mosaic"]))
                 acc["mosaic_calls"].append(len(by["mosaic"]))
                 acc["xla"].append(union_ns(by["xla"]))
                 acc["collective"].append(coll)
-                acc["collective_exposed"].append(
-                    coll - overlap_ns(by["collective"], compute))
+                acc["collective_exposed"].append(exposed)
                 acc["busy"].append(union_ns(compute + by["collective"]))
             for k, v in acc.items():
                 key = k if k == "mosaic_calls" else f"{k}_ms"
                 row[key] = _median(v) / (1 if k == "mosaic_calls" else 1e6)
-            row["mosaic_by_out_ms"] = {
-                out: _median(k.get(out, 0) for k in kernels) / 1e6
-                for out in sorted(set().union(*kernels))}
+            row["rows_ms"] = {k: _median(v) / 1e6 for k, v in rows.items()}
+            row["mosaic_by_kernel_ms"] = {
+                k: _median(v) / 1e6 for k, v in by_kernel.items()}
+            row["xla_by_phase_ms"] = {
+                k: _median(v) / 1e6 for k, v in xla_phase.items()}
         for e in chip["ops"]:
             if lo <= e["start_ns"] < hi:
                 label = op_label(e)
@@ -336,11 +413,13 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
         vals = [r[key] for r in out["per_chip"] if key in r]
         if vals:
             out[key] = sum(vals) / len(vals)
-    by_out = [r["mosaic_by_out_ms"] for r in out["per_chip"]
-              if "mosaic_by_out_ms" in r]
-    out["mosaic_by_out_ms"] = {
-        k: sum(c.get(k, 0.0) for c in by_out) / len(by_out)
-        for k in sorted(set().union(*by_out))}
+    stepped = [r for r in out["per_chip"] if "rows_ms" in r]
+    for key in ("mosaic_by_kernel_ms", "xla_by_phase_ms"):
+        out[key] = _mean_of_dicts([r[key] for r in stepped])
+    out["rows_ms"] = {}
+    for (layer, phase), ms in _mean_of_dicts(
+            [r["rows_ms"] for r in stepped]).items():
+        out["rows_ms"].setdefault(layer, {})[phase] = ms
     out["steps"] = min((r["steps"] for r in out["per_chip"]), default=0)
     # Top device ops by total time, summed over chips then averaged.
     out["device_ops"] = [[k, v / 1e9 / n] for k, v in sorted(

@@ -1,8 +1,9 @@
 """Device time per step in the fused MLP half-block kernel (forward and
-backward of each layer): the Mosaic custom calls whose result type is
-the kernel's (``lib/kernels.py::is_mlp_half_block``). At T=197 that is
-every Mosaic call of the step; a flash-attention kernel is not counted
-here."""
+backward of each layer): the Mosaic calls whose kernel the program names
+``lnmlp_fwd`` / ``lnmlp_bwd`` / ``mlp_fwd`` / ``mlp_bwd``
+(``lib/kernels.py::MLP_KERNELS``). Another kernel (flash attention, a
+kernel to come) is not counted here: its time is under the layer its
+scope names (``attn_core_ms`` for one under ``attn_core``)."""
 from benchmark.lib import kernels
 from benchmark.metrics._common import train_trace
 
@@ -12,13 +13,13 @@ LAYER, MOVES = "MLP half-block kernel", "train_img_s"
 
 
 def read(obs):
-    by_out = train_trace(obs, "mosaic_by_out_ms")
-    if not by_out:
+    by_kernel = train_trace(obs, "mosaic_by_kernel_ms")
+    if not by_kernel:
         return None
-    mine = {out: ms for out, ms in by_out.items()
-            if kernels.is_mlp_half_block(out, obs["model"]["mlp_size"])}
-    if len(mine) < len(by_out):
-        print(f"[mlp_kernel_ms] other Mosaic kernels, not counted here: "
-              f"{({o: v for o, v in by_out.items() if o not in mine})}",
-              flush=True)
-    return sum(mine.values()) or None
+    others = {k: ms for k, ms in by_kernel.items()
+              if k not in kernels.MLP_KERNELS}
+    if others:
+        print(f"[mlp_kernel_ms] other Mosaic kernels, counted under their "
+              f"scope's layer and not here: {others}", flush=True)
+    return sum(ms for k, ms in by_kernel.items()
+               if k in kernels.MLP_KERNELS) or None

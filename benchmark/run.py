@@ -23,6 +23,7 @@ import importlib
 import json
 import os
 import sys
+import time
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -69,14 +70,20 @@ def cell_metrics(cell: str, kind: str) -> dict:
 
 def reduce_capture(obs: dict, dump_to=None, dump_steps=0) -> None:
     """Trace directory -> ``obs["trace"]`` (numbers) and the breakdown;
-    the trace itself is deleted."""
-    from benchmark.lib import xplane
+    the trace itself is deleted. ``obs["hlo_text"]``, where a driver
+    gives it, is the optimized HLO of the traced program: the profile
+    keeps no scope path, so each op's is joined in from there."""
+    from benchmark.lib import scopes, xplane
 
     capture = obs.pop("capture", None)
+    hlo_text = obs.pop("hlo_text", None)
     if capture is None or not capture.started:
         return
+    t0 = time.perf_counter()
     try:
-        trace = xplane.load(xplane.find_xplane(capture.dir))
+        by_name = scopes.parse_scopes(hlo_text)["scopes"] if hlo_text \
+            else None
+        trace = xplane.load(xplane.find_xplane(capture.dir), by_name)
         anchor = xplane.module_end_ns(trace, f"jit_{capture.ANCHOR}")
         if anchor is not None:
             trace["planes"].append(capture.host_plane(anchor))
@@ -89,6 +96,12 @@ def reduce_capture(obs: dict, dump_to=None, dump_steps=0) -> None:
             trace, module_prefix=obs.get("module_prefix", ""))
         obs["trace"]["idle_gaps"] = xplane.attribute_gaps(
             obs["trace"].pop("gaps"), xplane.host_spans(trace))
+        if obs["trace"].get("rows_ms"):
+            print("[rows] " + xplane.format_rows(obs["trace"]), flush=True)
+        if obs["trace"]["chips"]:       # a device's trace: not on the CPU
+            print(f"[trace] read in {time.perf_counter() - t0:.1f} s after "
+                  f"the window, scopes of {len(by_name or ())} instructions "
+                  "joined in", flush=True)
     finally:
         capture.cleanup()
 

@@ -1,10 +1,13 @@
-"""The copied arithmetic equals the program's today, and the schedule is
-a pure function of its parameters and seed."""
+"""The copied arithmetic and the copied reading of a scope path equal
+the program's today, and the schedule is a pure function of its
+parameters and seed."""
 
 import numpy as np
 import pytest
 
-from benchmark.lib import flops, harness, kernels, schedule
+from benchmark.lib import flops, harness, kernels, schedule, scopes, xplane
+
+FIXTURES = harness.BENCH / "fixtures"
 
 
 @pytest.mark.parametrize("name,preset,gflop", [
@@ -75,3 +78,98 @@ def test_mlp_kernel_cost_by_hand():
     assert least["seconds"] == pytest.approx(cost["flops"] / 197e12)
     fwd = kernels.mlp_half_block_cost(n, d, m, layers=12, backward=False)
     assert fwd["flops"] * 3 == cost["flops"]
+
+
+# ------------------------------------- lib/scopes.py = telemetry/device_trace
+BLOCK = "jit(train_step)/jvp(ViT)/backbone/encoder_block_3"
+BACK = "jit(train_step)/transpose(jvp(ViT))/backbone/encoder_block_3"
+PATHS = [
+    "jit(train_step)/jvp(ViT)/backbone/patch_embedding/patch_conv/conv",
+    f"{BLOCK}/msa/norm/reduce_sum", f"{BACK}/msa/qkv/dot_general",
+    f"{BLOCK}/msa/attn_core/bqhd,bkhd->bhqk/dot_general",
+    f"{BACK}/msa/attn_core/flash_bwd_dq/pallas_call",
+    f"{BACK}/msa/out/dot_general", f"{BLOCK}/msa/squeeze", f"{BLOCK}/add",
+    f"{BLOCK}/mlp/norm/reduce_sum", f"{BACK}/mlp/lnmlp_bwd/pallas_call",
+    "jit(train_step)/jvp(ViT)/backbone/encoder_norm/mul",
+    "jit(train_step)/transpose(jvp(ViT))/head/dot_general",
+    "jit(train_step)/jvp(ViT)/slice",
+    "jit(train_step)/jvp(loss)/jit(take_along_axis)/gather",
+    "jit(train_step)/metrics/reduce_sum",
+    "jit(train_step)/optimizer/jit(clip)/max",
+    "jit(train_step)/transpose(jvp(ViT))/backbone/jvp(ViT)/backbone/"
+    "checkpoint/rematted_computation/encoder_block_1/msa/attn_core/exp",
+    f"{BACK}/msa/out/reshape;{BACK}/mlp/reshape",
+    "jit(train_step)/jit(_threefry_fold_in)/slice", ""]
+
+
+def test_layers_and_the_phase_rule_equal_the_programs():
+    from pytorch_vit_paper_replication_tpu.telemetry import device_trace
+
+    assert [(n, p.pattern) for n, p in scopes.LAYERS] == [
+        (n, p.pattern) for n, p in device_trace.LAYERS]
+    assert xplane.PHASES == device_trace.PHASES
+    for path in PATHS:
+        for extra in ({}, {"kernel": "lnmlp_bwd"}, {"op": "all-reduce-start"},
+                      {"name": "fusion.84.remat"}, {"by_block": True}):
+            assert scopes.classify(path, **extra) == \
+                device_trace.classify(path, **extra), (path, extra)
+        row = {"name": "lnmlp_fwd.2", "scope": path}
+        assert scopes.kernel_name(row) == device_trace.kernel_name(row)
+    # one example of each, so that two equal wrongs do not pass
+    assert scopes.classify(PATHS[4]) == ("attn_core", "backward")
+    assert scopes.classify(PATHS[9], kernel="lnmlp_bwd") == (
+        "lnmlp_bwd", "backward")
+    assert scopes.classify(PATHS[-4]) == ("attn_core", "recompute")
+
+
+def test_parse_scopes_equals_the_programs():
+    from pytorch_vit_paper_replication_tpu.telemetry import device_trace
+
+    mlp = "jit(train_step)/jvp(ViT)/backbone/encoder_block_0/mlp/" \
+        "lnmlp_fwd/pallas_call"
+    hlo = "\n".join([
+        "HloModule jit_train_step, is_scheduled=true",
+        "ENTRY %main {",
+        "  %copy-start.1 = (bf16[8]{0}, bf16[8]{0}) copy-start(%p.0)",
+        "  %copy-done.1 = bf16[8]{0} copy-done(%copy-start.1)",
+        "  %lnmlp_fwd.2 = bf16[8]{0} custom-call(%copy-done.1), "
+        'custom_call_target="tpu_custom_call", frontend_attributes='
+        '{kernel_metadata={}}, metadata={op_name="' + mlp + '" '
+        "stack_frame_id=7}",
+        "  %slice-start.3 = bf16[4]{0} slice-start(%lnmlp_fwd.2)",
+        "  ROOT %fusion.3 = f32[] fusion(%lnmlp_fwd.2), kind=kLoop, "
+        'metadata={op_name="jit(train_step)/optimizer/add"}',
+        "}"])
+    got = scopes.parse_scopes(hlo)
+    assert got == device_trace.parse_scopes(hlo)
+    assert got["module"] == "jit_train_step"
+    # an instruction of the compiler's own takes its consumer's path,
+    # else (nothing uses it) its operand's
+    assert got["scopes"] == {
+        "lnmlp_fwd.2": mlp, "fusion.3": "jit(train_step)/optimizer/add",
+        "copy-done.1": mlp, "copy-start.1": mlp, "slice-start.3": mlp}
+
+
+@pytest.mark.parametrize("fixture", [
+    "train_step_b16_scoped.events.json.gz",
+    "train_step_b16_dp4_scoped.events.json.gz"])
+def test_the_two_readers_agree_row_by_row_on_the_recorded_steps(fixture):
+    """The benchmark's ``rows_ms`` is the trainer's table on the same
+    recorded steps, row by row. The trainer's reader makes a row of every
+    Mosaic kernel; the benchmark's only of the MLP kernels, which are the
+    only kernels of these steps."""
+    from pytorch_vit_paper_replication_tpu.telemetry import device_trace
+
+    trace = xplane.load_events_json(FIXTURES / fixture)
+    mine = xplane.reduce_trace(trace, module_prefix="jit_train_step")
+    theirs = device_trace.reduce(trace)
+    assert theirs["steps"] == mine["steps"] >= 3
+    table = {(r["layer"], r["phase"]): r["ms"] for r in theirs["rows"]}
+    rows = {(layer, phase): ms for layer, by in mine["rows_ms"].items()
+            for phase, ms in by.items()}
+    assert set(rows) == set(table)
+    for key, ms in table.items():
+        assert rows[key] == pytest.approx(ms, rel=1e-9, abs=1e-9), key
+    for key in ("step_ms", "busy_ms", "mosaic_ms", "xla_ms",
+                "collective_ms", "collective_exposed_ms"):
+        assert mine[key] == pytest.approx(theirs[key], rel=1e-6), key

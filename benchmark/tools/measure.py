@@ -4,9 +4,11 @@
         --seconds 10 b16_train:6 b16_train:6 l16_train:t
 
 Each positional is ``<cell>:<n>`` (a set of n untraced runs, each with
-another seed) or ``<cell>:t`` (one traced run). Sets of one cell are
-reported apart, as the driver measures two sets. Per set and metric:
-median, quartiles and the spread (distance between the quartiles over
+another seed: ``--first-seed`` + 1 ... + n, the same seeds in every set,
+as the driver measures two sets) or ``<cell>:t`` (one traced run). Sets
+of one cell are reported apart. Per set and metric:
+median, quartiles (``statistics.quantiles(values, n=4)``, as the
+contract takes them) and the spread (distance between the quartiles over
 the median). This process never touches jax, so each child gets the
 chip. Every result line and the tail of each child's output land in
 ``--out``. It is no part of a run.
@@ -29,7 +31,7 @@ def quartiles(xs):
     xs = sorted(xs)
     if len(xs) < 2:
         return xs[0], xs[0], xs[0]
-    q = statistics.quantiles(xs, n=4, method="inclusive")
+    q = statistics.quantiles(xs, n=4)    # as the contract's spread is taken
     return q[0], q[1], q[2]
 
 
@@ -43,12 +45,12 @@ def main() -> int:
     args = ap.parse_args()
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
-    seed = args.first_seed
     log = open(out / "results.jsonl", "a")
     for k, spec in enumerate(args.sets):
         cell, n = spec.split(":")
         traced = n == "t"
         rows = []
+        seed = args.first_seed
         for _ in range(1 if traced else int(n)):
             seed += 1
             cmd = [sys.executable, "benchmark/run.py", "--workload", cell,
@@ -63,7 +65,7 @@ def main() -> int:
             wall = time.time() - t0
             lines = [ln for ln in proc.stdout.splitlines()
                      if not ln.startswith(("E0", "W0", "I0"))]
-            (out / f"{cell}.{seed}.out").write_text(
+            (out / f"{cell}.set{k}.{seed}.out").write_text(
                 "\n".join(lines[-40:]) + "\n--- stderr tail ---\n"
                 + proc.stderr[-4000:])
             row = {"cell": cell, "set": k, "seed": seed, "traced": traced,
