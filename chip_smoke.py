@@ -59,7 +59,9 @@ PKG = "pytorch_vit_paper_replication_tpu"
 PY = sys.executable
 
 PRESET = "ViT-B/16"
-LAYERS = 12              # B/16: one fwd + one bwd Mosaic call per layer
+LAYERS = 12              # B/16: per layer a fwd + a bwd Mosaic call of the
+#                          MLP half-block and of the attention core
+WIDTH = 768
 TOKENS = 197             # 224 px / 16 + CLS
 PER_CHIP = 256           # images per chip per step, the headline batch
 # 342 x 3 classes = 1026 train images: 4 steps an epoch at 256, exactly
@@ -176,14 +178,19 @@ def check_train_output(out: str, *, chips: int, per_chip: int) -> None:
     check(int(m.group(1)) == chips and m.group(2) == "tpu",
           f"trained on data={m.group(1)} platform={m.group(2)}, wanted "
           f"data={chips} on tpu")
-    m = find(r"^train step: (\d+) Mosaic kernel calls, operand rows "
-             r"\[([\d, ]*)\]", out, "Mosaic call report")
-    check(int(m.group(1)) == 2 * LAYERS,
-          f"{m.group(1)} Mosaic calls in the lowered step, wanted "
-          f"{2 * LAYERS} (a fused fwd + bwd kernel per layer)")
-    check(m.group(2) == str(per_chip * TOKENS),
-          f"kernel operand rows [{m.group(2)}], wanted the per-shard "
-          f"{per_chip * TOKENS} = {per_chip} images x {TOKENS} tokens")
+    m = find(r"^train step: (\d+) Mosaic kernel calls: (.*)$", out,
+             "Mosaic call report")
+    rows = [per_chip * TOKENS, WIDTH]
+    packed = [per_chip, TOKENS, 3 * WIDTH]
+    wanted = ", ".join(f"{name} x{LAYERS} {shape}" for name, shape in [
+        ("attn_short_bwd", packed), ("attn_short_fwd", packed),
+        ("lnmlp_bwd", rows), ("lnmlp_fwd", rows)])
+    check(int(m.group(1)) == 4 * LAYERS and m.group(2) == wanted,
+          f"Mosaic calls in the lowered step: {m.group(1)}: {m.group(2)}; "
+          f"wanted {4 * LAYERS}: {wanted} (per layer the fused MLP "
+          f"half-block forward and backward over the per-shard {rows[0]} "
+          f"= {per_chip} images x {TOKENS} tokens, and the attention "
+          "pair on the per-shard packed qkv projection)")
     mem = find(r"^device memory in use: ([\d. ]+) GiB", out,
                "device memory report").group(1).split()
     check(len(mem) == chips and all(float(g) > 0.5 for g in mem),

@@ -45,9 +45,15 @@ class ViTConfig:
     # Compute dtype for activations; params are kept in float32. bfloat16 is
     # native on the MXU and halves HBM traffic for activations.
     dtype: str = "bfloat16"
-    # "xla" = jax.nn.dot_product_attention (XLA fuses well at seq len 197);
+    # "xla" = the hand-rolled einsum attention of ops/attention.py, with
+    # the [B,H,T,T] probabilities materialized;
     # "flash" = the Pallas flash-attention kernel in ops/flash_attention.py;
-    # "auto" = flash on TPU when the sequence is long enough to pay off.
+    # "auto" = on a TPU the short-sequence kernel pair of
+    # ops/short_attention.py where the call allows it (no mask, no active
+    # attention dropout, bf16 probability storage, head size 64 or 128, a
+    # [T,T] tile that fits VMEM: T = 197 does, T = 577 does not), flash
+    # where the materialized logits would not fit HBM, xla for the rest and
+    # off the TPU.
     attention_impl: str = "auto"
     # MLP-block execution path: "xla" = two nn.Dense GEMMs with the hidden
     # activation materialized between them; "fused" = the Pallas fused

@@ -24,6 +24,7 @@ returned as the same ``{"train_loss": [...], ...}`` dict shape.
 from __future__ import annotations
 
 import time
+from collections import Counter
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 import flax.struct
@@ -302,11 +303,12 @@ def _report_first_step(train_step, state, batch, stats) -> None:
     boundary actually did, printed instead of trusted. The persistent
     cache's hit/miss counts and what the first calls cost by stage (the
     split of ``time_to_first_step``); on a TPU the Mosaic calls found in the
-    lowered step with their per-shard operand rows (the kernel dispatch
-    reads ``jax.default_backend()`` and falls back silently) — costs one
-    more lowering, so it is skipped elsewhere, where there is no Mosaic
-    to find; and the memory each local device holds, where the backend
-    reports it."""
+    lowered step, by kernel name with the per-shard operand's shape (the
+    MLP kernels' rows, the attention pair's packed qkv projection; the
+    kernel dispatch reads ``jax.default_backend()`` and the call's shapes
+    and falls back silently) — costs one more lowering, so it is skipped
+    elsewhere, where there is no Mosaic to find; and the memory each
+    local device holds, where the backend reports it."""
     from .ops.partition import mosaic_calls
 
     cache = stats.snapshot()
@@ -318,9 +320,11 @@ def _report_first_step(train_step, state, batch, stats) -> None:
                  f"cache read): {stats.programs_line(top=1)}")
     if jax.default_backend() == "tpu" and hasattr(train_step, "lower"):
         calls = mosaic_calls(train_step.lower(state, batch).as_text())
-        rows = sorted({shape[0] for _, shape in calls})
-        lines.append(f"train step: {len(calls)} Mosaic kernel calls, "
-                     f"operand rows {rows}")
+        by_kernel = ", ".join(
+            f"{name} x{n} {list(shape)}"
+            for (name, shape), n in sorted(Counter(calls).items()))
+        lines.append(f"train step: {len(calls)} Mosaic kernel calls: "
+                     f"{by_kernel}")
     stats = [d.memory_stats() or {} for d in jax.local_devices()]
     if all("bytes_in_use" in s for s in stats):
         # (peak_bytes_in_use adds nothing here: on the v5e it reads the

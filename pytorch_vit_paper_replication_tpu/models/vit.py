@@ -24,8 +24,9 @@ Differences, all deliberate and TPU-motivated:
   reference uses ``torch.rand`` uniform-[0,1) for both
   (``models/vit.py:35-42``), a known deviation from the paper that SURVEY.md
   §2.2 flags as not worth copying.
-* The attention core is :func:`..ops.attention.dot_product_attention`
-  (XLA-fused or Pallas flash), never a materialized ``[B,H,T,T]`` matrix.
+* The attention core is :func:`..ops.attention.self_attention`, handed
+  the packed qkv projection: the short-sequence Pallas kernel, XLA-fused
+  or Pallas flash, by what the call allows.
 * The encoder stack can be rematerialized (``config.remat``) to trade FLOPs
   for HBM on large configs.
 * Dropout draws uint8 threshold masks (:mod:`..ops.dropout`) instead of
@@ -39,15 +40,17 @@ ViT-B/16, reference main notebook cell 80) is asserted in
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
+from typing import Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..configs import ViTConfig
 from ..ops import partition
-from ..ops.attention import dot_product_attention
+from ..ops.attention import self_attention, short_attention_ok
 from ..ops.dropout import Dropout
 
 
@@ -147,40 +150,104 @@ class MultiHeadSelfAttentionBlock(nn.Module):
         # accumulation and there is no [N, mlp]-sized intermediate to
         # eliminate on this side. See PERF.md round-4 negative results.
         y = nn.LayerNorm(epsilon=cfg.ln_epsilon, dtype=_dtype(cfg), name="norm")(x)
+        dropout_rng = None
+        if train and cfg.attn_dropout > 0.0:
+            dropout_rng = self.make_rng("dropout")
+        dispatch = dict(
+            impl=cfg.attention_impl,
+            dropout_rate=cfg.attn_dropout,
+            deterministic=not train,
+            probs_dtype=cfg.attention_probs_dtype,
+            residual_dtype=cfg.attention_probs_residual_dtype,
+        )
+        heads = (cfg.num_heads, cfg.head_dim)
+        # Where the short-sequence kernel will serve the call, the
+        # projections are taken flat, so that the compiler lays their
+        # results out as the kernel reads them (_FlatDenseGeneral).
+        if _flat_projections(y.shape[:2] + (3,) + heads, _dtype(cfg),
+                             **dispatch):
+            dense = _FlatDenseGeneral
+        else:
+            dense = functools.partial(nn.DenseGeneral,
+                                      param_dtype=jnp.float32)
         # Under manual TP the caller passes a head-LOCAL config (flax
         # validates stored params against the declared features, so
         # num_heads here must equal the params' local head count — see
         # parallel/pipeline.py's block_cfg).
-        qkv = nn.DenseGeneral(
-            features=(3, cfg.num_heads, cfg.head_dim),
-            axis=-1, dtype=_dtype(cfg), param_dtype=jnp.float32,
-            name="qkv",
-        )(y)                                    # [B, T, 3, H(_local), Dh]
-        q, k, v = (qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
-        dropout_rng = None
-        if train and cfg.attn_dropout > 0.0:
-            dropout_rng = self.make_rng("dropout")
-        attn = dot_product_attention(
-            q, k, v,
-            impl=cfg.attention_impl,
-            dropout_rate=cfg.attn_dropout,
+        qkv = dense(features=(3,) + heads, axis=-1, dtype=_dtype(cfg),
+                    name="qkv")(y)              # [B, T, 3, H(_local), Dh]
+        # The projection goes to the dispatch packed: the short-sequence
+        # kernel reads it where it lies; every other path slices q, k, v.
+        attn = self_attention(
+            qkv,
             dropout_rng=dropout_rng,
-            deterministic=not train,
             # Manual TP hands this module a head-LOCAL config: tell the
             # dispatcher so its Ulysses divisibility pre-check doesn't
             # divide the already-local head count again (ADVICE r4).
             heads_already_local=self.tp_axis is not None,
             softmax=cfg.attention_softmax,
-            probs_dtype=cfg.attention_probs_dtype,
-            residual_dtype=cfg.attention_probs_residual_dtype,
+            **dispatch,
         )                                        # [B, T, H(_local), Dh]
-        out = nn.DenseGeneral(
-            features=cfg.embedding_dim, axis=(-2, -1),
-            dtype=_dtype(cfg), param_dtype=jnp.float32, name="out",
-        )(attn)
+        out = dense(features=cfg.embedding_dim, axis=(-2, -1),
+                    dtype=_dtype(cfg), name="out")(attn)
         if self.tp_axis is not None:
             out = jax.lax.psum(out, self.tp_axis)
         return out
+
+
+def _flat_projections(qkv_shape, dtype, **dispatch) -> bool:
+    """Whether this block's projections are taken over flattened feature
+    dims (:class:`_FlatDenseGeneral`): where the short-sequence kernel
+    serves the call, and no mesh axis splits the heads (a head-sharded
+    ``[D, 3, H, Dh]`` kernel has no flat ``[D, 3*D]`` sharding; there
+    the kernel still runs, per shard, on the 5-D projection)."""
+    if not short_attention_ok(qkv_shape, dtype, mask=None, **dispatch):
+        return False
+    part = partition.current()
+    return part is None or part.size(part.model_axis) == 1
+
+
+class _FlatDenseGeneral(nn.Module):
+    """``nn.DenseGeneral`` with the same parameters (names, shapes, the
+    flat-shape initialisation) and the same product, taken as ONE 2-D
+    GEMM over flattened input and output feature dims, the bias added to
+    the flat result.
+
+    Why it exists: with ``nn.DenseGeneral`` XLA:TPU sees the projection's
+    result as ``[B, T, 3, H, Dh]`` — minor dimension 64, half a lane
+    tile — and lays it out batch-minor (B/16) or token-minor (L/16). The
+    attention kernel reads ``[B, T, 3*D]`` row-major, so every layer paid
+    four transposing copies (qkv, o, do, dqkv: 1.5 GB a layer on B/16).
+    Given a flat GEMM whose result goes straight to the kernel, the
+    compiler emits the kernel's layout from the GEMM itself, and reads
+    the kernel's results the same way (compiled for the v5e in
+    ``tests/test_v5e_compile.py``; PERF.md, PR 26)."""
+
+    features: Union[int, Tuple[int, ...]]
+    axis: Union[int, Tuple[int, ...]] = -1    # trailing axes of the input
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        out_shape = tuple(np.atleast_1d(self.features).tolist())
+        n_in = np.atleast_1d(self.axis).size
+        lead, in_shape = x.shape[:x.ndim - n_in], x.shape[x.ndim - n_in:]
+        flat = (int(np.prod(in_shape)), int(np.prod(out_shape)))
+
+        def kernel_init(rng, shape, dtype=jnp.float32):
+            # nn.DenseGeneral's: initialised flat, so that fan-in and
+            # fan-out are the GEMM's
+            return nn.initializers.lecun_normal()(
+                rng, flat, dtype).reshape(shape)
+
+        kernel = self.param("kernel", kernel_init, in_shape + out_shape,
+                            jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, out_shape,
+                          jnp.float32)
+        y = jnp.dot(x.reshape(lead + flat[:1]).astype(self.dtype),
+                    kernel.reshape(flat).astype(self.dtype))
+        y = y + bias.reshape(flat[1:]).astype(self.dtype)
+        return y.reshape(lead + out_shape)
 
 
 class _DenseParams(nn.Module):

@@ -1,4 +1,4 @@
-from .attention import dot_product_attention
+from .attention import dot_product_attention, self_attention
 from .dropout import Dropout, dropout, quantized_rate
 from .flash_attention import flash_attention
 from .fused_mlp import fused_ln_mlp_residual, fused_mlp
@@ -8,4 +8,4 @@ from .quant import PROBS_DTYPES, dequantize_probs, quantize_probs
 __all__ = ["Dropout", "PROBS_DTYPES", "dequantize_probs",
            "dot_product_attention", "dropout", "flash_attention",
            "fused_ln_mlp_residual", "fused_mlp", "on_mesh",
-           "quantize_probs", "quantized_rate"]
+           "quantize_probs", "quantized_rate", "self_attention"]

@@ -17,10 +17,21 @@ function so the execution path can be swapped without touching model code:
                  online-softmax accumulator. O(T) memory: the only path
                  that runs when the ``[B,H,T,T]`` logits cannot fit
                  (t=8192 at B=8,H=12 OOMs the XLA path on 16 GB).
-* ``"auto"``   — xla unless the materialized logits would eat a large
-                 fraction of HBM (``_FLASH_MEMORY_BYTES``), then flash.
-                 Memory-based, not length-based: speed never favors the
-                 kernel on this hardware, only memory does.
+* ``"auto"``   — from what the call can observe. A self-attention
+                 call on the packed qkv projection
+                 (:func:`self_attention`) on a TPU with no mask, no
+                 active attention dropout, bf16 probability storage, a
+                 head size of 64 or 128 and a ``[T, T]`` tile that fits
+                 VMEM takes the short-sequence kernel pair
+                 (:mod:`.short_attention`: the projection read where it
+                 lies, ``[T, T]`` never in HBM). Since PR 26 speed does
+                 favour a kernel at T = 197: the XLA core ran at 5.6x
+                 its roofline there. Anything else is xla, unless the
+                 materialized logits would eat a large fraction of HBM
+                 (``_FLASH_MEMORY_BYTES``), then flash — between the two
+                 of them memory decides, not speed.
+* ``"short"`` is not a value: the kernel has no option of its own. Force
+  ``"xla"`` or ``"flash"`` to keep it out.
 
 Sequence parallelism rides on top of the dispatch rather than on ``impl``:
 tracing under :func:`.partition.on_mesh` (done by ``parallel.api``'s step
@@ -56,7 +67,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from . import partition
+from . import partition, short_attention
 from .quant import PROBS_DTYPES, dequantize_probs, quantize_probs
 
 # auto-dispatch: switch to the Pallas kernel when the XLA path would
@@ -450,3 +461,65 @@ def dot_product_attention(
                           deterministic=deterministic, mask=mask,
                           softmax=softmax, probs_dtype=probs_dtype,
                           residual_dtype=residual_dtype)
+
+
+def short_attention_ok(qkv_shape, dtype, *, impl, dropout_rate,
+                       deterministic, mask, probs_dtype,
+                       residual_dtype) -> bool:
+    """auto-mode: whether the short-sequence kernel pair serves a
+    self-attention call on a packed projection of this shape
+    ``[B, T, 3, H, Dh]``. Decided from the call alone, before the
+    projection exists (the model asks, to lay the projection out for the
+    kernel): the backend, the absence of what the kernel does not do
+    (mask, active attention dropout, quantised probability storage, a
+    sequence-parallel mesh) and the (per-shard) shapes
+    (:func:`.short_attention.supported`: head size, whole slabs of
+    heads, the ``[T, T]`` working set against VMEM). The softmax flavour
+    is not asked: the kernel's exact softmax serves either."""
+    if impl != "auto" or jax.default_backend() != "tpu":
+        return False
+    if mask is not None or (not deterministic and dropout_rate > 0.0):
+        return False
+    if probs_dtype != "bf16" or residual_dtype not in (None, "bf16"):
+        return False
+    if _sp_partition() is not None:
+        return False
+    return short_attention.supported(qkv_shape, dtype)
+
+
+def self_attention(qkv: jax.Array, *, impl: str = "auto",
+                   dropout_rate: float = 0.0,
+                   dropout_rng: Optional[jax.Array] = None,
+                   deterministic: bool = True,
+                   mask: Optional[jax.Array] = None,
+                   heads_already_local: bool = False,
+                   softmax: str = "saturating",
+                   probs_dtype: str = "bf16",
+                   residual_dtype: Optional[str] = None) -> jax.Array:
+    """Self-attention from the packed qkv projection
+    ``[batch, seq, 3, heads, head_dim]`` -> ``[batch, seq, heads,
+    head_dim]``; the keywords are :func:`dot_product_attention`'s.
+
+    Where ``impl="auto"`` finds the call one the short-sequence kernel
+    serves (:func:`short_attention_ok`), the projection goes to it as it
+    lies and no q, k or v is ever cut out of it. The kernel's softmax is the
+    exact, max-subtracted one, which equals the ``"saturating"`` flavour
+    over that flavour's whole exact range. Every other call gets q, k, v
+    sliced from the projection and :func:`dot_product_attention`; the
+    slices stay outside the ``attn_core`` scope, where a device trace
+    has always counted them (``msa_glue``).
+    """
+    if impl not in ("xla", "flash", "auto"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    if short_attention_ok(
+            qkv.shape, qkv.dtype, impl=impl, dropout_rate=dropout_rate,
+            deterministic=deterministic, mask=mask,
+            probs_dtype=probs_dtype, residual_dtype=residual_dtype):
+        with jax.named_scope("attn_core"):
+            return short_attention.short_attention(qkv)
+    return dot_product_attention(
+        qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], impl=impl,
+        dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+        deterministic=deterministic, mask=mask,
+        heads_already_local=heads_already_local, softmax=softmax,
+        probs_dtype=probs_dtype, residual_dtype=residual_dtype)

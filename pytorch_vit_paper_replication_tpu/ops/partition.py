@@ -8,9 +8,10 @@ plain HLO and partitions happily — so only a real multi-chip TPU sees the
 refusal. The program therefore partitions its own kernels: whoever jits a
 step over a mesh traces it under :func:`on_mesh`, and each kernel's
 public wrapper (:func:`.fused_mlp.fused_ln_mlp_residual`,
-:func:`.fused_mlp.fused_mlp`, :func:`.flash_attention.flash_attention`)
-asks :func:`current` and, when a mesh of more than one device is active,
-runs its ``pallas_call`` per shard inside ``jax.shard_map``.
+:func:`.fused_mlp.fused_mlp`, :func:`.flash_attention.flash_attention`,
+:func:`.short_attention.short_attention`) asks :func:`current` and,
+when a mesh of more than one device is active, runs its ``pallas_call``
+per shard inside ``jax.shard_map``.
 
 The same context carries the sequence-parallel choice: attention routes
 through ring/Ulysses when the active mesh's ``seq`` axis is >1

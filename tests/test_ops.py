@@ -449,3 +449,122 @@ def test_flash_on_a_mesh_draws_the_unsharded_dropout_masks(devices):
             got = jax.jit(grad)(q, k, v, m)
         for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
             np.testing.assert_allclose(g, w, rtol=2e-4, atol=5e-3)
+
+
+# --------------------------------------------------------------------------
+# The short-sequence kernel pair on the packed qkv projection
+# (ops/short_attention.py), in the interpreter
+# --------------------------------------------------------------------------
+
+from jax.experimental import pallas as pl  # noqa: E402
+
+from pytorch_vit_paper_replication_tpu.ops.attention import (  # noqa: E402
+    _xla_attention)
+from pytorch_vit_paper_replication_tpu.ops.short_attention import (  # noqa: E402
+    short_attention)
+
+# (B, T, H, Dh): the cells' length; an odd batch and more than one slab of
+# heads; a sequence of a few rows; one that fills its block; one head a
+# slab; a batch that overhangs its last block of images (6 in blocks of 4)
+SHORT_SHAPES = [(2, 197, 2, 64), (3, 197, 4, 64), (2, 5, 2, 64),
+                (2, 256, 2, 64), (2, 37, 1, 128), (6, 37, 2, 64)]
+SHORT_DTYPES = [(jnp.float32, TOL), (jnp.bfloat16, dict(rtol=5e-2, atol=5e-2))]
+
+
+def _packed(seed, b, t, h, d, dtype, scale=1.0):
+    qkv = scale * jax.random.normal(jax.random.key(seed), (b, t, 3, h, d))
+    return qkv.astype(dtype)
+
+
+def _exact_on_slices(qkv):
+    """The XLA path's exact softmax on slices of the same array."""
+    return _xla_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                          dropout_rate=0.0, dropout_rng=None,
+                          deterministic=True, softmax="exact")
+
+
+def _short(qkv):
+    return short_attention(qkv, interpret=True)
+
+
+@pytest.mark.parametrize("dtype,tol", SHORT_DTYPES,
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", SHORT_SHAPES, ids=str)
+def test_short_attention_forward_matches_exact_xla(shape, dtype, tol):
+    qkv = _packed(10, *shape, dtype)
+    out = _short(qkv)
+    assert out.shape == shape[:2] + shape[2:] and out.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(_exact_on_slices(qkv), np.float32), **tol)
+
+
+@pytest.mark.parametrize("dtype,tol", SHORT_DTYPES,
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", SHORT_SHAPES, ids=str)
+def test_short_attention_grad_of_the_packed_projection(shape, dtype, tol):
+    """One packed cotangent ``[B, T, 3, H, Dh]``: dq, dk and dv in the
+    thirds the projection's backward reads them from."""
+    qkv = _packed(11, *shape, dtype)
+    do = jax.random.normal(jax.random.key(12), shape).astype(dtype)
+
+    def loss(fn):
+        return lambda x: jnp.sum((fn(x) * do).astype(jnp.float32))
+
+    g = jax.grad(loss(_short))(qkv)
+    g_ref = jax.grad(loss(_exact_on_slices))(qkv)
+    assert g.shape == qkv.shape and g.dtype == dtype
+    for i, name in enumerate(("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(g[:, :, i], np.float32),
+            np.asarray(g_ref[:, :, i], np.float32), err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("what", ["o", "dq", "dk", "dv"])
+def test_short_attention_overhang_carries_nothing(what):
+    """T = 197 lies in one block of 256 rows; what the block holds past
+    T is whatever was there. The interpreter fills it with NaN (shown
+    first), so outputs that are finite and right have taken nothing
+    from it: the kernels zero the overhang of q, k, v and do and mask
+    the key rows and the log-sum-exp lanes past T."""
+    probe = pl.pallas_call(
+        lambda x_ref, o_ref: o_ref.__setitem__(..., x_ref[...]),
+        grid=(1,),
+        in_specs=[pl.BlockSpec((1, 256, 128), lambda i: (0, 0, 0))],
+        out_specs=pl.BlockSpec((1, 256, 128), lambda i: (0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, 256, 128), jnp.float32),
+        interpret=True)(jnp.ones((1, 197, 128)))
+    assert np.isnan(np.asarray(probe[:, 197:])).all()
+
+    qkv = _packed(13, 2, 197, 2, 64, jnp.float32)
+    if what == "o":
+        out, ref = _short(qkv), _exact_on_slices(qkv)
+    else:
+        i = ("dq", "dk", "dv").index(what)
+        out = jax.grad(lambda x: jnp.sum(_short(x) ** 2))(qkv)[:, :, i]
+        ref = jax.grad(
+            lambda x: jnp.sum(_exact_on_slices(x) ** 2))(qkv)[:, :, i]
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_short_attention_exact_softmax_at_logits_of_200(dtype):
+    """The saturating flavour's exact range ends near 96; the kernel's
+    max-subtracted softmax has none."""
+    # q . k of magnitude ~ 200 * sqrt(Dh) before the 1/sqrt(Dh) scale
+    qkv = _packed(14, 2, 197, 2, 64, dtype, scale=200.0 ** 0.5)
+    assert float(jnp.abs(jnp.einsum(
+        "bqhd,bkhd->bhqk", qkv[:, :, 0], qkv[:, :, 1],
+        preferred_element_type=jnp.float32)).max()) / 8 > 200
+    out = _short(qkv)
+    g = jax.grad(lambda x: jnp.sum(_short(x).astype(jnp.float32)))(qkv)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert np.isfinite(np.asarray(g, np.float32)).all()
+    # against f32 logits of the same inputs: the XLA path would round
+    # them to the compute dtype, 0.8 of a logit at this magnitude in bf16
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(_exact_on_slices(qkv.astype(jnp.float32))),
+        rtol=5e-2, atol=5e-2 * float(jnp.abs(qkv).max()))

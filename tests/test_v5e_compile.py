@@ -13,11 +13,14 @@ in this process, so the test patches it — exactly what it is standing
 in for.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (
+    Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding)
 
 from pytorch_vit_paper_replication_tpu import engine, parallel
 from pytorch_vit_paper_replication_tpu.configs import TrainConfig, ViTConfig
@@ -83,17 +86,50 @@ def dp4_step(v5e_2x2):
         return lowered.as_text(), lowered.compile().as_text()
 
 
+def _copies_beside_the_core(hlo, batch):
+    """Instructions of the entry computation that copy a ``[batch, 197,
+    ...]`` array under the scope ``attn_core``: a layout change between
+    a projection and the kernel that is a pass over HBM of its own (one
+    fused into a GEMM's operand is inside that fusion's computation,
+    not here)."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    return [line for line in entry.splitlines()
+            if re.search(r" (copy|transpose)\(", line)
+            and "/attn_core/" in line
+            and re.search(r"= \w+\[%d,197," % batch, line)]
+
+
 def test_dp4_train_step_compiles_with_per_shard_mosaic_calls(dp4_step):
-    """The step lowers with one fused fwd and one bwd Mosaic call per
-    layer, each over the PER-SHARD rows (256 images x 197 tokens), and
-    the v5e compiler takes it."""
+    """The step lowers with a forward and a backward Mosaic call per
+    layer for the MLP half-block and for the attention core, each over
+    the PER-SHARD operand (256 images x 197 tokens of rows; the packed
+    qkv projection of 256 images), and the v5e compiler takes it."""
     lowered_text, hlo = dp4_step
     calls = mosaic_calls(lowered_text)
-    assert sorted(name for name, _ in calls) == [
-        "lnmlp_bwd"] * 2 + ["lnmlp_fwd"] * 2
-    assert {shape for _, shape in calls} == {(256 * 197, 768)}
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 4
+    assert sorted(name for name, _ in calls) == (
+        ["attn_short_bwd"] * 2 + ["attn_short_fwd"] * 2
+        + ["lnmlp_bwd"] * 2 + ["lnmlp_fwd"] * 2)
+    assert {shape for name, shape in calls if name.startswith("lnmlp")} == {
+        (256 * 197, 768)}
+    assert {shape for name, shape in calls if name.startswith("attn")} == {
+        (256, 197, 3 * 768)}
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 8
     assert "all-reduce" in hlo   # the gradient sum over 'data'
+
+
+def test_compiled_step_holds_no_logits_and_no_layout_copies(dp4_step):
+    """What the kernel is for: no ``[B, H, T, T]`` tensor anywhere in the
+    optimized HLO, nothing under ``msa`` outside norm / qkv / attn_core /
+    out (the q, k, v slices and their layout copies: ``msa_glue``), and
+    no copy beside the kernels - the flat projections let the compiler
+    write the kernel's layout from the GEMM itself and read its results
+    the same way."""
+    _, hlo = dp4_step
+    assert not re.search(r"\[256,12,197,197\]", hlo)
+    program = device_trace.parse_scopes(hlo)
+    assert not [path for path in program["scopes"].values()
+                if device_trace.classify(path)[0] == "msa_glue"]
+    assert not _copies_beside_the_core(hlo, 256)
 
 
 def test_compiled_step_keeps_the_scopes_and_the_kernels_names(dp4_step):
@@ -112,15 +148,66 @@ def test_compiled_step_keeps_the_scopes_and_the_kernels_names(dp4_step):
                   "attn_core/", "/encoder_norm/", "/head/"):
         assert any(scope in path for path in paths), scope
     kernels = sorted(
-        device_trace.kernel_name({"name": name, "scope": scope})
+        (device_trace.kernel_name({"name": name, "scope": scope}), scope)
         for name, scope in program["scopes"].items()
-        if name.startswith("lnmlp") and "pallas_call" in scope)
-    assert kernels == ["lnmlp_bwd"] * 2 + ["lnmlp_fwd"] * 2
+        if name.startswith(("lnmlp", "attn_short"))
+        and "pallas_call" in scope)
+    assert [name for name, _ in kernels] == (
+        ["attn_short_bwd"] * 2 + ["attn_short_fwd"] * 2
+        + ["lnmlp_bwd"] * 2 + ["lnmlp_fwd"] * 2)
+    # the attention pair under the core's scope, forward and backward
+    for name, scope in kernels[:4]:
+        assert "/msa/attn_core/" in scope, scope
+        assert ("transpose(jvp(ViT))" in scope) == name.endswith("bwd"), scope
+        assert device_trace.classify(scope)[0] == "attn_core"
     # nearly every path of an instruction (the program's arguments are
     # named after the state's leaves) has a layer of the table
     layers = [device_trace.classify(path)[0] for path in paths
               if path.startswith("jit(")]
     assert layers.count("other") < 0.05 * len(layers)
+
+
+def test_one_chip_l16_width_layer_compiles(v5e_2x2, monkeypatch):
+    """ViT-L/16's width on one chip (16 heads of 64, 1024 wide, bs 96):
+    eight slabs of heads, a batch that is no multiple of 128 - the
+    compiler lays that projection out token-minor where B/16's is
+    batch-minor - and the same kernel pair, no logits, no copies beside
+    it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ViTConfig(num_layers=1, num_classes=3, embedding_dim=1024,
+                    num_heads=16, mlp_size=4096)
+    lowered = _lower_train_step(v5e_2x2[:1], cfg, dp=1, tp=1, batch=96)
+    calls = mosaic_calls(lowered.as_text())
+    assert sorted(calls) == sorted([
+        ("attn_short_fwd", (96, 197, 3072)),
+        ("attn_short_bwd", (96, 197, 3072)),
+        # rows padded up to whole 256-row blocks
+        ("lnmlp_fwd", (18944, 1024)), ("lnmlp_bwd", (18944, 1024))])
+    hlo = lowered.compile().as_text()
+    assert not re.search(r"\[96,16,197,197\]", hlo)
+    assert not _copies_beside_the_core(hlo, 96)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((32, 512, 3, 12, 64), jnp.bfloat16),    # 3 images a grid step
+    ((16, 540, 3, 12, 64), jnp.bfloat16),    # 1: the longest at Dh = 64
+    ((16, 577, 3, 8, 128), jnp.bfloat16),    # one head in flight
+    ((8, 512, 3, 12, 64), jnp.float32),
+], ids=str)
+def test_attention_kernels_compile_at_the_longest_lengths_planned(
+        v5e_2x2, shape, dtype):
+    """Where ``short_attention.plan`` still finds room, Mosaic does too:
+    forward and backward compile inside the VMEM limit they are given."""
+    from pytorch_vit_paper_replication_tpu.ops import short_attention
+
+    b, t, _, _, dh = shape
+    assert short_attention.plan(b, t, dh, jnp.dtype(dtype).itemsize)
+    qkv = jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(v5e_2x2[0]))
+    compiled = jax.jit(jax.grad(lambda x: jnp.sum(
+        short_attention.short_attention(x, interpret=False).astype(
+            jnp.float32)))).lower(qkv).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 def test_dp2_tp2_step_lowers_hidden_sliced_mlp_and_per_shard_flash(
