@@ -10,6 +10,9 @@ headline model (ViT-B/16, 224 px, bf16, random weights from a seed):
   train       `python -m ...train --synthetic --preset ViT-B/16
               --batch-size 256`: 8 optimizer steps, an eval pass per
               epoch, a checkpoint and the final/ export
+  train-lm    `python -m ...train --model lm --preset lm-tiny --synthetic`:
+              3 steps of the tiny token model (routed experts, causal /
+              window attention) and its eval pass
   serve       `python -m ...serve --checkpoint <that run> --sync-warmup
               --buckets 1,8`, fed image paths and ::stats on stdin
   train-dp4   the same trainer with its default mesh over four chips
@@ -242,6 +245,36 @@ def train(ctx) -> None:
     ctx["synth"] = synth
 
 
+def train_lm(ctx) -> None:
+    """The token model through the same entry point: 3 steps of the tiny
+    preset (the routed experts' Mosaic kernels at their smallest, XLA
+    attention at T = 64), then its eval pass."""
+    jsonl = ctx["work"] / "train_lm.jsonl"
+    out = run_child(
+        "train-lm",
+        [PY, "-m", f"{PKG}.train", "--model", "lm", "--preset", "lm-tiny",
+         "--synthetic", "--batch-size", "8", "--epochs", "1",
+         "--steps-per-epoch", "3", "--metrics-jsonl", str(jsonl)],
+        env=child_env(ctx["one_chip"]), log_dir=ctx["logs"], timeout=600)
+    m = find(r"mesh: \{'data': (\d+), .*platform: (\w+) \|", out,
+             "model/mesh line")
+    check(int(m.group(1)) == 1 and m.group(2) == "tpu",
+          f"token model trained on data={m.group(1)} {m.group(2)}")
+    calls = find(r"^train step: (\d+) Mosaic kernel calls: (.*)$", out,
+                 "Mosaic call report").group(2)
+    check(all(k in calls for k in ("moe_gmm_fwd x12", "moe_gmm_dx x8",
+                                   "moe_gmm_dw x8")),
+          f"the routed experts' kernels in the lowered step: {calls}")
+    (row,) = train_rows(jsonl)
+    say(f"  {row['step']} steps, train_loss {row['train_loss']:.4f}, "
+        f"test_loss {row['test_loss']:.4f} | {calls}")
+    check(row["step"] == 3 and all(
+        v == v and abs(v) != float("inf") and 0 < v < 6.0
+        for v in (row["train_loss"], row["test_loss"])),
+        f"token model: 3 steps with finite losses near log 256 = 5.5 "
+        f"wanted, got {row}")
+
+
 def serve(ctx) -> None:
     images = sorted((ctx["synth"] / "test").glob("*/*.jpg"))
     requests = [str(p) for p in images[::max(1, len(images) // 6)][:6]]
@@ -395,6 +428,7 @@ def main() -> int:
     try:
         # Every later phase needs the device and the trained checkpoint.
         if run("probe", probe) and run("train", train):
+            run("train-lm", train_lm)
             run("serve", serve)
             if ctx["device"]["count"] >= 4:
                 for name, fn in FOUR_CHIP:

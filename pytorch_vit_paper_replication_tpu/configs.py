@@ -100,15 +100,82 @@ class ViTConfig:
     # pipeline's head-LOCAL block config sets it so halving num_heads
     # keeps the true head width (parallel/pipeline.py).
     head_dim_override: int | None = None
+    # --- block options of the one encoder (a decoder-only language model
+    # is this encoder with them set; every default is the ViT's) ---
+    # Rows of the token embedding and of the untied per-position head
+    # held here. 0 = an image model (patches in, one pooled class out);
+    # > 0 = tokens in, next-token logits out at every position, and
+    # attention is causal. A vocabulary shared over chips is this chip's
+    # slice: ids, logits and loss are over the slice.
+    vocab_size: int = 0
+    # Positions of a token sequence (the image geometry is unused then).
+    max_seq_len: int = 0
+    # "layernorm" (scale + bias) or "rmsnorm" (scale only), at
+    # ``ln_epsilon``.
+    norm: str = "layernorm"
+    # Key/value heads; None = ``num_heads``. Fewer makes the projection
+    # grouped-query: query head g reads key/value head g // (H // Hkv).
+    num_kv_heads: int | None = None
+    # Biases on the attention projections.
+    attn_bias: bool = True
+    # Per layer, 1 = rotary positions on q and k (rotate-half over the
+    # whole head, ``rope_theta``), 0 = none. () = none anywhere. A layout
+    # shorter than the depth repeats (one period is enough).
+    rope_layout: Tuple[int, ...] = ()
+    rope_theta: float = 10000.0
+    # Per layer, 1 = causal attention over the last ``sliding_window``
+    # keys only, 0 = over every earlier key. Token models only.
+    sliding_window_layout: Tuple[int, ...] = ()
+    sliding_window: int = 0
+    # Routed feed-forward in place of the MLP: ``num_experts`` > 0 routes
+    # every token to its ``experts_per_token`` largest of ``num_experts``
+    # router logits (softmax over the selected), each expert a
+    # ReLU-gated ``embedding_dim -> expert_width -> embedding_dim``. This
+    # chip holds experts ``expert_offset ..+ experts_held`` and computes
+    # their part of the result (None = all of them).
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    experts_held: int | None = None
+    expert_offset: int = 0
+    # Std of the normal initialiser of a token model's matrices (its
+    # embedding rows start at N(0, 1): ``models/vit.py::TokenEmbedding``).
+    init_std: float = 0.02
 
     def __post_init__(self):
+        for name in ("rope_layout", "sliding_window_layout"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.num_heads % self.kv_heads != 0:
+            raise ValueError(
+                f"num_heads ({self.num_heads}) must be a multiple of "
+                f"num_kv_heads ({self.kv_heads})")
+        if self.vocab_size and self.max_seq_len <= 0:
+            raise ValueError("a token model needs max_seq_len")
+        if any(self.sliding_window_layout) and not (
+                self.vocab_size and self.sliding_window > 0):
+            raise ValueError("sliding_window_layout needs a token model "
+                             "and sliding_window > 0")
+        if self.num_experts:
+            held = self.num_experts_held
+            if not (0 < self.experts_per_token <= self.num_experts
+                    and self.expert_width > 0 and held > 0
+                    and 0 <= self.expert_offset
+                    and self.expert_offset + held <= self.num_experts):
+                raise ValueError(
+                    f"routed feed-forward: {self.experts_per_token} of "
+                    f"{self.num_experts} experts of width "
+                    f"{self.expert_width}, {held} held from "
+                    f"{self.expert_offset}")
         if self.image_size % self.patch_size != 0:
             # Reference asserts the same invariant at models/vit.py:25.
             raise ValueError(
                 f"image_size ({self.image_size}) must be divisible by "
                 f"patch_size ({self.patch_size})"
             )
-        if self.embedding_dim % self.num_heads != 0:
+        if (self.head_dim_override is None
+                and self.embedding_dim % self.num_heads != 0):
             raise ValueError(
                 f"embedding_dim ({self.embedding_dim}) must be divisible by "
                 f"num_heads ({self.num_heads})"
@@ -142,8 +209,35 @@ class ViTConfig:
 
     @property
     def seq_len(self) -> int:
-        """Token count including the CLS token (197 for 224/16)."""
+        """Token count including the CLS token (197 for 224/16); a token
+        model's ``max_seq_len``."""
+        if self.vocab_size:
+            return self.max_seq_len
         return self.num_patches + (1 if self.pool == "cls" else 0)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def num_experts_held(self) -> int:
+        return (self.num_experts if self.experts_held is None
+                else self.experts_held)
+
+    def layer_rope(self, layer: int) -> bool:
+        """Whether block ``layer`` turns q and k by their positions."""
+        lay = self.rope_layout
+        return bool(lay and lay[layer % len(lay)])
+
+    def attention_kind(self, layer: int):
+        """Block ``layer``'s attention as structure: ``("full", 0)``
+        bidirectional, ``("causal", 0)`` or ``("causal_window", w)``."""
+        if not self.vocab_size:
+            return ("full", 0)
+        lay = self.sliding_window_layout
+        if lay and lay[layer % len(lay)]:
+            return ("causal_window", self.sliding_window)
+        return ("causal", 0)
 
     @property
     def head_dim(self) -> int:
@@ -190,12 +284,52 @@ def vit_h14(**kw) -> ViTConfig:
                      mlp_size=5120, **kw)
 
 
+def smallthinker_21b_a3b_ep4(**kw) -> ViTConfig:
+    """SmallThinker-21BA3B-Instruct (huggingface.co/PowerInfer), one
+    chip's share of a deployment in which 4 chips share each layer: every
+    published width (2560, 28 query / 4 key-value heads of 128, experts
+    of width 768, top 6 of 64 router outputs, window 4096, theta 1.5e6),
+    one period of its 52 layers (full and position-free, then three
+    windowed with rotary positions), experts 0-15 of 64 and rows
+    0-37,983 of the 151,936-row vocabulary. What is assumed of the source
+    is in ``benchmark/configs/smallthinker-21b-a3b-ep4.json``."""
+    base = dict(
+        vocab_size=37984, max_seq_len=16384, num_layers=4, num_heads=28,
+        num_kv_heads=4, head_dim_override=128, embedding_dim=2560,
+        norm="rmsnorm", ln_epsilon=1e-6, attn_bias=False,
+        rope_layout=(0, 1, 1, 1), rope_theta=1.5e6,
+        sliding_window_layout=(0, 1, 1, 1), sliding_window=4096,
+        num_experts=64, experts_per_token=6, expert_width=768,
+        experts_held=16, expert_offset=0, attn_dropout=0.0,
+        mlp_dropout=0.0, embedding_dropout=0.0)
+    return ViTConfig(**{**base, **kw})
+
+
+def lm_tiny(**kw) -> ViTConfig:
+    """The same block at a size for tests: 4 layers of one period, width
+    64, 4 query / 2 key-value heads of 16, 8 experts of width 32 of which
+    4 are held, top 2, window 16, 256 rows, 64 positions."""
+    return smallthinker_21b_a3b_ep4(**{**dict(
+        vocab_size=256, max_seq_len=64, num_heads=4, num_kv_heads=2,
+        head_dim_override=16, embedding_dim=64, sliding_window=16,
+        num_experts=8, experts_per_token=2, expert_width=32,
+        experts_held=4), **kw})
+
+
 PRESETS = {
     "ViT-Ti/16": vit_ti16,
     "ViT-S/16": vit_s16,
     "ViT-B/16": vit_b16,
     "ViT-L/16": vit_l16,
     "ViT-H/14": vit_h14,
+}
+
+# Token models: ``--model lm --preset <name>``. Kept apart from PRESETS,
+# whose factories all take image geometry (``model_tier``, the serving
+# tiers and the CLI's image options walk that table).
+LM_PRESETS = {
+    "smallthinker-21b-a3b-ep4": smallthinker_21b_a3b_ep4,
+    "lm-tiny": lm_tiny,
 }
 
 # The fields that make two configs the same *servable architecture*

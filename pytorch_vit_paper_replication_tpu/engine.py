@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import optax
 
 Batch = Dict[str, jax.Array]  # {"image": [B,H,W,C] float, "label": [B] int32}
+# or, for a token model, {"tokens": [B,T] int32, "label": [B,T] int32}:
+# each position's next token.
 
 
 @flax.struct.dataclass
@@ -120,6 +122,47 @@ def _metrics(loss, logits, labels) -> Dict[str, jax.Array]:
     }
 
 
+def _moe_metrics(moe_stats) -> Dict[str, jax.Array]:
+    """The routed layers' counters of one step, from what the blocks
+    sowed (``moe_stats``: per layer ``counts [E_held]``, ``kept``,
+    ``routed``): pairs on the emptiest, the mean and the fullest held
+    expert over all layers, the share of the routed pairs that were
+    computed (1.0: there is no capacity) and the pairs dropped."""
+    from flax.traverse_util import flatten_dict
+    sown = flatten_dict(moe_stats)     # (..., block, "mlp", key) -> (v,)
+    leaves = lambda key: [v for path, vs in sown.items()
+                          if path[-1] == key for v in vs]
+    counts = jnp.stack(leaves("counts")).astype(jnp.float32)
+    kept = sum(leaves("kept")).astype(jnp.float32)
+    routed = sum(leaves("routed")).astype(jnp.float32)
+    return {
+        "moe_pairs_per_expert_min": counts.min(),
+        "moe_pairs_per_expert_mean": counts.mean(),
+        "moe_pairs_per_expert_max": counts.max(),
+        # (a TPU's a / a need not be 1.0: the equal case is spelled out)
+        "moe_pairs_kept_share": jnp.where(
+            kept == routed, 1.0, kept / jnp.maximum(routed, 1.0)),
+        "moe_dropped_pairs": routed - kept,
+    }
+
+
+def _token_loss(state, params, batch, dropout_rng):
+    """A token model's objective: mean next-token cross entropy over
+    every position of ``batch["tokens"]`` against ``batch["label"]``,
+    taken by the model's head in chunks (no ``[B, T, V]`` logits), and
+    the metrics of the step: ``correct`` counts sequences' worth of
+    right positions, so that ``correct / count`` is the token accuracy."""
+    (loss, right), sown = state.apply_fn(
+        {"params": params}, batch["tokens"], True, labels=batch["label"],
+        rngs={"dropout": dropout_rng}, mutable=["moe_stats"])
+    n, t = batch["label"].shape
+    metrics = {"loss_sum": loss * n, "correct": right / t,
+               "count": jnp.asarray(n, jnp.float32)}
+    if sown.get("moe_stats"):
+        metrics.update(_moe_metrics(sown["moe_stats"]))
+    return loss, metrics
+
+
 def _masked_metrics(losses, logits, labels, mask) -> Dict[str, jax.Array]:
     """Example-weighted sums over the valid (mask=1) rows only — used by
     eval, where ragged final batches are padded up to the data-parallel
@@ -168,8 +211,15 @@ def make_train_step(label_smoothing: float = 0.0, nan_guard: bool = False,
     def train_step(state: TrainState, batch: Batch
                    ) -> Tuple[TrainState, Dict[str, jax.Array]]:
         dropout_rng = jax.random.fold_in(state.rng, state.step)
+        # What is trained is read off the batch: token ids in, each
+        # position's next token as the label.
+        tokens = "tokens" in batch
+        if tokens and (distill_alpha is not None or label_smoothing):
+            raise ValueError("a token model trains on plain cross entropy")
 
         def loss_fn(params):
+            if tokens:
+                return _token_loss(state, params, batch, dropout_rng)
             logits = state.apply_fn(
                 {"params": params}, batch["image"], True,
                 rngs={"dropout": dropout_rng})
@@ -186,17 +236,18 @@ def make_train_step(label_smoothing: float = 0.0, nan_guard: bool = False,
 
         # The scopes below and the modules' own names are what
         # telemetry/device_trace.py sums a captured step by.
-        (loss, logits), grads = jax.value_and_grad(
+        # aux: the logits, or a token model's metrics (it has no logits)
+        (loss, aux), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         with jax.named_scope("optimizer"):
             updates, opt_state = state.tx.update(grads, state.opt_state,
                                                  state.params)
             params = optax.apply_updates(state.params, updates)
         with jax.named_scope("metrics"):
-            metrics = _metrics(loss, logits, batch["label"])
+            metrics = aux if tokens else _metrics(loss, aux, batch["label"])
             if distill_alpha is not None:
                 metrics["teacher_agree"] = jnp.sum(
-                    jnp.argmax(logits, axis=-1) ==
+                    jnp.argmax(aux, axis=-1) ==
                     jnp.argmax(batch["teacher_logits"], axis=-1)
                 ).astype(jnp.float32)
             metrics["grad_norm"] = optax.global_norm(grads)
@@ -227,6 +278,13 @@ def make_eval_step():
     reference's test_step."""
 
     def eval_step(state: TrainState, batch: Batch) -> Dict[str, jax.Array]:
+        if "tokens" in batch:
+            loss, right = state.apply_fn(
+                {"params": state.params}, batch["tokens"], False,
+                labels=batch["label"])
+            n, t = batch["label"].shape
+            return {"loss_sum": loss * n, "correct": right / t,
+                    "count": jnp.asarray(n, jnp.float32)}
         logits = state.apply_fn({"params": state.params}, batch["image"],
                                 False)
         labels = batch["label"]
@@ -497,11 +555,21 @@ def train(
             steps += 1
             global_step += 1
             if telemetry is not None:
+                # A token model's routing counters ride the barriered
+                # steps only: the step has finished there, so the fetch
+                # waits for nothing.
+                counters = None
+                if blocked and "moe_dropped_pairs" in metrics:
+                    # vitlint: hot-path-ok(sampled, on steps already barriered)
+                    counters = {k: float(v) for k, v in jax.device_get(
+                        {k: v for k, v in metrics.items()
+                         if k.startswith("moe_")}).items()}
                 telemetry.step(
                     data_wait_s=data_wait,
                     exec_s=time.perf_counter() - t_step,
                     images=int(batch["label"].shape[0]),
-                    step=global_step, epoch=epoch_no, blocked=blocked)
+                    step=global_step, epoch=epoch_no, blocked=blocked,
+                    counters=counters)
             if (checkpoint_every_steps and checkpointer is not None
                     and global_step % checkpoint_every_steps == 0):
                 t_ck = time.perf_counter()

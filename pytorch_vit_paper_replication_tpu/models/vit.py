@@ -50,12 +50,62 @@ import numpy as np
 
 from ..configs import ViTConfig
 from ..ops import partition
-from ..ops.attention import self_attention, short_attention_ok
+from ..ops.attention import (dot_product_attention, self_attention,
+                             short_attention_ok)
 from ..ops.dropout import Dropout
 
 
 def _dtype(cfg: ViTConfig):
     return jnp.dtype(cfg.dtype)
+
+
+def _norm(cfg: ViTConfig, name: str) -> nn.Module:
+    """The block's normalisation: LayerNorm, or RMSNorm (scale only)."""
+    if cfg.norm == "rmsnorm":
+        return nn.RMSNorm(epsilon=cfg.ln_epsilon, dtype=_dtype(cfg),
+                          name=name)
+    return nn.LayerNorm(epsilon=cfg.ln_epsilon, dtype=_dtype(cfg), name=name)
+
+
+def rotary(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding of ``x [B, T, H, Dh]`` at positions
+    ``0..T-1``: rotate-half over the whole head (pairs ``(i, i + Dh/2)``
+    turned by ``t * theta^(-2i/Dh)``), computed in float32."""
+    t, dh = x.shape[1], x.shape[-1]
+    freq = theta ** (-jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, axis=-1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, axis=-1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    half = jnp.concatenate([-x32[..., dh // 2:], x32[..., :dh // 2]],
+                           axis=-1)
+    return (x32 * cos + half * sin).astype(x.dtype)
+
+
+class TokenEmbedding(nn.Module):
+    """Token ids ``[B, T]`` -> ``[B, T, D]``: a row of the table each. No
+    position is added here: a token model's positions are its blocks'
+    rotary embeddings (or none).
+
+    Rows start at N(0, 1) (``torch.nn.Embedding``'s default; what rows
+    of std 0.02 scaled by sqrt(D) are at D = 2560), not at ``init_std``:
+    the residual stream a block's norms and routers read is then the
+    token's own row. At 0.02 it is what the first position-free causal
+    layer adds to every position alike, and an untrained router sends a
+    whole sequence to the same experts."""
+
+    config: ViTConfig
+
+    @nn.compact
+    def __call__(self, ids: jax.Array) -> jax.Array:
+        cfg = self.config
+        if ids.shape[1] > cfg.max_seq_len:
+            raise ValueError(f"{ids.shape[1]} tokens, max_seq_len "
+                             f"{cfg.max_seq_len}")
+        table = self.param("embedding",
+                           nn.initializers.normal(1.0),
+                           (cfg.vocab_size, cfg.embedding_dim), jnp.float32)
+        return jnp.take(table, ids, axis=0).astype(_dtype(cfg))
 
 
 class _PatchConv(nn.Module):
@@ -139,10 +189,15 @@ class MultiHeadSelfAttentionBlock(nn.Module):
 
     config: ViTConfig
     tp_axis: Optional[str] = None
+    layer: int = 0     # which block: picks the layer's rotary / window
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, x: jax.Array, train: bool = False,
+                 with_normed: bool = False):
         cfg = self.config
+        if cfg.vocab_size:
+            out, y = _token_attention(self, x, train)
+            return (out, y) if with_normed else out
         # Deliberately NOT Pallas-fused: a fused LN+QKV kernel (the
         # fused_mlp treatment applied here) measured a net LOSS — isolated
         # full-vjp 10.5 -> 11.5 ms, full step 306 -> 344 ms — because XLA's
@@ -192,7 +247,48 @@ class MultiHeadSelfAttentionBlock(nn.Module):
                     dtype=_dtype(cfg), name="out")(attn)
         if self.tp_axis is not None:
             out = jax.lax.psum(out, self.tp_axis)
-        return out
+        return (out, y) if with_normed else out
+
+def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
+                     train: bool):
+    """(Called from the block's compact ``__call__``; a free function and
+    not a method, since flax names a scope after a method and the paths
+    ``msa/norm``, ``msa/qkv``, ``msa/attn_core``, ``msa/out`` are what
+    the device trace reads.)
+
+    A token model's attention: one projection to ``H`` query and
+    ``2 x Hkv`` key/value heads, rotary positions where the layer has
+    them, causal (or causal-window) structure. Returns the output
+    and the normed input (the router of a routed block reads it)."""
+    cfg = self.config
+    if self.tp_axis is not None:
+        raise ValueError("a token model has no manual tensor "
+                         "parallelism")
+    dt = _dtype(cfg)
+    hq, hkv = cfg.num_heads, cfg.kv_heads
+    dense = functools.partial(
+        nn.DenseGeneral, use_bias=cfg.attn_bias, dtype=dt,
+        param_dtype=jnp.float32,
+        kernel_init=nn.initializers.normal(cfg.init_std))
+    y = _norm(cfg, "norm")(x)
+    qkv = dense(features=(hq + 2 * hkv, cfg.head_dim), axis=-1,
+                name="qkv")(y)               # [B, T, H + 2 Hkv, Dh]
+    q, k, v = (qkv[:, :, :hq], qkv[:, :, hq:hq + hkv],
+               qkv[:, :, hq + hkv:])
+    if cfg.layer_rope(self.layer):
+        with jax.named_scope("rope"):
+            q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+    kind, window = cfg.attention_kind(self.layer)
+    dropout_rng = None
+    if train and cfg.attn_dropout > 0.0:
+        dropout_rng = self.make_rng("dropout")
+    attn = dot_product_attention(
+        q, k, v, impl=cfg.attention_impl, kind=kind, window=window,
+        dropout_rate=cfg.attn_dropout, dropout_rng=dropout_rng,
+        deterministic=not train, softmax=cfg.attention_softmax)
+    out = dense(features=cfg.embedding_dim, axis=(-2, -1),
+                name="out")(attn)
+    return out, y
 
 
 def _flat_projections(qkv_shape, dtype, **dispatch) -> bool:
@@ -285,6 +381,8 @@ class _LnParams(nn.Module):
 def _mlp_fused(cfg: ViTConfig) -> bool:
     """Whether ``config.mlp_impl`` selects the Pallas path here."""
     impl = cfg.mlp_impl
+    if cfg.norm != "layernorm":
+        return False        # the kernel's fused norm is LayerNorm
     return impl == "fused" or (impl == "auto"
                                and jax.default_backend() == "tpu")
 
@@ -355,7 +453,7 @@ class MLPBlock(nn.Module):
                 dropout_rate=cfg.mlp_dropout, dropout_rng=dropout_rng,
                 deterministic=not train)
 
-        y = nn.LayerNorm(epsilon=cfg.ln_epsilon, dtype=dt, name="norm")(x)
+        y = _norm(cfg, "norm")(x)
         if fused:
             from ..ops.fused_mlp import fused_mlp
             w1, b1 = _DenseParams((cfg.embedding_dim, cfg.mlp_size),
@@ -381,18 +479,70 @@ class MLPBlock(nn.Module):
         return y + x if self.include_residual else y
 
 
+class RoutedMLPBlock(nn.Module):
+    """Routed feed-forward in the MLP's place, residual included: ``x +
+    sum over a token's held experts e of p_e (relu(u W_gate,e) * (u
+    W_up,e)) W_down,e`` with ``u = norm(x)``.
+
+    The router reads ``routed_from`` — the block's *pre-attention* normed
+    input — and routes over all ``num_experts``; this chip holds experts
+    ``expert_offset .. + experts_held`` and adds their part only
+    (:mod:`..ops.moe`). The counters of the routing are sown into the
+    collection ``moe_stats`` (kept when the caller makes it mutable).
+    """
+
+    config: ViTConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, routed_from: jax.Array,
+                 train: bool = False) -> jax.Array:
+        from ..ops import moe
+        cfg = self.config
+        d, f, held = (cfg.embedding_dim, cfg.expert_width,
+                      cfg.num_experts_held)
+        init = nn.initializers.normal(cfg.init_std)
+        u = _norm(cfg, "norm")(x)
+        with jax.named_scope("moe_router"):
+            # float32 at full precision: the selection is a comparison,
+            # and 64 outputs cost nothing.
+            logits = nn.Dense(
+                cfg.num_experts, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, kernel_init=init,
+                precision=jax.lax.Precision.HIGHEST, name="router")(
+                routed_from.astype(jnp.float32))
+        ids, probs = moe.route(logits, cfg.experts_per_token)
+        gate = self.param("gate", init, (held, d, f), jnp.float32)
+        up = self.param("up", init, (held, d, f), jnp.float32)
+        down = self.param("down", init, (held, f, d), jnp.float32)
+        y, stats = moe.moe_experts(u, ids, probs, gate, up, down,
+                                   expert_offset=cfg.expert_offset)
+        for key, value in stats.items():
+            self.sow("moe_stats", key, value)
+        return x + y
+
+
 class TransformerEncoderBlock(nn.Module):
     """Pre-norm residual encoder block: ``x = msa(x)+x; x = mlp(x)+x``.
 
     Reference: ``models/vit.py:133-169`` (residual wiring at :167-168).
+    ``layer`` is the block's index: a token model's rotary positions and
+    attention kind are chosen per layer (``configs.ViTConfig``).
     """
 
     config: ViTConfig
     tp_axis: Optional[str] = None
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+        if self.config.num_experts:
+            attn, normed = MultiHeadSelfAttentionBlock(
+                self.config, tp_axis=self.tp_axis, layer=self.layer,
+                name="msa")(x, train, with_normed=True)
+            return RoutedMLPBlock(self.config, name="mlp")(
+                attn + x, normed, train)
         x = MultiHeadSelfAttentionBlock(self.config, tp_axis=self.tp_axis,
+                                        layer=self.layer,
                                         name="msa")(x, train) + x
         # The MLP half's residual is OWNED by MLPBlock (one owner on
         # every impl/backend; unlocks the full-half-block kernel).
@@ -414,14 +564,19 @@ class ViTFeatureExtractor(nn.Module):
     @nn.compact
     def __call__(self, images: jax.Array, train: bool = False) -> jax.Array:
         cfg = self.config
-        x = PatchEmbedding(cfg, name="patch_embedding")(images, train)
+        if cfg.vocab_size:
+            # ``images`` are token ids [B, T]. The scope is the name the
+            # device trace's table has for the input embedding.
+            with jax.named_scope("patch_embedding"):
+                x = TokenEmbedding(cfg, name="token_embedding")(images)
+        else:
+            x = PatchEmbedding(cfg, name="patch_embedding")(images, train)
         block = TransformerEncoderBlock
         if cfg.remat:
             block = nn.remat(block, static_argnums=(2,))
         for i in range(cfg.num_layers):
-            x = block(cfg, name=f"encoder_block_{i}")(x, train)
-        x = nn.LayerNorm(epsilon=cfg.ln_epsilon, dtype=_dtype(cfg), name="encoder_norm")(x)
-        return x
+            x = block(cfg, layer=i, name=f"encoder_block_{i}")(x, train)
+        return _norm(cfg, "encoder_norm")(x)
 
 
 class ViT(nn.Module):
@@ -439,9 +594,12 @@ class ViT(nn.Module):
     config: ViTConfig
 
     @nn.compact
-    def __call__(self, images: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, images: jax.Array, train: bool = False,
+                 labels: Optional[jax.Array] = None):
         cfg = self.config
         tokens = ViTFeatureExtractor(cfg, name="backbone")(images, train)
+        if cfg.vocab_size:
+            return LMHead(cfg, name="head")(tokens, labels)
         if cfg.pool == "cls":
             pooled = tokens[:, 0]
         else:
@@ -450,6 +608,29 @@ class ViT(nn.Module):
                           param_dtype=jnp.float32, name="head")(
             pooled.astype(jnp.float32))
         return logits
+
+
+class LMHead(nn.Module):
+    """A token model's untied head: float32 logits ``[B, T, V]`` at every
+    position or, given ``labels [B, T]`` (each position's next token),
+    ``(mean cross entropy, positions predicted right)`` without the
+    logits ever being whole (:mod:`..ops.lm_loss`)."""
+
+    config: ViTConfig
+
+    @nn.compact
+    def __call__(self, tokens: jax.Array,
+                 labels: Optional[jax.Array] = None):
+        cfg = self.config
+        kernel = self.param("kernel", nn.initializers.normal(cfg.init_std),
+                            (cfg.embedding_dim, cfg.vocab_size), jnp.float32)
+        if labels is None:
+            return jnp.dot(tokens, kernel.astype(tokens.dtype),
+                           preferred_element_type=jnp.float32)
+        from ..ops.lm_loss import head_cross_entropy
+        return head_cross_entropy(
+            tokens.reshape(-1, cfg.embedding_dim), kernel,
+            labels.reshape(-1))
 
 
 def apply_tail(cfg: ViTConfig, params, tokens: jax.Array) -> jax.Array:

@@ -42,6 +42,16 @@ Model code never changes; that is the point. The same context makes the
 flash kernel run per shard on a data/model mesh (XLA will not partition a
 Mosaic call by itself).
 
+**Structure, not a mask.** ``kind`` says which keys a query sees:
+``"full"`` (every key: the ViT), ``"causal"`` (key j <= query i) or
+``"causal_window"`` (also ``i - j < window``). The flash kernels compute
+it from block positions and skip the blocks outside it; the XLA path —
+short sequences, and everything off the TPU — builds the ``[T, T]``
+boolean it stands for. k and v may have fewer heads than q
+(grouped-query attention): flash reads each key/value head where it
+lies, the XLA path repeats it. The short-sequence kernel and the
+sequence-parallel paths serve ``"full"`` with equal head counts only.
+
 Masks run natively on both single-device paths (in-kernel on flash since
 round 4 — broadcast dims stream unmaterialized). The one remaining
 fallback is explicit: an active sequence-parallel mesh that
@@ -315,6 +325,19 @@ def _xla_attention(q, k, v, *, dropout_rate: float, dropout_rng,
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+def structure_mask(kind: str, window: int, t: int):
+    """The ``[1, 1, T, T]`` boolean (True = attend) that ``kind`` stands
+    for, or None for ``"full"``."""
+    if kind == "full":
+        return None
+    i = jnp.arange(t)[:, None]
+    j = jnp.arange(t)[None, :]
+    vis = j <= i
+    if kind == "causal_window":
+        vis = vis & (i - j < window)
+    return vis[None, None]
+
+
 def _flash_ok(q) -> bool:
     """auto-mode: use the Pallas kernel only when the XLA path's
     materialized logits would not fit comfortably (and shapes qualify).
@@ -351,12 +374,18 @@ def dot_product_attention(
     softmax: str = "saturating",
     probs_dtype: str = "bf16",
     residual_dtype: Optional[str] = None,
+    kind: str = "full",
+    window: int = 0,
 ) -> jax.Array:
     """Multi-head scaled dot-product attention.
 
     Args:
-      q, k, v: ``[batch, seq, heads, head_dim]``.
+      q, k, v: ``[batch, seq, heads, head_dim]``; k and v may have a
+        divisor of q's heads (grouped-query attention).
       impl: ``"xla"``, ``"flash"``, or ``"auto"``.
+      kind / window: the attention's structure (module docstring):
+        ``"full"``, ``"causal"`` or ``"causal_window"`` over ``window``
+        keys.
       dropout_rate / dropout_rng / deterministic: attention-weight dropout
         (reference ``attn_dropout``, models/vit.py:75).
       mask: optional boolean ``[batch, heads, q, k]`` mask (True = attend).
@@ -411,7 +440,33 @@ def dot_product_attention(
         raise ValueError(f"unknown residual_dtype {residual_dtype!r}; "
                          f"expected one of {PROBS_DTYPES}")
 
+    if kind not in ("full", "causal", "causal_window"):
+        raise ValueError(f"unknown attention kind {kind!r}")
+    structured = kind != "full" or k.shape[2] != q.shape[2]
+
+    def xla(q, k, v, mask):
+        """The XLA path, given what ``kind`` and the head counts stand
+        for: the visibility matrix and repeated key/value heads."""
+        if structured:
+            group = q.shape[2] // k.shape[2]
+            if group > 1:
+                k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+            vis = structure_mask(kind, window, q.shape[1])
+            if vis is not None:
+                mask = vis if mask is None else jnp.logical_and(mask, vis)
+        return _xla_attention(q, k, v, dropout_rate=dropout_rate,
+                              dropout_rng=dropout_rng,
+                              deterministic=deterministic, mask=mask,
+                              softmax=softmax, probs_dtype=probs_dtype,
+                              residual_dtype=residual_dtype)
+
     sp = _sp_partition()
+    if sp is not None and structured:
+        _warn_once(
+            "sequence_parallel: ring/ulysses attention serve bidirectional "
+            "attention with equal head counts only; using the (gathered) "
+            "XLA path instead")
+        return xla(q, k, v, mask)
     if sp is not None:
         b, t, h = q.shape[0], q.shape[1], q.shape[2]
         seq_size = sp.size(sp.seq_axis)
@@ -443,42 +498,37 @@ def dot_product_attention(
         # Honor the fallback message: never hand seq-sharded operands to
         # the Pallas kernel — GSPMD only guarantees the gathered semantics
         # for the plain XLA ops.
-        return _xla_attention(q, k, v, dropout_rate=dropout_rate,
-                              dropout_rng=dropout_rng,
-                              deterministic=deterministic, mask=mask,
-                              softmax=softmax, probs_dtype=probs_dtype,
-                              residual_dtype=residual_dtype)
+        return xla(q, k, v, mask)
 
     use_flash = impl == "flash" or (impl == "auto" and _flash_ok(q))
     if use_flash:
         from .flash_attention import flash_attention
-        return flash_attention(q, k, v, mask=mask,
-                               dropout_rate=dropout_rate,
+        return flash_attention(q, k, v, kind=kind, window=window,
+                               mask=mask, dropout_rate=dropout_rate,
                                dropout_rng=dropout_rng,
                                deterministic=deterministic)
-    return _xla_attention(q, k, v, dropout_rate=dropout_rate,
-                          dropout_rng=dropout_rng,
-                          deterministic=deterministic, mask=mask,
-                          softmax=softmax, probs_dtype=probs_dtype,
-                          residual_dtype=residual_dtype)
+    return xla(q, k, v, mask)
 
 
 def short_attention_ok(qkv_shape, dtype, *, impl, dropout_rate,
                        deterministic, mask, probs_dtype,
-                       residual_dtype) -> bool:
+                       residual_dtype, kind: str = "full") -> bool:
     """auto-mode: whether the short-sequence kernel pair serves a
     self-attention call on a packed projection of this shape
     ``[B, T, 3, H, Dh]``. Decided from the call alone, before the
     projection exists (the model asks, to lay the projection out for the
     kernel): the backend, the absence of what the kernel does not do
-    (mask, active attention dropout, quantised probability storage, a
-    sequence-parallel mesh) and the (per-shard) shapes
+    (mask, causal or windowed structure, active attention dropout,
+    quantised probability storage, a sequence-parallel mesh) and the
+    (per-shard) shapes
     (:func:`.short_attention.supported`: head size, whole slabs of
     heads, the ``[T, T]`` working set against VMEM). The softmax flavour
     is not asked: the kernel's exact softmax serves either."""
     if impl != "auto" or jax.default_backend() != "tpu":
         return False
     if mask is not None or (not deterministic and dropout_rate > 0.0):
+        return False
+    if kind != "full":
         return False
     if probs_dtype != "bf16" or residual_dtype not in (None, "bf16"):
         return False
@@ -495,10 +545,13 @@ def self_attention(qkv: jax.Array, *, impl: str = "auto",
                    heads_already_local: bool = False,
                    softmax: str = "saturating",
                    probs_dtype: str = "bf16",
-                   residual_dtype: Optional[str] = None) -> jax.Array:
+                   residual_dtype: Optional[str] = None,
+                   kind: str = "full", window: int = 0) -> jax.Array:
     """Self-attention from the packed qkv projection
     ``[batch, seq, 3, heads, head_dim]`` -> ``[batch, seq, heads,
     head_dim]``; the keywords are :func:`dot_product_attention`'s.
+    (A grouped-query projection is not packed this way: its block calls
+    :func:`dot_product_attention` with q, k and v.)
 
     Where ``impl="auto"`` finds the call one the short-sequence kernel
     serves (:func:`short_attention_ok`), the projection goes to it as it
@@ -514,7 +567,8 @@ def self_attention(qkv: jax.Array, *, impl: str = "auto",
     if short_attention_ok(
             qkv.shape, qkv.dtype, impl=impl, dropout_rate=dropout_rate,
             deterministic=deterministic, mask=mask,
-            probs_dtype=probs_dtype, residual_dtype=residual_dtype):
+            probs_dtype=probs_dtype, residual_dtype=residual_dtype,
+            kind=kind):
         with jax.named_scope("attn_core"):
             return short_attention.short_attention(qkv)
     return dot_product_attention(
@@ -522,4 +576,5 @@ def self_attention(qkv: jax.Array, *, impl: str = "auto",
         dropout_rate=dropout_rate, dropout_rng=dropout_rng,
         deterministic=deterministic, mask=mask,
         heads_already_local=heads_already_local, softmax=softmax,
-        probs_dtype=probs_dtype, residual_dtype=residual_dtype)
+        probs_dtype=probs_dtype, residual_dtype=residual_dtype,
+        kind=kind, window=window)

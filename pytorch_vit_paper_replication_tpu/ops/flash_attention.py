@@ -30,6 +30,24 @@ unbiased. The softmax normalizer uses the *undropped* probabilities
 (dropout applies to the normalized attention weights, matching
 ``torch.nn.MultiheadAttention``/the XLA path's semantics).
 
+**Causal and windowed attention are structure, not a mask array**
+(``kind`` = ``"causal"`` / ``"causal_window"``): key j is visible to
+query i iff ``j <= i`` and, with a window w, ``i - j < w``. Each kernel
+computes that from the positions of the block it holds and visits only
+the blocks that hold a visible pair — the loop over key blocks (forward,
+dq) or query blocks (dk/dv) runs from the first such block to the last,
+so a window layer at T = 16,384, w = 4,096 touches 22% of the square and
+a causal layer 50%.
+
+**Grouped-query attention**: q may have ``G`` times the heads of k and v.
+Query head h reads key/value head ``h // G`` through the block index (k
+and v are never repeated in HBM, and consecutive query heads of a group
+find the block already in VMEM). dk and dv come out per query head in
+float32 and are summed over the group outside the kernel.
+
+The matrix products take their operands in the input dtype (bfloat16 on
+the MXU) and accumulate in float32; the softmax statistics are float32.
+
 Use :func:`..ops.attention.dot_product_attention` with ``impl="flash"``/
 ``"auto"`` rather than calling this directly.
 """
@@ -48,12 +66,134 @@ from jax.sharding import PartitionSpec as P
 from . import partition
 from .dropout import positional_dropout_seed, positional_meta
 
-# 256x256 measured fastest on v5e at every length >= 1024 (1.8x the
-# 128x128 fwd+bwd step at t=8192 and t=4096, neutral at 577); larger
-# blocks regress (VMEM pressure).
+# Blocks of 256 up to 2,047 tokens (T = 577 pads to 768, not 1,024),
+# of 512 from there. Measured on the v5e with bf16 operands at T =
+# 16,384, Dh = 128, 28 query heads over 4, forward + backward of a
+# causal / a 4,096-window layer (PR 27, my chip run): 128x128 265.7 /
+# 124.3 ms, 256x256 130.1 / 64.6, 512x512 67.4 / 37.3, 1024x1024 63.1 /
+# 38.2, and 512x256 100.3 / 52.8, 256x512 80.5 / 43.4: the per-block
+# softmax (VPU) and loop overheads are paid per block, the MXU work is
+# not. (With float32 operands, an earlier installation found 256 best.)
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
+LONG_SEQUENCE = 2048
+LONG_BLOCK = 512
 _NEG_INF = float(-1e30)
+_MIB = 1024 * 1024
+
+
+def _vmem_limit(*resident):
+    """Scoped-VMEM limit for a call that keeps ``resident`` whole
+    operands (bytes each, double-buffered) beside its blocks: the
+    compiler's default (16 MiB on the v5e) stops at T ~ 8k of bf16 k and
+    v at head size 128."""
+    need = 2 * sum(resident) + 16 * _MIB
+    return int(min(max(need, 32 * _MIB), 100 * _MIB))
+
+
+def _slab_bytes(x, head_dim):
+    """Bytes of one head's ``[T, Dh]`` slab of a ``[.., T, ..]`` array."""
+    return x.shape[1] * head_dim * x.dtype.itemsize
+
+
+def _layout(structure, x):
+    """``(programs, head_dim, q_at, kv_at)`` of the arrays' layout.
+
+    Folded (``structure[3] == 0``): q ``[B*H, T, Dh]``, k / v ``[B*Hkv,
+    T, Dh]``. Flat (``structure[3] == H``, taken where Dh is a multiple
+    of the 128 lanes): q ``[B, T, H*Dh]``, k / v ``[B, T, Hkv*Dh]`` — the
+    projection's own layout, a head being a column block, so no
+    transposed copy of q, k, v, o or their gradients is ever made.
+    ``q_at(b)`` / ``kv_at(b)`` give program ``b``'s ``(leading index,
+    column block)`` in a q-like and a k-like array."""
+    _, _, group, heads = structure
+    if not heads:
+        return (x.shape[0], x.shape[2], lambda b: (b, 0),
+                lambda b: (b // group, 0))
+    return (x.shape[0] * heads, x.shape[2] // heads,
+            lambda b: (b // heads, b % heads),
+            lambda b: (b // heads, (b % heads) // group))
+
+
+def _visible(structure, row, col):
+    """Whether key ``col`` is visible to query ``row`` (arrays of global
+    positions) under ``structure = (causal, window, query heads a
+    key/value head, query heads of the flat layout or 0)``."""
+    causal, window = structure[:2]
+    ok = col <= row
+    if window:
+        ok = jnp.logical_and(ok, row - col < window)
+    return ok
+
+
+def _kv_block_range(structure, qi, block_q, block_k, num_kv):
+    """Key blocks ``[lo, hi)`` that hold a key visible to some query of
+    query block ``qi``."""
+    causal, window = structure[:2]
+    if not causal:
+        return 0, num_kv
+    hi = jnp.minimum(num_kv, ((qi + 1) * block_q + block_k - 1) // block_k)
+    lo = 0
+    if window:
+        lo = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
+    return lo, hi
+
+
+def _kv_full_range(structure, qi, block_q, block_k, lo, hi):
+    """Of ``[lo, hi)``, the key blocks ``[full_lo, full_hi)`` whose every
+    key is visible to every query of block ``qi``: no mask is needed
+    there (the blocks before and after it are the window's and the
+    diagonal's edges)."""
+    causal, window = structure[:2]
+    if not causal:
+        return lo, hi
+    full_hi = jnp.clip((qi * block_q + 1) // block_k, lo, hi)
+    full_lo = lo
+    if window:
+        full_lo = jnp.clip(
+            (qi * block_q + block_q - window + block_k - 1) // block_k,
+            lo, full_hi)
+    return full_lo, full_hi
+
+
+def _q_full_range(structure, ki, block_q, block_k, lo, hi):
+    """Of ``[lo, hi)``, the query blocks ``[full_lo, full_hi)`` whose
+    every query sees every key of block ``ki``."""
+    causal, window = structure[:2]
+    if not causal:
+        return lo, hi
+    full_hi = hi
+    if window:
+        full_hi = jnp.clip(
+            (window + ki * block_k - block_q + 1 + block_q - 1) // block_q,
+            lo, hi)
+    full_lo = jnp.clip(((ki + 1) * block_k - 1 + block_q - 1) // block_q,
+                       lo, full_hi)
+    return full_lo, full_hi
+
+
+def _edges_and_interior(body, lo, full_lo, full_hi, hi, carry):
+    """``body(i, carry, masked)`` over ``[lo, hi)``: with the structure's
+    mask on the edge blocks, without it on ``[full_lo, full_hi)``."""
+    edge = functools.partial(body, masked=True)
+    carry = jax.lax.fori_loop(lo, full_lo, edge, carry)
+    carry = jax.lax.fori_loop(full_lo, full_hi,
+                              functools.partial(body, masked=False), carry)
+    return jax.lax.fori_loop(full_hi, hi, edge, carry)
+
+
+def _q_block_range(structure, ki, block_q, block_k, num_q):
+    """Query blocks ``[lo, hi)`` that hold a query which sees some key
+    of key block ``ki``."""
+    causal, window = structure[:2]
+    if not causal:
+        return 0, num_q
+    lo = (ki * block_k) // block_q
+    hi = num_q
+    if window:
+        hi = jnp.minimum(
+            num_q, ((ki + 1) * block_k - 1 + window - 1) // block_q + 1)
+    return lo, hi
 
 
 def _fold_heads(x):
@@ -201,33 +341,43 @@ def _mask_block_cols(mask_ref, mask_info, qi, block_q, block_k):
 # --------------------------------------------------------------------------
 
 def _fwd_kernel(meta_ref, q_ref, k_ref, v_ref, *rest, scale,
-                block_k, kv_len, threshold, mask_info, heads):
+                block_k, kv_len, threshold, mask_info, heads, structure):
     """One (batch·head, q-block) program: online-softmax over K/V blocks."""
     if mask_info is not None:
         mask_ref, o_ref, lse_ref = rest
     else:
         mask_ref, (o_ref, lse_ref) = None, rest
-    q = q_ref[0].astype(jnp.float32)  # [Bq, Dh]
+    q = q_ref[0]                      # [Bq, Dh]
     block_q, head_dim = q.shape
     padded_kv = k_ref.shape[1]
     num_kv = padded_kv // block_k
     bh = _global_bh(meta_ref, heads)
     qi = pl.program_id(1)
+    causal = structure[0]
 
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
 
-    def body(ki, carry):
+    padded = kv_len != padded_kv       # static: a last block of padding
+
+    def body(ki, carry, masked=True):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
+        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
+        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(col < kv_len, s, _NEG_INF)
+        if padded or (causal and masked):
+            col = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            keep_s = col < kv_len
+            if causal and masked:
+                row = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                keep_s = jnp.logical_and(keep_s,
+                                         _visible(structure, row, col))
+            s = jnp.where(keep_s, s, _NEG_INF)
         if mask_info is not None:
             attend = _mask_block_rows(mask_ref, mask_info, ki, block_q,
                                       block_k)
@@ -255,10 +405,14 @@ def _fwd_kernel(meta_ref, q_ref, k_ref, v_ref, *rest, scale,
                               (block_q, block_k), threshold)
             p = jnp.where(keep, p, 0.0)
         acc_new = acc * correction + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m0, l0, acc0))
+    # Every query sees its own position, so a visited row's m is finite
+    # after its first block and the _NEG_INF entries underflow to 0.
+    lo, hi = _kv_block_range(structure, qi, block_q, block_k, num_kv)
+    full = _kv_full_range(structure, qi, block_q, block_k, lo, hi)
+    m, l, acc = _edges_and_interior(body, lo, *full, hi, (m0, l0, acc0))
     # Guard fully-masked rows (padded query rows): l == 0 there.
     l_safe = jnp.where(l == 0.0, 1.0, l)
     keep_prob = 1.0 - threshold / 256.0  # quantized, like ops.dropout
@@ -288,9 +442,9 @@ def _pad_to_false(x, axis, multiple):
 
 
 def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
-         block_k, threshold, interpret):
-    bh, q_len, head_dim = q.shape
-    kv_len = k.shape[1]
+         block_k, threshold, interpret, structure):
+    q_len, kv_len = q.shape[1], k.shape[1]
+    bh, head_dim, q_at, kv_at = _layout(structure, q)
     qp = _pad_to(q, 1, block_q)
     kp = _pad_to(k, 1, block_k)
     vp = _pad_to(v, 1, block_k)
@@ -298,11 +452,14 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
 
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                kv_len=kv_len, threshold=threshold,
-                               mask_info=mask_info, heads=heads)
+                               mask_info=mask_info, heads=heads,
+                               structure=structure)
+    q_rows = lambda b, i, *_: (q_at(b)[0], i, q_at(b)[1])
+    kv_whole = lambda b, i, *_: (kv_at(b)[0], 0, kv_at(b)[1])
     in_specs = [
-        pl.BlockSpec((1, block_q, head_dim), lambda b, i, *_: (b, i, 0)),
-        pl.BlockSpec((1, kp.shape[1], head_dim), lambda b, i, *_: (b, 0, 0)),
-        pl.BlockSpec((1, vp.shape[1], head_dim), lambda b, i, *_: (b, 0, 0)),
+        pl.BlockSpec((1, block_q, head_dim), q_rows),
+        pl.BlockSpec((1, kp.shape[1], head_dim), kv_whole),
+        pl.BlockSpec((1, vp.shape[1], head_dim), kv_whole),
     ]
     operands = [qp, kp, vp]
     if mask_info is not None:
@@ -318,8 +475,7 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
             grid=grid,
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, head_dim),
-                             lambda b, i, *_: (b, i, 0)),
+                pl.BlockSpec((1, block_q, head_dim), q_rows),
                 pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i)),
             ],
         ),
@@ -327,6 +483,9 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
             jax.ShapeDtypeStruct(qp.shape, q.dtype),
             jax.ShapeDtypeStruct((bh, 1, qp.shape[1]), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(_slab_bytes(kp, head_dim),
+                                         _slab_bytes(vp, head_dim))),
         interpret=interpret,
     )(seed, *operands)
     return out[:, :q_len], lse[:, 0, :q_len]
@@ -338,13 +497,14 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
 
 def _bwd_dq_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    *rest, scale, block_k, kv_len, threshold, mask_info,
-                   heads):
+                   heads, structure):
     if mask_info is not None:
         mask_ref, dq_ref = rest
     else:
         mask_ref, (dq_ref,) = None, rest
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
+    causal = structure[0]
     lse = lse_ref[0, 0][:, None]       # [Bq, 1]
     delta = delta_ref[0, 0][:, None]   # [Bq, 1]
     block_q, head_dim = q.shape
@@ -353,15 +513,25 @@ def _bwd_dq_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(1)
     inv_keep = 256.0 / (256.0 - threshold)
 
-    def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :].astype(jnp.float32)
+    padded = kv_len != k_ref.shape[1]
+
+    def body(ki, dq, masked=True):
+        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
+        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        p = jnp.where(col < kv_len, jnp.exp(s - lse), 0.0)
+        p = jnp.exp(s - lse)
+        if padded or (causal and masked):
+            col = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            keep_s = col < kv_len
+            if causal and masked:
+                row = qi * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                keep_s = jnp.logical_and(keep_s,
+                                         _visible(structure, row, col))
+            p = jnp.where(keep_s, p, 0.0)
         if mask_info is not None:
             attend = _mask_block_rows(mask_ref, mask_info, ki, block_q,
                                       block_k)
@@ -376,40 +546,54 @@ def _bwd_dq_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                               (block_q, block_k), threshold)
             dp = jnp.where(keep, dp * inv_keep, 0.0)
         ds = p * (dp - delta) * scale
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        return dq + jnp.dot(ds.astype(k.dtype), k,
+                            preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(
-        0, num_kv, body, jnp.zeros((block_q, head_dim), jnp.float32))
+    lo, hi = _kv_block_range(structure, qi, block_q, block_k, num_kv)
+    full = _kv_full_range(structure, qi, block_q, block_k, lo, hi)
+    dq = _edges_and_interior(
+        body, lo, *full, hi, jnp.zeros((block_q, head_dim), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, *rest, scale, block_q, q_len, threshold,
-                    mask_info, heads):
+                    mask_info, heads, structure):
     if mask_info is not None:
         mask_ref, dk_ref, dv_ref = rest
     else:
         mask_ref, (dk_ref, dv_ref) = None, rest
-    k = k_ref[0].astype(jnp.float32)   # [Bk, Dh]
-    v = v_ref[0].astype(jnp.float32)
+    k = k_ref[0]                       # [Bk, Dh]
+    v = v_ref[0]
+    causal = structure[0]
     block_k, head_dim = k.shape
     num_q = q_ref.shape[1] // block_q
     bh = _global_bh(meta_ref, heads)
     ki = pl.program_id(1)
     inv_keep = 256.0 / (256.0 - threshold)
 
-    def body(qi, carry):
+    padded = q_len != q_ref.shape[1]
+
+    def body(qi, carry, masked=True):
         dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
+        q = q_ref[0, pl.ds(qi * block_q, block_q), :]
+        do = do_ref[0, pl.ds(qi * block_q, block_q), :]
         lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
         delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        p = jnp.where(row < q_len, jnp.exp(s - lse), 0.0)
+        p = jnp.exp(s - lse)
+        if padded or (causal and masked):
+            row = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            keep_s = row < q_len
+            if causal and masked:
+                col = ki * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                keep_s = jnp.logical_and(keep_s,
+                                         _visible(structure, row, col))
+            p = jnp.where(keep_s, p, 0.0)
         if mask_info is not None:
             attend = _mask_block_cols(mask_ref, mask_info, qi, block_q,
                                       block_k)
@@ -421,7 +605,7 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         else:
             p_dropped = p
         dv_new = dv + jax.lax.dot_general(
-            p_dropped, do, (((0,), (0,)), ((), ())),
+            p_dropped.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -430,12 +614,14 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             dp = jnp.where(keep, dp * inv_keep, 0.0)
         ds = p * (dp - delta) * scale                    # [Bq, Bk]
         dk_new = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
-    dk, dv = jax.lax.fori_loop(
-        0, num_q, body,
+    lo, hi = _q_block_range(structure, ki, block_q, block_k, num_q)
+    full = _q_full_range(structure, ki, block_q, block_k, lo, hi)
+    dk, dv = _edges_and_interior(
+        body, lo, *full, hi,
         (jnp.zeros((block_k, head_dim), jnp.float32),
          jnp.zeros((block_k, head_dim), jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -446,35 +632,42 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 # custom_vjp wiring
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, seed, mask3, threshold, block_q, block_k, interpret,
-           mask_info, heads):
-    scale = q.shape[-1] ** -0.5
+           mask_info, heads, structure):
+    scale = _layout(structure, q)[1] ** -0.5
     out, _ = _fwd(q, k, v, seed, mask3, mask_info, heads=heads, scale=scale,
                   block_q=block_q, block_k=block_k, threshold=threshold,
-                  interpret=interpret)
+                  interpret=interpret, structure=structure)
     return out
 
 
 def _flash_fwd(q, k, v, seed, mask3, threshold, block_q, block_k,
-               interpret, mask_info, heads):
-    scale = q.shape[-1] ** -0.5
+               interpret, mask_info, heads, structure):
+    scale = _layout(structure, q)[1] ** -0.5
     out, lse = _fwd(q, k, v, seed, mask3, mask_info, heads=heads,
                     scale=scale,
                     block_q=block_q, block_k=block_k, threshold=threshold,
-                    interpret=interpret)
+                    interpret=interpret, structure=structure)
     return out, (q, k, v, seed, mask3, out, lse)
 
 
 def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
-               res, do):
+               structure, res, do):
     q, k, v, seed, mask3, out, lse = res
-    scale = q.shape[-1] ** -0.5
-    bh, q_len, head_dim = q.shape
-    kv_len = k.shape[1]
+    scale = _layout(structure, q)[1] ** -0.5
+    q_len, kv_len = q.shape[1], k.shape[1]
+    group, flat_heads = structure[2:]
+    bh, head_dim, q_at, kv_at = _layout(structure, q)
 
     # delta_i = rowsum(dO_i * O_i) — cheap elementwise, fused by XLA.
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    if flat_heads:        # [B, T, H*Dh] -> [B*H, T]
+        delta = prod.reshape(prod.shape[:2] + (flat_heads, head_dim)
+                             ).sum(-1).transpose(0, 2, 1).reshape(bh, q_len)
+    else:
+        delta = jnp.sum(prod, axis=-1)
 
     qp = _pad_to(q, 1, block_q)
     dop = _pad_to(do, 1, block_q)
@@ -486,9 +679,10 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
     vp = _pad_to(v, 1, block_k)
     padded_q, padded_kv = qp.shape[1], kp.shape[1]
 
-    q_spec = pl.BlockSpec((1, block_q, head_dim), lambda b, i, *_: (b, i, 0))
+    q_spec = pl.BlockSpec((1, block_q, head_dim),
+                          lambda b, i, *_: (q_at(b)[0], i, q_at(b)[1]))
     kv_full = pl.BlockSpec((1, padded_kv, head_dim),
-                           lambda b, i, *_: (b, 0, 0))
+                           lambda b, i, *_: (kv_at(b)[0], 0, kv_at(b)[1]))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i))
 
     dq_in_specs = [q_spec, kv_full, kv_full, q_spec, row_spec, row_spec]
@@ -506,7 +700,8 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
                           kv_len=kv_len, threshold=threshold,
-                          mask_info=mask_info, heads=heads),
+                          mask_info=mask_info, heads=heads,
+                          structure=structure),
         name="flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -515,29 +710,53 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
             out_specs=q_spec,
         ),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(_slab_bytes(kp, head_dim),
+                                         _slab_bytes(vp, head_dim))),
         interpret=interpret,
     )(seed, qp, kp, vp, dop, lsep, deltap, *mask_operands)[:, :q_len]
 
-    q_full = pl.BlockSpec((1, padded_q, head_dim), lambda b, i, *_: (b, 0, 0))
-    k_spec = pl.BlockSpec((1, block_k, head_dim), lambda b, i, *_: (b, i, 0))
+    q_full = pl.BlockSpec((1, padded_q, head_dim),
+                          lambda b, i, *_: (q_at(b)[0], 0, q_at(b)[1]))
+    k_in = pl.BlockSpec((1, block_k, head_dim),
+                        lambda b, i, *_: (kv_at(b)[0], i, kv_at(b)[1]))
+    k_spec = pl.BlockSpec((1, block_k, head_dim),
+                          lambda b, i, *_: (q_at(b)[0], i, q_at(b)[1]))
     row_full = pl.BlockSpec((1, 1, padded_q), lambda b, i, *_: (b, 0, 0))
+    # One dk / dv per QUERY head (laid out as q is); a group's are
+    # summed below, in float32.
+    dkv_shape = (qp.shape[0], padded_kv, qp.shape[2])
+    dkv_dtype = k.dtype if group == 1 else jnp.float32
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                           q_len=q_len, threshold=threshold,
-                          mask_info=mask_info, heads=heads),
+                          mask_info=mask_info, heads=heads,
+                          structure=structure),
         name="flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, padded_kv // block_k),
-            in_specs=[q_full, k_spec, k_spec, q_full, row_full, row_full]
+            in_specs=[q_full, k_in, k_in, q_full, row_full, row_full]
             + dkv_extra_specs,
             out_specs=[k_spec, k_spec],
         ),
-        out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vp.shape, v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(dkv_shape, dkv_dtype),
+                   jax.ShapeDtypeStruct(dkv_shape, dkv_dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(_slab_bytes(qp, head_dim),
+                                         _slab_bytes(dop, head_dim))),
         interpret=interpret,
     )(seed, qp, kp, vp, dop, lsep, deltap, *mask_operands)
+    if group > 1 and flat_heads:      # [B, T, (Hkv, G, Dh)] -> sum over G
+        fold = lambda g, like: g.reshape(
+            g.shape[:2] + (flat_heads // group, group, head_dim)
+        ).sum(3).reshape(g.shape[:2] + (-1,)).astype(like.dtype)
+        dk, dv = fold(dk, k), fold(dv, v)
+    elif group > 1:                   # [(B, Hkv, G), T, Dh] -> sum over G
+        fold = lambda g, like: g.reshape(
+            (bh // group, group) + g.shape[1:]).sum(1).astype(like.dtype)
+        dk, dv = fold(dk, k), fold(dv, v)
     seed_zero = np.zeros(seed.shape, dtype=jax.dtypes.float0)
     mask_zero = (None if mask3 is None
                  else np.zeros(res[4].shape, dtype=jax.dtypes.float0))
@@ -547,10 +766,11 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, mask=None, dropout_rate: float = 0.0,
+def flash_attention(q, k, v, *, kind: str = "full", window: int = 0,
+                    mask=None, dropout_rate: float = 0.0,
                     dropout_rng=None, deterministic: bool = True,
-                    block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
+                    block_q: int | None = None,
+                    block_k: int | None = None,
                     interpret=None) -> jax.Array:
     """Flash attention over ``[B, T, H, Dh]`` inputs, optional mask+dropout.
 
@@ -573,6 +793,11 @@ def flash_attention(q, k, v, *, mask=None, dropout_rate: float = 0.0,
     the ``softmax="exact"`` escape hatch keeps the old uniform-fill
     artifact there.
 
+    ``kind`` / ``window``: the attention's structure (module docstring):
+    ``"full"`` bidirectional, ``"causal"``, or ``"causal_window"`` over
+    the last ``window`` keys. k and v may have fewer heads than q (a
+    divisor of q's): grouped-query attention.
+
     ``interpret``: run the Pallas interpreter instead of Mosaic (default:
     auto — True off-TPU, so a forced ``impl="flash"`` works everywhere
     and the CPU suite exercises the identical kernel code).
@@ -585,9 +810,25 @@ def flash_attention(q, k, v, *, mask=None, dropout_rate: float = 0.0,
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    long = k.shape[1] >= LONG_SEQUENCE
+    if block_q is None:
+        block_q = LONG_BLOCK if long else DEFAULT_BLOCK_Q
+    if block_k is None:
+        block_k = LONG_BLOCK if long else DEFAULT_BLOCK_K
     threshold, seed = positional_dropout_seed(
         "flash_attention", dropout_rate, dropout_rng, deterministic)
     h_total = q.shape[2]
+    if kind not in ("full", "causal", "causal_window"):
+        raise ValueError(f"unknown attention kind {kind!r}")
+    if (kind == "causal_window") != bool(window):
+        raise ValueError(f"kind {kind!r} with window {window}")
+    if h_total % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{h_total} query heads over {k.shape[2]} / "
+                         f"{v.shape[2]} key / value heads")
+    if kind != "full" and q.shape[1] != k.shape[1]:
+        raise ValueError("causal attention is self-attention: q and k "
+                         "have the same positions")
+    group = h_total // k.shape[2]
 
     def local(q, k, v, seed, mask, batch0=0, head0=0):
         b, t, h, _ = q.shape
@@ -598,9 +839,18 @@ def flash_attention(q, k, v, *, mask=None, dropout_rate: float = 0.0,
         # impl="flash" is forced at short unaligned sequence lengths).
         bq = min(block_q, max(8, -(-t // 8) * 8))
         bk = min(block_k, max(8, -(-k.shape[1] // 8) * 8))
+        dh = q.shape[-1]
+        if dh % 128 == 0:
+            # A head is a whole-lane column block of the projection: the
+            # kernels read [B, T, H*Dh] where it lies (``_layout``).
+            flat = lambda x: x.reshape(x.shape[:2] + (-1,))
+            out = _flash(flat(q), flat(k), flat(v), meta, mask3, threshold,
+                         bq, bk, interpret, mask_info, (h, h_total),
+                         (kind != "full", int(window), group, h))
+            return out.reshape(b, t, h, dh)
         out = _flash(_fold_heads(q), _fold_heads(k), _fold_heads(v), meta,
                      mask3, threshold, bq, bk, interpret, mask_info,
-                     (h, h_total))
+                     (h, h_total), (kind != "full", int(window), group, 0))
         return _unfold_heads(out, b, h)
 
     part = partition.current()

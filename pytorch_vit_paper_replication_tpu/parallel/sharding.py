@@ -77,6 +77,12 @@ def pspec_for_path(path, leaf=None) -> P:
         return P("pipe")
     for pattern, spec in TP_RULES:
         if names[-len(pattern):] == pattern:
+            # A token model's grouped-query projection has the names and
+            # not the ranks ([D, H + 2 Hkv, Dh], no bias): it stays
+            # replicated (validate_mesh_for_config refuses it a model
+            # axis).
+            if leaf is not None and len(spec) > len(leaf.shape):
+                return P()
             return spec
     return P()
 
@@ -163,5 +169,15 @@ def validate_sp_divisibility(config, mesh: Mesh) -> None:
 
 def validate_mesh_for_config(config, mesh: Mesh) -> None:
     """All mesh-vs-architecture divisibility checks in one call."""
+    if getattr(config, "vocab_size", 0):
+        others = {a: n for a, n in mesh.shape.items()
+                  if a != "data" and n > 1}
+        if others:
+            raise ValueError(
+                "a token model trains data-parallel only: its grouped-"
+                "query projection, routed experts and head have no "
+                f"sharding over {sorted(others)} (experts spread over "
+                "chips need an expert axis the mesh does not have)")
+        return
     validate_tp_divisibility(config, mesh)
     validate_sp_divisibility(config, mesh)

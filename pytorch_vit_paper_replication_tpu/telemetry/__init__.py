@@ -93,7 +93,8 @@ serve_bench A/B; ``tools/trace_demo.py`` regenerates it.
 from .chrome_trace import (to_chrome_trace, validate_chrome_trace,
                            write_chrome_trace)
 from .flops import (CHIP_PEAKS, analytic_mfu, peak_bf16_tflops,
-                    train_step_flops_per_image)
+                    train_step_flops_per_image,
+                    train_step_flops_per_sequence)
 from .profiling import (ProfileController, parse_profile_steps,
                         sample_device_memory)
 from .registry import (HELP_TEXT, INSTRUMENTS, TelemetryRegistry,
@@ -112,5 +113,6 @@ __all__ = [
     "memory_report", "parse_profile_steps", "peak_bf16_tflops",
     "render_prometheus", "sample_device_memory", "start_metrics_http",
     "to_chrome_trace", "trace_sample", "train_step_flops_per_image",
+    "train_step_flops_per_sequence",
     "validate_chrome_trace", "write_chrome_trace",
 ]

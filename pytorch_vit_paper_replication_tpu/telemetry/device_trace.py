@@ -88,6 +88,23 @@ LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
     ("optimizer", r"optimizer"),
 ))
 
+# A token model's rows, asked BEFORE :data:`LAYERS` (which stays as the
+# benchmark's frozen copy has it): the named scopes of the routed
+# feed-forward inside ``mlp`` (``ops/moe.py``), the rotary embedding
+# inside ``msa``, the token embedding and the head with its loss
+# (``ops/lm_loss.py``). A ViT's paths match none of them.
+TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
+                     for name, pat in (
+    ("moe_router", r"mlp/moe_router"),
+    ("moe_dispatch", r"mlp/(?:.*/)?moe_dispatch"),
+    ("moe_experts", r"mlp/(?:.*/)?moe_experts"),
+    ("moe_combine", r"mlp/(?:.*/)?moe_combine"),
+    ("rope", r"msa/rope"),
+    ("token_embedding", r"token_embedding"),
+    ("head_loss", r"head/(?:.*/)?loss"),
+    ("head", r"head/(?:.*/)?head"),
+))
+
 
 # ------------------------------------------------------------------ scopes
 def parse_scopes(hlo_text: str) -> dict:
@@ -129,7 +146,8 @@ def classify(scope: str, *, op: str = "", name: str = "",
     scope = (scope or "").split(";")[0]
     path = _WRAPPER.sub("", scope)
     layer = kernel or next(
-        (layer for layer, pat in LAYERS if pat.search(path)), "other")
+        (layer for layer, pat in TOKEN_LAYERS + LAYERS if pat.search(path)),
+        "other")
     if layer == "optimizer":
         phase = "optimizer"
     elif "rematted_computation" in scope or ".remat" in name:

@@ -54,6 +54,49 @@ def train_step_flops_per_image(cfg) -> float:
     return 3.0 * forward
 
 
+def visible_pairs(tokens: int, window: int = 0) -> int:
+    """Query-key pairs of causal attention over ``tokens`` positions
+    (key j <= query i), with ``window`` > 0 also ``i - j < window``."""
+    if not window or window >= tokens:
+        return tokens * (tokens + 1) // 2
+    return window * (window + 1) // 2 + (tokens - window) * window
+
+
+def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
+    """Analytic forward FLOPs of one sequence of a token model
+    (:class:`..configs.ViTConfig` with ``vocab_size`` > 0): visible
+    query-key pairs only (causal, and the window where the layer has
+    one), the expected pairs on the experts HELD (``experts_per_token x
+    held / num_experts`` a token: routing is counted as uniform), the
+    router over all experts, the head over the vocabulary rows held.
+    The embedding is a lookup and the rotary embedding elementwise:
+    neither is counted."""
+    t = seq_len or cfg.max_seq_len
+    d, dh, hq, hkv = cfg.embedding_dim, cfg.head_dim, cfg.num_heads, \
+        cfg.kv_heads
+    total = 0.0
+    for layer in range(cfg.num_layers):
+        _, window = cfg.attention_kind(layer)
+        total += 2 * t * d * (hq + 2 * hkv) * dh        # q, k, v
+        total += 2 * 2 * visible_pairs(t, window) * hq * dh   # QK^T, PV
+        total += 2 * t * hq * dh * d                    # out projection
+        if cfg.num_experts:
+            total += 2 * t * d * cfg.num_experts        # router
+            pairs = t * cfg.experts_per_token * cfg.num_experts_held \
+                / cfg.num_experts
+            total += 3 * 2 * pairs * d * cfg.expert_width   # gate, up, down
+        else:
+            total += 2 * 2 * t * d * cfg.mlp_size
+    return total + 2 * t * d * cfg.vocab_size           # head
+
+
+def train_step_flops_per_sequence(cfg, seq_len: Optional[int] = None
+                                  ) -> float:
+    """3 x forward (module docstring's convention): the token model's
+    ``flops_per_image``, a sequence being its "image"."""
+    return 3.0 * forward_flops_per_sequence(cfg, seq_len)
+
+
 def analytic_mfu(images_per_sec_per_chip: float, flops_per_image: float,
                  peak_tflops: float) -> float:
     """Model-FLOPs utilization from a per-chip image rate and that

@@ -60,6 +60,12 @@ INSTRUMENTS: Dict[str, str] = {
     "tel_eval_s": "histogram",          # eval-pass span
     "tel_images_per_sec": "gauge",      # live window throughput (global)
     "tel_mfu": "gauge",                 # analytic-FLOPs MFU (per chip)
+    # routed experts (a token model's step counters, engine._moe_metrics)
+    "tel_moe_pairs_per_expert_min": "gauge",
+    "tel_moe_pairs_per_expert_mean": "gauge",
+    "tel_moe_pairs_per_expert_max": "gauge",
+    "tel_moe_pairs_kept_share": "gauge",
+    "tel_moe_dropped_pairs_total": "counter",
     "tel_goodput_pct": "gauge",         # step-exec share of wall time
     "tel_data_wait_frac": "gauge",      # data-wait share of wall time
     "tel_steps_total": "counter",
@@ -268,6 +274,16 @@ HELP_TEXT: Dict[str, str] = {
     "tel_eval_s": "Eval-pass span seconds",
     "tel_images_per_sec": "Live window throughput, global images/sec",
     "tel_mfu": "Analytic model-FLOPs utilization per chip",
+    "tel_moe_pairs_per_expert_min":
+        "Token-expert pairs on the emptiest held expert, last sampled step",
+    "tel_moe_pairs_per_expert_mean":
+        "Token-expert pairs per held expert, mean, last sampled step",
+    "tel_moe_pairs_per_expert_max":
+        "Token-expert pairs on the fullest held expert, last sampled step",
+    "tel_moe_pairs_kept_share":
+        "Share of the pairs routed to held experts that were computed",
+    "tel_moe_dropped_pairs_total":
+        "Pairs routed to a held expert and not computed (sampled steps)",
     "tel_goodput_pct": "Step-exec share of epoch wall time, percent",
     "tel_data_wait_frac": "Data-wait share of epoch wall time",
     "tel_steps_total": "Train steps recorded",

@@ -177,9 +177,21 @@ class StepTelemetry:
 
     def step(self, *, data_wait_s: float, exec_s: float, images: int,
              step: Optional[int] = None, epoch: Optional[int] = None,
-             blocked: bool = False) -> None:
-        """Record one completed train step's spans."""
+             blocked: bool = False,
+             counters: Optional[dict] = None) -> None:
+        """Record one completed train step's spans. ``counters``: the
+        step's own counters as host floats (a token model's ``moe_*``
+        metrics, which the loop fetches on barriered steps only):
+        published as ``tel_<name>`` gauges — ``moe_dropped_pairs`` as the
+        counter ``tel_moe_dropped_pairs_total`` — and on the step's
+        row."""
         reg = self.registry
+        if counters:
+            for name, value in counters.items():
+                if name == "moe_dropped_pairs":
+                    reg.count("tel_moe_dropped_pairs_total", int(value))
+                else:
+                    reg.gauge(f"tel_{name}", round(float(value), 4))
         if self.profiler is not None and self.profiler.active:
             # The same two intervals, on the host's clock, for the open
             # capture to set beside the device's (before on_step_end,
@@ -245,6 +257,9 @@ class StepTelemetry:
                                    self.flops_per_image, self.peak_tflops)
                 reg.gauge("tel_mfu", round(mfu, 4))
                 row["tel_mfu"] = round(mfu, 4)
+            if counters:
+                row.update({f"tel_{k}": round(float(v), 4)
+                            for k, v in counters.items()})
             if step is not None:
                 row["step"] = int(step)
             if epoch is not None:

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 
 from . import engine, parallel
 from .checkpoint import Checkpointer
-from .configs import MeshConfig, PRESETS, TrainConfig
+from .configs import LM_PRESETS, MeshConfig, PRESETS, TrainConfig
 from .data import create_dataloaders, make_synthetic_image_folder
 from .data.transforms import make_transform
 from .metrics import MetricsLogger
@@ -116,12 +116,20 @@ def build_parser() -> argparse.ArgumentParser:
                            "distribution — and OFF for scratch runs)")
 
     model = p.add_argument_group("model")
-    model.add_argument("--model", choices=["vit", "tinyvgg"], default="vit",
+    model.add_argument("--model", choices=["vit", "tinyvgg", "lm"],
+                       default="vit",
                        help="tinyvgg = the reference script entry point's "
                             "baseline CNN (going_modular train.py:39-43)")
     model.add_argument("--hidden-units", type=int, default=10,
                        help="TinyVGG conv width (reference train.py:14)")
-    model.add_argument("--preset", choices=sorted(PRESETS), default="ViT-B/16")
+    model.add_argument("--preset", default="ViT-B/16",
+                       choices=sorted(PRESETS) + sorted(LM_PRESETS),
+                       help="a ViT preset, or with --model lm a token "
+                            "model's (" + ", ".join(sorted(LM_PRESETS))
+                            + ")")
+    model.add_argument("--steps-per-epoch", type=int, default=8,
+                       help="--model lm --synthetic: batches an epoch of "
+                            "the seeded token stream")
     model.add_argument("--patch-size", type=int, default=None)
     model.add_argument("--dtype", default="bfloat16",
                        choices=["bfloat16", "float32"])
@@ -605,7 +613,39 @@ def main(argv=None) -> dict:
     if args.augment and args.dataset == "packed":
         print("[info] --augment is already the default for --dataset packed")
 
-    if args.dataset == "cifar10":
+    if args.model == "lm":
+        # A token model trains on packed token sequences; the only
+        # source here is the seeded stream (data/tokens.py).
+        if args.preset not in LM_PRESETS:
+            raise SystemExit(f"--model lm takes a token model's preset "
+                             f"({', '.join(sorted(LM_PRESETS))}), not "
+                             f"{args.preset!r}")
+        if not args.synthetic:
+            raise SystemExit("--model lm reads no corpus yet: pass "
+                             "--synthetic (a seeded, predictable token "
+                             "stream)")
+        if (args.pretrained or args.freeze_backbone or args.distill_from
+                or args.mesh_pipe != 1 or args.elastic_worker_id is not None
+                or args.label_smoothing):
+            raise SystemExit(
+                "--model lm trains from scratch on plain cross entropy, "
+                "data-parallel: --pretrained/--freeze-backbone/"
+                "--distill-from/--label-smoothing/--mesh-pipe/--elastic "
+                "do not apply")
+        from .data.tokens import TokenLoader, TokenSource
+        cfg = LM_PRESETS[args.preset](
+            dtype=args.dtype, attention_impl=args.attention,
+            attention_softmax=args.attention_softmax, remat=args.remat)
+        source = TokenSource(args.seed, cfg.vocab_size, cfg.seq_len)
+        train_dl = TokenLoader(source, loader_kwargs["batch_size"],
+                               args.steps_per_epoch,
+                               stream=2 * proc_idx)
+        test_dl = TokenLoader(source, loader_kwargs["batch_size"],
+                              max(1, args.steps_per_epoch // 4),
+                              stream=2 * proc_idx + 1)
+        class_names = [f"{cfg.vocab_size} vocabulary rows x {cfg.seq_len} "
+                       "tokens"]
+    elif args.dataset == "cifar10":
         from .data import DataLoader, ResizedArrayDataset, load_cifar10, \
             make_fake_cifar10
         # CIFAR preprocessing is a plain square resize (+ optional
@@ -742,7 +782,10 @@ def main(argv=None) -> dict:
         get_registry().gauge("distill_alpha", args.distill_alpha)
         get_registry().gauge("distill_t", args.distill_t)
 
-    if args.model == "tinyvgg":
+    if args.model == "lm":
+        model = ViT(cfg)
+        model_name = args.preset
+    elif args.model == "tinyvgg":
         # Reference script-entry parity (going_modular train.py:39-43).
         if args.pretrained or args.freeze_backbone:
             raise SystemExit(
@@ -826,6 +869,11 @@ def main(argv=None) -> dict:
     if args.pretrained:
         params = init_from_pretrained(model, cfg, args.pretrained, rng=rng)
         print(f"initialized backbone from {args.pretrained}")
+    elif args.model == "lm":
+        # Jitted, so that only the initialisers run: the shapes do not
+        # depend on the sequence, and the forward pass is dead code.
+        params = jax.jit(model.init)(
+            rng, jnp.zeros((1, 8), jnp.int32))["params"]
     else:
         dummy = jnp.zeros((1, args.image_size, args.image_size, 3))
         params = model.init(rng, dummy)["params"]
@@ -991,7 +1039,8 @@ def main(argv=None) -> dict:
                 or args.ship_to or args.metrics_port is not None):
             from .telemetry import (ProfileController, StepTelemetry,
                                     Watchdog, peak_bf16_tflops,
-                                    train_step_flops_per_image)
+                                    train_step_flops_per_image,
+                                    train_step_flops_per_sequence)
             watchdog = None
             if args.watchdog_s > 0:
                 pm = args.postmortem or str(run_dir / "postmortem.txt")
@@ -1020,8 +1069,11 @@ def main(argv=None) -> dict:
             telemetry = obs_stack.enter_context(StepTelemetry(
                 args.telemetry_jsonl,
                 sample_every=args.telemetry_every,
-                flops_per_image=(train_step_flops_per_image(cfg)
-                                 if cfg is not None else None),
+                flops_per_image=(
+                    None if cfg is None
+                    else train_step_flops_per_sequence(cfg)
+                    if cfg.vocab_size
+                    else train_step_flops_per_image(cfg)),
                 peak_tflops=peak_bf16_tflops(dev0.device_kind),
                 n_chips=mesh.size,
                 watchdog=watchdog, profiler=profiler))
@@ -1216,11 +1268,13 @@ def main(argv=None) -> dict:
             save_model(export, Path(args.checkpoint_dir), "final")
             # Record the transform decision so predict applies the same
             # one — atomically, so a concurrent predict/serve reading
-            # the fresh checkpoint can't see a torn spec.
-            atomic_write_json(
-                Path(args.checkpoint_dir) / "transform.json",
-                transform_spec)
-            if cfg is not None:
+            # the fresh checkpoint can't see a torn spec. (A token model
+            # has no image transform, and no serving path reads it yet.)
+            if args.model != "lm":
+                atomic_write_json(
+                    Path(args.checkpoint_dir) / "transform.json",
+                    transform_spec)
+            if cfg is not None and args.model != "lm":
                 # Pin the model identity next to the transform: the
                 # inference loaders refuse a tier-mismatched restore
                 # loudly instead of shape-erroring mid-warmup.

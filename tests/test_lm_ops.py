@@ -1,0 +1,219 @@
+"""The token model's operators at tiny shapes on the CPU (Pallas in the
+interpreter): the flash kernels' causal / causal-window structure with
+grouped heads against an explicit visibility matrix, the dispatch's
+structure argument, rotary positions per layer, the routed experts'
+shares against the plain reference (``benchmark/lib/reference_lm.py``),
+and the head + loss in chunks.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import reference_lm
+from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+from pytorch_vit_paper_replication_tpu.models import ViT
+from pytorch_vit_paper_replication_tpu.models import vit as vit_module
+from pytorch_vit_paper_replication_tpu.ops import moe
+from pytorch_vit_paper_replication_tpu.ops.attention import (
+    dot_product_attention, short_attention_ok)
+from pytorch_vit_paper_replication_tpu.ops.flash_attention import (
+    flash_attention)
+from pytorch_vit_paper_replication_tpu.ops.lm_loss import head_cross_entropy
+
+
+def _tiny(**kw):
+    # float32 compute: the comparison is of the mathematics
+    return LM_PRESETS["lm-tiny"](dtype="float32", **kw)
+
+
+# ------------------------------------------------------- attention kinds
+def _dense_attention(q, k, v, kind, window):
+    b, t, h, d = q.shape
+    group = h // k.shape[2]
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * d ** -0.5
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None]
+    visible = j <= i
+    if kind == "causal_window":
+        visible = visible & (i - j < window)
+    p = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+@pytest.mark.parametrize("kind,window,blocks", [
+    ("causal", 0, (16, 16)),
+    ("causal_window", 12, (16, 16)),
+    ("causal_window", 40, (16, 16)),      # wider than a block
+    ("causal_window", 12, (32, 8)),       # unequal blocks
+])
+def test_flash_kernels_compute_the_structure_from_positions(
+        kind, window, blocks):
+    """7 query heads to a key/value head, T = 50 (no multiple of a
+    block), forward and the three gradients, through the Pallas
+    interpreter, against the explicit visibility matrix."""
+    ks = jax.random.split(jax.random.key(5), 4)
+    q = jax.random.normal(ks[0], (2, 50, 7, 16))
+    k = jax.random.normal(ks[1], (2, 50, 1, 16))
+    v = jax.random.normal(ks[2], (2, 50, 1, 16))
+    cot = jax.random.normal(ks[3], q.shape)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, kind=kind, window=window, block_q=blocks[0],
+        block_k=blocks[1], interpret=True)
+    dense = lambda q, k, v: _dense_attention(q, k, v, kind, window)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * cot), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+@pytest.mark.parametrize("kind,window", [("causal", 0),
+                                         ("causal_window", 5)])
+def test_dispatch_takes_the_kind_as_structure(kind, window):
+    """Off the TPU the XLA path builds the matrix the kind stands for,
+    for grouped heads too; and the short-sequence kernel goes on
+    refusing a kind it cannot do."""
+    ks = jax.random.split(jax.random.key(6), 3)
+    q = jax.random.normal(ks[0], (1, 20, 4, 8))
+    k = jax.random.normal(ks[1], (1, 20, 2, 8))
+    v = jax.random.normal(ks[2], (1, 20, 2, 8))
+    got = dot_product_attention(q, k, v, kind=kind, window=window)
+    np.testing.assert_allclose(got, _dense_attention(q, k, v, kind, window),
+                               atol=2e-5)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        ask = dict(impl="auto", dropout_rate=0.0, deterministic=True,
+                   mask=None, probs_dtype="bf16", residual_dtype=None)
+        shape = (256, 197, 3, 12, 64)
+        assert short_attention_ok(shape, jnp.bfloat16, **ask)
+        assert not short_attention_ok(shape, jnp.bfloat16, kind=kind, **ask)
+
+
+def test_rotary_follows_rope_layout():
+    """Layer 0 (rope_layout 0) is position-free: with every layer's
+    window lifted, a model of that layer alone gives a permutation of
+    the earlier tokens the same last-token output; a layer with rotary
+    positions does not. The matrices start at 1/sqrt(width), so that the
+    layer adds as much to the stream as the embedding's rows hold."""
+    def last_token(layout):
+        cfg = _tiny(num_layers=1, rope_layout=layout,
+                    sliding_window_layout=(0,), init_std=0.125)
+        model = ViT(cfg)
+        ids = jax.random.randint(jax.random.key(7), (1, 12), 0,
+                                 cfg.vocab_size)
+        params = model.init(jax.random.key(8), ids)["params"]
+        perm = jnp.concatenate([ids[:, :11][:, ::-1], ids[:, 11:]], 1)
+        a = model.apply({"params": params}, ids, False)[0, -1]
+        b = model.apply({"params": params}, perm, False)[0, -1]
+        return float(jnp.abs(a - b).max())
+
+    assert last_token((0,)) < 1e-5
+    assert last_token((1,)) > 1e-3
+    cfg = _tiny()
+    assert [cfg.layer_rope(i) for i in range(4)] == [False, True, True, True]
+    assert [cfg.attention_kind(i)[0] for i in range(4)] == [
+        "causal", "causal_window", "causal_window", "causal_window"]
+    x = jax.random.normal(jax.random.key(9), (1, 6, 2, 16))
+    want = jnp.stack([reference_lm.rotary(x[0], cfg.rope_theta)])
+    np.testing.assert_allclose(vit_module.rotary(x, cfg.rope_theta), want,
+                               atol=1e-6)
+
+
+# ------------------------------------------------------------ the experts
+def _layer(held, offset, key=10):
+    """A routed layer's inputs and 8 experts' weights; ``held`` from
+    ``offset`` are handed to the program and to the reference."""
+    ks = jax.random.split(jax.random.key(key), 6)
+    d, f, e = 32, 16, 8
+    u = jax.random.normal(ks[0], (2, 24, d))
+    h = jax.random.normal(ks[1], (2, 24, d))
+    p = {"router": {"kernel": jax.random.normal(ks[2], (d, e))},
+         "gate": 0.2 * jax.random.normal(ks[3], (e, d, f)),
+         "up": 0.2 * jax.random.normal(ks[4], (e, d, f)),
+         "down": 0.2 * jax.random.normal(ks[5], (e, f, d))}
+    share = {**p, **{k: p[k][offset:offset + held]
+                     for k in ("gate", "up", "down")}}
+    return u, h, p, share
+
+
+def _program_share(u, h, share, offset, tile=8):
+    logits = jnp.einsum("btd,de->bte", h, share["router"]["kernel"],
+                        precision="highest")
+    ids, probs = moe.route(logits, 2)
+    return moe.moe_experts(u, ids, probs, share["gate"], share["up"],
+                           share["down"], expert_offset=offset, tile=tile)
+
+
+@pytest.mark.parametrize("shares", [((4, 0), (4, 4)),
+                                    ((2, 0), (2, 2), (2, 4), (2, 6))])
+def test_the_shares_add_up_to_the_uncut_layer(shares):
+    """The parts of ``y`` that every chip's experts give sum to the uncut
+    reference's ``y`` for the whole layer (the attention part, which
+    every chip computes alike, is not in ``y`` and so is counted once)."""
+    model = {"experts_per_token": 2}
+    u, h, whole, _ = _layer(8, 0)
+    want = jnp.stack([reference_lm.routed_ffn(u[b], h[b], whole, model)
+                      for b in range(2)])
+    total, pairs = 0.0, 0
+    for held, offset in shares:
+        _, _, _, share = _layer(held, offset)
+        y, stats = _program_share(u, h, share, offset)
+        ref = jnp.stack([reference_lm.routed_ffn(
+            u[b], h[b], share, {**model, "expert_offset": offset})
+            for b in range(2)])
+        np.testing.assert_allclose(y, ref, atol=2e-5)
+        assert int(stats["kept"]) == int(stats["routed"]) \
+            == int(stats["counts"].sum())
+        total, pairs = total + y, pairs + int(stats["kept"])
+    np.testing.assert_allclose(total, want, atol=5e-5)
+    assert pairs == 2 * 24 * 2          # every pair computed once
+
+
+def test_no_pair_is_dropped_when_every_token_goes_to_one_expert():
+    """A router forced onto expert 5 (and 6 as its second): the share
+    that holds them computes all 2 x 24 x 2 pairs, groups of 48 rows on
+    tiles of 8, and the other share's experts get an empty tile each."""
+    u, h, p, _ = _layer(8, 0)
+    forced = jnp.zeros((2, 24, 8)).at[..., 5].set(9.0).at[..., 6].set(8.0)
+    ids, probs = moe.route(forced, 2)
+    assert set(np.unique(ids)) == {5, 6}
+    y, stats = moe.moe_experts(u, ids, probs, p["gate"][4:], p["up"][4:],
+                               p["down"][4:], expert_offset=4, tile=8)
+    assert stats["counts"].tolist() == [0, 48, 48, 0]
+    assert int(stats["kept"]) == int(stats["routed"]) == 96
+    want = 0.0
+    for slot, e in enumerate((5, 6)):
+        hid = jax.nn.relu(u @ p["gate"][e]) * (u @ p["up"][e])
+        want = want + probs[..., slot:slot + 1] * (hid @ p["down"][e])
+    np.testing.assert_allclose(y, want, atol=5e-5)
+    y0, stats0 = moe.moe_experts(u, ids, probs, p["gate"][:4], p["up"][:4],
+                                 p["down"][:4], expert_offset=0, tile=8)
+    assert int(stats0["kept"]) == 0 and float(jnp.abs(y0).max()) == 0.0
+    # and the empty groups' weight gradients are written (zeros)
+    g = jax.grad(lambda w: jnp.sum(moe.moe_experts(
+        u, ids, probs, w, p["up"][4:], p["down"][4:], expert_offset=4,
+        tile=8)[0]))(p["gate"][4:])
+    assert float(jnp.abs(g[0]).max()) == 0.0 < float(jnp.abs(g[1]).max())
+
+
+def test_head_cross_entropy_in_chunks_equals_the_whole():
+    ks = jax.random.split(jax.random.key(11), 3)
+    hid = jax.random.normal(ks[0], (40, 16))
+    w = jax.random.normal(ks[1], (16, 50))
+    y = jax.random.randint(ks[2], (40,), 0, 50)
+
+    def whole(hid, w):
+        lg = hid @ w
+        return jnp.mean(jax.nn.logsumexp(lg, -1)
+                        - jnp.take_along_axis(lg, y[:, None], 1)[:, 0])
+
+    chunked = lambda hid, w: head_cross_entropy(hid, w, y, 16)[0]
+    got, got_g = jax.value_and_grad(chunked, (0, 1))(hid, w)
+    want, want_g = jax.value_and_grad(whole, (0, 1))(hid, w)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, wg in zip(got_g, want_g):
+        np.testing.assert_allclose(g, wg, atol=1e-6)
+    right = head_cross_entropy(hid, w, y, 16)[1]
+    assert float(right) == float(jnp.sum(jnp.argmax(hid @ w, -1) == y))

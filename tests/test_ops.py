@@ -66,6 +66,37 @@ def test_flash_bfloat16():
                                rtol=5e-2, atol=5e-2)
 
 
+@pytest.mark.parametrize("t", [197, 577])
+def test_flash_bfloat16_full_forward_and_backward_at_vit_shapes(t):
+    """``kind="full"`` (what a ViT run gets from ``--attention flash``,
+    or from ``auto`` where ``[B,H,T,T]`` cannot fit) takes bf16 operands
+    on the MXU and rounds p and ds to bf16 before PV, dq, dk and dv
+    (since PR 27; float32 before). Against the float32 XLA result, as rms
+    in units of its standard deviation: out 0.0022, dq / dk / dv 0.0028-
+    0.0029 at both lengths over 3 seeds, which is what XLA's own bf16
+    path reads there (0.0023-0.0024, 0.0028-0.0029): the rounding of the
+    results, not of p."""
+    ks = jax.random.split(jax.random.key(t), 4)
+    q, k, v = (jax.random.normal(kk, (2, t, 3, 64), jnp.bfloat16)
+               for kk in ks[:3])
+    w = jax.random.normal(ks[3], (2, t, 3, 64))
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def out_and_grads(fn, args):
+        grads = jax.grad(lambda a: jnp.sum(f32(fn(*a)) * w))(args)
+        return (fn(*args), *grads)
+
+    want = out_and_grads(jax.nn.dot_product_attention,
+                         tuple(map(f32, (q, k, v))))
+    got = out_and_grads(lambda *a: flash_attention(*a, interpret=True),
+                        (q, k, v))
+    assert got[0].dtype == jnp.bfloat16
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        b = np.asarray(b, np.float64)
+        err = np.sqrt(np.mean((np.asarray(f32(a), np.float64) - b) ** 2))
+        assert err / b.std() < 0.004, (name, err / b.std())
+
+
 def test_dispatch_xla_on_cpu():
     """auto must choose the XLA path on CPU regardless of length."""
     q, k, v = _qkv(4, 1, 640, 2, 64)

@@ -229,3 +229,108 @@ def test_dp2_tp2_step_lowers_hidden_sliced_mlp_and_per_shard_flash(
     assert sorted(calls) == sorted([
         ("mlp_fwd", (mlp_rows, 768)), ("mlp_bwd", (mlp_rows, 768)),
         ("flash_fwd", q), ("flash_bwd_dq", q), ("flash_bwd_dkv", q)])
+
+
+# ------------------------------------------------------- the token model
+def _lower_lm_step(devices, cfg, *, dp, batch, seq_len):
+    """The trainer's step builder for a token model, from avals alone."""
+    mesh = Mesh(np.array(devices).reshape(dp, 1, 1, 1), parallel.AXES)
+    model = ViT(cfg)
+    tx = make_optimizer(TrainConfig(batch_size=batch), 100)
+
+    def abstract_state():
+        params = model.init(jax.random.key(0),
+                            jnp.zeros((1, 8), jnp.int32))["params"]
+        return engine.TrainState.create(
+            apply_fn=model.apply, params=params, tx=tx,
+            rng=jax.random.key(0, impl="unsafe_rbg"))
+
+    state = jax.eval_shape(abstract_state)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        state, parallel.state_shardings(state, mesh))
+    rows = NamedSharding(mesh, P("data"))
+    ids = jax.ShapeDtypeStruct((batch, seq_len), jnp.int32, sharding=rows)
+    return parallel.make_parallel_train_step(state, mesh).lower(
+        state, {"tokens": ids, "label": ids})
+
+
+@pytest.mark.parametrize("kind,window", [("causal", 0),
+                                         ("causal_window", 4096)])
+def test_flash_kernels_compile_at_the_token_cells_shapes(v5e_2x2, kind,
+                                                        window):
+    """T = 16,384, 28 query heads over 4 key/value heads of 128: whole k
+    and v of a head in VMEM (4 MiB each, beyond the default scoped limit),
+    loop bounds computed from the block's position, heads read as column
+    blocks of the projection: forward, dq and dk/dv compile."""
+    from pytorch_vit_paper_replication_tpu.ops.flash_attention import (
+        flash_attention)
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+    compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, kind=kind, window=window, interpret=False).astype(
+        jnp.float32)), argnums=(0, 1, 2))).lower(q, kv, kv).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    # no transposed copy of q (or of its gradient) around the kernels
+    assert not re.search(r"bf16\[28,16384,128\]", hlo)
+
+
+def test_token_models_step_compiles_and_fits_one_chip(v5e_2x2, monkeypatch):
+    """The cell ``st21b_train_16k``'s step, as the trainer builds it: one
+    16,384-token sequence through SmallThinker's period of four layers at
+    every published width. It names the flash kernels once a layer and
+    the grouped products of the held experts (two chunks of tokens; the
+    first product taken again in the backward pass), compiles for the
+    v5e, and fits 15.75 GiB with room, without rematerialisation."""
+    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LM_PRESETS["smallthinker-21b-a3b-ep4"]()
+    lowered = _lower_lm_step(v5e_2x2[:1], cfg, dp=1, batch=1,
+                             seq_len=cfg.max_seq_len)
+    names = [name for name, _ in mosaic_calls(lowered.as_text())]
+    assert {n: names.count(n) for n in set(names)} == {
+        "flash_fwd": 4, "flash_bwd_dq": 4, "flash_bwd_dkv": 4,
+        "moe_gmm_fwd": 24, "moe_gmm_dx": 16, "moe_gmm_dw": 16}
+    compiled = lowered.compile()
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert held < 14.5 * 2**30, held / 2**30
+    paths = set(device_trace.parse_scopes(compiled.as_text())["scopes"]
+                .values())
+    for scope in ("/mlp/moe_router/", "/mlp/moe_dispatch/",
+                  "/mlp/moe_experts/", "/mlp/moe_combine/",
+                  "patch_embedding/token_embedding", "/head/head/",
+                  "/head/loss/", "/msa/attn_core/"):
+        assert any(scope in path for path in paths), scope
+    # (the rotary embedding's scope, ``msa/rope``, is in the lowered text
+    # only: the compiler fuses it into its neighbours, and a fusion
+    # carries one path)
+    assert "/msa/rope/" in lowered.as_text(debug_info=True)
+    layers = [device_trace.classify(path)[0] for path in paths
+              if path.startswith("jit(")]
+    assert layers.count("other") < 0.05 * len(layers)
+
+
+def test_token_model_is_partitioned_per_shard_on_a_data_mesh(v5e_2x2,
+                                                             monkeypatch):
+    """dp = 4, two sequences a chip at the tiny preset's depth and the
+    cell's head size: the routed layer and the flash kernels arrive
+    wrapped per shard (lowered only)."""
+    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = LM_PRESETS["lm-tiny"](
+        num_layers=1, max_seq_len=1024, head_dim_override=128,
+        embedding_dim=256, expert_width=128, attention_impl="flash")
+    calls = mosaic_calls(_lower_lm_step(v5e_2x2, cfg, dp=4, batch=8,
+                                        seq_len=1024).as_text())
+    assert {name for name, _ in calls} == {
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm_fwd",
+        "moe_gmm_dx", "moe_gmm_dw"}
+    assert {shape for name, shape in calls if name == "flash_fwd"} == {
+        (2, 1024, 4 * 128)}
