@@ -125,9 +125,13 @@ def _metrics(loss, logits, labels) -> Dict[str, jax.Array]:
 def _moe_metrics(moe_stats) -> Dict[str, jax.Array]:
     """The routed layers' counters of one step, from what the blocks
     sowed (``moe_stats``: per layer ``counts [E_held]``, ``kept``,
-    ``routed``): pairs on the emptiest, the mean and the fullest held
-    expert over all layers, the share of the routed pairs that were
-    computed (1.0: there is no capacity) and the pairs dropped."""
+    ``routed``, ``passes [chunks]``): pairs on the emptiest, the mean and
+    the fullest held expert over all layers, the share of the routed
+    pairs that were computed (1.0: there is no capacity), the pairs
+    dropped, the most passes over its row buffer that any chunk of
+    tokens of any layer took, and the share of the chunks served in one
+    (``ops.moe.buffer_rows``: a load above 4/3 of an even router's costs
+    a pass more, never a pair)."""
     from flax.traverse_util import flatten_dict
     sown = flatten_dict(moe_stats)     # (..., block, "mlp", key) -> (v,)
     leaves = lambda key: [v for path, vs in sown.items()
@@ -135,6 +139,7 @@ def _moe_metrics(moe_stats) -> Dict[str, jax.Array]:
     counts = jnp.stack(leaves("counts")).astype(jnp.float32)
     kept = sum(leaves("kept")).astype(jnp.float32)
     routed = sum(leaves("routed")).astype(jnp.float32)
+    passes = jnp.concatenate(leaves("passes"))
     return {
         "moe_pairs_per_expert_min": counts.min(),
         "moe_pairs_per_expert_mean": counts.mean(),
@@ -143,6 +148,8 @@ def _moe_metrics(moe_stats) -> Dict[str, jax.Array]:
         "moe_pairs_kept_share": jnp.where(
             kept == routed, 1.0, kept / jnp.maximum(routed, 1.0)),
         "moe_dropped_pairs": routed - kept,
+        "moe_passes_max": passes.max().astype(jnp.float32),
+        "moe_one_pass_share": jnp.mean((passes == 1).astype(jnp.float32)),
     }
 
 

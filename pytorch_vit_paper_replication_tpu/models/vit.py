@@ -515,7 +515,8 @@ class RoutedMLPBlock(nn.Module):
         up = self.param("up", init, (held, d, f), jnp.float32)
         down = self.param("down", init, (held, f, d), jnp.float32)
         y, stats = moe.moe_experts(u, ids, probs, gate, up, down,
-                                   expert_offset=cfg.expert_offset)
+                                   expert_offset=cfg.expert_offset,
+                                   num_experts=logits.shape[-1])
         for key, value in stats.items():
             self.sow("moe_stats", key, value)
         return x + y

@@ -280,6 +280,18 @@ def _events(plane, line_name):
                  if ln["name"] == line_name), [])
 
 
+def leaves(events) -> list:
+    """The ops of one ``XLA Ops`` line without the control flow that
+    encloses others: the line lays a ``while`` over the ops of its body
+    (the passes of ``ops/moe.py``: 85.8 ms a step counted twice in the
+    first traced run of PR 28), and the body's ops are the device's
+    work. A loop whose body left no event of its own stays."""
+    events = sorted(events, key=lambda e: (e["start_ns"], -e["dur_ns"]))
+    return [e for e, after in zip(events, events[1:] + [None])
+            if e.get("op") not in ("while", "conditional", "call")
+            or after is None or _iv(after)[1] > _iv(e)[1]]
+
+
 def module_end_ns(trace: dict, prefix: str):
     """End of the first execution of the program named ``prefix...`` on
     the first chip that ran it (the clock anchor), or None."""
@@ -300,8 +312,8 @@ def shift_spans(spans, host_ns: int, trace_ns: int) -> list:
 
 def _chip(plane, module_prefix, by_block):
     """One chip's share of :func:`reduce`, or the reason it has none."""
-    ops = sorted(_events(plane, OPS_LINE) + _events(plane, ASYNC_LINE),
-                 key=lambda e: e["start_ns"])
+    ops = sorted(leaves(_events(plane, OPS_LINE))
+                 + _events(plane, ASYNC_LINE), key=lambda e: e["start_ns"])
     runs = sorted((m for m in _events(plane, MODULE_LINE)
                    if m["name"].startswith(module_prefix)),
                   key=lambda m: m["start_ns"])

@@ -66,6 +66,8 @@ INSTRUMENTS: Dict[str, str] = {
     "tel_moe_pairs_per_expert_max": "gauge",
     "tel_moe_pairs_kept_share": "gauge",
     "tel_moe_dropped_pairs_total": "counter",
+    "tel_moe_passes_max": "gauge",
+    "tel_moe_one_pass_share": "gauge",
     "tel_goodput_pct": "gauge",         # step-exec share of wall time
     "tel_data_wait_frac": "gauge",      # data-wait share of wall time
     "tel_steps_total": "counter",
@@ -284,6 +286,11 @@ HELP_TEXT: Dict[str, str] = {
         "Share of the pairs routed to held experts that were computed",
     "tel_moe_dropped_pairs_total":
         "Pairs routed to a held expert and not computed (sampled steps)",
+    "tel_moe_passes_max":
+        "Most passes over the routed row buffer by one chunk of tokens, "
+        "last sampled step",
+    "tel_moe_one_pass_share":
+        "Share of the routed layers' token chunks served in one pass",
     "tel_goodput_pct": "Step-exec share of epoch wall time, percent",
     "tel_data_wait_frac": "Data-wait share of epoch wall time",
     "tel_steps_total": "Train steps recorded",

@@ -163,6 +163,33 @@ def test_reduce_reports_other_and_a_collectives_exposed_part():
     assert sum(rows.values()) == pytest.approx(got["busy_ms"])
 
 
+def test_reduce_counts_a_loops_body_and_not_the_while_laid_over_it():
+    """The ``XLA Ops`` line lays a ``while`` event over its body's ops
+    (the passes of the routed layer): the body's ops are counted under
+    their own scopes, the enclosing event under none, and a loop whose
+    body left no event stays a row of its own."""
+    plane = _plane(0, 100_000)
+    ops = plane["lines"][1]["events"]
+    mlp = f"{BLOCK}/mlp"
+    for i in range(5):
+        t0 = 1000 + i * 100_000 + 20_000
+        ops += [
+            _op("while.3", t0, 5000, f"{mlp}/while", op="while"),
+            _op("fusion.30", t0 + 100, 1500,
+                f"{mlp}/while/body/moe_dispatch/gather"),
+            _op("moe_gmm_fwd.4", t0 + 1700, 3000,
+                f"{mlp}/while/body/moe_experts/moe_gmm_fwd/pallas_call",
+                mosaic=True, op="custom-call", kernel="moe_gmm_fwd"),
+            _op("while.5", t0 + 6000, 40, f"{mlp}/moe_dispatch/while",
+                op="while")]
+    got = device_trace.reduce({"planes": [plane]})
+    rows = {(r["layer"], r["phase"]): r["ms"] for r in got["rows"]}
+    assert rows[("moe_dispatch", "forward")] == pytest.approx(1.54e-3)
+    assert rows[("moe_gmm_fwd", "forward")] == pytest.approx(3e-3)
+    assert ("mlp_xla", "forward") not in rows
+    assert sum(rows.values()) == pytest.approx(got["busy_ms"])
+
+
 def test_reduce_needs_three_complete_steps_and_says_why():
     got = device_trace.reduce({"planes": [_plane(0, 100_000, runs=3)]})
     assert "rows" not in got and "2 complete executions" in got["reason"]

@@ -113,8 +113,27 @@ def test_train_step_learns_and_counts(tiny):
     assert float(m["moe_pairs_per_expert_min"]) <= mean \
         <= float(m["moe_pairs_per_expert_max"])
     assert 0.25 * T < mean < 0.75 * T
+    # half the experts held: a buffer of 2/3 of the pairs and a tile a
+    # group, one pass at an even router's load
+    assert float(m["moe_passes_max"]) >= 1.0
+    assert 0.0 <= float(m["moe_one_pass_share"]) <= 1.0
     ev = jax.jit(engine.make_eval_step())(state, batch)
     assert float(ev["count"]) == 2.0 and np.isfinite(float(ev["loss_sum"]))
+
+
+@pytest.mark.parametrize("passes,most,share", [
+    ([[1, 1], [1, 1]], 1.0, 1.0), ([[1, 2], [3, 1]], 3.0, 0.5)])
+def test_step_metrics_count_the_passes(passes, most, share):
+    """``moe_passes_max`` / ``moe_one_pass_share`` over every chunk of
+    every routed layer, from what the blocks sowed."""
+    sown = {f"encoder_block_{i}": {"mlp": {
+        "counts": (jnp.array([3, 5]),), "kept": (jnp.int32(8),),
+        "routed": (jnp.int32(8),), "passes": (jnp.array(p),)}}
+        for i, p in enumerate(passes)}
+    m = engine._moe_metrics(sown)
+    assert float(m["moe_passes_max"]) == most
+    assert float(m["moe_one_pass_share"]) == share
+    assert float(m["moe_dropped_pairs"]) == 0.0
 
 
 def test_vit_presets_take_none_of_the_token_models_options():
@@ -140,12 +159,17 @@ def test_counters_reach_step_telemetry_and_the_registry():
                        "moe_pairs_per_expert_mean": 24.0,
                        "moe_pairs_per_expert_max": 61.0,
                        "moe_pairs_kept_share": 1.0,
-                       "moe_dropped_pairs": 0.0})
+                       "moe_dropped_pairs": 0.0,
+                       "moe_passes_max": 2.0,
+                       "moe_one_pass_share": 0.875})
     snap = reg.snapshot()
     assert snap["gauges"]["tel_moe_pairs_per_expert_max"] == 61.0
     assert snap["gauges"]["tel_moe_pairs_kept_share"] == 1.0
     assert snap["counters"].get("tel_moe_dropped_pairs_total", 0) == 0
-    for name in ("tel_moe_pairs_per_expert_min",
+    assert snap["gauges"]["tel_moe_passes_max"] == 2.0
+    assert snap["gauges"]["tel_moe_one_pass_share"] == 0.875
+    for name in ("tel_moe_passes_max", "tel_moe_one_pass_share",
+                 "tel_moe_pairs_per_expert_min",
                  "tel_moe_pairs_per_expert_mean",
                  "tel_moe_pairs_per_expert_max", "tel_moe_pairs_kept_share",
                  "tel_moe_dropped_pairs_total"):
@@ -221,6 +245,8 @@ def test_entry_point_trains_the_tiny_preset(tmp_path, capsys):
     sampled = [r for r in rows if "tel_moe_pairs_kept_share" in r]
     assert sampled and all(r["tel_moe_pairs_kept_share"] == 1.0
                            and r["tel_moe_dropped_pairs"] == 0.0
+                           and r["tel_moe_passes_max"] >= 1.0
+                           and 0.0 <= r["tel_moe_one_pass_share"] <= 1.0
                            for r in sampled)
     with pytest.raises(SystemExit, match="token model's preset"):
         main(["--model", "lm", "--preset", "ViT-B/16", "--synthetic"])

@@ -143,7 +143,8 @@ def _program_share(u, h, share, offset, tile=8):
                         precision="highest")
     ids, probs = moe.route(logits, 2)
     return moe.moe_experts(u, ids, probs, share["gate"], share["up"],
-                           share["down"], expert_offset=offset, tile=tile)
+                           share["down"], expert_offset=offset,
+                           num_experts=logits.shape[-1], tile=tile)
 
 
 @pytest.mark.parametrize("shares", [((4, 0), (4, 4)),
@@ -180,22 +181,143 @@ def test_no_pair_is_dropped_when_every_token_goes_to_one_expert():
     ids, probs = moe.route(forced, 2)
     assert set(np.unique(ids)) == {5, 6}
     y, stats = moe.moe_experts(u, ids, probs, p["gate"][4:], p["up"][4:],
-                               p["down"][4:], expert_offset=4, tile=8)
+                               p["down"][4:], expert_offset=4,
+                               num_experts=8, tile=8)
     assert stats["counts"].tolist() == [0, 48, 48, 0]
     assert int(stats["kept"]) == int(stats["routed"]) == 96
+    # 14 tiles through a buffer of 8 + 4 (4/3 of half the pairs, and a
+    # tile a group): a second pass, and no pair dropped
+    assert moe.buffer_rows(96, 4, 8, 8) == 96 < moe.worst_case_rows(96, 4, 8)
+    assert stats["passes"].tolist() == [2]
     want = 0.0
     for slot, e in enumerate((5, 6)):
         hid = jax.nn.relu(u @ p["gate"][e]) * (u @ p["up"][e])
         want = want + probs[..., slot:slot + 1] * (hid @ p["down"][e])
     np.testing.assert_allclose(y, want, atol=5e-5)
     y0, stats0 = moe.moe_experts(u, ids, probs, p["gate"][:4], p["up"][:4],
-                                 p["down"][:4], expert_offset=0, tile=8)
+                                 p["down"][:4], expert_offset=0,
+                                 num_experts=8, tile=8)
     assert int(stats0["kept"]) == 0 and float(jnp.abs(y0).max()) == 0.0
+    assert stats0["passes"].tolist() == [1]
     # and the empty groups' weight gradients are written (zeros)
     g = jax.grad(lambda w: jnp.sum(moe.moe_experts(
         u, ids, probs, w, p["up"][4:], p["down"][4:], expert_offset=4,
-        tile=8)[0]))(p["gate"][4:])
+        num_experts=8, tile=8)[0]))(p["gate"][4:])
     assert float(jnp.abs(g[0]).max()) == 0.0 < float(jnp.abs(g[1]).max())
+
+
+# ------------------------------------------------------------- the passes
+# 48 tokens x 2 over 16 experts, experts 3 and 4 held, tiles of 8: the
+# buffer is 8 x (ceil(96 x 2/16 x 4/3 / 8) + 2) = 32 rows (4 tiles) of
+# the worst case's 112. ``counts``: tokens sent to expert 3 (the first
+# so many, in slot 0) and to expert 4 (the last so many, in slot 1).
+_PASS_CASES = {
+    # name: (counts, chunks of tokens, passes of each chunk)
+    "one_pass": ((9, 7), 1, [1]),
+    "ends_on_the_boundary": ((16, 16), 1, [1]),
+    "group_straddles_two_passes": ((40, 20), 1, [2]),
+    "expert_without_a_pair_in_the_later_pass": ((8, 48), 1, [2]),
+    "every_pair_held_three_passes": ((48, 48), 1, [3]),
+    # 24 tokens a chunk: 24 rows (3 tiles) of 64
+    "two_chunks_one_pass_each": ((9, 7), 2, [1, 1]),
+    "two_chunks_every_pair_held": ((48, 48), 2, [2, 2]),
+    "two_chunks_of_which_the_first_takes_two": ((24, 8), 2, [2, 1]),
+}
+
+
+def _pass_case(counts):
+    ks = jax.random.split(jax.random.key(21), 7)
+    n, d, f = 48, 32, 16
+    token = jnp.arange(n)
+    ids = jnp.stack([jnp.where(token < counts[0], 3, 0),
+                     jnp.where(token >= n - counts[1], 4, 1)],
+                    axis=-1).astype(jnp.int32).reshape(2, 24, 2)
+    return {"u": jax.random.normal(ks[0], (2, 24, d)), "ids": ids,
+            "logits": jax.random.normal(ks[1], (2, 24, 2)),
+            "gate": 0.2 * jax.random.normal(ks[2], (2, d, f)),
+            "up": 0.2 * jax.random.normal(ks[3], (2, d, f)),
+            "down": 0.2 * jax.random.normal(ks[4], (2, f, d)),
+            "dy": jax.random.normal(ks[5], (2, 24, d))}
+
+
+def _dense_share(u, logits, gate, up, down, ids):
+    """The held experts' part, every token through every held expert in
+    float32 and weighted afterwards: the plain reference."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    y = 0.0
+    for e in range(gate.shape[0]):
+        weight = jnp.sum(jnp.where(ids == 3 + e, probs, 0.0), axis=-1)
+        hidden = jax.nn.relu(jnp.einsum(
+            "btd,df->btf", u, gate[e], precision="highest")) * jnp.einsum(
+            "btd,df->btf", u, up[e], precision="highest")
+        y = y + weight[..., None] * jnp.einsum(
+            "btf,fd->btd", hidden, down[e], precision="highest")
+    return y
+
+
+@pytest.mark.parametrize("case", list(_PASS_CASES))
+def test_passes_over_the_row_buffer_equal_the_dense_layer(case, monkeypatch):
+    """Output and every gradient (input, router probabilities through
+    their logits, the three weights) of the held experts' part against
+    the dense reference, at loads that take one, two and three passes
+    over the buffer, alone and with the tokens in two chunks (whose
+    weight gradients go on from the first chunk's); no pair dropped."""
+    counts, chunks, passes = _PASS_CASES[case]
+    c = _pass_case(counts)
+    monkeypatch.setattr(moe, "MAX_PAIRS", 96 // chunks)
+    assert moe.buffer_rows(96 // chunks, 2, 16, 8) == (32 if chunks == 1
+                                                       else 24)
+
+    def program(u, logits, gate, up, down):
+        y, stats = moe.moe_experts(
+            u, c["ids"], jax.nn.softmax(logits, axis=-1), gate, up, down,
+            expert_offset=3, num_experts=16, tile=8)
+        return y, stats
+
+    args = tuple(c[k] for k in ("u", "logits", "gate", "up", "down"))
+    y, pull, stats = jax.vjp(program, *args, has_aux=True)
+    want, want_pull = jax.vjp(
+        lambda *a: _dense_share(*a, c["ids"]), *args)
+    assert stats["passes"].tolist() == passes
+    assert stats["counts"].tolist() == list(counts)
+    assert int(stats["kept"]) == int(stats["routed"]) == sum(counts)
+    np.testing.assert_allclose(y, want, atol=3e-5)
+    for name, got, ref in zip(("du", "dlogits", "dgate", "dup", "ddown"),
+                              pull(c["dy"]), want_pull(c["dy"])):
+        np.testing.assert_allclose(got, ref, atol=1e-4, err_msg=name)
+
+
+def _whiles(fn, *args):
+    """``while`` equations in the jaxpr of ``fn`` and of its gradient,
+    inner jaxprs included."""
+    def count(jaxpr):
+        total = 0
+        for eqn in jaxpr.eqns:
+            total += eqn.primitive.name == "while"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                total += count(sub)
+        return total
+
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2, 3, 4))
+    return count(jax.make_jaxpr(grad)(*args).jaxpr)
+
+
+def test_no_loop_is_built_where_every_expert_is_held():
+    """A buffer as long as the layout is one pass by construction: the
+    caller that holds every expert runs the program without passes (its
+    jaxpr has no ``while``); one that holds a share gets one loop a
+    chunk of tokens forward and one backward."""
+    c = _pass_case((9, 7))
+    args = tuple(c[k] for k in ("u", "logits", "gate", "up", "down"))
+
+    def share(num_experts):
+        return lambda u, logits, gate, up, down: moe.moe_experts(
+            u, c["ids"] - 3, jax.nn.softmax(logits, axis=-1), gate, up,
+            down, num_experts=num_experts, tile=8)[0]
+
+    assert moe.buffer_rows(96, 2, 2, 8) == moe.worst_case_rows(96, 2, 8)
+    assert _whiles(share(None), *args) == _whiles(share(2), *args) == 0
+    assert _whiles(share(16), *args) == 2
 
 
 def test_head_cross_entropy_in_chunks_equals_the_whole():

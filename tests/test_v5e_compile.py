@@ -232,6 +232,9 @@ def test_dp2_tp2_step_lowers_hidden_sliced_mlp_and_per_shard_flash(
 
 
 # ------------------------------------------------------- the token model
+# The cell's step program by ``memory_analysis``, GiB (the parent of PR 28
+# compiled to 13.54).
+STEP_GIB = 13.9
 def _lower_lm_step(devices, cfg, *, dp, batch, seq_len):
     """The trainer's step builder for a token model, from avals alone."""
     mesh = Mesh(np.array(devices).reshape(dp, 1, 1, 1), parallel.AXES)
@@ -282,10 +285,14 @@ def test_token_models_step_compiles_and_fits_one_chip(v5e_2x2, monkeypatch):
     """The cell ``st21b_train_16k``'s step, as the trainer builds it: one
     16,384-token sequence through SmallThinker's period of four layers at
     every published width. It names the flash kernels once a layer and
-    the grouped products of the held experts (two chunks of tokens; the
-    first product taken again in the backward pass), compiles for the
-    v5e, and fits 15.75 GiB with room, without rematerialisation."""
+    the grouped products of the held experts (two chunks of tokens, each
+    a loop of passes whose body is lowered once; the first product taken
+    again in the backward pass), compiles for the v5e, fits 15.75 GiB
+    with room, without rematerialisation, and holds no activation array
+    of the routed layer's worst-case rows: its passes run over
+    ``moe.buffer_rows`` rows."""
     from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+    from pytorch_vit_paper_replication_tpu.ops import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = LM_PRESETS["smallthinker-21b-a3b-ep4"]()
@@ -299,11 +306,17 @@ def test_token_models_step_compiles_and_fits_one_chip(v5e_2x2, monkeypatch):
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes)
-    assert held < 14.5 * 2**30, held / 2**30
-    paths = set(device_trace.parse_scopes(compiled.as_text())["scopes"]
-                .values())
-    for scope in ("/mlp/moe_router/", "/mlp/moe_dispatch/",
-                  "/mlp/moe_experts/", "/mlp/moe_combine/",
+    assert held < STEP_GIB * 2**30, held / 2**30
+    hlo = compiled.as_text()
+    paths = set(device_trace.parse_scopes(hlo)["scopes"].values())
+    layers = [device_trace.classify(path)[0] for path in paths
+              if path.startswith("jit(")]
+    for layer in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine"):
+        assert layer in layers, layer
+    for scope in ("/mlp/while/body/moe_dispatch/",
+                  "/mlp/while/body/moe_experts/",
+                  "/mlp/while/body/moe_combine/",
                   "patch_embedding/token_embedding", "/head/head/",
                   "/head/loss/", "/msa/attn_core/"):
         assert any(scope in path for path in paths), scope
@@ -311,9 +324,23 @@ def test_token_models_step_compiles_and_fits_one_chip(v5e_2x2, monkeypatch):
     # only: the compiler fuses it into its neighbours, and a fusion
     # carries one path)
     assert "/msa/rope/" in lowered.as_text(debug_info=True)
-    layers = [device_trace.classify(path)[0] for path in paths
-              if path.startswith("jit(")]
     assert layers.count("other") < 0.05 * len(layers)
+    # 8,192 tokens x 6 a chunk: 53,248 rows in the worst case, 20,480 a
+    # pass. Of the worst case only the int32 tables are left (one column).
+    pairs = cfg.max_seq_len // 2 * cfg.experts_per_token
+    worst = moe.worst_case_rows(pairs, cfg.num_experts_held, moe.ROW_TILE)
+    rows = moe.buffer_rows(pairs, cfg.num_experts_held, cfg.num_experts,
+                           moe.ROW_TILE)
+    assert (worst, rows) == (53248, 20480)
+    tables = -(-worst // rows) * rows
+    wide = set(re.findall(r"\w+\[(?:%d|%d),\d+[\],]" % (worst, tables),
+                          hlo))
+    assert not wide, wide
+    assert re.search(r"bf16\[%d,2560\]" % rows, hlo)
+    # the weight gradients go from pass to pass and from chunk to chunk
+    # where they lie: no copy of one is made
+    assert not re.search(r"= f32\[16,(?:2560,1536|768,2560)\]\S* copy\(",
+                         hlo)
 
 
 def test_token_model_is_partitioned_per_shard_on_a_data_mesh(v5e_2x2,
