@@ -72,10 +72,6 @@ STEP_FLOOR_IMG_S = 800.0
 # top. Gated via rows_ok + per-row vit_*_mfu_ok.
 L16_MFU_BAND = (0.35, 0.65)
 H14_MFU_BAND = (0.28, 0.55)
-# r6 bytes-side attention A/B variants re-measured every driver run
-# (tools/attn_bytes_ab.py is the full harness; these are the headline
-# three: baseline, one fp8, the 256-level exact-range fixed point).
-ATTN_PROBS_AB_VARIANTS = ("bf16", "fp8_e4m3", "u8")
 # Non-gate keys that ride the final compact line anyway (r8: the cold/
 # warm seconds travel WITH cold_start_ok so a tail capture carries the
 # evidence, not just the verdict; r9: the measured telemetry overhead
@@ -143,15 +139,6 @@ def compact_gates_line(payload: dict) -> str:
     line = json.dumps(compact, separators=(",", ":"))
     assert len(line) <= 900, f"compact gates line grew to {len(line)} chars"
     return line
-
-
-def attention_probs_mb(cfg, batch_size: int, probs_dtype: str) -> float:
-    """MB of one materialized [B,H,T,T] attention-probs tensor in the
-    given storage format (ops/quant.py owns the formula)."""
-    from pytorch_vit_paper_replication_tpu.ops.quant import probs_tensor_mb
-
-    return probs_tensor_mb(batch_size, cfg.num_heads, cfg.seq_len,
-                           probs_dtype)
 
 
 def train_step_flops_per_image(cfg) -> float:
@@ -822,27 +809,10 @@ def main() -> None:
         gc.collect()
         h14_img_s = _try_row("vit_h14", h14_cfg, 64)
         gc.collect()
-        # r6 bytes-side attention A/B (VERDICT r5 weak #3, driver-
-        # verifiable): the headline storage variants for the materialized
-        # softmax probs, each measured IN the full jitted B/16 train step
-        # in THIS process — the r5 discipline (isolated-core wins
-        # routinely reverse in-step). Informational fields; the default
-        # only changes on a >+2% win recorded in PERF.md.
-        attn_ab = {}
-        for pd in ATTN_PROBS_AB_VARIANTS:
-            img = _try_row(
-                f"attn_probs_{pd}",
-                cfg.replace(attention_probs_dtype=pd), batch_size)
-            attn_ab[pd] = {
-                "images_per_sec": round(img, 2) if img is not None else None,
-                "probs_tensor_mb": round(
-                    attention_probs_mb(cfg, batch_size, pd), 1)}
-            gc.collect()
     else:
         shape_ceiling, ceiling_runs, fused_pair = 0.0, [], 0.0
         l16_cfg = h14_cfg = None
         l16_img_s = h14_img_s = None
-        attn_ab = None
     cold_rates, cached_img_s = bench_input_pipeline(cfg.image_size,
                                                     batch_size)
     cold_med = sorted(cold_rates)[len(cold_rates) // 2]
@@ -1040,15 +1010,6 @@ def main() -> None:
                                              L16_MFU_BAND)
     h14_tflops, h14_mfu, h14_ok = _row_stats(h14_img_s, h14_cfg,
                                              H14_MFU_BAND)
-    attn_probs_best = attn_probs_best_win_pct = None
-    if attn_ab and attn_ab.get("bf16", {}).get("images_per_sec"):
-        _base = attn_ab["bf16"]["images_per_sec"]
-        _narrow = {k: v["images_per_sec"] for k, v in attn_ab.items()
-                   if k != "bf16" and v["images_per_sec"]}
-        if _narrow:
-            attn_probs_best = max(_narrow, key=_narrow.get)
-            attn_probs_best_win_pct = round(
-                100.0 * (_narrow[attn_probs_best] / _base - 1.0), 2)
 
     payload = {
         # The long prose note comes FIRST: the driver captures a
@@ -1095,11 +1056,7 @@ def main() -> None:
             "random-read path the gate replaced). r6: l16/h14 rows "
             "carry analytic tflops/mfu with expected bands "
             "(vit_*_mfu_ok, folded into rows_ok — a null OR out-of-band "
-            "row fails); attn_probs_ab = bytes-side attention A/B "
-            "(storage dtype of the materialized softmax probs, "
-            "full-step img/s per variant in this process, "
-            "tools/attn_bytes_ab.py + PERF.md r6 — informational, the "
-            "default changes only on a >+2% win); serve_* (r7, "
+            "row fails); serve_* (r7, "
             "tools/serve_bench.py at bench scale): online micro-batcher "
             "closed-loop at 32 clients vs sequential batch-of-1 through "
             "the same warmed jit — serve_throughput_ok gates >= 3x "
@@ -1264,14 +1221,6 @@ def main() -> None:
         # design, not failed: the gates stay true (no permanently-false
         # gates — r4 VERDICT #4's principle).
         "rows_ok": bool(l16_ok and h14_ok),
-        # r6 bytes-side attention A/B (VERDICT r5 weak #3): full-step
-        # img/s per probs-storage variant, measured in THIS process.
-        # Informational — the DEFAULT only changes on a >+2% win
-        # (PERF.md r6 records the decision either way).
-        "attention_probs_dtype": cfg.attention_probs_dtype,
-        "attn_probs_ab": attn_ab,
-        "attn_probs_best": attn_probs_best,
-        "attn_probs_best_win_pct": attn_probs_best_win_pct,
         "flops_per_image": round(train_step_flops_per_image(cfg) / 1e9, 2),
         "input_pipeline_images_per_sec": round(cold_med, 2),
         # Raw image-folder JPEG cold decode — informational only (r4

@@ -50,10 +50,10 @@ class ViTConfig:
     # "flash" = the Pallas flash-attention kernel in ops/flash_attention.py;
     # "auto" = on a TPU the short-sequence kernel pair of
     # ops/short_attention.py where the call allows it (no mask, no active
-    # attention dropout, bf16 probability storage, head size 64 or 128, a
-    # [T,T] tile that fits VMEM: T = 197 does, T = 577 does not), flash
-    # where the materialized logits would not fit HBM, xla for the rest and
-    # off the TPU.
+    # attention dropout, head size 64 or 128, a [T,T] tile that fits VMEM:
+    # T = 197 does, T = 577 does not), flash where the materialized logits
+    # would not fit HBM, xla for the rest and off the TPU
+    # (ops/attention.py::choose).
     attention_impl: str = "auto"
     # MLP-block execution path: "xla" = two nn.Dense GEMMs with the hidden
     # activation materialized between them; "fused" = the Pallas fused
@@ -72,24 +72,6 @@ class ViTConfig:
     # growth (the ViT-22B/QK-norm failure mode). Flash/ring/ulysses
     # paths always carry their own exact online softmax.
     attention_softmax: str = "saturating"
-    # Storage format of the XLA attention path's materialized softmax
-    # weights — the step's largest HBM tensor at T=197 and the carrier of
-    # the ~25-MFU-point "softmax tax" PERF.md r5 priced (ops/quant.py
-    # formats). "bf16" = compute dtype, unquantized — bit-identical to
-    # the pre-r6 path; "fp8_e4m3"/"fp8_e5m2"/"u8" store 8 bits/element
-    # (probs are in [0,1]; u8 is a 256-level exact-range fixed point)
-    # via a custom_vjp whose backward dequantizes in-register. Measured
-    # A/B: tools/attn_bytes_ab.py + the bench's attn_probs_ab rows; the
-    # default changes only on a >+2% full-step win (PERF.md r6).
-    # Quantized storage does not compose with attn_dropout > 0 (falls
-    # back to bf16 storage, warns once) and is ignored by the
-    # flash/ring/ulysses paths, which never materialize the probs.
-    attention_probs_dtype: str = "bf16"
-    # Storage format of the attention backward RESIDUAL alone (None =
-    # follow attention_probs_dtype). "bf16" probs + a narrow residual
-    # keeps the forward numerics exact and shrinks only the saved tensor
-    # the backward re-reads.
-    attention_probs_residual_dtype: str | None = None
     # Rematerialize encoder blocks to trade FLOPs for HBM (for huge configs).
     remat: bool = False
     # Pool strategy for classification: "cls" token (reference vit.py:235)
@@ -189,18 +171,6 @@ class ViTConfig:
         if self.attention_softmax not in ("saturating", "exact"):
             raise ValueError(
                 f"unknown attention_softmax {self.attention_softmax!r}")
-        from .ops.quant import PROBS_DTYPES
-        if self.attention_probs_dtype not in PROBS_DTYPES:
-            raise ValueError(
-                f"unknown attention_probs_dtype "
-                f"{self.attention_probs_dtype!r}; expected one of "
-                f"{PROBS_DTYPES}")
-        if (self.attention_probs_residual_dtype is not None
-                and self.attention_probs_residual_dtype not in PROBS_DTYPES):
-            raise ValueError(
-                f"unknown attention_probs_residual_dtype "
-                f"{self.attention_probs_residual_dtype!r}; expected one of "
-                f"{PROBS_DTYPES} (or None to follow attention_probs_dtype)")
 
     @property
     def num_patches(self) -> int:

@@ -50,8 +50,7 @@ import numpy as np
 
 from ..configs import ViTConfig
 from ..ops import partition
-from ..ops.attention import (dot_product_attention, self_attention,
-                             short_attention_ok)
+from ..ops.attention import choose, dot_product_attention, self_attention
 from ..ops.dropout import Dropout
 
 
@@ -208,19 +207,11 @@ class MultiHeadSelfAttentionBlock(nn.Module):
         dropout_rng = None
         if train and cfg.attn_dropout > 0.0:
             dropout_rng = self.make_rng("dropout")
-        dispatch = dict(
-            impl=cfg.attention_impl,
-            dropout_rate=cfg.attn_dropout,
-            deterministic=not train,
-            probs_dtype=cfg.attention_probs_dtype,
-            residual_dtype=cfg.attention_probs_residual_dtype,
-        )
         heads = (cfg.num_heads, cfg.head_dim)
         # Where the short-sequence kernel will serve the call, the
         # projections are taken flat, so that the compiler lays their
         # results out as the kernel reads them (_FlatDenseGeneral).
-        if _flat_projections(y.shape[:2] + (3,) + heads, _dtype(cfg),
-                             **dispatch):
+        if _flat_projections(cfg, y.shape[:2] + (3,) + heads, train):
             dense = _FlatDenseGeneral
         else:
             dense = functools.partial(nn.DenseGeneral,
@@ -234,20 +225,20 @@ class MultiHeadSelfAttentionBlock(nn.Module):
         # The projection goes to the dispatch packed: the short-sequence
         # kernel reads it where it lies; every other path slices q, k, v.
         attn = self_attention(
-            qkv,
-            dropout_rng=dropout_rng,
+            qkv, impl=cfg.attention_impl, dropout_rate=cfg.attn_dropout,
+            dropout_rng=dropout_rng, deterministic=not train,
+            softmax=cfg.attention_softmax,
             # Manual TP hands this module a head-LOCAL config: tell the
             # dispatcher so its Ulysses divisibility pre-check doesn't
             # divide the already-local head count again (ADVICE r4).
             heads_already_local=self.tp_axis is not None,
-            softmax=cfg.attention_softmax,
-            **dispatch,
         )                                        # [B, T, H(_local), Dh]
         out = dense(features=cfg.embedding_dim, axis=(-2, -1),
                     dtype=_dtype(cfg), name="out")(attn)
         if self.tp_axis is not None:
             out = jax.lax.psum(out, self.tp_axis)
         return (out, y) if with_normed else out
+
 
 def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
                      train: bool):
@@ -291,13 +282,18 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     return out, y
 
 
-def _flat_projections(qkv_shape, dtype, **dispatch) -> bool:
+def _flat_projections(cfg: ViTConfig, qkv_shape, train: bool) -> bool:
     """Whether this block's projections are taken over flattened feature
     dims (:class:`_FlatDenseGeneral`): where the short-sequence kernel
-    serves the call, and no mesh axis splits the heads (a head-sharded
-    ``[D, 3, H, Dh]`` kernel has no flat ``[D, 3*D]`` sharding; there
-    the kernel still runs, per shard, on the 5-D projection)."""
-    if not short_attention_ok(qkv_shape, dtype, mask=None, **dispatch):
+    will serve the block's call (asked of the dispatch's own
+    :func:`..ops.attention.choose`, before the projection exists), and
+    no mesh axis splits the heads (a head-sharded ``[D, 3, H, Dh]``
+    kernel has no flat ``[D, 3*D]`` sharding; there the kernel still
+    runs, per shard, on the 5-D projection)."""
+    served, _ = choose(qkv_shape, _dtype(cfg), impl=cfg.attention_impl,
+                       dropout_rate=cfg.attn_dropout,
+                       deterministic=not train)
+    if served != "short":
         return False
     part = partition.current()
     return part is None or part.size(part.model_axis) == 1

@@ -3,9 +3,7 @@ from .dropout import Dropout, dropout, quantized_rate
 from .flash_attention import flash_attention
 from .fused_mlp import fused_ln_mlp_residual, fused_mlp
 from .partition import on_mesh
-from .quant import PROBS_DTYPES, dequantize_probs, quantize_probs
 
-__all__ = ["Dropout", "PROBS_DTYPES", "dequantize_probs",
-           "dot_product_attention", "dropout", "flash_attention",
+__all__ = ["Dropout", "dot_product_attention", "dropout", "flash_attention",
            "fused_ln_mlp_residual", "fused_mlp", "on_mesh",
-           "quantize_probs", "quantized_rate", "self_attention"]
+           "quantized_rate", "self_attention"]

@@ -17,11 +17,11 @@ function so the execution path can be swapped without touching model code:
                  online-softmax accumulator. O(T) memory: the only path
                  that runs when the ``[B,H,T,T]`` logits cannot fit
                  (t=8192 at B=8,H=12 OOMs the XLA path on 16 GB).
-* ``"auto"``   — from what the call can observe. A self-attention
-                 call on the packed qkv projection
-                 (:func:`self_attention`) on a TPU with no mask, no
-                 active attention dropout, bf16 probability storage, a
-                 head size of 64 or 128 and a ``[T, T]`` tile that fits
+* ``"auto"``   — from what the call can observe (:func:`choose`, the
+                 one place that decides). A self-attention call on the
+                 packed qkv projection (:func:`self_attention`) on a
+                 TPU with no mask, no active attention dropout, a head
+                 size of 64 or 128 and a ``[T, T]`` tile that fits
                  VMEM takes the short-sequence kernel pair
                  (:mod:`.short_attention`: the projection read where it
                  lies, ``[T, T]`` never in HBM). Since PR 26 speed does
@@ -72,13 +72,12 @@ from __future__ import annotations
 
 import functools
 import warnings
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from . import partition, short_attention
-from .quant import PROBS_DTYPES, dequantize_probs, quantize_probs
 
 # auto-dispatch: switch to the Pallas kernel when the XLA path would
 # materialize this much for attention logits (+probs +backward residual,
@@ -96,24 +95,16 @@ _FLASH_MIN_SEQ = 512  # Pallas kernel's own tiling floor
 _SOFTMAX_SHIFT = 16.0
 _SOFTMAX_CLAMP = 80.0
 
-def _sp_partition():
-    """The active :class:`.partition.Partition` when its seq axis is >1
-    (attention must then run sequence-parallel), else None."""
-    part = partition.current()
-    if part is None or part.size(part.seq_axis) <= 1:
-        return None
-    return part
-
 
 @functools.lru_cache(maxsize=None)
 def _warn_once(msg: str) -> None:
     warnings.warn(msg, stacklevel=3)
 
 
-def _sp_attention(q, k, v, part, *, dropout_rate=0.0, dropout_rng=None,
+def _sp_attention(q, k, v, served, *, dropout_rate=0.0, dropout_rng=None,
                   deterministic=True):
-    """Dispatch to ring or Ulysses attention over the seq axis
-    (shard_map'd, per the partition's sp_impl).
+    """``"ring"`` or ``"ulysses"`` attention over the active partition's
+    seq axis (shard_map'd).
 
     Batch is sharded over the data axis and heads over the model axis (a
     size-1 axis is a no-op), so the same call serves dp x tp x sp meshes.
@@ -124,8 +115,9 @@ def _sp_attention(q, k, v, part, *, dropout_rate=0.0, dropout_rng=None,
     from ..parallel.ring_attention import make_ring_attention
     from ..parallel.ulysses import make_ulysses_attention
 
+    part = partition.current()
     mesh = part.mesh
-    make = (make_ulysses_attention if part.sp_impl == "ulysses"
+    make = (make_ulysses_attention if served == "ulysses"
             else make_ring_attention)
     head_axis = (part.model_axis if part.model_axis in mesh.axis_names
                  else None)
@@ -137,108 +129,9 @@ def _sp_attention(q, k, v, part, *, dropout_rate=0.0, dropout_rng=None,
     return fn(q, k, v)
 
 
-def _softmax32(logits32, softmax: str):
-    """The XLA path's f32 softmax over [B, H, T, Tk] logits — factored so
-    the plain path and the quantized-storage custom_vjp share one
-    definition. See ``_xla_attention`` for the saturating/exact trade."""
-    if softmax == "exact":
-        m = jax.lax.stop_gradient(jnp.max(logits32, axis=-1,
-                                          keepdims=True))
-        e = jnp.exp(logits32 - m)
-        return e / jnp.sum(e, axis=-1, keepdims=True)
-    e = jnp.exp(jnp.minimum(logits32 - _SOFTMAX_SHIFT, _SOFTMAX_CLAMP))
-    return e / (jnp.sum(e, axis=-1, keepdims=True) + 1e-35)
-
-
-# --- low-precision materialized-probs storage (the bytes-side attack) -----
-#
-# PERF.md r5 priced the residual 25 MFU points at T=197 as ~98 ms of pure
-# HBM traffic on the materialized [B,H,T,T] softmax tensors, and measured
-# every graph-RESTRUCTURING attack (flash kernel, remat, deferred
-# normalization, ...) negative at these shapes. The one untried mechanism
-# class is shrinking the BYTES: probs live in [0,1], so 8-bit storage
-# (fp8 or fixed-point u8, ops/quant.py) halves the largest tensor's
-# traffic without touching the graph shape. The custom_vjp below is what
-# makes that real on the backward side too: jax's AD would save the bf16
-# weights as the PV-matmul residual regardless of what the forward
-# stored, so the narrow tensor must be the residual BY CONSTRUCTION, with
-# the backward dequantizing in-register.
-#
-# Backward math: with w = e/(s+eps) (either softmax flavor), the exact
-# vjp is dl_k = w_k * (dw_k - sum_j dw_j w_j) — the epsilon and any
-# constant shift cancel. One approximation, documented: the saturating
-# flavor's clamp gate (zero grad through entries with logit-shift > 80)
-# is not reproducible from the saved probs alone and is treated as
-# pass-through; the saturated regime is a documented pathology
-# (attention-logit growth) where quantized storage should not be used
-# anyway — config validation is the guard rail, this comment is the
-# record.
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
-def _quantized_softmax_pv(logits32, v, softmax: str, probs_dtype: str,
-                          residual_dtype: str, out_dtype: str):
-    """softmax(logits) @ v with the materialized probs stored in
-    ``probs_dtype`` and the backward residual stored in
-    ``residual_dtype`` (ops/quant.py formats; "bf16" = compute dtype).
-
-    ``logits32``: f32 [B,H,T,Tk], already scaled/masked. ``v``:
-    [B,Tk,H,Dh]. Returns [B,T,H,Dh] in ``out_dtype``.
-    """
-    out, _ = _quantized_softmax_pv_fwd(logits32, v, softmax, probs_dtype,
-                                       residual_dtype, out_dtype)
-    return out
-
-
-def _quantized_softmax_pv_fwd(logits32, v, softmax, probs_dtype,
-                              residual_dtype, out_dtype):
-    w32 = _softmax32(logits32, softmax)
-    if probs_dtype == "bf16":
-        # Forward-exact storage; only the backward residual is narrow.
-        w_pv = w32.astype(out_dtype)
-        wq = (w_pv if residual_dtype == "bf16"
-              else quantize_probs(w32, residual_dtype))
-    else:
-        wq_fwd = quantize_probs(w32, probs_dtype)
-        w_pv = dequantize_probs(wq_fwd, probs_dtype, out_dtype)
-        if residual_dtype == probs_dtype:
-            wq = wq_fwd
-        elif residual_dtype == "bf16":
-            # "bf16" means COMPUTE dtype everywhere in this subsystem
-            # (ops/quant.py docstring) — for f32-compute models the
-            # residual stays f32, matching the probs_dtype=="bf16"
-            # branch above.
-            wq = w32.astype(out_dtype)
-        else:
-            wq = quantize_probs(w32, residual_dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", w_pv, v)
-    return out, (wq, v)
-
-
-def _quantized_softmax_pv_bwd(softmax, probs_dtype, residual_dtype,
-                              out_dtype, res, g):
-    wq, v = res
-    w = (wq if residual_dtype == "bf16"
-         else dequantize_probs(wq, residual_dtype, out_dtype))
-    # Mirror the AD path's matmul dtypes: operands in the compute dtype
-    # (the MXU accumulates f32 internally either way).
-    dv = jnp.einsum("bhqk,bqhd->bkhd", w, g)
-    dw = jnp.einsum("bqhd,bkhd->bhqk", g, v)
-    w32 = w.astype(jnp.float32)
-    dw32 = dw.astype(jnp.float32)
-    dl = w32 * (dw32 - jnp.sum(dw32 * w32, axis=-1, keepdims=True))
-    return dl, dv
-
-
-_quantized_softmax_pv.defvjp(_quantized_softmax_pv_fwd,
-                             _quantized_softmax_pv_bwd)
-
-
 def _xla_attention(q, k, v, *, dropout_rate: float, dropout_rng,
                    deterministic: bool, mask=None,
-                   softmax: str = "saturating",
-                   probs_dtype: str = "bf16",
-                   residual_dtype: Optional[str] = None):
+                   softmax: str = "saturating"):
     """Reference-semantics attention via XLA, shapes [B, T, H, Dh].
 
     Hand-rolled einsum rather than ``jax.nn.dot_product_attention`` — the
@@ -257,18 +150,6 @@ def _xla_attention(q, k, v, *, dropout_rate: float, dropout_rng,
     20% on the ISOLATED core vjp but regresses the FULL step 304 -> 318
     ms — the bf16 ``e``/f32 ``s`` pair changes which residuals XLA
     saves; kept f32.)
-
-    ``probs_dtype`` / ``residual_dtype`` (r6, the bytes-side attack):
-    storage format of the materialized softmax weights and of the
-    backward residual respectively (``ops/quant.py`` formats —
-    ``"bf16"``/``"fp8_e4m3"``/``"fp8_e5m2"``/``"u8"``).
-    ``residual_dtype=None`` follows ``probs_dtype``. The default
-    ``("bf16", None)`` is BIT-IDENTICAL to the pre-r6 path (same jaxpr);
-    anything narrower routes through :func:`_quantized_softmax_pv`, whose
-    custom_vjp saves the narrow tensor and dequantizes in-register in the
-    backward. Quantized storage does not compose with attention dropout
-    (the 1/keep rescale pushes weights above the [0,1] packing range):
-    such calls warn once and use bf16 storage.
     """
     scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -302,22 +183,16 @@ def _xla_attention(q, k, v, *, dropout_rate: float, dropout_rng,
     # The epsilon also gives fully-MASKED rows the same zero-output
     # semantics as the flash kernel. Measured on the B/16 step: 304.6
     # -> 299.5 ms (+1.7%), the row-max read was the last avoidable
-    # full-tensor pass. (The softmax itself lives in _softmax32, shared
-    # with the quantized-storage custom_vjp.)
+    # full-tensor pass.
     logits32 = logits.astype(jnp.float32)
-    rd = residual_dtype if residual_dtype is not None else probs_dtype
-    quantized = probs_dtype != "bf16" or rd != "bf16"
-    if quantized and not deterministic and dropout_rate > 0.0:
-        _warn_once(
-            "attention probs quantization (attention_probs_dtype/"
-            "attention_probs_residual_dtype) does not compose with "
-            "attention dropout — the 1/keep rescale exceeds the [0,1] "
-            "packing range; using bf16 storage for dropout calls")
-        quantized = False
-    if quantized:
-        return _quantized_softmax_pv(logits32, v, softmax, probs_dtype,
-                                     rd, jnp.dtype(q.dtype).name)
-    weights = _softmax32(logits32, softmax)
+    if softmax == "exact":
+        m = jax.lax.stop_gradient(jnp.max(logits32, axis=-1,
+                                          keepdims=True))
+        e = jnp.exp(logits32 - m)
+        weights = e / jnp.sum(e, axis=-1, keepdims=True)
+    else:
+        e = jnp.exp(jnp.minimum(logits32 - _SOFTMAX_SHIFT, _SOFTMAX_CLAMP))
+        weights = e / (jnp.sum(e, axis=-1, keepdims=True) + 1e-35)
     if not deterministic and dropout_rate > 0.0:
         from .dropout import dropout as _u8_dropout
         weights = _u8_dropout(weights, dropout_rate, dropout_rng)
@@ -338,27 +213,111 @@ def structure_mask(kind: str, window: int, t: int):
     return vis[None, None]
 
 
-def _flash_ok(q) -> bool:
-    """auto-mode: use the Pallas kernel only when the XLA path's
-    materialized logits would not fit comfortably (and shapes qualify).
-    Under a mesh the logits are split over batch and heads, so it is the
-    per-device share that is weighed."""
-    if jax.default_backend() != "tpu":
-        return False
-    b, t, h, dh = q.shape
+def choose(shape, dtype, k_shape=None, *, impl: str = "auto",
+           kind: str = "full", mask=None, dropout_rate: float = 0.0,
+           deterministic: bool = True, heads_already_local: bool = False,
+           backend: Optional[str] = None) -> Tuple[str, Optional[str]]:
+    """Which implementation serves an attention call, from what the call
+    can observe: ``(name, reason)``, ``name`` one of ``"short"``,
+    ``"flash"``, ``"ring"``, ``"ulysses"``, ``"xla"``. Every other
+    function here, and the model where it lays its projections out for
+    the kernel before they exist, asks this one; no other place holds a
+    rule.
+
+    ``shape`` is the packed projection's ``[B, T, 3, H, Dh]``
+    (:func:`self_attention`) or q's ``[B, T, H, Dh]`` with ``k_shape``
+    where k has heads of its own; ``backend`` defaults to
+    ``jax.default_backend()``; the mesh is the active
+    :class:`.partition.Partition`'s. The keywords are the call's
+    (:func:`dot_product_attention`). ``reason`` is set where a ``seq``
+    mesh could not be honoured: the caller warns with it, once.
+
+    The rules, in order:
+
+    1. A ``seq`` axis > 1 decides alone. Structure (``kind`` or fewer
+       key heads), a mask, a batch or token count the mesh does not
+       divide, or Ulysses with heads the axis does not divide take the
+       XLA path, which GSPMD keeps correct by gathering K/V; never a
+       Pallas kernel on seq-sharded operands. Else ``sp_impl``'s ring
+       or Ulysses.
+    2. A packed call under ``"auto"`` on a TPU with nothing the
+       short-sequence kernel does not do (mask, structure, active
+       attention dropout) and (per-shard) shapes it serves
+       (:func:`.short_attention.supported`: head size, whole slabs of
+       heads, the ``[T, T]`` working set against VMEM) takes that
+       kernel. The softmax flavour is not asked: the kernel's exact
+       softmax serves either.
+    3. Flash where forced, or under ``"auto"`` on a TPU where the XLA
+       path's logits, probabilities and backward residual (3x the
+       per-shard logits) pass ``_FLASH_MEMORY_BYTES`` at a length and
+       head size the kernel tiles. Memory decides, not speed.
+    4. XLA.
+    """
+    if impl not in ("xla", "flash", "auto"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    if kind not in ("full", "causal", "causal_window"):
+        raise ValueError(f"unknown attention kind {kind!r}")
+    packed = len(shape) == 5
+    (b, t), (h, dh) = shape[:2], shape[-2:]
+    structured = kind != "full" or (k_shape is not None
+                                    and k_shape[2] != h)
     part = partition.current()
-    if part is not None:
-        b = -(-b // part.size(part.data_axis))
-        h = -(-h // part.size(part.model_axis))
-    if t < _FLASH_MIN_SEQ or dh not in (32, 64, 128, 256):
-        return False
-    logits_bytes = b * h * t * t * jnp.dtype(q.dtype).itemsize
-    return 3 * logits_bytes > _FLASH_MEMORY_BYTES
+
+    if part is not None and part.size(part.seq_axis) > 1:
+        seq_size = part.size(part.seq_axis)
+        if structured:
+            return "xla", (
+                "sequence_parallel: ring/ulysses attention serve "
+                "bidirectional attention with equal head counts only; "
+                "using the (gathered) XLA path instead")
+        if mask is not None:
+            return "xla", (
+                "sequence_parallel: attention masks are not supported by "
+                "ring/ulysses attention; using the (gathered) XLA path "
+                "instead")
+        if t % seq_size or b % part.size(part.data_axis):
+            return "xla", (
+                f"sequence_parallel: shape (batch={b}, tokens={t}) not "
+                f"divisible by mesh axes {dict(part.mesh.shape)}; using the "
+                "(gathered) XLA path instead. Hint: pool='gap' removes the "
+                "odd CLS token from the sequence length")
+        if part.sp_impl == "ring":
+            return "ring", None
+        if not heads_already_local:
+            # Under GSPMD-TP the traced h is global and must be divided
+            # down to the per-shard head count; manual-TP callers hold
+            # local heads already and say so via heads_already_local.
+            h = max(1, h // part.size(part.model_axis))
+        if h % seq_size:
+            return "xla", (
+                f"sequence_parallel: sp_impl='ulysses' needs heads ({h}) "
+                f"divisible by the seq axis ({seq_size}); using the "
+                "(gathered) XLA path instead — or use sp_impl='ring'")
+        return "ulysses", None
+
+    auto_on_tpu = (impl == "auto"
+                   and (backend or jax.default_backend()) == "tpu")
+    if (auto_on_tpu and packed and not structured and mask is None
+            and (deterministic or dropout_rate <= 0.0)
+            and short_attention.supported(shape, dtype)):
+        return "short", None
+    if impl == "flash":
+        return "flash", None
+    if auto_on_tpu and t >= _FLASH_MIN_SEQ and dh in (32, 64, 128, 256):
+        # Under a mesh the logits are split over batch and heads: it is
+        # the per-device share that is weighed.
+        if part is not None:
+            b = -(-b // part.size(part.data_axis))
+            h = -(-h // part.size(part.model_axis))
+        logits_bytes = b * h * t * t * jnp.dtype(dtype).itemsize
+        if 3 * logits_bytes > _FLASH_MEMORY_BYTES:
+            return "flash", None
+    return "xla", None
 
 
-# One scope for every implementation (XLA, flash, ring, ulysses), so that a
-# device trace splits `msa` into norm / qkv / attn_core / out and what is
-# left: the slices and transposes between them (telemetry/device_trace.py).
+# One scope for every implementation (short, XLA, flash, ring, ulysses), so
+# that a device trace splits `msa` into norm / qkv / attn_core / out and what
+# is left: the slices and transposes between them (telemetry/device_trace.py).
 @jax.named_scope("attn_core")
 def dot_product_attention(
     q: jax.Array,
@@ -366,18 +325,17 @@ def dot_product_attention(
     v: jax.Array,
     *,
     impl: str = "auto",
+    kind: str = "full",
+    window: int = 0,
+    mask: Optional[jax.Array] = None,
     dropout_rate: float = 0.0,
     dropout_rng: Optional[jax.Array] = None,
     deterministic: bool = True,
-    mask: Optional[jax.Array] = None,
-    heads_already_local: bool = False,
     softmax: str = "saturating",
-    probs_dtype: str = "bf16",
-    residual_dtype: Optional[str] = None,
-    kind: str = "full",
-    window: int = 0,
+    heads_already_local: bool = False,
 ) -> jax.Array:
-    """Multi-head scaled dot-product attention.
+    """Multi-head scaled dot-product attention, by the implementation
+    :func:`choose` names for the call.
 
     Args:
       q, k, v: ``[batch, seq, heads, head_dim]``; k and v may have a
@@ -386,32 +344,21 @@ def dot_product_attention(
       kind / window: the attention's structure (module docstring):
         ``"full"``, ``"causal"`` or ``"causal_window"`` over ``window``
         keys.
+      mask: optional boolean ``[batch, heads, q, k]`` mask (True = attend).
       dropout_rate / dropout_rng / deterministic: attention-weight dropout
         (reference ``attn_dropout``, models/vit.py:75).
-      mask: optional boolean ``[batch, heads, q, k]`` mask (True = attend).
+      softmax: XLA-path softmax flavor — ``"saturating"`` (default,
+        +1.7% step: no row-max read; exact for logits <= ~96, saturates
+        beyond) or ``"exact"`` (max-subtracted, any magnitude). See
+        ``configs.ViTConfig.attention_softmax``. Ignored by the
+        short/flash/ring/ulysses paths, which carry their own exact
+        softmax.
       heads_already_local: set by manual-TP callers (inside ``shard_map``,
         e.g. the pipeline's head-sliced MSA) whose ``q`` already carries
         per-shard heads — the Ulysses divisibility pre-check then uses
         ``heads`` as-is instead of dividing by the model-axis size
         (ADVICE r4: guessing from the mesh under-counted and could
         spuriously route to the gathered XLA fallback).
-      softmax: XLA-path softmax flavor — ``"saturating"`` (default,
-        +1.7% step: no row-max read; exact for logits <= ~96, saturates
-        beyond) or ``"exact"`` (max-subtracted, any magnitude). See
-        ``configs.ViTConfig.attention_softmax``. Ignored by the
-        flash/ring/ulysses paths, which carry their own exact online
-        softmax.
-      probs_dtype: storage format for the XLA path's materialized softmax
-        weights (``ops/quant.py``: ``"bf16"`` = compute dtype /
-        ``"fp8_e4m3"`` / ``"fp8_e5m2"`` / ``"u8"`` fixed-point — probs
-        are in [0,1], so u8 quantizes exactly that range in 256 levels).
-        The bytes-side attack on the [B,H,T,T] HBM tax (PERF.md r6).
-        Irrelevant to — and ignored by — the flash/ring/ulysses paths:
-        they never materialize the probs at all.
-      residual_dtype: storage format for the backward residual alone
-        (``None`` = follow ``probs_dtype``). ``"bf16"`` probs + a narrow
-        residual keeps the forward exact and shrinks only the saved
-        tensor the backward re-reads.
 
     Returns:
       ``[batch, seq, heads, head_dim]`` attention output (pre out-projection).
@@ -427,154 +374,55 @@ def dot_product_attention(
     softmax with nonzero grads — so don't combine "exact" with
     fully-masked rows expecting zeros. The one remaining
     fallback (warns once per process): an active sequence-parallel
-    mesh with a mask or shapes not divisible by the mesh axes uses the
-    XLA path, which GSPMD keeps correct by gathering K/V instead of
-    ring-rotating them. Attention dropout rides the ring natively.
+    mesh with structure, a mask or shapes not divisible by the mesh
+    axes uses the XLA path (:func:`choose`, rule 1). Attention dropout
+    rides the ring natively.
     """
-    if impl not in ("xla", "flash", "auto"):
-        raise ValueError(f"unknown attention impl {impl!r}")
-    if probs_dtype not in PROBS_DTYPES:
-        raise ValueError(f"unknown probs_dtype {probs_dtype!r}; "
-                         f"expected one of {PROBS_DTYPES}")
-    if residual_dtype is not None and residual_dtype not in PROBS_DTYPES:
-        raise ValueError(f"unknown residual_dtype {residual_dtype!r}; "
-                         f"expected one of {PROBS_DTYPES}")
-
-    if kind not in ("full", "causal", "causal_window"):
-        raise ValueError(f"unknown attention kind {kind!r}")
-    structured = kind != "full" or k.shape[2] != q.shape[2]
-
-    def xla(q, k, v, mask):
-        """The XLA path, given what ``kind`` and the head counts stand
-        for: the visibility matrix and repeated key/value heads."""
-        if structured:
-            group = q.shape[2] // k.shape[2]
-            if group > 1:
-                k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
-            vis = structure_mask(kind, window, q.shape[1])
-            if vis is not None:
-                mask = vis if mask is None else jnp.logical_and(mask, vis)
-        return _xla_attention(q, k, v, dropout_rate=dropout_rate,
-                              dropout_rng=dropout_rng,
-                              deterministic=deterministic, mask=mask,
-                              softmax=softmax, probs_dtype=probs_dtype,
-                              residual_dtype=residual_dtype)
-
-    sp = _sp_partition()
-    if sp is not None and structured:
-        _warn_once(
-            "sequence_parallel: ring/ulysses attention serve bidirectional "
-            "attention with equal head counts only; using the (gathered) "
-            "XLA path instead")
-        return xla(q, k, v, mask)
-    if sp is not None:
-        b, t, h = q.shape[0], q.shape[1], q.shape[2]
-        seq_size = sp.size(sp.seq_axis)
-        if not heads_already_local:
-            # Under GSPMD-TP the traced h is global and must be divided
-            # down to the per-shard head count; manual-TP callers hold
-            # local heads already and say so via heads_already_local.
-            h = max(1, h // sp.size(sp.model_axis))
-        if mask is not None:
-            _warn_once(
-                "sequence_parallel: attention masks are not supported by "
-                "ring/ulysses attention; using the (gathered) XLA path "
-                "instead")
-        elif t % seq_size or b % sp.size(sp.data_axis):
-            _warn_once(
-                f"sequence_parallel: shape (batch={b}, tokens={t}) not "
-                f"divisible by mesh axes {dict(sp.mesh.shape)}; using the "
-                "(gathered) XLA path instead. Hint: pool='gap' removes the "
-                "odd CLS token from the sequence length")
-        elif sp.sp_impl == "ulysses" and h % seq_size:
-            _warn_once(
-                f"sequence_parallel: sp_impl='ulysses' needs heads ({h}) "
-                f"divisible by the seq axis ({seq_size}); using the "
-                "(gathered) XLA path instead — or use sp_impl='ring'")
-        else:
-            return _sp_attention(q, k, v, sp, dropout_rate=dropout_rate,
-                                 dropout_rng=dropout_rng,
-                                 deterministic=deterministic)
-        # Honor the fallback message: never hand seq-sharded operands to
-        # the Pallas kernel — GSPMD only guarantees the gathered semantics
-        # for the plain XLA ops.
-        return xla(q, k, v, mask)
-
-    use_flash = impl == "flash" or (impl == "auto" and _flash_ok(q))
-    if use_flash:
+    served, reason = choose(
+        q.shape, q.dtype, k.shape, impl=impl, kind=kind, mask=mask,
+        dropout_rate=dropout_rate, deterministic=deterministic,
+        heads_already_local=heads_already_local)
+    if reason is not None:
+        _warn_once(reason)
+    dropout = dict(dropout_rate=dropout_rate, dropout_rng=dropout_rng,
+                   deterministic=deterministic)
+    if served in ("ring", "ulysses"):
+        return _sp_attention(q, k, v, served, **dropout)
+    if served == "flash":
         from .flash_attention import flash_attention
         return flash_attention(q, k, v, kind=kind, window=window,
-                               mask=mask, dropout_rate=dropout_rate,
-                               dropout_rng=dropout_rng,
-                               deterministic=deterministic)
-    return xla(q, k, v, mask)
+                               mask=mask, **dropout)
+    # The XLA path, given what ``kind`` and the head counts stand for: the
+    # visibility matrix and repeated key/value heads.
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    vis = structure_mask(kind, window, q.shape[1])
+    if vis is not None:
+        mask = vis if mask is None else jnp.logical_and(mask, vis)
+    return _xla_attention(q, k, v, mask=mask, softmax=softmax, **dropout)
 
 
-def short_attention_ok(qkv_shape, dtype, *, impl, dropout_rate,
-                       deterministic, mask, probs_dtype,
-                       residual_dtype, kind: str = "full") -> bool:
-    """auto-mode: whether the short-sequence kernel pair serves a
-    self-attention call on a packed projection of this shape
-    ``[B, T, 3, H, Dh]``. Decided from the call alone, before the
-    projection exists (the model asks, to lay the projection out for the
-    kernel): the backend, the absence of what the kernel does not do
-    (mask, causal or windowed structure, active attention dropout,
-    quantised probability storage, a sequence-parallel mesh) and the
-    (per-shard) shapes
-    (:func:`.short_attention.supported`: head size, whole slabs of
-    heads, the ``[T, T]`` working set against VMEM). The softmax flavour
-    is not asked: the kernel's exact softmax serves either."""
-    if impl != "auto" or jax.default_backend() != "tpu":
-        return False
-    if mask is not None or (not deterministic and dropout_rate > 0.0):
-        return False
-    if kind != "full":
-        return False
-    if probs_dtype != "bf16" or residual_dtype not in (None, "bf16"):
-        return False
-    if _sp_partition() is not None:
-        return False
-    return short_attention.supported(qkv_shape, dtype)
-
-
-def self_attention(qkv: jax.Array, *, impl: str = "auto",
-                   dropout_rate: float = 0.0,
+def self_attention(qkv: jax.Array, *, window: int = 0,
                    dropout_rng: Optional[jax.Array] = None,
-                   deterministic: bool = True,
-                   mask: Optional[jax.Array] = None,
-                   heads_already_local: bool = False,
-                   softmax: str = "saturating",
-                   probs_dtype: str = "bf16",
-                   residual_dtype: Optional[str] = None,
-                   kind: str = "full", window: int = 0) -> jax.Array:
-    """Self-attention from the packed qkv projection
-    ``[batch, seq, 3, heads, head_dim]`` -> ``[batch, seq, heads,
-    head_dim]``; the keywords are :func:`dot_product_attention`'s.
-    (A grouped-query projection is not packed this way: its block calls
-    :func:`dot_product_attention` with q, k and v.)
+                   softmax: str = "saturating", **call) -> jax.Array:
+    """:func:`dot_product_attention` from the packed qkv projection
+    ``[batch, seq, 3, heads, head_dim]``, with its keywords (named here:
+    the three :func:`choose` does not read). A grouped-query projection
+    is not packed this way: its block calls
+    :func:`dot_product_attention` with q, k and v.
 
-    Where ``impl="auto"`` finds the call one the short-sequence kernel
-    serves (:func:`short_attention_ok`), the projection goes to it as it
-    lies and no q, k or v is ever cut out of it. The kernel's softmax is the
-    exact, max-subtracted one, which equals the ``"saturating"`` flavour
-    over that flavour's whole exact range. Every other call gets q, k, v
-    sliced from the projection and :func:`dot_product_attention`; the
+    Where :func:`choose` names the short-sequence kernel, the projection
+    goes to it as it lies and no q, k or v is ever cut out of it. The
+    kernel's softmax is the exact, max-subtracted one, which equals the
+    ``"saturating"`` flavour over that flavour's whole exact range.
+    Every other call gets q, k, v sliced from the projection; the
     slices stay outside the ``attn_core`` scope, where a device trace
     has always counted them (``msa_glue``).
     """
-    if impl not in ("xla", "flash", "auto"):
-        raise ValueError(f"unknown attention impl {impl!r}")
-    if short_attention_ok(
-            qkv.shape, qkv.dtype, impl=impl, dropout_rate=dropout_rate,
-            deterministic=deterministic, mask=mask,
-            probs_dtype=probs_dtype, residual_dtype=residual_dtype,
-            kind=kind):
+    if choose(qkv.shape, qkv.dtype, **call)[0] == "short":
         with jax.named_scope("attn_core"):
             return short_attention.short_attention(qkv)
     return dot_product_attention(
-        qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], impl=impl,
-        dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-        deterministic=deterministic, mask=mask,
-        heads_already_local=heads_already_local, softmax=softmax,
-        probs_dtype=probs_dtype, residual_dtype=residual_dtype,
-        kind=kind, window=window)
+        qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], window=window,
+        dropout_rng=dropout_rng, softmax=softmax, **call)

@@ -70,13 +70,6 @@ DEFAULT_BLOCK_ROWS = 256
 _SQRT_HALF = math.sqrt(0.5)
 _INV_SQRT_2PI = 1.0 / math.sqrt(2.0 * math.pi)
 
-# Measured-A/B hook (ADVICE r4; tools/h_dtype_ab.py): dtype the backward
-# residual ``h`` is saved in. None = the compute dtype (production
-# default). Trace-time only — set before jitting, not a public API; the
-# measured step-cost/gradient-effect numbers that keep the default are
-# in PERF.md r5.
-SAVED_H_DTYPE = None
-
 
 def _erf(x):
     """erf via Abramowitz & Stegun 7.1.26 (max abs error 1.5e-7 — below
@@ -138,7 +131,7 @@ def _fwd_kernel(meta_ref, x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref,
     ``h`` is the ROUNDED pre-activation, so the backward re-derives
     GELU'(h)/dropout from a value that differs from the f32 ``h`` the
     forward used — a one-ulp-of-bf16 gradient mismatch invisible to the
-    f32 parity tests. MEASURED r5 (tools/h_dtype_ab.py, PERF.md): saving
+    f32 parity tests. MEASURED r5 (PERF.md): saving
     h as f32 instead costs ~2.5% of the full B/16 step (848->827 img/s,
     the doubled [rows, mlp_size] residual round-trip) while moving no
     grad's error vs an f32 reference (both variants ~3-5e-3, dominated
@@ -250,7 +243,7 @@ def _fused_call(x, w1, b1, w2, b2, seed, threshold, block_rows, interpret,
     if save_h:
         out_specs.append(pl.BlockSpec((block_rows, f), lambda i, *_: (i, 0)))
         out_shape.append(
-            jax.ShapeDtypeStruct((n, f), SAVED_H_DTYPE or x.dtype))
+            jax.ShapeDtypeStruct((n, f), x.dtype))
     res = pl.pallas_call(
         kernel,
         name="mlp_fwd",
@@ -471,7 +464,7 @@ def _lnmlp_call(x, gamma, beta, w1, b1, w2, b2, seed, threshold, block_rows,
     if save_h:
         out_specs.append(pl.BlockSpec((block_rows, f), lambda i, *_: (i, 0)))
         out_shape.append(
-            jax.ShapeDtypeStruct((n, f), SAVED_H_DTYPE or x.dtype))
+            jax.ShapeDtypeStruct((n, f), x.dtype))
     res = pl.pallas_call(
         kernel,
         name="lnmlp_fwd",

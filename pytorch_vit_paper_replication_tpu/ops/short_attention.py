@@ -33,10 +33,10 @@ of VMEM. This pair of kernels is written for that case.
   the compute dtype with ``preferred_element_type=float32``; the softmax
   is f32.
 
-Selected by :func:`.attention.self_attention` (``attention_impl="auto"``
-on a TPU: no mask, no active attention dropout, no quantised
-probabilities, Dh 64 or 128, and :func:`plan` finding the working set
-inside the VMEM budget). Under a mesh the call runs per shard inside
+Selected by :func:`.attention.choose` for :func:`.attention.self_attention`
+(``attention_impl="auto"`` on a TPU: no mask, no active attention
+dropout, Dh 64 or 128, and :func:`plan` finding the working set inside
+the VMEM budget). Under a mesh the call runs per shard inside
 ``shard_map`` (batch over the data axis, heads over the model axis), as
 the MLP kernels do (:mod:`.partition`).
 """

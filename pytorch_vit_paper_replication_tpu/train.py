@@ -147,24 +147,6 @@ def build_parser() -> argparse.ArgumentParser:
                             "max-subtracted at any magnitude (use under "
                             "attention-logit growth, the ViT-22B/QK-norm "
                             "regime)")
-    model.add_argument("--attention-probs-dtype", default="bf16",
-                       choices=["bf16", "fp8_e4m3", "fp8_e5m2", "u8"],
-                       help="storage format of the XLA attention path's "
-                            "materialized softmax weights — the step's "
-                            "largest HBM tensor (r6 bytes-side attack; "
-                            "ops/quant.py). 'bf16' = compute dtype, "
-                            "bit-identical to r5; 8-bit formats halve "
-                            "that tensor's traffic via a custom_vjp "
-                            "(dequantized in-register in backward). "
-                            "A/B'd by tools/attn_bytes_ab.py; see "
-                            "PERF.md r6 before changing it")
-    model.add_argument("--attention-probs-residual-dtype", default=None,
-                       choices=["bf16", "fp8_e4m3", "fp8_e5m2", "u8"],
-                       help="storage format of the attention backward "
-                            "residual alone (default: follow "
-                            "--attention-probs-dtype). bf16 probs + a "
-                            "narrow residual keeps forward numerics "
-                            "exact and shrinks only the saved tensor")
     model.add_argument("--sp-impl", default="ring",
                        choices=["ring", "ulysses"],
                        help="sequence-parallel strategy for --mesh-seq>1: "
@@ -558,9 +540,6 @@ def main(argv=None) -> dict:
     cfg_kwargs = dict(image_size=args.image_size, dtype=args.dtype,
                       attention_impl=args.attention,
                       attention_softmax=args.attention_softmax,
-                      attention_probs_dtype=args.attention_probs_dtype,
-                      attention_probs_residual_dtype=(
-                          args.attention_probs_residual_dtype),
                       mlp_impl=args.mlp_impl, remat=args.remat,
                       pool=args.pool)
     if args.patch_size:

@@ -94,8 +94,8 @@ def _batch(rng, n=8):
 
 def test_resharded_restore_dp4_to_dp2_bit_faithful(tmp_path, devices):
     """A dp=4-saved checkpoint loads onto a dp=2 mesh with bit-equal
-    params/opt state and an IDENTICAL next-step loss — what survivor
-    re-formation relies on."""
+    params/opt state and the same next-step loss (to the last place of
+    its float32 sum) — what survivor re-formation relies on."""
     cfg = _tiny_cfg()
     st4, mesh4 = _make_state(cfg, 4)
     rng = np.random.default_rng(0)
@@ -130,10 +130,16 @@ def test_resharded_restore_dp4_to_dp2_bit_faithful(tmp_path, devices):
     next_batch = _batch(rng)
     _, m2 = step(st2, parallel.shard_batch(next_batch, mesh2))
     _, m4 = step(ref4, parallel.shard_batch(next_batch, mesh4))
-    assert float(jax.device_get(m2["loss_sum"])) == \
-        float(jax.device_get(m4["loss_sum"]))
-    assert float(jax.device_get(m2["grad_norm"])) == \
-        float(jax.device_get(m4["grad_norm"]))
+    # The same per-example losses and gradients from the same bits, summed
+    # over 2 shards of 4 and over 4 of 2: another order of float32
+    # additions, so the two sums (and the norm of the summed gradient) may
+    # differ in their last places. A few ulps, not bit-equality, is what a
+    # change of layout keeps; everything restored above is bit-exact.
+    for name in ("loss_sum", "grad_norm"):
+        np.testing.assert_allclose(
+            np.float32(jax.device_get(m2[name])),
+            np.float32(jax.device_get(m4[name])),
+            rtol=8 * np.finfo(np.float32).eps, atol=0, err_msg=name)
     ck.close()
 
 

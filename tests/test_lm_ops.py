@@ -17,7 +17,7 @@ from pytorch_vit_paper_replication_tpu.models import ViT
 from pytorch_vit_paper_replication_tpu.models import vit as vit_module
 from pytorch_vit_paper_replication_tpu.ops import moe
 from pytorch_vit_paper_replication_tpu.ops.attention import (
-    dot_product_attention, short_attention_ok)
+    choose, dot_product_attention)
 from pytorch_vit_paper_replication_tpu.ops.flash_attention import (
     flash_attention)
 from pytorch_vit_paper_replication_tpu.ops.lm_loss import head_cross_entropy
@@ -82,13 +82,11 @@ def test_dispatch_takes_the_kind_as_structure(kind, window):
     got = dot_product_attention(q, k, v, kind=kind, window=window)
     np.testing.assert_allclose(got, _dense_attention(q, k, v, kind, window),
                                atol=2e-5)
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(jax, "default_backend", lambda: "tpu")
-        ask = dict(impl="auto", dropout_rate=0.0, deterministic=True,
-                   mask=None, probs_dtype="bf16", residual_dtype=None)
-        shape = (256, 197, 3, 12, 64)
-        assert short_attention_ok(shape, jnp.bfloat16, **ask)
-        assert not short_attention_ok(shape, jnp.bfloat16, kind=kind, **ask)
+    ask = dict(impl="auto", dropout_rate=0.0, deterministic=True,
+               mask=None, backend="tpu")
+    shape = (256, 197, 3, 12, 64)
+    assert choose(shape, jnp.bfloat16, **ask)[0] == "short"
+    assert choose(shape, jnp.bfloat16, kind=kind, **ask)[0] == "xla"
 
 
 def test_rotary_follows_rope_layout():

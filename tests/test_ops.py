@@ -150,20 +150,6 @@ def test_xla_attention_bf16_gradients_finite_and_close():
                                    err_msg=f"d{name}")
 
 
-def test_auto_dispatch_is_memory_based(monkeypatch):
-    """auto picks flash only when the XLA path's materialized logits would
-    not fit (v5e measurements: XLA is faster at every length that fits)."""
-    from pytorch_vit_paper_replication_tpu.ops import attention as A
-
-    monkeypatch.setattr(A.jax, "default_backend", lambda: "tpu")
-    small = jnp.zeros((8, 577, 12, 64), jnp.bfloat16)
-    assert not A._flash_ok(small)          # 64 MB logits: XLA wins
-    huge = jnp.zeros((8, 8192, 12, 64), jnp.bfloat16)
-    assert A._flash_ok(huge)               # 12.9 GB logits: only flash fits
-    short = jnp.zeros((1024, 256, 12, 64), jnp.bfloat16)
-    assert not A._flash_ok(short)          # below the kernel's tiling floor
-
-
 # --- flash-attention in-kernel dropout (VERDICT r2 #7) ---------------------
 
 

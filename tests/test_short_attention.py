@@ -1,14 +1,16 @@
-"""The short-sequence attention kernel's dispatch, its life on a mesh and
-in the model (ops/short_attention.py, ops/attention.py::self_attention,
-models/vit.py). The kernels' numerics are in tests/test_ops.py; what the
-v5e compiler makes of them is in tests/test_v5e_compile.py.
+"""Which implementation serves an attention call
+(ops/attention.py::choose), and the short-sequence kernel's life on a
+mesh and in the model (ops/short_attention.py,
+ops/attention.py::self_attention, models/vit.py). The kernels' numerics
+are in tests/test_ops.py; what the v5e compiler makes of them is in
+tests/test_v5e_compile.py.
 
-``"auto"`` reads ``jax.default_backend()``, ``cpu`` here, so the tests
-that stand on a TPU's side of it patch it, and where the kernel then has
-to run they hand it the interpreter."""
+``choose`` takes the backend as an argument, so the table below asks it
+as a TPU would without patching anything. The tests that run a model on
+the TPU's side of the choice patch ``jax.default_backend()``, ``cpu``
+here, and hand the kernel the interpreter."""
 
-import functools
-
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,9 +27,6 @@ from pytorch_vit_paper_replication_tpu.ops import (
     attention, partition, short_attention)
 from pytorch_vit_paper_replication_tpu.optim import make_optimizer
 
-CALL = dict(impl="auto", dropout_rate=0.0, deterministic=True, mask=None,
-            probs_dtype="bf16", residual_dtype=None)
-
 
 @pytest.fixture()
 def on_tpu(monkeypatch):
@@ -37,10 +36,16 @@ def on_tpu(monkeypatch):
 @pytest.fixture()
 def interpreted(on_tpu, monkeypatch):
     """The dispatch believes in a TPU; the kernel runs in the
-    interpreter."""
-    monkeypatch.setattr(
-        short_attention, "short_attention",
-        functools.partial(short_attention.short_attention, interpret=True))
+    interpreter, and the shape of every projection it was handed is
+    returned."""
+    calls, real = [], short_attention.short_attention
+
+    def kernel(qkv):
+        calls.append(qkv.shape)
+        return real(qkv, interpret=True)
+
+    monkeypatch.setattr(short_attention, "short_attention", kernel)
+    return calls
 
 
 def _mesh(config):
@@ -49,60 +54,144 @@ def _mesh(config):
     return parallel.make_mesh(config, jax.devices()[:n])
 
 
-def _ok(shape, dtype=jnp.bfloat16, **changed):
-    return attention.short_attention_ok(shape, dtype, **{**CALL, **changed})
+B16 = (8, 197, 3, 12, 64)       # B/16's projection, a small batch of it
+SEQ2 = MeshConfig(data=2, model=1, seq=2)
 
 
-@pytest.mark.parametrize("shape,mesh,dtype", [
-    ((256, 197, 3, 12, 64), None, jnp.bfloat16),             # b16_train
-    ((96, 197, 3, 16, 64), None, jnp.bfloat16),              # l16_train
-    # b16_train_dp4: a shard is b16_train's operand
-    ((1024, 197, 3, 12, 64), MeshConfig(data=4), jnp.bfloat16),
-    ((64, 197, 3, 12, 64), MeshConfig(data=2, model=2), jnp.bfloat16),
-    ((8, 197, 3, 6, 64), None, jnp.bfloat16),                # S/16
-    ((8, 257, 3, 16, 128), None, jnp.bfloat16),              # one head a slab
-    ((8, 197, 3, 12, 64), None, jnp.float32),
-], ids=str)
-def test_auto_takes_the_kernel(on_tpu, devices, shape, mesh, dtype):
-    with partition.on_mesh(None if mesh is None else _mesh(mesh)):
-        assert _ok(shape, dtype)
+def _case(why, shape, served, *, mesh=None, sp_impl="ring", cpu="xla",
+          reason=None, **call):
+    """One row: a call's facts (``shape`` the packed projection's or
+    q's, ``call`` the keywords of :func:`attention.choose`), the mesh it
+    is traced on, the name a TPU is served by and the name the CPU is
+    (off the TPU no rule but a ``seq`` mesh or a forced ``"flash"`` says
+    anything but xla); ``reason`` is a part of the warning a ``seq``
+    mesh that could not be honoured gives."""
+    return pytest.param(shape, mesh, sp_impl, call,
+                        {"tpu": served, "cpu": cpu}, reason, id=why)
 
 
-@pytest.mark.parametrize("why,shape,changed", [
-    ("a mask", (8, 197, 3, 12, 64), dict(mask=jnp.ones((1, 1, 197, 197), bool))),
-    ("active attention dropout", (8, 197, 3, 12, 64),
-     dict(dropout_rate=0.1, deterministic=False)),
-    ("Dh = 16, the rehearsal model", (8, 17, 3, 2, 16), {}),
-    ("T = 577, over the VMEM budget", (8, 577, 3, 12, 64), {}),
-    ("quantised probabilities", (8, 197, 3, 12, 64), dict(probs_dtype="u8")),
-    ("a quantised residual", (8, 197, 3, 12, 64),
-     dict(residual_dtype="fp8_e4m3")),
-    ("forced xla", (8, 197, 3, 12, 64), dict(impl="xla")),
-    ("forced flash", (8, 197, 3, 12, 64), dict(impl="flash")),
-    ("three heads are a slab and a half", (8, 197, 3, 3, 64), {}),
-    ("f16", (8, 197, 3, 12, 64), dict(dtype=jnp.float16)),
-], ids=lambda x: x if isinstance(x, str) else "")
-def test_auto_keeps_the_old_paths(on_tpu, why, shape, changed):
-    assert not _ok(shape, **changed), why
+THE_CHOICE = [
+    # --- the four cells, as the ledger runs them ---
+    _case("b16_train", (256, 197, 3, 12, 64), "short"),
+    _case("l16_train", (96, 197, 3, 16, 64), "short"),
+    _case("b16_train_dp4: a shard is b16_train's operand",
+          (1024, 197, 3, 12, 64), "short", mesh=MeshConfig(data=4)),
+    _case("st21b_train_16k, a full causal layer", (1, 16384, 28, 128),
+          "flash", k_shape=(1, 16384, 4, 128), kind="causal"),
+    _case("st21b_train_16k, a window layer", (1, 16384, 28, 128), "flash",
+          k_shape=(1, 16384, 4, 128), kind="causal_window"),
+    # --- other models the repo names ---
+    _case("lm-tiny: T 64, Dh 16", (8, 64, 4, 16), "xla",
+          k_shape=(8, 64, 2, 16), kind="causal"),
+    _case("H/14: T 257, Dh 80", (64, 257, 3, 16, 80), "xla"),
+    _case("B/16 at 384 px, bs 64: 1.5 GiB of logits x 3",
+          (64, 577, 3, 12, 64), "xla"),
+    _case("B/16 at 384 px, bs 256: 6 GiB, over the 4 GiB rule",
+          (256, 577, 3, 12, 64), "flash"),
+    _case("B/16 at 384 px, bs 256 over data=4: a shard's 1.5 GiB",
+          (256, 577, 3, 12, 64), "xla", mesh=MeshConfig(data=4)),
+    _case("S/16", (8, 197, 3, 6, 64), "short"),
+    _case("Dh 128: one head a slab", (8, 257, 3, 16, 128), "short"),
+    _case("float32", B16, "short", dtype=jnp.float32),
+    _case("data=2 x model=2: whole slabs a shard", (64, 197, 3, 12, 64),
+          "short", mesh=MeshConfig(data=2, model=2)),
+    # --- what the short-sequence kernel does not do ---
+    _case("a mask", B16, "xla", mask=jnp.ones((1, 1, 197, 197), bool)),
+    _case("active attention dropout", B16, "xla", dropout_rate=0.1,
+          deterministic=False),
+    _case("eval mode with attn_dropout 0.1", B16, "short",
+          dropout_rate=0.1, deterministic=True),
+    _case("forced xla", B16, "xla", impl="xla"),
+    _case("forced flash", B16, "flash", cpu="flash", impl="flash"),
+    _case("float16", B16, "xla", dtype=jnp.float16),
+    _case("three heads are a slab and a half", (8, 197, 3, 3, 64), "xla"),
+    _case("Dh = 16, the rehearsal model", (8, 17, 3, 2, 16), "xla"),
+    _case("T = 577, over the VMEM budget", (8, 577, 3, 12, 64), "xla"),
+    _case("a causal packed call", (256, 197, 3, 12, 64), "xla",
+          kind="causal"),
+    _case("q, k and v, not the packed projection", (256, 197, 12, 64),
+          "xla", k_shape=(256, 197, 12, 64)),
+    _case("a batch the data axis does not divide", (6, 197, 3, 12, 64),
+          "xla", mesh=MeshConfig(data=4)),
+    _case("a shard left with a slab and a half", B16, "xla",
+          mesh=MeshConfig(data=1, model=4)),
+    # --- flash by memory alone (tests/test_ops.py had these three) ---
+    _case("64 MB of logits: xla", (8, 577, 12, 64), "xla"),
+    _case("12.9 GB of logits: only flash fits", (8, 8192, 12, 64), "flash"),
+    _case("below flash's tiling floor", (1024, 256, 12, 64), "xla"),
+    _case("a head size flash has not", (64, 8192, 12, 80), "xla"),
+    # --- a seq axis decides alone, on any backend ---
+    _case("seq=2", (8, 196, 3, 12, 64), "ring", cpu="ring", mesh=SEQ2),
+    _case("seq=2, forced flash: still the ring", (8, 196, 3, 12, 64),
+          "ring", cpu="ring", mesh=SEQ2, impl="flash"),
+    _case("seq=2, ulysses", (8, 196, 3, 12, 64), "ulysses", cpu="ulysses",
+          mesh=SEQ2, sp_impl="ulysses"),
+    _case("seq=2, ulysses, heads the axis does not divide",
+          (8, 196, 3, 3, 64), "xla", mesh=SEQ2, sp_impl="ulysses",
+          reason="needs heads (3) divisible by the seq axis (2)"),
+    _case("seq=2 x model=2, ulysses, 2 global heads are 1 a shard",
+          (8, 196, 2, 64), "xla", sp_impl="ulysses",
+          mesh=MeshConfig(data=1, model=2, seq=2),
+          reason="needs heads (1) divisible by the seq axis (2)"),
+    _case("seq=2 x model=2, ulysses, 2 heads already local",
+          (8, 196, 2, 64), "ulysses", cpu="ulysses", sp_impl="ulysses",
+          mesh=MeshConfig(data=1, model=2, seq=2),
+          heads_already_local=True),
+    _case("seq=2, a mask", (8, 196, 3, 12, 64), "xla", mesh=SEQ2,
+          mask=jnp.ones((1, 1, 196, 196), bool),
+          reason="masks are not supported by ring/ulysses"),
+    _case("seq=2, T the axis does not divide", B16, "xla", mesh=SEQ2,
+          reason="shape (batch=8, tokens=197) not divisible by mesh axes"),
+    _case("seq=2, a batch the data axis does not divide",
+          (7, 196, 3, 12, 64), "xla", mesh=SEQ2,
+          reason="shape (batch=7, tokens=196) not divisible by mesh axes"),
+    _case("seq=2, causal", (8, 196, 12, 64), "xla", mesh=SEQ2,
+          kind="causal", reason="bidirectional attention with equal head"),
+    _case("seq=2, grouped heads", (8, 196, 12, 64), "xla", mesh=SEQ2,
+          k_shape=(8, 196, 4, 64), reason="equal head counts only"),
+]
 
 
-def test_auto_stays_xla_off_the_tpu():
-    assert jax.default_backend() == "cpu"
-    assert not _ok((256, 197, 3, 12, 64))
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("shape,mesh,sp_impl,call,served,reason",
+                         THE_CHOICE)
+def test_the_choice_of_implementation(devices, backend, shape, mesh,
+                                      sp_impl, call, served, reason):
+    """The one function that decides, asked for a call's facts: nothing
+    runs. A reason comes exactly where a ``seq`` mesh was not honoured,
+    and ends as the warning always has."""
+    call = dict(call)
+    dtype = call.pop("dtype", jnp.bfloat16)
+    k_shape = call.pop("k_shape", None)
+    with partition.on_mesh(None if mesh is None else _mesh(mesh),
+                           sp_impl=sp_impl):
+        got, why = attention.choose(shape, dtype, k_shape, backend=backend,
+                                    **call)
+    assert got == served[backend]
+    if reason is None:
+        assert why is None
+    else:
+        assert why.startswith("sequence_parallel: ") and reason in why
+        assert "using the (gathered) XLA path instead" in why
 
 
-@pytest.mark.parametrize("why,mesh,shape", [
-    ("a seq axis of 2", MeshConfig(data=2, model=1, seq=2),
-     (8, 196, 3, 12, 64)),
-    ("a batch the data axis does not divide", MeshConfig(data=4),
-     (6, 197, 3, 12, 64)),
-    ("a shard left with a slab and a half", MeshConfig(data=1, model=4),
-     (8, 197, 3, 12, 64)),
-], ids=lambda x: x if isinstance(x, str) else "")
-def test_auto_keeps_the_old_paths_on_a_mesh(on_tpu, devices, why, mesh,
-                                            shape):
-    with partition.on_mesh(_mesh(mesh)):
-        assert not _ok(shape), why
+def test_the_choice_reads_the_backend_it_is_not_given(on_tpu):
+    assert attention.choose((256, 197, 3, 12, 64), jnp.bfloat16) == (
+        "short", None)
+    assert attention.choose(
+        (256, 197, 3, 12, 64), jnp.bfloat16, backend="cpu") == ("xla", None)
+
+
+@pytest.mark.parametrize("call", [dict(impl="short"), dict(kind="window")],
+                         ids=str)
+def test_the_choice_refuses_a_name_it_does_not_know(call):
+    for ask in (lambda: attention.choose(B16, jnp.bfloat16, **call),
+                lambda: attention.self_attention(jnp.zeros((1, 4, 3, 2, 8)),
+                                                 **call),
+                lambda: attention.dot_product_attention(
+                    *[jnp.zeros((1, 4, 2, 8))] * 3, **call)):
+        with pytest.raises(ValueError, match="unknown attention"):
+            ask()
 
 
 def test_the_length_limit_is_computed_from_the_shapes():
@@ -197,12 +286,36 @@ MSA = ViTConfig(image_size=32, patch_size=8, num_layers=2, num_heads=2,
                 dtype="float32", mlp_impl="xla")
 
 
-def test_the_block_with_the_kernel_is_the_block_without(interpreted):
+def _projections(block, params, x):
+    """The classes of the block's ``qkv`` and ``out`` projections in a
+    call."""
+    seen = {}
+
+    def spy(call, args, kwargs, context):
+        if context.module.name in ("qkv", "out"):
+            seen[context.module.name] = type(context.module).__name__
+        return call(*args, **kwargs)
+
+    with nn.intercept_methods(spy):
+        block.apply(params, x)
+    return seen
+
+
+@pytest.mark.parametrize("tokens,served", [(17, "short"), (577, "xla")])
+def test_the_block_with_the_kernel_is_the_block_without(interpreted, tokens,
+                                                        served):
     """Engaged, the block takes its projections as flat GEMMs
     (``_FlatDenseGeneral``): the same parameters - names, shapes and,
     from the same key, values - and the same function as the
-    ``nn.DenseGeneral`` block on the XLA path."""
-    x = jax.random.normal(jax.random.key(1), (2, 17, 128))
+    ``nn.DenseGeneral`` block on the XLA path. And the block and the
+    dispatch cannot disagree: the projections are flat exactly where the
+    kernel is then called, and both exactly where the one function says
+    ``"short"`` (a length the kernel plans for, and one whose ``[T, T]``
+    tile is over its VMEM budget)."""
+    x = jax.random.normal(jax.random.key(1), (2, tokens, 128))
+    qkv_shape = (2, tokens, 3, MSA.num_heads, MSA.head_dim)
+    assert attention.choose(qkv_shape, x.dtype)[0] == served
+    engaged = served == "short"
     on = MultiHeadSelfAttentionBlock(MSA)
     off = MultiHeadSelfAttentionBlock(MSA.replace(attention_impl="xla"))
     p_on = on.init(jax.random.key(2), x)
@@ -210,10 +323,18 @@ def test_the_block_with_the_kernel_is_the_block_without(interpreted):
     assert (jax.tree.structure(p_on) == jax.tree.structure(p_off))
     for a, b in zip(jax.tree.leaves(p_on), jax.tree.leaves(p_off)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    interpreted.clear()
+    dense = "_FlatDenseGeneral" if engaged else "DenseGeneral"
+    assert _projections(on, p_on, x) == {"qkv": dense, "out": dense}
+    assert interpreted == [qkv_shape] * engaged
+    assert _projections(off, p_on, x) == {"qkv": "DenseGeneral",
+                                          "out": "DenseGeneral"}
+    assert interpreted == [qkv_shape] * engaged
     jaxpr = jax.make_jaxpr(lambda p: on.apply(p, x))(p_on).jaxpr
-    assert len(_name_stacks(jaxpr, ("pallas_call",))) == 1
-    assert all("/qkv" in s or "/out" in s
-               for s in _name_stacks(jaxpr, ("dot_general",)))
+    assert len(_name_stacks(jaxpr, ("pallas_call",))) == engaged
+    if engaged:
+        assert all("/qkv" in s or "/out" in s
+                   for s in _name_stacks(jaxpr, ("dot_general",)))
 
     def loss(module):
         return lambda p: jnp.sum(jnp.sin(module.apply(p, x)))

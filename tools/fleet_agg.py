@@ -412,12 +412,19 @@ def run_fleet_demo(workdir: str | Path, *, image_size: int = 32,
             "--duration-s", str(serve_duration_s),
             "--stop-file", str(stop_file)]
         t0 = time.perf_counter()
-        train_p = subprocess.Popen(train_cmd, env=_child_env(),
-                                   stdout=subprocess.PIPE,
-                                   stderr=subprocess.STDOUT, text=True)
-        serve_p = subprocess.Popen(serve_cmd, env=_child_env(),
-                                   stdout=subprocess.PIPE,
-                                   stderr=subprocess.STDOUT, text=True)
+        # The children write to files: nobody reads a pipe while the
+        # loop below polls, and a child that fills one (a populated
+        # compile cache logs a line per hit) would wait on it for ever.
+        logs = {name: workdir / f"{name}_child.log"
+                for name in ("train", "serve")}
+
+        def spawn(name, cmd):
+            with open(logs[name], "w") as out:
+                return subprocess.Popen(cmd, env=_child_env(), stdout=out,
+                                        stderr=subprocess.STDOUT)
+
+        train_p = spawn("train", train_cmd)
+        serve_p = spawn("serve", serve_cmd)
         # Poll for the both-alive moment — the fleet claim the
         # artifact exists to prove: two REAL processes, one merged
         # view, both inside the staleness deadline at once.
@@ -435,18 +442,12 @@ def run_fleet_demo(workdir: str | Path, *, image_size: int = 32,
         # Release the serve child: the overlap (or the children's own
         # exit) has been observed; it ships a final frame and leaves.
         stop_file.touch()
-        train_out = train_p.communicate(
-            timeout=max(1.0, deadline - time.monotonic()))[0]
-        serve_out = serve_p.communicate(
-            timeout=max(1.0, deadline - time.monotonic()))[0]
-        if train_p.returncode != 0:
-            raise RuntimeError(
-                f"train child failed rc={train_p.returncode}:\n"
-                f"{train_out[-2000:]}")
-        if serve_p.returncode != 0:
-            raise RuntimeError(
-                f"serve child failed rc={serve_p.returncode}:\n"
-                f"{serve_out[-2000:]}")
+        for name, proc in (("train", train_p), ("serve", serve_p)):
+            proc.wait(timeout=max(1.0, deadline - time.monotonic()))
+            if proc.returncode != 0:
+                raise RuntimeError(
+                    f"{name} child failed rc={proc.returncode}:\n"
+                    f"{logs[name].read_text()[-2000:]}")
         wall_s = time.perf_counter() - t0
         final_snapshot = agg.fleet_snapshot()
         prometheus = agg.to_prometheus()
