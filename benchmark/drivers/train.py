@@ -133,6 +133,7 @@ def run(cell: dict, config: dict, args) -> dict:
     # each): when the feed began and how long it took, how long the host
     # then waited for the device, and when the step before last ended.
     feeds, waits, ticks = [], [], []
+    collections = harness.GcWatch()
 
     def feed():
         i = 0
@@ -192,6 +193,7 @@ def run(cell: dict, config: dict, args) -> dict:
     fed = np.array([d for t0, d in feeds
                     if w["t_open"] <= t0 < w["t_close"]]) * 1e3
     elapsed = w["t_close"] - w["t_open"]
+    collected = collections.report(w["t_open"], w["t_close"])
     if not args.rehearsal:
         print(f"[window] {elapsed:.3f} s, steps {w['steps']} | step wall "
               f"ms p50 {np.median(walls):.1f} max {walls.max():.1f} "
@@ -200,7 +202,8 @@ def run(cell: dict, config: dict, args) -> dict:
               f"{fed.sum() / 1e3:.2f} s, waited for the device "
               f"{sum(waits):.2f} s = {100 * sum(waits) / elapsed:.1f}% of "
               "the window | intervals ms (the first has no step before it "
-              "to wait for): " + " ".join(f"{x:.0f}" for x in walls),
+              "to wait for): " + " ".join(f"{x:.0f}" for x in walls)
+              + " | " + collected,
               flush=True)
     losses = [float(h) / batch for h in step.handles]
     window_losses = losses[warm:]
@@ -238,6 +241,15 @@ def run(cell: dict, config: dict, args) -> dict:
         "reference": err <= reference_vit.TOLERANCE,
         "no_compile_in_window": w["misses_close"] == w["misses_open"],
     }
+    # Each number compared, beside its limit (the result's last key).
+    compared = {
+        "reference_err": (float(err), reference_vit.TOLERANCE),
+        "loss_last_quarter": (float(np.mean(window_losses[-q:])),
+                              float(np.mean(window_losses[:q]))),
+        "losses_not_finite": (int(np.sum(~np.isfinite(losses))), 0),
+        "compiles_in_window": (w["misses_close"] - w["misses_open"], 0),
+        **kernels.compared_calls(found, expect),
+    }
     if not args.rehearsal:
         print("[setup] seconds since process start: " + ", ".join(
             f"{k} {v:.1f}" for k, v in phases), flush=True)
@@ -254,7 +266,7 @@ def run(cell: dict, config: dict, args) -> dict:
     return {
         "setup_s": w["setup_s"],
         "attempted": w["steps"], "failed": 0, "checks": checks,
-        "devices": devices, "program_bytes": step_bytes,
+        "compared": compared, "devices": devices, "program_bytes": step_bytes,
         "train": {"steps": w["steps"], "images": w["steps"] * batch,
                   "elapsed_s": w["t_close"] - w["t_open"], "chips": chips,
                   "batch_per_chip": p["batch_per_chip"],

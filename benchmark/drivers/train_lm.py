@@ -30,7 +30,7 @@ from .train import (MAX_IN_FLIGHT, SCHEDULE_STEPS, TRACE_STEPS,
 # Limits of the comparison with the reference, set from the chip's
 # readings (PERF.md section 5 gives both of each). The rms difference of
 # the logits in units of the reference's standard deviation: the
-# program's bf16 forward read 0.0067-0.0100 in 17 runs, the reference
+# program's bf16 forward read 0.0065-0.0108 in 60 runs, the reference
 # with fp8 e4m3 matmul inputs 0.523 and 0.545 (0.523 with the rounding
 # confined to the attention core's two products); the limit is their
 # geometric middle. The relative difference of the loss (at most 1.6e-4
@@ -87,6 +87,24 @@ def make_pool(seed: int, n_batches: int, batch: int, seq_len: int,
         pool.append({"tokens": np.ascontiguousarray(seq[:, :-1]),
                      "label": np.ascontiguousarray(seq[:, 1:])})
     return pool
+
+
+def work_of(p: dict, seed: int) -> tuple[int, list]:
+    """``(work seed, order)`` of a run: the seed that the weights and
+    the pool are drawn from, and the order in which the pool's batches
+    are fed. The step's time follows the pairs that the router sends to
+    the held experts (0.19 us a pair), and which share of them a random
+    router sends there is the draw's: 1,275 to 1,705 pairs a held expert
+    over 23 seeds where a balanced router sends 1,536 (PERF.md, PR 30).
+    So a cell lists ``work_seeds``, draws whose load was measured at
+    1,536 +- 8, ``--seed`` takes one of them and orders its batches,
+    and every seed does the same work in another order. A cell without
+    the list draws everything from ``--seed``."""
+    seeds = p.get("work_seeds")
+    work = seeds[seed % len(seeds)] if seeds else seed
+    order = np.random.default_rng([seed, 0x0DE]).permutation(
+        p["pool_batches"]).tolist()
+    return work, order
 
 
 def compare_with_reference(model, model_fields: dict, params, batch, mesh,
@@ -180,16 +198,17 @@ def run(cell: dict, config: dict, args) -> dict:
     chips = cell["chips"]
     batch = p["batch_per_chip"] * chips
     seq_len = min(p["seq_len"], cfg.max_seq_len)
+    work, order = work_of(p, args.seed)
     pool = []
     pool_thread = threading.Thread(target=lambda: pool.extend(make_pool(
-        args.seed, p["pool_batches"], batch, seq_len, cfg.vocab_size,
+        work, p["pool_batches"], batch, seq_len, cfg.vocab_size,
         p["successors"])))
     pool_thread.start()
     devices = harness.claim_devices(chips, rehearsal=args.rehearsal)
     mark("chip")
     mesh = parallel.make_mesh(MeshConfig(), devices=devices)
     assert mesh.shape["data"] == chips, "the trainer's default mesh"
-    tx = make_optimizer(TrainConfig(batch_size=batch, seed=args.seed,
+    tx = make_optimizer(TrainConfig(batch_size=batch, seed=work,
                                     **p.get("recipe", {})),
                         SCHEDULE_STEPS)
 
@@ -201,8 +220,8 @@ def run(cell: dict, config: dict, args) -> dict:
             params=model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
             tx=tx, rng=dropout_key)
 
-    keys = (jax.random.key(args.seed),
-            jax.random.key(args.seed, impl=p["rng_impl"]))
+    keys = (jax.random.key(work),
+            jax.random.key(work, impl=p["rng_impl"]))
     shardings = parallel.state_shardings(
         jax.eval_shape(make_state, *keys), mesh)
     state = jax.jit(make_state, out_shardings=shardings)(*keys)
@@ -218,13 +237,15 @@ def run(cell: dict, config: dict, args) -> dict:
     trace_last = trace_first + TRACE_STEPS
     w = {"steps": 0}
     feeds, waits, ticks = [], [], []
+    collections = harness.GcWatch()
 
     def feed():
         i = 0
         while True:
             t0 = time.perf_counter()
             with harness.annotate("bench.feed"):
-                batch_i = parallel.shard_batch(pool[i % len(pool)], mesh)
+                batch_i = parallel.shard_batch(
+                    pool[order[i % len(pool)]], mesh)
             feeds.append((t0, time.perf_counter() - t0))
             yield batch_i
             i += 1
@@ -274,6 +295,7 @@ def run(cell: dict, config: dict, args) -> dict:
     fed = np.array([d for t0, d in feeds
                     if w["t_open"] <= t0 < w["t_close"]]) * 1e3
     elapsed = w["t_close"] - w["t_open"]
+    collected = collections.report(w["t_open"], w["t_close"])
     if not args.rehearsal:
         print(f"[window] {elapsed:.3f} s, steps {w['steps']} | step wall "
               f"ms p50 {np.median(walls):.1f} max {walls.max():.1f} "
@@ -282,7 +304,8 @@ def run(cell: dict, config: dict, args) -> dict:
               f"{fed.sum() / 1e3:.2f} s, waited for the device "
               f"{sum(waits):.2f} s = {100 * sum(waits) / elapsed:.1f}% of "
               "the window | intervals ms (the first has no step before it "
-              "to wait for): " + " ".join(f"{x:.0f}" for x in walls),
+              "to wait for): " + " ".join(f"{x:.0f}" for x in walls)
+              + " | " + collected,
               flush=True)
     seen = jax.device_get(step.metrics)
     losses = [float(m["loss_sum"]) / batch for m in seen]
@@ -300,7 +323,7 @@ def run(cell: dict, config: dict, args) -> dict:
 
     # Logits and loss of the program's model on one pool sequence of the
     # timed length against the plain float32 reference.
-    one = {k: v[:chips] for k, v in pool[1].items()}
+    one = {k: v[:chips] for k, v in pool[order[1]].items()}
     ref = compare_with_reference(model, config["model"], state.params, one,
                                  mesh)
 
@@ -320,13 +343,27 @@ def run(cell: dict, config: dict, args) -> dict:
             np.all(counters["moe_dropped_pairs"] == 0)
             and np.all(counters["moe_pairs_kept_share"] == 1.0)),
     }
+    # Each number compared, beside its limit (the result's last key).
+    compared = {
+        "logits_rms_err": (ref["rms"], LOGITS_RMS_TOLERANCE),
+        "loss_rel_err": (ref["loss_error"], LOSS_TOLERANCE),
+        "loss_last_quarter": (float(np.mean(window_losses[-q:])),
+                              float(np.mean(window_losses[:q]))),
+        "losses_not_finite": (int(np.sum(~np.isfinite(losses))), 0),
+        "compiles_in_window": (w["misses_close"] - w["misses_open"], 0),
+        "dropped_pairs": (float(counters["moe_dropped_pairs"].sum()), 0),
+        "pairs_kept_share_min": (
+            float(counters["moe_pairs_kept_share"].min()), 1.0),
+        **kernels.compared_calls(found, expect),
+    }
     load = counters["moe_pairs_per_expert_max"] / np.maximum(
         counters["moe_pairs_per_expert_mean"], 1e-9)
     if not args.rehearsal:
         print("[setup] seconds since process start: " + ", ".join(
             f"{k} {v:.1f}" for k, v in phases), flush=True)
     print(f"[train] steps {w['steps']} sequences {batch} x {seq_len} "
-          f"tokens, chips {chips} | loss first-quarter "
+          f"tokens, chips {chips} | weights and pool of work seed {work}, "
+          f"batches fed in the order {order} | loss first-quarter "
           f"{np.mean(window_losses[:q]):.4f} last-quarter "
           f"{np.mean(window_losses[-q:]):.4f} final {losses[-1]:.6f} | "
           f"mosaic kernels {found} (the cell names {expect}; not named by "
@@ -345,7 +382,7 @@ def run(cell: dict, config: dict, args) -> dict:
     return {
         "setup_s": w["setup_s"],
         "attempted": w["steps"], "failed": 0, "checks": checks,
-        "devices": devices, "program_bytes": step_bytes,
+        "compared": compared, "devices": devices, "program_bytes": step_bytes,
         "train": {"steps": w["steps"], "images": w["steps"] * batch,
                   "elapsed_s": w["t_close"] - w["t_open"], "chips": chips,
                   "batch_per_chip": p["batch_per_chip"],

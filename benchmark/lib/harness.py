@@ -6,6 +6,7 @@ the environment."""
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import shutil
 import threading
@@ -108,6 +109,41 @@ def program_bytes(compiled) -> int:
         return 0
     return int(m.argument_size_in_bytes + m.temp_size_in_bytes
                + m.output_size_in_bytes - m.alias_size_in_bytes)
+
+
+class GcWatch:
+    """Every collection of Python's garbage collector, timed on the
+    host's clock (two reads a collection, nothing between them): a run
+    that reads far off because one step's wait returned seconds late
+    (PERF.md section 7) can then be told apart: a collection that long
+    is the interpreter's, none is the machine's."""
+
+    def __init__(self):
+        self.pauses = []            # (start, seconds, generation)
+        self._t0 = None
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase, info):
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.pauses.append((self._t0, time.perf_counter() - self._t0,
+                                info["generation"]))
+            self._t0 = None
+
+    def report(self, lo: float, hi: float) -> str:
+        """The collections that began in ``[lo, hi)``, in a few words;
+        the watch ends here."""
+        if self._on in gc.callbacks:
+            gc.callbacks.remove(self._on)
+        seen = [p for p in self.pauses if lo <= p[0] < hi]
+        if not seen:
+            return "gc in the window: none"
+        t0, longest, gen = max(seen, key=lambda p: p[1])
+        return (f"gc in the window: {len(seen)} collections, "
+                f"{sum(p[1] for p in seen) * 1e3:.1f} ms together, longest "
+                f"{longest * 1e3:.1f} ms (generation {gen}, "
+                f"{t0 - lo:.2f} s after it opened)")
 
 
 class Capture:

@@ -95,13 +95,55 @@ def kernel_counts(lowered_text: str) -> dict:
     return dict(sorted(counts.items()))
 
 
+def family_of(name: str, expect: dict):
+    """The key of ``expect`` that holds the kernel ``name``: its own
+    name, else the family (a key that ends in ``*``) whose text before
+    the ``*`` it begins with, else None."""
+    if name in expect:
+        return name
+    return next((key for key in expect
+                 if key.endswith("*") and name.startswith(key[:-1])), None)
+
+
+def kernel_calls(found: dict, expect: dict) -> tuple:
+    """``(calls, unnamed)``: the calls found under each key of
+    ``expect`` (a family's members together), and the kernels that no
+    key holds, with their counts."""
+    calls, unnamed = dict.fromkeys(expect, 0), {}
+    for name, n in found.items():
+        key = family_of(name, expect)
+        if key is None:
+            unnamed[name] = n
+        else:
+            calls[key] += n
+    return calls, unnamed
+
+
 def check_kernels(found: dict, expect: dict) -> tuple:
-    """``(ok, unnamed)``: whether every kernel a cell names occurs
-    exactly as often as it says, and the kernels it does not name, with
-    their counts. A kernel the cell's author did not foresee does not
-    make a program incorrect (its numbers are held by the reference
-    check); a named kernel that is missing, or a call short or over,
-    does: the XLA fallback, or another kernel in its place, would pass
-    every numeric check and be another program."""
-    ok = all(found.get(name, 0) == n for name, n in expect.items())
-    return ok, {k: n for k, n in found.items() if k not in expect}
+    """``(ok, unnamed)``: whether the step holds what a cell names, and
+    the kernels it does not name, with their counts.
+
+    A plain key of ``expect`` names one kernel, which has to occur
+    exactly that often. A key that ends in ``*`` names a family, every
+    kernel whose name begins with the text before the ``*``; its value
+    is ``[least, most]`` calls of the family together (a kernel that has
+    a plain key of its own counts there and not in a family). That is
+    what the check guards: no fall-back to XLA and no layer without its
+    kernel (none of the family, or too few), no other program in its
+    place (too many), and not how a backward pass is cut into calls. A
+    kernel the cell's author did not foresee does not make a program
+    incorrect (its numbers are held by the reference check); a member
+    of a named family is named, and is not listed among those."""
+    calls, unnamed = kernel_calls(found, expect)
+    bounds = {key: want if key.endswith("*") else (want, want)
+              for key, want in expect.items()}
+    return all(least <= calls[key] <= most
+               for key, (least, most) in bounds.items()), unnamed
+
+
+def compared_calls(found: dict, expect: dict) -> dict:
+    """``{"calls.<key>": (calls found, what the cell's file says)}`` for
+    every kernel or family a cell names: the numbers that
+    ``check_kernels`` compared, as a run prints them."""
+    calls, _ = kernel_calls(found, expect)
+    return {f"calls.{key}": (calls[key], expect[key]) for key in expect}

@@ -237,6 +237,24 @@ def _line(plane, name):
     return []
 
 
+CONTROL_FLOW = ("while", "conditional", "call")
+
+
+def leaves(events) -> list:
+    """The ops of one ``XLA Ops`` line without the control flow that
+    encloses others: the line lays a ``while`` (a ``conditional``, a
+    ``call``) over the ops of its body, and the body's ops are the
+    device's work; added together, a loop's time counts twice (87 ms a
+    step in ``st21b_train_16k`` from PR 28 to PR 29: the passes of
+    ``ops/moe.py``). A loop whose body left no event of its own stays.
+    Copied from ``telemetry/device_trace.py::leaves`` and held equal to
+    it by ``tests/test_copies.py``."""
+    events = sorted(events, key=lambda e: (e["start_ns"], -e["dur_ns"]))
+    return [e for e, after in zip(events, events[1:] + [None])
+            if e.get("op") not in CONTROL_FLOW
+            or after is None or _iv(after)[1] > _iv(e)[1]]
+
+
 @functools.lru_cache(maxsize=None)
 def _row_of(scope: str, op: str, name: str, kernel: str) -> tuple:
     """``(layer, phase)`` of an op: ``scopes.classify``, asked once for
@@ -319,10 +337,14 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
     that scope. The collectives' exposed part is the row ``collective``,
     so that the rows of a step sum to its ``busy_ms`` (to within ops
     that overlap). ``xla_by_phase_ms`` is the same sum over the ops that
-    are neither Mosaic calls nor collectives. Busy time is the union of
-    all op intervals (modules where a plane has no op line) inside the
-    traced window, which is ``window_ns`` or else ``_default_window``.
-    Values are means over chips of per-chip medians."""
+    are neither Mosaic calls nor collectives. **A step with loops is
+    read once:** every sum (the rows, the XLA / Mosaic split, the time
+    by kernel, ``device_ops``) is over ``leaves`` of the op line, so a
+    ``while`` laid over its body's ops adds nothing to them. Busy time
+    is the union of all op intervals, control flow with them (modules
+    where a plane has no op line), inside the traced window, which is
+    ``window_ns`` or else ``_default_window``: a union counts nothing
+    twice. Values are means over chips of per-chip medians."""
     devs = [p for p in trace["planes"] if DEVICE_PLANE.match(p["name"])]
     if not devs:
         return {"chips": 0, "busy_s": 0.0, "window_s": 0.0,
@@ -334,8 +356,12 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
         mods = _line(plane, MODULE_LINE)
         busy_iv = [_iv(e) for e in (ops or mods)]
         all_iv.extend(busy_iv)
-        per_chip.append({"plane": plane["name"], "ops": ops, "mods": mods,
+        work = leaves(ops)
+        kept = {id(e) for e in work}
+        per_chip.append({"plane": plane["name"], "ops": work, "mods": mods,
                          "busy_iv": busy_iv,
+                         # control flow laid over its body: busy, no work
+                         "over": [_iv(e) for e in ops if id(e) not in kept],
                          "async": _line(plane, ASYNC_LINE)})
     if window_ns is None:
         window_ns = _default_window(per_chip, all_iv, module_prefix)
@@ -366,6 +392,7 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
                 s0, s1 = _iv(m)
                 a = bisect.bisect_left(starts, s0)
                 b = bisect.bisect_left(starts, s1)
+                over = [iv for iv in chip["over"] if s0 <= iv[0] < s1]
                 by = {"mosaic": [], "collective": [], "xla": []}
                 for e in ops_sorted[a:b]:
                     cls = op_class(e)
@@ -391,7 +418,8 @@ def reduce_trace(trace: dict, *, module_prefix: str = "",
                 acc["xla"].append(union_ns(by["xla"]))
                 acc["collective"].append(coll)
                 acc["collective_exposed"].append(exposed)
-                acc["busy"].append(union_ns(compute + by["collective"]))
+                acc["busy"].append(
+                    union_ns(compute + by["collective"] + over))
             for k, v in acc.items():
                 key = k if k == "mosaic_calls" else f"{k}_ms"
                 row[key] = _median(v) / (1 if k == "mosaic_calls" else 1e6)

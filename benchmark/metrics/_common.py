@@ -17,3 +17,13 @@ def rows_ms(obs, *layers):
     where there is no table."""
     rows = train_trace(obs, "rows_ms")
     return layer_ms(rows, *layers) if rows else None
+
+
+def rows_phase_ms(obs, layer, *phases):
+    """Milliseconds per step under ``layer`` in ``phases`` of the same
+    table (a phase no op of the layer ran in counts as 0), or None where
+    there is no table."""
+    rows = train_trace(obs, "rows_ms")
+    if not rows:
+        return None
+    return sum(rows.get(layer, {}).get(phase, 0.0) for phase in phases)

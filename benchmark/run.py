@@ -8,7 +8,9 @@ reports is what ``BENCHMARK.json`` lists for it (an entry without
 ``workloads`` holds for every cell), so a new metric changes no cell it
 does not name. This file has no table of any of them. The last line of
 standard output is the result: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, with ``--trace 1``, ``breakdown``.
+``metrics``, ``device``, with ``--trace 1`` ``breakdown``, and last
+``compared``: each number that ``correct`` compared beside its limit
+(also the last lines on standard error).
 
 ``--rehearsal`` is the harness's own switch for the CPU: the cell's and
 the configuration's ``rehearsal`` sizes, virtual CPU devices, and a
@@ -159,6 +161,14 @@ def main(argv=None) -> int:
     if args.trace and not args.rehearsal:
         result["breakdown"] = {"device_ops": trace["device_ops"],
                                "idle_gaps": trace["idle_gaps"]}
+    # Each number that ``correct`` compared, beside its limit: the
+    # result's last key, and the last lines on standard error.
+    result["compared"] = {
+        name: {"value": value, "limit": limit}
+        for name, (value, limit) in obs.get("compared", {}).items()}
+    for name, c in result["compared"].items():
+        print(f"[compared] {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

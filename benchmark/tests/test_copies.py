@@ -32,13 +32,18 @@ def test_peaks_equal_the_programs_and_unknown_kind_is_an_error():
         flops.peaks("TPU v9 imaginary")
 
 
-def test_config_file_is_the_programs_preset():
-    from pytorch_vit_paper_replication_tpu.configs import PRESETS
+@pytest.mark.parametrize("path", sorted(
+    (harness.BENCH / "configs").glob("*.json")), ids=lambda p: p.stem)
+def test_config_file_is_the_programs_preset(path):
+    """A ViT's file is one of ``configs.PRESETS``, a token model's (it
+    has a ``vocab_size``) one of ``configs.LM_PRESETS``."""
+    from pytorch_vit_paper_replication_tpu.configs import (LM_PRESETS,
+                                                           PRESETS)
 
-    for path in sorted((harness.BENCH / "configs").glob("*.json")):
-        config = harness.load_json(path)
-        cfg, _ = harness.build_model(config)
-        assert cfg == PRESETS[config["program_preset"]]()
+    config = harness.load_json(path)
+    cfg, _ = harness.build_model(config)
+    presets = LM_PRESETS if config["model"].get("vocab_size") else PRESETS
+    assert cfg == presets[config["program_preset"]]()
 
 
 def test_schedule_is_a_pure_function_of_the_seed():
@@ -148,6 +153,35 @@ def test_parse_scopes_equals_the_programs():
     assert got["scopes"] == {
         "lnmlp_fwd.2": mlp, "fusion.3": "jit(train_step)/optimizer/add",
         "copy-done.1": mlp, "copy-start.1": mlp, "slice-start.3": mlp}
+
+
+def test_leaves_equals_the_programs():
+    """``xplane.leaves`` is ``telemetry/device_trace.py::leaves``: on
+    loops over ops, a loop with no body event, nested control flow, an
+    op that outlasts the loop it began in, events in any order, and an
+    empty line. (What the reducer makes of them is ``test_xplane.py``.)"""
+    from pytorch_vit_paper_replication_tpu.telemetry import device_trace
+
+    def e(name, op, start, dur):
+        return {"name": name, "op": op, "start_ns": start, "dur_ns": dur}
+    events = [
+        e("while.1", "while", 0, 100), e("fusion.1", "fusion", 0, 40),
+        e("lnmlp_fwd.2", "custom-call", 40, 60),
+        e("while.2", "while", 100, 50),                 # no body event
+        e("conditional.3", "conditional", 150, 100),
+        e("while.4", "while", 160, 80), e("fusion.5", "fusion", 170, 70),
+        e("call.6", "call", 250, 50), e("copy.7", "copy", 290, 30),
+        e("fusion.8", "fusion", 320, 10), e("while.9", "while", 330, 5)]
+    want = ["fusion.1", "lnmlp_fwd.2", "while.2", "fusion.5", "call.6",
+            "copy.7", "fusion.8", "while.9"]
+    assert [x["name"] for x in xplane.leaves(events)] == want
+    rng = np.random.default_rng(30)
+    for _ in range(40):
+        order = [events[i] for i in rng.permutation(len(events))]
+        assert xplane.leaves(order) == device_trace.leaves(order)
+        assert [x["name"] for x in xplane.leaves(order)] == want
+    assert xplane.leaves([]) == device_trace.leaves([]) == []
+    assert xplane.CONTROL_FLOW == ("while", "conditional", "call")
 
 
 @pytest.mark.parametrize("fixture", [

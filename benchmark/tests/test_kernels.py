@@ -67,15 +67,111 @@ def test_a_cell_that_names_no_kernel_takes_any():
     assert kernels.check_kernels(FLASH, FLASH) == (True, {})
 
 
-@pytest.mark.parametrize("path", sorted(
-    (harness.BENCH / "workloads").glob("*.json")), ids=lambda p: p.stem)
-def test_every_cell_names_mlp_kernels_once_per_layer(path):
+# ---- the family form: what the token cell's file and the ViT cells'
+# files ask, on the steps a later PR may bring (ISSUE 30's table).
+TOKEN = {"flash_fwd": 4, "flash_bwd*": [4, 8], "moe_gmm_fwd": 24,
+         "moe_gmm_dx": 16, "moe_gmm_dw": 16}
+MOE = {"moe_gmm_fwd": 24, "moe_gmm_dx": 16, "moe_gmm_dw": 16}
+VIT = {**B16, "attn_short_fwd": 12, "attn_short_bwd": 12}
+
+
+@pytest.mark.parametrize("expect,found,ok,unnamed", [
+    # today's token step: two backward kernels a layer, 8 calls
+    (TOKEN, {"flash_fwd": 4, "flash_bwd_dq": 4, "flash_bwd_dkv": 4, **MOE},
+     True, {}),
+    # a backward of one call a layer, whatever it is called
+    (TOKEN, {"flash_fwd": 4, "flash_bwd": 4, **MOE}, True, {}),
+    (TOKEN, {"flash_fwd": 4, "flash_bwd_fused": 4, **MOE}, True, {}),
+    # no flash backward at all: the fall-back to XLA
+    (TOKEN, {"flash_fwd": 4, **MOE}, False, {}),
+    # a layer without its backward kernel
+    (TOKEN, {"flash_fwd": 4, "flash_bwd": 3, **MOE}, False, {}),
+    # three backward calls a layer: another program
+    (TOKEN, {"flash_fwd": 4, "flash_bwd_dq": 4, "flash_bwd_dk": 4,
+             "flash_bwd_dv": 4, **MOE}, False, {}),
+    # the forward is exact: one layer fell back
+    (TOKEN, {"flash_fwd": 3, "flash_bwd_dq": 4, "flash_bwd_dkv": 4, **MOE},
+     False, {}),
+    # the grouped products are exact
+    (TOKEN, {"flash_fwd": 4, "flash_bwd": 4, **MOE, "moe_gmm_dx": 15},
+     False, {}),
+    # a member of a named family is named; another kernel is listed
+    (TOKEN, {"flash_fwd": 4, "flash_bwd_dq": 4, "flash_bwd_dkv": 4, **MOE,
+             "rope_fwd": 4}, True, {"rope_fwd": 4}),
+    # a kernel with a key of its own counts there, not in a family
+    ({"flash_bwd_dq": 4, "flash_bwd*": [4, 4]},
+     {"flash_bwd_dq": 4, "flash_bwd_dkv": 4}, True, {}),
+    # a ViT step as the program makes it, and one whose attention fell
+    # back to XLA in the backward pass, in the forward pass, in both
+    (VIT, VIT, True, {}),
+    (VIT, {**B16, "attn_short_fwd": 12}, False, {}),
+    (VIT, {**B16, "attn_short_bwd": 12}, False, {}),
+    (VIT, B16, False, {}),
+    (VIT, {**VIT, "attn_short_bwd": 11}, False, {}),
+], ids=["token-today-8", "token-one-call-a-layer", "token-any-member-name",
+        "token-no-backward", "token-backward-3", "token-backward-12",
+        "token-forward-3", "token-gmm-off", "token-unnamed-listed",
+        "own-key-before-family", "vit-as-made", "vit-no-attn-bwd",
+        "vit-no-attn-fwd", "vit-xla-attention", "vit-attn-bwd-short"])
+def test_a_family_asks_how_many_calls_not_which(expect, found, ok, unnamed):
+    text = lowered(**found)
+    counts = kernels.kernel_counts(text)
+    assert kernels.check_kernels(counts, expect) == (ok, unnamed)
+    # what a run prints beside its limits: the calls under each key
+    calls = kernels.compared_calls(counts, expect)
+    assert set(calls) == {f"calls.{key}" for key in expect}
+    assert all(limit == expect[key[len("calls."):]]
+               for key, (_, limit) in calls.items())
+    assert sum(n for n, _ in calls.values()) + sum(unnamed.values()) == \
+        sum(found.values())
+
+
+CELLS = sorted((harness.BENCH / "workloads").glob("*.json"))
+
+
+@pytest.mark.parametrize("path", CELLS, ids=lambda p: p.stem)
+def test_every_cell_names_its_kernels_once_per_layer(path):
+    """A ViT cell: the MLP half-block pair and (a train cell on the
+    TPU's default path) the short-attention pair, one call a layer each,
+    exactly. The token cell: the flash forward exactly, the flash
+    backward as a family of one or two calls a layer, and the routed
+    layer's grouped products by its two chunks (the cell's ``notes``)."""
     cell, config = harness.load_cell(path.stem)
     expect = cell[cell["driver"]]["expect_kernels"]
     layers = config["model"]["num_layers"]
-    assert set(expect) <= set(kernels.MLP_KERNELS)
+    if config["model"].get("vocab_size"):
+        assert expect == {
+            "flash_fwd": layers, "flash_bwd*": [layers, 2 * layers],
+            "moe_gmm_fwd": 6 * layers, "moe_gmm_dx": 4 * layers,
+            "moe_gmm_dw": 4 * layers}
+        return
+    named = set(kernels.MLP_KERNELS) | {"attn_short_fwd", "attn_short_bwd"}
+    assert set(expect) <= named and not any(k.endswith("*") for k in expect)
     assert set(expect.values()) == {layers}
-    assert ("lnmlp_bwd" in expect) is (cell["driver"] == "train")
+    train = cell["driver"] == "train"
+    assert ("lnmlp_bwd" in expect) is train
+    assert ("attn_short_bwd" in expect) is ("attn_short_fwd" in expect) \
+        is train
+
+
+@pytest.mark.parametrize("path", CELLS, ids=lambda p: p.stem)
+def test_todays_step_passes_its_cells_check_and_a_fall_back_fails(path):
+    """The kernels the program's step holds today (PERF.md, Findings PR
+    29: read from the lowered steps on the chip) pass each cell's file;
+    the same step with any one named kernel or family gone fails."""
+    cell, config = harness.load_cell(path.stem)
+    expect = cell[cell["driver"]]["expect_kernels"]
+    layers = config["model"]["num_layers"]
+    today = {"flash_fwd": layers, "flash_bwd_dq": layers,
+             "flash_bwd_dkv": layers, "moe_gmm_fwd": 6 * layers,
+             "moe_gmm_dx": 4 * layers, "moe_gmm_dw": 4 * layers} \
+        if config["model"].get("vocab_size") else \
+        {key: layers for key in expect}
+    assert kernels.check_kernels(today, expect) == (True, {})
+    for key in expect:
+        prefix = key.rstrip("*")
+        gone = {k: n for k, n in today.items() if not k.startswith(prefix)}
+        assert kernels.check_kernels(gone, expect)[0] is False, key
 
 
 def test_kernel_names_equal_the_programs():

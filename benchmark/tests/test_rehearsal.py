@@ -14,7 +14,7 @@ import pytest
 
 from benchmark.lib import harness
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 CELLS = sorted(p.stem for p in (harness.BENCH / "workloads").glob("*.json"))
 
 
@@ -52,11 +52,46 @@ def test_rehearsal_walks_the_driver(cell, trace, cache_dir):
     assert set(result["device"]) == {"platform", "kind", "count",
                                      "memory_peak_bytes"}
     assert result["metrics"], "at least one metric of this kind"
+    # each number that ``correct`` compared, beside its limit: the
+    # result's last key, and the last lines on standard error
+    assert list(result)[-1] == "compared"
+    errs = [ln for ln in proc.stderr.splitlines() if ln.strip()]
+    if result["compared"]:             # (the serve driver names none yet)
+        assert all(set(c) == {"value", "limit"}
+                   for c in result["compared"].values())
+        assert [ln.split()[1] for ln in errs[-len(result["compared"]):]
+                if ln.startswith("[compared] ")] == list(result["compared"])
+        assert result["compared"]["losses_not_finite"] == {
+            "value": 0, "limit": 0}
     if not trace:
         assert "setup_s" in result["metrics"]
     for m in result["metrics"].values():
         assert set(m) == {"value", "unit"}
         assert m["value"] is None, "a CPU run prints no time and no rate"
+
+
+def test_the_gc_watch_times_the_collections_of_a_window():
+    """What the ``[window]`` line says of the interpreter's collections:
+    those that began inside the window, and the longest."""
+    import gc
+    import time
+
+    watch = harness.GcWatch()
+    t0 = time.perf_counter()
+    gc.collect()
+    t1 = time.perf_counter()
+    gc.collect(0)
+    t2 = time.perf_counter()
+    assert [g for _, _, g in watch.pauses][-2:] == [2, 0]
+    assert all(0 <= s < t2 - t0 for _, s, _ in watch.pauses[-2:])
+    assert watch.report(t2, t2 + 1) == "gc in the window: none"
+    assert watch._on not in gc.callbacks
+    assert watch.pauses[-2][0] >= t0
+    again = harness.GcWatch()
+    gc.collect()
+    line = again.report(t1, time.perf_counter())
+    assert line.startswith("gc in the window: 1 collections, ")
+    assert "(generation 2, " in line
 
 
 def test_no_tpu_means_no_result(cache_dir):

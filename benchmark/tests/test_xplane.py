@@ -6,8 +6,10 @@ from pathlib import Path
 import pytest
 
 from benchmark.lib import flops, harness, scopes, xplane
-from benchmark.metrics import (attn_core_ms, attn_core_roofline_pct, ln_ms,
-                               mlp_glue_ms, mlp_kernel_bwd_ms,
+from benchmark.metrics import (attn_core_bwd_ms, attn_core_fwd_ms,
+                               attn_core_ms, attn_core_roofline_pct,
+                               lm_attn_core_roofline_pct, lm_step_mfu_pct,
+                               ln_ms, mlp_glue_ms, mlp_kernel_bwd_ms,
                                mlp_kernel_fwd_ms, mlp_kernel_ms,
                                mlp_kernel_roofline_pct, msa_glue_ms,
                                msa_proj_ms, optimizer_ms, other_ms,
@@ -217,6 +219,157 @@ def test_rows_by_layer_and_phase_by_hand(capsys):
     assert attn_core_ms.read(none) is None and other_ms.read(none) is None
 
 
+def control(op, n, start, dur, scope=""):
+    """A ``while`` / ``conditional`` / ``call`` as the op line names it:
+    by its whole instruction, a tuple shape first."""
+    return ev(f"%{op}.{n} = (s32[], f32[8,4]{{1,0}}) {op}((s32[], "
+              f"f32[8,4]{{1,0}}) %tuple.{n}), condition=%cond.{n}, "
+              f"body=%body.{n}", start, dur, scope)
+
+
+def looped_trace(steps=4, chips=1):
+    """Steps of 2000 ns (one every 2500) with control flow laid over
+    ops, as the token cell's step has it since PR 28. In each step:
+
+    * 0-100 a qkv fusion;
+    * 100-700 a ``while`` under ``mlp`` over two XLA ops (100-300,
+      500-690) and the MLP kernel (300-500): 10 ns of the loop's own at
+      its end;
+    * 700-800 a ``while`` whose body left no event: the device's work;
+    * 800-1200 a ``conditional`` over a ``while`` (820-1180) over two
+      backward fusions of the attention core (830-1000, 1000-1180);
+    * 1200-1300 a ``call`` over one optimizer op (1205-1295);
+    * 1300-1400 an all-reduce, 1350-1500 a fusion of no scope.
+
+    Added up with the control flow the step reads 600 + 400 + 360 + 100
+    = 1,460 ns too long; its busy time is 1,500 ns."""
+    mlp, mlp_scope = kernel_call(
+        "lnmlp_fwd.2", f"{BLOCK}/mlp/while/body/lnmlp_fwd/pallas_call")
+    planes = []
+    for chip in range(chips):
+        mods, ops = [], []
+        for i in range(steps):
+            t = 2500 * i + 7 * chip
+            mods.append(ev("jit_train_step(1)", t, 2000))
+            ops += [
+                ev("%fusion.1", t, 100, f"{BACK}/msa/qkv/dot_general"),
+                control("while", 1, t + 100, 600, f"{BLOCK}/mlp/while"),
+                ev("%fusion.2", t + 100, 200, f"{BLOCK}/mlp/while/body/add"),
+                ev(mlp, t + 300, 200, mlp_scope),
+                ev("%fusion.3", t + 500, 190, f"{BACK}/mlp/while/body/mul"),
+                control("while", 2, t + 700, 100, f"{BLOCK}/mlp/while"),
+                control("conditional", 3, t + 800, 400,
+                        f"{BACK}/msa/attn_core/cond"),
+                control("while", 4, t + 820, 360,
+                        f"{BACK}/msa/attn_core/cond/branch_1_fun/while"),
+                ev("%fusion.4", t + 830, 170, f"{BACK}/msa/attn_core/mul"),
+                ev("%fusion.5", t + 1000, 180, f"{BACK}/msa/attn_core/dot"),
+                control("call", 5, t + 1200, 100,
+                        "jit(train_step)/optimizer/call"),
+                ev("%fusion.6", t + 1205, 90,
+                   "jit(train_step)/optimizer/add"),
+                ev("%all-reduce.7 = f32[4,4]{1,0} all-reduce(f32[4,4]{1,0} "
+                   "%fusion.1), replica_groups={{0,1}}", t + 1300, 100),
+                ev("%fusion.8", t + 1350, 150, "")]
+        planes.append({"name": f"/device:TPU:{chip}", "lines": [
+            {"name": "XLA Modules", "events": mods},
+            {"name": "XLA Ops", "events": ops[::-1]}]})   # in any order
+    return {"planes": planes}
+
+
+def test_a_step_with_loops_is_read_once():
+    trace = looped_trace()
+    ops = trace["planes"][0]["lines"][1]["events"]
+    kept = xplane.leaves(ops)
+    names = lambda evs: {e["name"] for e in evs}
+    # the control flow that encloses an op goes; the loop without a body
+    # event stays, and so does every op
+    assert names(ops) - names(kept) == {"call.5", "conditional.3",
+                                        "while.1", "while.4"}
+    assert len(kept) == len(ops) - 4 * 4
+    r = xplane.reduce_trace(trace, module_prefix="jit_train_step")
+    ns = lambda x: pytest.approx(x * 1e-6)
+    assert r["steps"] == 3
+    assert r["rows_ms"] == {
+        "msa_qkv": {"backward": ns(100)},
+        "mlp_xla": {"forward": ns(200 + 100), "backward": ns(190)},
+        "lnmlp_fwd": {"forward": ns(200)},
+        "attn_core": {"backward": ns(170 + 180)},
+        "optimizer": {"optimizer": ns(90)},
+        "other": {"forward": ns(150)},
+        "collective": {"forward": ns(50)}}
+    assert r["mosaic_by_kernel_ms"] == {"lnmlp_fwd": ns(200)}
+    assert r["xla_by_phase_ms"] == {
+        "forward": ns(200 + 100 + 150), "backward": ns(100 + 190 + 350),
+        "optimizer": ns(90)}
+    # the XLA ops' union holds no kernel under a loop any more
+    assert (r["mosaic_ms"], r["xla_ms"]) == (ns(200), ns(1180))
+    assert r["mosaic_calls"] == 1
+    # the rows sum to the ops' time: 1,430 ns. The step's busy time is a
+    # union, and keeps what is the loops' own (10 + 30 + 10 + 20 ns
+    # under a loop and under no op of its body)
+    assert xplane.layer_ms(r["rows_ms"], *r["rows_ms"]) == ns(1430)
+    assert r["busy_ms"] == ns(1500)
+    assert r["busy_s"] == pytest.approx(3 * 1500e-9)
+    # ... and the list the driver's breakdown is made from names no loop
+    # that has a body
+    labels = [label for label, _ in r["device_ops"]]
+    assert not any(label.startswith(("conditional", "call"))
+                   for label in labels)
+    assert [s for label, s in r["device_ops"]
+            if label.startswith("while")] == [pytest.approx(3 * 100e-9)]
+    obs = {"trace": r, "train": {"batch_per_chip": 1}}
+    assert mlp_glue_ms.read(obs) == ns(490)
+    assert xla_backward_ms.read(obs) == ns(640)
+    assert xla_ops_ms.read(obs) == ns(1180)
+    assert attn_core_ms.read(obs) == ns(350)
+    assert attn_core_fwd_ms.read(obs) == 0.0
+    assert attn_core_bwd_ms.read(obs) == ns(350)
+
+
+def test_the_attention_core_by_direction_sums_to_the_core():
+    """``attn_core_fwd_ms`` + ``attn_core_bwd_ms`` = ``attn_core_ms``,
+    by the phase of the op's scope: on the by-hand step above (forward
+    200, backward 400, recompute 100) and on the recorded one."""
+    rows = {"attn_core": {"forward": 0.2, "backward": 0.4, "recompute": 0.1},
+            "msa_qkv": {"backward": 0.3}}
+    obs = {"trace": {"rows_ms": rows}, "train": {"batch_per_chip": 256}}
+    assert attn_core_fwd_ms.read(obs) == pytest.approx(0.2)
+    assert attn_core_bwd_ms.read(obs) == pytest.approx(0.5)
+    # a step with no attention core reads 0, not nothing; no table,
+    # nothing
+    none = {"trace": {"rows_ms": {"msa_qkv": {"backward": 0.3}}},
+            "train": obs["train"]}
+    assert attn_core_fwd_ms.read(none) == attn_core_bwd_ms.read(none) == 0.0
+    assert attn_core_bwd_ms.read({"trace": {"rows_ms": {}},
+                                  "train": obs["train"]}) is None
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_the_two_readers_agree_row_by_row_on_a_step_with_loops(chips):
+    """The trainer's reader (``telemetry/device_trace.py``, repaired in
+    PR 28) and the benchmark's on the same synthetic events: the same
+    rows, none of them holding a loop laid over its body."""
+    from pytorch_vit_paper_replication_tpu.telemetry import device_trace
+
+    trace = looped_trace(steps=5, chips=chips)
+    mine = xplane.reduce_trace(trace, module_prefix="jit_train_step")
+    theirs = device_trace.reduce(trace)
+    assert theirs["steps"] == mine["steps"] == 4
+    table = {(r["layer"], r["phase"]): r["ms"] for r in theirs["rows"]}
+    rows = {(layer, phase): ms for layer, by in mine["rows_ms"].items()
+            for phase, ms in by.items()}
+    assert set(rows) == set(table)
+    for key, ms in table.items():
+        assert rows[key] == pytest.approx(ms, rel=1e-9), key
+    for key in ("step_ms", "mosaic_ms", "xla_ms", "collective_ms",
+                "collective_exposed_ms"):
+        assert mine[key] == pytest.approx(theirs[key], rel=1e-9), key
+    # the trainer's busy time is the union of the ops it kept; the
+    # benchmark's keeps the loops' own 70 ns
+    assert mine["busy_ms"] - theirs["busy_ms"] == pytest.approx(70e-6)
+
+
 def test_window_defaults_to_the_device_events_or_the_steps():
     r = xplane.reduce_trace(made_up_trace())
     assert r["window_s"] == pytest.approx(2200e-9)
@@ -318,6 +471,100 @@ def test_recorded_steps_rows_sum_to_its_busy_time(recorded):
     assert rows["attn_core"]["backward"] > 2 * rows["attn_core"]["forward"]
     assert sum(r["xla_by_phase_ms"].values()) == pytest.approx(
         r["xla_ms"], rel=1e-3)
+
+
+@pytest.fixture(scope="module")
+def token_recorded():
+    """Four steps of ``st21b_train_16k``'s step on one v5e chip (PR 30,
+    recorded as the ViT fixtures were: ``run.py --workload
+    st21b_train_16k --seed 3001 --trace 1 --dump-events ... --dump-steps
+    4``): the first recorded step with loops that have work in them (the
+    passes of ``ops/moe.py``, 32 ``while`` events a step) and with
+    kernels outside the MLP (flash attention, the grouped products)."""
+    return xplane.load_events_json(FIXTURE.with_name(
+        "train_step_st21b_scoped.events.json.gz"))
+
+
+def token_obs(trace):
+    cell, config = harness.load_cell("st21b_train_16k")
+    return {"train": {"batch_per_chip": 1}, "model": config["model"],
+            "lm": {"seq_len": 16384}, "peak": flops.peaks("TPU v5 lite"),
+            "trace": xplane.reduce_trace(trace,
+                                         module_prefix="jit_train_step")}
+
+
+def test_recorded_token_step_is_read_once(token_recorded):
+    ops = token_recorded["planes"][0]["lines"][1]["events"]
+    kept = xplane.leaves(ops)
+    gone = [e for e in ops if e["op"] in xplane.CONTROL_FLOW]
+    # every loop of this step has work in it: all 32 a step go
+    assert len(gone) == 4 * 32 and len(kept) == len(ops) - len(gone)
+    assert {e["op"] for e in gone} == {"while"}
+    obs = token_obs(token_recorded)
+    r = obs["trace"]
+    assert r["steps"] == 3 and r["step_ms"] == pytest.approx(481.80, abs=.01)
+    rows = r["rows_ms"]
+    # what the loops laid over their bodies, a step: the 85.9 ms that
+    # ``mlp_glue_ms`` and the rows' sum held twice from PR 28 to PR 29
+    twice = sum(e["dur_ns"] for e in gone) / 4 / 1e6
+    assert twice == pytest.approx(85.86, abs=0.01)
+    assert xplane.layer_ms(rows, *rows) == pytest.approx(481.53, abs=0.01)
+    assert xplane.layer_ms(rows, *rows) == pytest.approx(
+        r["busy_ms"], rel=1e-3)
+    assert r["busy_ms"] / r["step_ms"] > 0.999
+    assert mlp_glue_ms.read(obs) == pytest.approx(126.48, abs=0.01)
+    assert xla_ops_ms.read(obs) == pytest.approx(279.39, abs=0.01)
+    assert xla_backward_ms.read(obs) == pytest.approx(127.66, abs=0.01)
+    assert r["mosaic_ms"] + r["xla_ms"] == pytest.approx(r["busy_ms"],
+                                                         rel=1e-3)
+    assert other_ms.read(obs) < 0.01
+    assert not any(label.startswith("while") for label, _ in
+                   r["device_ops"])
+    # the attention core by direction, whatever implements it: here the
+    # three flash kernels and 16 ms of XLA
+    by_kernel = r["mosaic_by_kernel_ms"]
+    assert [round(by_kernel[k], 1) for k in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")] == [44.6, 46.6, 71.3]
+    assert attn_core_fwd_ms.read(obs) == pytest.approx(47.52, abs=0.01)
+    assert attn_core_bwd_ms.read(obs) == pytest.approx(130.94, abs=0.01)
+    assert attn_core_fwd_ms.read(obs) + attn_core_bwd_ms.read(obs) == \
+        pytest.approx(attn_core_ms.read(obs), rel=1e-9)
+    assert lm_attn_core_roofline_pct.read(obs) == pytest.approx(37.97,
+                                                                abs=0.01)
+    assert lm_step_mfu_pct.read(obs) == pytest.approx(36.55, abs=0.01)
+
+
+def test_the_two_readers_agree_on_the_recorded_token_step(token_recorded):
+    """The trainer's table has a row for every kernel and for the routed
+    layer's scopes (``TOKEN_LAYERS``); folded into the benchmark's
+    layers it is the benchmark's table, phase by phase."""
+    from pytorch_vit_paper_replication_tpu.telemetry import device_trace
+
+    mine = xplane.reduce_trace(token_recorded,
+                               module_prefix="jit_train_step")
+    theirs = device_trace.reduce(token_recorded)
+    assert theirs["steps"] == mine["steps"] == 3
+    for key in ("step_ms", "mosaic_ms", "xla_ms"):
+        assert mine[key] == pytest.approx(theirs[key], rel=1e-9), key
+    # the benchmark's busy time keeps the loops' own (0.15 ms a step)
+    assert 0 <= mine["busy_ms"] - theirs["busy_ms"] < 0.2
+    fold = {"moe_router": "mlp_xla", "moe_dispatch": "mlp_xla",
+            "moe_experts": "mlp_xla", "moe_combine": "mlp_xla",
+            "moe_gmm_fwd": "mlp_xla", "moe_gmm_dx": "mlp_xla",
+            "moe_gmm_dw": "mlp_xla", "flash_fwd": "attn_core",
+            "flash_bwd_dq": "attn_core", "flash_bwd_dkv": "attn_core",
+            "rope": "msa_glue", "token_embedding": "patch_embed",
+            "head": "final_norm_head", "head_loss": "final_norm_head"}
+    table = {}
+    for row in theirs["rows"]:
+        key = (fold.get(row["layer"], row["layer"]), row["phase"])
+        table[key] = table.get(key, 0.0) + row["ms"]
+    rows = {(layer, phase): ms for layer, by in mine["rows_ms"].items()
+            for phase, ms in by.items()}
+    assert set(rows) == set(table)
+    for key, ms in table.items():
+        # (a sum of medians against the median of a sum)
+        assert rows[key] == pytest.approx(ms, rel=2e-3, abs=2e-3), key
 
 
 def test_async_collective_span_counts_from_start_to_done():
