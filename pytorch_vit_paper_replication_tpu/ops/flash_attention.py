@@ -11,16 +11,34 @@ Layout: inputs are ``[B, T, H, Dh]``; internally folded to ``[B·H, T, Dh]``.
 The grid walks (batch·head, query-block); each program streams K/V blocks with
 ``lax.fori_loop``. Sequence lengths that are not block-aligned are padded by
 the wrapper and masked inside the kernel, so 577-token (384px) ViT sequences
-work. The backward pass is the standard flash recomputation: a ``dq`` kernel
-gridded over query blocks and a ``dk/dv`` kernel gridded over key blocks, both
-reusing the saved row logsumexp.
+work.
+
+The backward pass is the flash recomputation in ONE kernel
+(``flash_bwd``) that visits every visible (query block, key block) pair
+once: from the saved row logsumexp it takes ``s``, ``p = exp(s - lse)``,
+``dp = do v^T`` and ``ds = p (dp - delta)`` once a pair and all three
+gradients from them (five products a pair; a ``dq`` kernel and a
+``dk/dv`` kernel, as the file had until PR 31, take seven and the
+softmax twice). Its grid walks (key/value head, query head of the group,
+query block) and each program streams the visible key blocks: ``dq`` of
+the query block is carried over them, and ``dk`` / ``dv`` are added into
+two float32 ``[T, Dh]`` slabs of the key/value head that stay in VMEM
+over its query blocks AND over the query heads of its group, zeroed at
+the head's first program and cast and written once after its last: the
+group's sum is taken there, and no per-query-head ``dk`` / ``dv`` ever
+reaches HBM. ``delta_i = rowsum(dO_i * O_i)`` is taken in the kernel
+from the block of ``o`` (XLA laid a float32 transposed copy of ``o``
+through HBM for it). The kernel holds a pair's block transposed,
+``[Bk, Bq]`` (``s^T = k q^T``), so that ``dv += p^T do`` and ``dk +=
+ds^T q`` are plain products, only ``dq`` contracts rows, and ``lse`` /
+``delta`` broadcast along sublanes as they lie.
 
 **Attention dropout** (reference ``attn_dropout``, models/vit.py:75) runs
 in-kernel so long-sequence configs keep the O(T) memory property: the
 ``[T, T]`` drop mask is never materialized. Each element's keep/drop bit is
 a pure counter-based hash of ``(seed, batch·head, row, column)`` — an
 integer avalanche mix (xor-shift-multiply, murmur3-finalizer family)
-evaluated with plain vector ops, so the forward and both backward kernels
+evaluated with plain vector ops, so the forward and the backward kernel
 regenerate bit-identical masks independent of block iteration order, and
 the same code path runs under the Pallas CPU interpreter (the pltpu
 hardware PRNG has no interpret-mode lowering). Like :mod:`.dropout`, the
@@ -34,16 +52,16 @@ unbiased. The softmax normalizer uses the *undropped* probabilities
 (``kind`` = ``"causal"`` / ``"causal_window"``): key j is visible to
 query i iff ``j <= i`` and, with a window w, ``i - j < w``. Each kernel
 computes that from the positions of the block it holds and visits only
-the blocks that hold a visible pair — the loop over key blocks (forward,
-dq) or query blocks (dk/dv) runs from the first such block to the last,
-so a window layer at T = 16,384, w = 4,096 touches 22% of the square and
+the blocks that hold a visible pair — the loop over key blocks (forward
+and backward alike) runs from the first such block to the last, so a
+window layer at T = 16,384, w = 4,096 touches 22% of the square and
 a causal layer 50%.
 
 **Grouped-query attention**: q may have ``G`` times the heads of k and v.
 Query head h reads key/value head ``h // G`` through the block index (k
 and v are never repeated in HBM, and consecutive query heads of a group
-find the block already in VMEM). dk and dv come out per query head in
-float32 and are summed over the group outside the kernel.
+find the block already in VMEM). dk and dv come out once a key/value
+head, summed over its group inside the backward kernel in float32.
 
 The matrix products take their operands in the input dtype (bfloat16 on
 the MXU) and accumulate in float32; the softmax statistics are float32.
@@ -74,6 +92,10 @@ from .dropout import positional_dropout_seed, positional_meta
 # 38.2, and 512x256 100.3 / 52.8, 256x512 80.5 / 43.4: the per-block
 # softmax (VPU) and loop overheads are paid per block, the MXU work is
 # not. (With float32 operands, an earlier installation found 256 best.)
+# The one-pass backward alone at the same shapes, a causal / a window
+# layer (PR 31, my chip run; the pair it replaced 50.3 / 27.6 at 512x512):
+# 512x512 28.4 / 14.5 ms, 512x1024 28.2 / 15.8, 1024x512 28.5 / 15.9,
+# 256x256 52.1 / 24.7.
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 LONG_SEQUENCE = 2048
@@ -82,12 +104,12 @@ _NEG_INF = float(-1e30)
 _MIB = 1024 * 1024
 
 
-def _vmem_limit(*resident):
+def _vmem_limit(*resident, scratch=0):
     """Scoped-VMEM limit for a call that keeps ``resident`` whole
-    operands (bytes each, double-buffered) beside its blocks: the
-    compiler's default (16 MiB on the v5e) stops at T ~ 8k of bf16 k and
-    v at head size 128."""
-    need = 2 * sum(resident) + 16 * _MIB
+    operands or results (bytes each, double-buffered) and ``scratch``
+    bytes of its own beside its blocks: the compiler's default (16 MiB
+    on the v5e) stops at T ~ 8k of bf16 k and v at head size 128."""
+    need = 2 * sum(resident) + scratch + 16 * _MIB
     return int(min(max(need, 32 * _MIB), 100 * _MIB))
 
 
@@ -156,22 +178,6 @@ def _kv_full_range(structure, qi, block_q, block_k, lo, hi):
     return full_lo, full_hi
 
 
-def _q_full_range(structure, ki, block_q, block_k, lo, hi):
-    """Of ``[lo, hi)``, the query blocks ``[full_lo, full_hi)`` whose
-    every query sees every key of block ``ki``."""
-    causal, window = structure[:2]
-    if not causal:
-        return lo, hi
-    full_hi = hi
-    if window:
-        full_hi = jnp.clip(
-            (window + ki * block_k - block_q + 1 + block_q - 1) // block_q,
-            lo, hi)
-    full_lo = jnp.clip(((ki + 1) * block_k - 1 + block_q - 1) // block_q,
-                       lo, full_hi)
-    return full_lo, full_hi
-
-
 def _edges_and_interior(body, lo, full_lo, full_hi, hi, carry):
     """``body(i, carry, masked)`` over ``[lo, hi)``: with the structure's
     mask on the edge blocks, without it on ``[full_lo, full_hi)``."""
@@ -180,20 +186,6 @@ def _edges_and_interior(body, lo, full_lo, full_hi, hi, carry):
     carry = jax.lax.fori_loop(full_lo, full_hi,
                               functools.partial(body, masked=False), carry)
     return jax.lax.fori_loop(full_hi, hi, edge, carry)
-
-
-def _q_block_range(structure, ki, block_q, block_k, num_q):
-    """Query blocks ``[lo, hi)`` that hold a query which sees some key
-    of key block ``ki``."""
-    causal, window = structure[:2]
-    if not causal:
-        return 0, num_q
-    lo = (ki * block_k) // block_q
-    hi = num_q
-    if window:
-        hi = jnp.minimum(
-            num_q, ((ki + 1) * block_k - 1 + window - 1) // block_q + 1)
-    return lo, hi
 
 
 def _fold_heads(x):
@@ -218,28 +210,32 @@ def _pad_to(x, axis, multiple):
     return jnp.pad(x, widths)
 
 
-def _keep_mask(seed, bh, row0, col0, shape, threshold):
+def _keep_mask(seed, bh, row0, col0, shape, threshold, query_dim=0):
     """Keep/drop mask for one attention block: the shared positional hash
     (:func:`..ops.dropout.positional_keep_u8`) on the block's global
-    coordinates. Deterministic per element, so every kernel (fwd, dq,
-    dkv) regenerates the identical mask regardless of grid/loop order."""
+    coordinates (queries along ``query_dim`` of ``shape``, keys along the
+    other). Deterministic per element, so both kernels regenerate the
+    identical mask regardless of grid/loop order and of how they hold
+    the block."""
     from .dropout import positional_keep_u8
 
-    row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, query_dim)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - query_dim)
     return positional_keep_u8(seed, bh, row, col, threshold)
 
 
-def _global_bh(meta_ref, heads):
-    """The GLOBAL batch·head index of this program, the ``bh`` the mask
-    hash is keyed on. ``meta_ref`` is the scalar-prefetch triple
+def _global_bh(meta_ref, heads, bh=None):
+    """The GLOBAL batch·head index of this program (``bh``: its local
+    one, ``program_id(0)`` unless given), the ``bh`` the mask hash is
+    keyed on. ``meta_ref`` is the scalar-prefetch triple
     ``[seed, batch0, head0]`` and ``heads`` the static ``(local,
     global)`` head counts: under a mesh this shard holds batch rows from
     ``batch0`` and heads from ``head0``, so shards never share a mask
     and an element's mask is the one ring attention gives it. On one
     device (offsets 0, local == global) this is ``program_id(0)``."""
     h_local, h_total = heads
-    bh = pl.program_id(0)
+    if bh is None:
+        bh = pl.program_id(0)
     return ((meta_ref[1] + jax.lax.div(bh, jnp.int32(h_local))) * h_total
             + meta_ref[2] + jax.lax.rem(bh, jnp.int32(h_local)))
 
@@ -308,32 +304,12 @@ def _mask_spec_rows(mask_info, h, padded_kv, block_q):
                         lambda b, i, *_: (bhi(b), i, 0))
 
 
-def _mask_spec_cols(mask_info, h, padded_q, block_k):
-    """BlockSpec for the dk/dv kernel gridded over (bh, k-block): the
-    k-column strip [1, padded_q|1, block_k]."""
-    bh_mode, q_bcast = mask_info
-    bhi = _mask_bh_index(bh_mode, h)
-    rows = 1 if q_bcast else padded_q
-    return pl.BlockSpec((1, rows, block_k),
-                        lambda b, i, *_: (bhi(b), 0, i))
-
-
 def _mask_block_rows(mask_ref, mask_info, ki, block_q, block_k):
     """[Bq|1, Bk] attend-mask tile for a (q-strip kernel, kv block ki)."""
     _, q_bcast = mask_info
     rows = 1 if q_bcast else block_q
     return mask_ref[0, :, pl.ds(ki * block_k, block_k)].reshape(
         rows, block_k)
-
-
-def _mask_block_cols(mask_ref, mask_info, qi, block_q, block_k):
-    """[Bq|1, Bk] attend-mask tile for the (k-strip dkv kernel, q block
-    qi)."""
-    _, q_bcast = mask_info
-    if q_bcast:
-        return mask_ref[0, :, :].reshape(1, block_k)
-    return mask_ref[0, pl.ds(qi * block_q, block_q), :].reshape(
-        block_q, block_k)
 
 
 # --------------------------------------------------------------------------
@@ -495,137 +471,100 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
 # Backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   *rest, scale, block_k, kv_len, threshold, mask_info,
-                   heads, structure):
+def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                *rest, scale, block_k, kv_len, threshold, mask_info, heads,
+                structure):
+    """One (key/value head, query head of its group, query block)
+    program: every visible key block once, ``s``, ``p``, ``dp`` and
+    ``ds`` taken once a pair of blocks and all three gradients from
+    them. ``dq`` of the query block is carried over the key blocks;
+    ``dk`` and ``dv`` are added into the key/value head's two float32
+    ``[T, Dh]`` slabs, which stay in VMEM over the query blocks and over
+    the group's query heads and leave once, after the last.
+
+    The block is held transposed, ``[Bk, Bq]`` (``s^T = k q^T``): ``dv
+    += p^T do`` and ``dk += ds^T q`` are then plain products and only
+    ``dq`` contracts rows, and the row statistics broadcast along
+    sublanes as they lie."""
+    group = structure[2]
+    mask_ref = None
     if mask_info is not None:
-        mask_ref, dq_ref = rest
-    else:
-        mask_ref, (dq_ref,) = None, rest
-    q = q_ref[0]
+        mask_ref, *rest = rest
+    dq_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
+    q = q_ref[0]                       # [Bq, Dh]
     do = do_ref[0]
     causal = structure[0]
-    lse = lse_ref[0, 0][:, None]       # [Bq, 1]
-    delta = delta_ref[0, 0][:, None]   # [Bq, 1]
-    block_q, head_dim = q.shape
+    block_q = q.shape[0]
     num_kv = k_ref.shape[1] // block_k
-    bh = _global_bh(meta_ref, heads)
-    qi = pl.program_id(1)
+    g, qi = pl.program_id(1), pl.program_id(2)
+    bh = _global_bh(meta_ref, heads, pl.program_id(0) * group + g)
     inv_keep = 256.0 / (256.0 - threshold)
+    shape = (block_k, block_q)
 
     padded = kv_len != k_ref.shape[1]
+    lse = lse_ref[0]                   # [1, Bq]
+    # delta_i = rowsum(dO_i * O_i), turned to lie along the lanes as lse
+    # does (it already carries the forward's dropout).
+    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    delta = jnp.broadcast_to(delta, (block_q, 128)).T[:1]
+
+    @pl.when(jnp.logical_and(g == 0, qi == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def body(ki, dq, masked=True):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
+        keys = pl.ds(ki * block_k, block_k)
+        k = k_ref[0, keys, :]
+        v = v_ref[0, keys, :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [Bk, Bq]
         p = jnp.exp(s - lse)
         if padded or (causal and masked):
-            col = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
             keep_s = col < kv_len
             if causal and masked:
                 row = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
+                    jnp.int32, shape, 1)
                 keep_s = jnp.logical_and(keep_s,
                                          _visible(structure, row, col))
             p = jnp.where(keep_s, p, 0.0)
         if mask_info is not None:
-            attend = _mask_block_rows(mask_ref, mask_info, ki, block_q,
-                                      block_k)
-            p = jnp.where(attend, p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if threshold:
-            # dS = P ⊙ (M/keep ⊙ dP − delta): the mask enters through dP;
-            # delta = rowsum(dO⊙O) already carries the forward's dropout.
-            keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
-                              (block_q, block_k), threshold)
-            dp = jnp.where(keep, dp * inv_keep, 0.0)
-        ds = p * (dp - delta) * scale
-        return dq + jnp.dot(ds.astype(k.dtype), k,
-                            preferred_element_type=jnp.float32)
-
-    lo, hi = _kv_block_range(structure, qi, block_q, block_k, num_kv)
-    full = _kv_full_range(structure, qi, block_q, block_k, lo, hi)
-    dq = _edges_and_interior(
-        body, lo, *full, hi, jnp.zeros((block_q, head_dim), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, *rest, scale, block_q, q_len, threshold,
-                    mask_info, heads, structure):
-    if mask_info is not None:
-        mask_ref, dk_ref, dv_ref = rest
-    else:
-        mask_ref, (dk_ref, dv_ref) = None, rest
-    k = k_ref[0]                       # [Bk, Dh]
-    v = v_ref[0]
-    causal = structure[0]
-    block_k, head_dim = k.shape
-    num_q = q_ref.shape[1] // block_q
-    bh = _global_bh(meta_ref, heads)
-    ki = pl.program_id(1)
-    inv_keep = 256.0 / (256.0 - threshold)
-
-    padded = q_len != q_ref.shape[1]
-
-    def body(qi, carry, masked=True):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qi * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
-        p = jnp.exp(s - lse)
-        if padded or (causal and masked):
-            row = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            keep_s = row < q_len
-            if causal and masked:
-                col = ki * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                keep_s = jnp.logical_and(keep_s,
-                                         _visible(structure, row, col))
-            p = jnp.where(keep_s, p, 0.0)
-        if mask_info is not None:
-            attend = _mask_block_cols(mask_ref, mask_info, qi, block_q,
-                                      block_k)
-            p = jnp.where(attend, p, 0.0)
+            p = jnp.where(mask_ref[0, keys, :], p, 0.0)  # [Bk, Bq|1]
         if threshold:
             keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
-                              (block_q, block_k), threshold)
+                              shape, threshold, query_dim=1)
             p_dropped = jnp.where(keep, p * inv_keep, 0.0)
         else:
             p_dropped = p
-        dv_new = dv + jax.lax.dot_general(
-            p_dropped.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dv_acc[keys, :] += jnp.dot(p_dropped.astype(do.dtype), do,
+                                   preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+            v, do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if threshold:
+            # dS = P * (M/keep * dP - delta): the mask enters through dP.
             dp = jnp.where(keep, dp * inv_keep, 0.0)
-        ds = p * (dp - delta) * scale                    # [Bq, Bk]
-        dk_new = dk + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        ds = (p * (dp - delta) * scale).astype(q.dtype)  # [Bk, Bq]
+        dk_acc[keys, :] += jnp.dot(ds, q,
+                                   preferred_element_type=jnp.float32)
+        return dq + jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dk_new, dv_new
 
-    lo, hi = _q_block_range(structure, ki, block_q, block_k, num_q)
-    full = _q_full_range(structure, ki, block_q, block_k, lo, hi)
-    dk, dv = _edges_and_interior(
-        body, lo, *full, hi,
-        (jnp.zeros((block_k, head_dim), jnp.float32),
-         jnp.zeros((block_k, head_dim), jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    lo, hi = _kv_block_range(structure, qi, block_q, block_k, num_kv)
+    full = _kv_full_range(structure, qi, block_q, block_k, lo, hi)
+    dq = _edges_and_interior(body, lo, *full, hi,
+                             jnp.zeros(q.shape, jnp.float32))
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+
+    @pl.when(jnp.logical_and(g == group - 1,
+                             qi == pl.num_programs(2) - 1))
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -656,111 +595,79 @@ def _flash_fwd(q, k, v, seed, mask3, threshold, block_q, block_k,
 def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
                structure, res, do):
     q, k, v, seed, mask3, out, lse = res
-    scale = _layout(structure, q)[1] ** -0.5
     q_len, kv_len = q.shape[1], k.shape[1]
-    group, flat_heads = structure[2:]
+    group = structure[2]
     bh, head_dim, q_at, kv_at = _layout(structure, q)
 
-    # delta_i = rowsum(dO_i * O_i) — cheap elementwise, fused by XLA.
-    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
-    if flat_heads:        # [B, T, H*Dh] -> [B*H, T]
-        delta = prod.reshape(prod.shape[:2] + (flat_heads, head_dim)
-                             ).sum(-1).transpose(0, 2, 1).reshape(bh, q_len)
-    else:
-        delta = jnp.sum(prod, axis=-1)
-
     qp = _pad_to(q, 1, block_q)
+    outp = _pad_to(out, 1, block_q)
     dop = _pad_to(do, 1, block_q)
-    # Row statistics ride as [bh, 1, T] (TPU tiling: sublane dim == 1 ==
-    # full array dim is legal; a bare [bh, T] with 1-row blocks is not).
+    # The row statistic rides as [bh, 1, T] (TPU tiling: sublane dim == 1
+    # == full array dim is legal; a bare [bh, T] with 1-row blocks is
+    # not).
     lsep = _pad_to(lse, 1, block_q)[:, None, :]
-    deltap = _pad_to(delta, 1, block_q)[:, None, :]
     kp = _pad_to(k, 1, block_k)
     vp = _pad_to(v, 1, block_k)
-    padded_q, padded_kv = qp.shape[1], kp.shape[1]
+    padded_kv = kp.shape[1]
 
-    q_spec = pl.BlockSpec((1, block_q, head_dim),
-                          lambda b, i, *_: (q_at(b)[0], i, q_at(b)[1]))
-    kv_full = pl.BlockSpec((1, padded_kv, head_dim),
-                           lambda b, i, *_: (kv_at(b)[0], 0, kv_at(b)[1]))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i))
+    # Grid (key/value head n, query head g of its group, query block i):
+    # query head n * group + g.
+    def q_rows(n, g, i, *_):
+        at = q_at(n * group + g)
+        return at[0], i, at[1]
 
-    dq_in_specs = [q_spec, kv_full, kv_full, q_spec, row_spec, row_spec]
-    dq_operands = [qp, kp, vp, dop, lsep, deltap]
-    dkv_extra_specs = []
-    mask_operands = []
+    def kv_whole(n, g, i, *_):
+        at = kv_at(n * group)
+        return at[0], 0, at[1]
+
+    q_spec = pl.BlockSpec((1, block_q, head_dim), q_rows)
+    kv_spec = pl.BlockSpec((1, padded_kv, head_dim), kv_whole)
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, q_spec,
+                pl.BlockSpec((1, 1, block_q),
+                             lambda n, g, i, *_: (n * group + g, 0, i))]
+    operands = [qp, kp, vp, outp, dop, lsep]
     if mask_info is not None:
-        mask3 = _pad_mask(mask3, mask_info, block_q, block_k)
-        dq_in_specs.append(_mask_spec_rows(mask_info, heads[0],
-                                           mask3.shape[2], block_q))
-        dkv_extra_specs.append(_mask_spec_cols(mask_info, heads[0],
-                                               mask3.shape[1], block_k))
-        mask_operands.append(mask3)
+        # The kernel holds the block transposed: so is its mask.
+        mask_t = _pad_mask(mask3, mask_info, block_q, block_k
+                           ).transpose(0, 2, 1)
+        bhi = _mask_bh_index(mask_info[0], heads[0])
+        in_specs.append(pl.BlockSpec(
+            (1, padded_kv, 1 if mask_info[1] else block_q),
+            lambda n, g, i, *_: (bhi(n * group + g), 0,
+                                 0 if mask_info[1] else i)))
+        operands.append(mask_t)
+    slab = _slab_bytes(kp, head_dim)
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, block_k=block_k,
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=head_dim ** -0.5,
+                          block_k=block_k,
                           kv_len=kv_len, threshold=threshold,
                           mask_info=mask_info, heads=heads,
                           structure=structure),
-        name="flash_bwd_dq",
+        name="flash_bwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, padded_q // block_q),
-            in_specs=dq_in_specs,
-            out_specs=q_spec,
+            grid=(bh // group, group, qp.shape[1] // block_q),
+            in_specs=in_specs,
+            out_specs=[q_spec, kv_spec, kv_spec],
+            scratch_shapes=[
+                pltpu.VMEM((padded_kv, head_dim), jnp.float32)] * 2,
         ),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(qp.shape, q.dtype),
+                   jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vp.shape, v.dtype)],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(_slab_bytes(kp, head_dim),
-                                         _slab_bytes(vp, head_dim))),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(
+                slab, slab, slab, slab,
+                scratch=2 * 4 * padded_kv * head_dim)),
         interpret=interpret,
-    )(seed, qp, kp, vp, dop, lsep, deltap, *mask_operands)[:, :q_len]
-
-    q_full = pl.BlockSpec((1, padded_q, head_dim),
-                          lambda b, i, *_: (q_at(b)[0], 0, q_at(b)[1]))
-    k_in = pl.BlockSpec((1, block_k, head_dim),
-                        lambda b, i, *_: (kv_at(b)[0], i, kv_at(b)[1]))
-    k_spec = pl.BlockSpec((1, block_k, head_dim),
-                          lambda b, i, *_: (q_at(b)[0], i, q_at(b)[1]))
-    row_full = pl.BlockSpec((1, 1, padded_q), lambda b, i, *_: (b, 0, 0))
-    # One dk / dv per QUERY head (laid out as q is); a group's are
-    # summed below, in float32.
-    dkv_shape = (qp.shape[0], padded_kv, qp.shape[2])
-    dkv_dtype = k.dtype if group == 1 else jnp.float32
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                          q_len=q_len, threshold=threshold,
-                          mask_info=mask_info, heads=heads,
-                          structure=structure),
-        name="flash_bwd_dkv",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, padded_kv // block_k),
-            in_specs=[q_full, k_in, k_in, q_full, row_full, row_full]
-            + dkv_extra_specs,
-            out_specs=[k_spec, k_spec],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(dkv_shape, dkv_dtype),
-                   jax.ShapeDtypeStruct(dkv_shape, dkv_dtype)],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(_slab_bytes(qp, head_dim),
-                                         _slab_bytes(dop, head_dim))),
-        interpret=interpret,
-    )(seed, qp, kp, vp, dop, lsep, deltap, *mask_operands)
-    if group > 1 and flat_heads:      # [B, T, (Hkv, G, Dh)] -> sum over G
-        fold = lambda g, like: g.reshape(
-            g.shape[:2] + (flat_heads // group, group, head_dim)
-        ).sum(3).reshape(g.shape[:2] + (-1,)).astype(like.dtype)
-        dk, dv = fold(dk, k), fold(dv, v)
-    elif group > 1:                   # [(B, Hkv, G), T, Dh] -> sum over G
-        fold = lambda g, like: g.reshape(
-            (bh // group, group) + g.shape[1:]).sum(1).astype(like.dtype)
-        dk, dv = fold(dk, k), fold(dv, v)
+    )(seed, *operands)
     seed_zero = np.zeros(seed.shape, dtype=jax.dtypes.float0)
     mask_zero = (None if mask3 is None
-                 else np.zeros(res[4].shape, dtype=jax.dtypes.float0))
-    return dq, dk[:, :kv_len], dv[:, :kv_len], seed_zero, mask_zero
+                 else np.zeros(mask3.shape, dtype=jax.dtypes.float0))
+    return (dq[:, :q_len], dk[:, :kv_len], dv[:, :kv_len], seed_zero,
+            mask_zero)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

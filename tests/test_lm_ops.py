@@ -42,22 +42,15 @@ def _dense_attention(q, k, v, kind, window):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
-@pytest.mark.parametrize("kind,window,blocks", [
-    ("causal", 0, (16, 16)),
-    ("causal_window", 12, (16, 16)),
-    ("causal_window", 40, (16, 16)),      # wider than a block
-    ("causal_window", 12, (32, 8)),       # unequal blocks
-])
-def test_flash_kernels_compute_the_structure_from_positions(
-        kind, window, blocks):
-    """7 query heads to a key/value head, T = 50 (no multiple of a
-    block), forward and the three gradients, through the Pallas
-    interpreter, against the explicit visibility matrix."""
+def _flash_against_dense(shape, kv_heads, kind, window, blocks):
+    """Forward and the three gradients of the flash kernels, through the
+    Pallas interpreter, against the explicit visibility matrix."""
+    b, t, _, d = shape
     ks = jax.random.split(jax.random.key(5), 4)
-    q = jax.random.normal(ks[0], (2, 50, 7, 16))
-    k = jax.random.normal(ks[1], (2, 50, 1, 16))
-    v = jax.random.normal(ks[2], (2, 50, 1, 16))
-    cot = jax.random.normal(ks[3], q.shape)
+    q = jax.random.normal(ks[0], shape)
+    k = jax.random.normal(ks[1], (b, t, kv_heads, d))
+    v = jax.random.normal(ks[2], (b, t, kv_heads, d))
+    cot = jax.random.normal(ks[3], shape)
     flash = lambda q, k, v: flash_attention(
         q, k, v, kind=kind, window=window, block_q=blocks[0],
         block_k=blocks[1], interpret=True)
@@ -67,6 +60,61 @@ def test_flash_kernels_compute_the_structure_from_positions(
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), (0, 1, 2))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=5e-5)
+
+
+@pytest.mark.parametrize("kind,window,blocks", [
+    ("causal", 0, (16, 16)),
+    ("causal_window", 12, (16, 16)),
+    ("causal_window", 40, (16, 16)),      # wider than a block
+    ("causal_window", 12, (32, 8)),       # unequal blocks
+])
+def test_flash_kernels_compute_the_structure_from_positions(
+        kind, window, blocks):
+    """7 query heads to a key/value head, T = 50 (no multiple of a
+    block): the folded layout (Dh = 16)."""
+    _flash_against_dense((2, 50, 7, 16), 1, kind, window, blocks)
+
+
+@pytest.mark.parametrize("kv_heads", [2, 1])
+@pytest.mark.parametrize("kind,window,blocks", [
+    ("causal", 0, (16, 16)),
+    ("causal_window", 21, (32, 16)),      # no multiple of either block
+    ("causal_window", 21, (16, 32)),
+])
+def test_flash_kernels_read_heads_as_column_blocks_of_the_projection(
+        kind, window, blocks, kv_heads):
+    """The flat layout (Dh = 128: q ``[B, T, H*Dh]`` read where the
+    projection left it, 4 query heads over 2 and over 1 key/value
+    heads), T = 70: the one backward kernel takes the group's sum
+    inside."""
+    _flash_against_dense((2, 70, 4, 128), kv_heads, kind, window, blocks)
+
+
+@pytest.mark.parametrize("shape,kv_heads,extra", [
+    ((2, 300, 14, 128), 2, dict(kind="causal")),            # flat, group 7
+    ((2, 300, 14, 128), 2, dict(kind="causal_window", window=100)),
+    ((2, 577, 4, 64), 4, dict(                               # folded, group 1
+        mask=np.ones((2, 1, 1, 577), bool), dropout_rate=0.1,
+        dropout_rng=jax.random.key(0), deterministic=False)),
+    ((2, 300, 6, 64), 2, dict(kind="causal")),               # folded, group 3
+], ids=["flat-causal", "flat-window", "folded-mask-dropout",
+        "folded-grouped"])
+def test_the_backward_of_flash_attention_is_one_mosaic_call(
+        shape, kv_heads, extra):
+    """Lowered for the TPU (nothing compiled, nothing run): the forward
+    kernel and ONE backward kernel, whatever the group, the layout, the
+    structure, a mask or dropout."""
+    from pytorch_vit_paper_replication_tpu.ops.partition import mosaic_calls
+
+    b, t, _, dh = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((b, t, kv_heads, dh), jnp.bfloat16)
+    grad = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, interpret=False, **extra).astype(jnp.float32)),
+        argnums=(0, 1, 2)))
+    text = grad.trace(q, kv, kv).lower(lowering_platforms=("tpu",)).as_text()
+    assert [name for name, _ in mosaic_calls(text)] == [
+        "flash_fwd", "flash_bwd"]
 
 
 @pytest.mark.parametrize("kind,window", [("causal", 0),

@@ -234,6 +234,78 @@ def test_flash_dropout_forward_backward_match_masked_reference():
                                    err_msg=f"d{name}", **TOL)
 
 
+def test_flash_backward_with_mask_dropout_and_ragged_length_at_once():
+    """The one backward kernel with everything a ViT caller can ask of
+    it in one call: a key-padding mask, in-kernel dropout (the mask
+    recovered from the kernel itself), T = 200 over unequal blocks of 64
+    queries and 32 keys (padding on both sides): output and the three
+    gradients against the explicit masked reference."""
+    rate, b, t, h, d = 0.25, 2, 200, 2, 64
+    rng = jax.random.key(4)
+    drop, _ = _recover_drop_mask(rng, b, h, t, rate)
+    drop = jnp.asarray(drop.reshape(b, h, t, t))
+    attend = jax.random.bernoulli(jax.random.key(21), 0.8, (b, 1, 1, t))
+    attend = attend.at[..., 0].set(True)
+    q, k, v = _qkv(9, b, t, h, d)
+
+    def flash_fn(args):
+        out = flash_attention(*args, mask=attend, dropout_rate=rate,
+                              dropout_rng=rng, deterministic=False,
+                              block_q=64, block_k=32, interpret=True)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    def ref_fn(args):
+        q, k, v = args
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (d ** -0.5)
+        p = jax.nn.softmax(jnp.where(attend, s, -jnp.inf), axis=-1)
+        z = jnp.where(drop, p, 0.0) / 0.75
+        return (jnp.einsum("bhqk,bkhd->bqhd", z, v) ** 2).sum()
+
+    np.testing.assert_allclose(flash_fn((q, k, v)), ref_fn((q, k, v)),
+                               rtol=1e-3)
+    g = jax.grad(flash_fn)((q, k, v))
+    g_ref = jax.grad(ref_fn)((q, k, v))
+    for name, a, r in zip("qkv", g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r),
+                                   err_msg=f"d{name}", **TOL)
+
+
+@pytest.mark.parametrize("blocks", [(16, 16), (16, 32), (32, 16)], ids=str)
+def test_flash_backward_adds_each_visible_block_once(blocks):
+    """What the backward kernel keeps from grid step to grid step: a
+    window of 20 over T = 96 (6 blocks of 16 a side, 3 query heads to a
+    key/value head, 2 of those, 2 sequences). A query block adds to two
+    or three key blocks' rows of the key/value head's ``dk`` / ``dv``
+    slabs and to nothing of the rest, a key block's first add comes from
+    an edge block (the diagonal), the slabs go on through the group's
+    three query heads, and every key/value head after the first finds
+    them as the last one left them: a slab not zeroed at its first step,
+    a block added twice or one left out moves a gradient by its own
+    size, not by 1e-5."""
+    t, window = 96, 20
+    ks = jax.random.split(jax.random.key(8), 4)
+    q = jax.random.normal(ks[0], (2, t, 6, 16))
+    k = jax.random.normal(ks[1], (2, t, 2, 16))
+    v = jax.random.normal(ks[2], (2, t, 2, 16))
+    cot = jax.random.normal(ks[3], q.shape)
+
+    def dense(q, k, v):
+        kk, vv = jnp.repeat(k, 3, axis=2), jnp.repeat(v, 3, axis=2)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * 16 ** -0.5
+        i, j = jnp.arange(t)[:, None], jnp.arange(t)[None]
+        p = jax.nn.softmax(
+            jnp.where((j <= i) & (i - j < window), s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, vv)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, kind="causal_window", window=window, block_q=blocks[0],
+        block_k=blocks[1], interpret=True)
+    got = jax.grad(lambda *a: jnp.sum(flash(*a) * cot), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * cot), (0, 1, 2))(q, k, v)
+    for name, g, w in zip("qkv", got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5, err_msg=f"d{name}")
+
+
 def test_flash_dropout_actually_drops():
     """Kernel-path dropout visibly perturbs the output vs deterministic
     (and VERDICT r2 #7's done-criterion: dropout no longer forces the

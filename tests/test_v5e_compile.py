@@ -228,7 +228,7 @@ def test_dp2_tp2_step_lowers_hidden_sliced_mlp_and_per_shard_flash(
     q = (32 * 6, 768, 64)
     assert sorted(calls) == sorted([
         ("mlp_fwd", (mlp_rows, 768)), ("mlp_bwd", (mlp_rows, 768)),
-        ("flash_fwd", q), ("flash_bwd_dq", q), ("flash_bwd_dkv", q)])
+        ("flash_fwd", q), ("flash_bwd", q)])
 
 
 # ------------------------------------------------------- the token model
@@ -265,7 +265,10 @@ def test_flash_kernels_compile_at_the_token_cells_shapes(v5e_2x2, kind,
     """T = 16,384, 28 query heads over 4 key/value heads of 128: whole k
     and v of a head in VMEM (4 MiB each, beyond the default scoped limit),
     loop bounds computed from the block's position, heads read as column
-    blocks of the projection: forward, dq and dk/dv compile."""
+    blocks of the projection: the forward and the one backward kernel
+    compile, the backward with k and v whole, the two float32 slabs in
+    which it sums dk and dv over a key/value head's query blocks and its
+    group of 7, and their results in VMEM at once (64 MiB asked)."""
     from pytorch_vit_paper_replication_tpu.ops.flash_attention import (
         flash_attention)
 
@@ -276,9 +279,12 @@ def test_flash_kernels_compile_at_the_token_cells_shapes(v5e_2x2, kind,
         q, k, v, kind=kind, window=window, interpret=False).astype(
         jnp.float32)), argnums=(0, 1, 2))).lower(q, kv, kv).compile()
     hlo = compiled.as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     # no transposed copy of q (or of its gradient) around the kernels
     assert not re.search(r"bf16\[28,16384,128\]", hlo)
+    # dk and dv leave the kernel once a key/value head: none per query
+    # head in float32 for XLA to sum over the group
+    assert not re.search(r"f32\[1,16384,3584\]", hlo)
 
 
 def test_token_models_step_compiles_and_fits_one_chip(v5e_2x2, monkeypatch):
@@ -300,7 +306,7 @@ def test_token_models_step_compiles_and_fits_one_chip(v5e_2x2, monkeypatch):
                              seq_len=cfg.max_seq_len)
     names = [name for name, _ in mosaic_calls(lowered.as_text())]
     assert {n: names.count(n) for n in set(names)} == {
-        "flash_fwd": 4, "flash_bwd_dq": 4, "flash_bwd_dkv": 4,
+        "flash_fwd": 4, "flash_bwd": 4,
         "moe_gmm_fwd": 24, "moe_gmm_dx": 16, "moe_gmm_dw": 16}
     compiled = lowered.compile()
     m = compiled.memory_analysis()
@@ -357,7 +363,7 @@ def test_token_model_is_partitioned_per_shard_on_a_data_mesh(v5e_2x2,
     calls = mosaic_calls(_lower_lm_step(v5e_2x2, cfg, dp=4, batch=8,
                                         seq_len=1024).as_text())
     assert {name for name, _ in calls} == {
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "moe_gmm_fwd",
-        "moe_gmm_dx", "moe_gmm_dw"}
+        "flash_fwd", "flash_bwd", "moe_gmm_fwd", "moe_gmm_dx",
+        "moe_gmm_dw"}
     assert {shape for name, shape in calls if name == "flash_fwd"} == {
         (2, 1024, 4 * 128)}
