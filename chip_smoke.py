@@ -10,9 +10,11 @@ headline model (ViT-B/16, 224 px, bf16, random weights from a seed):
   train       `python -m ...train --synthetic --preset ViT-B/16
               --batch-size 256`: 8 optimizer steps, an eval pass per
               epoch, a checkpoint and the final/ export
-  train-lm    `python -m ...train --model lm --preset lm-tiny --synthetic`:
-              3 steps of the tiny token model (routed experts, causal /
-              window attention) and its eval pass
+  train-lm    `python -m ...train --model lm --preset lm-tiny --synthetic`,
+              then `--preset mla-tiny`: 3 steps of each tiny token model
+              (routed experts, causal / window attention; latent
+              attention, shared expert, multi-token prediction) and its
+              eval pass
   serve       `python -m ...serve --checkpoint <that run> --sync-warmup
               --buckets 1,8`, fed image paths and ::stats on stdin
   train-dp4   the same trainer with its default mesh over four chips
@@ -246,33 +248,42 @@ def train(ctx) -> None:
 
 
 def train_lm(ctx) -> None:
-    """The token model through the same entry point: 3 steps of the tiny
-    preset (the routed experts' Mosaic kernels at their smallest, XLA
-    attention at T = 64), then its eval pass."""
-    jsonl = ctx["work"] / "train_lm.jsonl"
-    out = run_child(
-        "train-lm",
-        [PY, "-m", f"{PKG}.train", "--model", "lm", "--preset", "lm-tiny",
-         "--synthetic", "--batch-size", "8", "--epochs", "1",
-         "--steps-per-epoch", "3", "--metrics-jsonl", str(jsonl)],
-        env=child_env(ctx["one_chip"]), log_dir=ctx["logs"], timeout=600)
-    m = find(r"mesh: \{'data': (\d+), .*platform: (\w+) \|", out,
-             "model/mesh line")
-    check(int(m.group(1)) == 1 and m.group(2) == "tpu",
-          f"token model trained on data={m.group(1)} {m.group(2)}")
-    calls = find(r"^train step: (\d+) Mosaic kernel calls: (.*)$", out,
-                 "Mosaic call report").group(2)
-    check(all(k in calls for k in ("moe_gmm_fwd x12", "moe_gmm_dx x8",
-                                   "moe_gmm_dw x8")),
-          f"the routed experts' kernels in the lowered step: {calls}")
-    (row,) = train_rows(jsonl)
-    say(f"  {row['step']} steps, train_loss {row['train_loss']:.4f}, "
-        f"test_loss {row['test_loss']:.4f} | {calls}")
-    check(row["step"] == 3 and all(
-        v == v and abs(v) != float("inf") and 0 < v < 6.0
-        for v in (row["train_loss"], row["test_loss"])),
-        f"token model: 3 steps with finite losses near log 256 = 5.5 "
-        f"wanted, got {row}")
+    """The token models through the same entry point: 3 steps of each
+    tiny preset (SmallThinker's blocks, then GLM-4.7-Flash's: latent
+    attention, a dense layer under routed ones with a shared expert, the
+    multi-token-prediction module), the routed experts' Mosaic kernels at
+    their smallest, XLA attention at T = 64, then the eval pass."""
+    # (preset, routed blocks, the objective's ceiling: log 256 = 5.5, and
+    # 1.3 x that with the module's term)
+    for preset, routed, ceiling in (("lm-tiny", 4, 6.0),
+                                    ("mla-tiny", 3, 8.0)):
+        jsonl = ctx["work"] / f"train_{preset}.jsonl"
+        out = run_child(
+            "train-lm",
+            [PY, "-m", f"{PKG}.train", "--model", "lm", "--preset", preset,
+             "--synthetic", "--batch-size", "8", "--epochs", "1",
+             "--steps-per-epoch", "3", "--metrics-jsonl", str(jsonl)],
+            env=child_env(ctx["one_chip"]), log_dir=ctx["logs"], timeout=600)
+        m = find(r"mesh: \{'data': (\d+), .*platform: (\w+) \|", out,
+                 "model/mesh line")
+        check(int(m.group(1)) == 1 and m.group(2) == "tpu",
+              f"{preset} trained on data={m.group(1)} {m.group(2)}")
+        calls = find(r"^train step: (\d+) Mosaic kernel calls: (.*)$", out,
+                     "Mosaic call report").group(2)
+        check(all(k in calls for k in (f"moe_gmm_fwd x{3 * routed}",
+                                       f"moe_gmm_dx x{2 * routed}",
+                                       f"moe_gmm_dw x{2 * routed}")),
+              f"{preset}: the routed experts' kernels in the lowered step: "
+              f"{calls}")
+        (row,) = train_rows(jsonl)
+        say(f"  {preset}: {row['step']} steps, train_loss "
+            f"{row['train_loss']:.4f}, test_loss {row['test_loss']:.4f} | "
+            f"{calls}")
+        check(row["step"] == 3 and all(
+            v == v and abs(v) != float("inf") and 0 < v < ceiling
+            for v in (row["train_loss"], row["test_loss"])),
+            f"{preset}: 3 steps with finite losses under {ceiling} wanted, "
+            f"got {row}")
 
 
 def serve(ctx) -> None:

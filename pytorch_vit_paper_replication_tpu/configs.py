@@ -110,16 +110,59 @@ class ViTConfig:
     sliding_window_layout: Tuple[int, ...] = ()
     sliding_window: int = 0
     # Routed feed-forward in place of the MLP: ``num_experts`` > 0 routes
-    # every token to its ``experts_per_token`` largest of ``num_experts``
-    # router logits (softmax over the selected), each expert a
-    # ReLU-gated ``embedding_dim -> expert_width -> embedding_dim``. This
-    # chip holds experts ``expert_offset ..+ experts_held`` and computes
-    # their part of the result (None = all of them).
+    # every token to ``experts_per_token`` of ``num_experts`` by its
+    # router scores (``router_scoring``), each expert a gated
+    # ``embedding_dim -> expert_width -> embedding_dim``
+    # (``expert_activation`` on the gate). This chip holds experts
+    # ``expert_offset ..+ experts_held`` and computes their part of the
+    # result (None = all of them).
     num_experts: int = 0
     experts_per_token: int = 0
     expert_width: int = 0
     experts_held: int | None = None
     expert_offset: int = 0
+    # "softmax": the largest router logits, softmax over the selected.
+    # "sigmoid": scores ``sigmoid(logits)``; selected by score + a
+    # correction bias (a parameter that takes no gradient), weighted by
+    # the scores alone, normalised over the selected and scaled by
+    # ``router_scale``.
+    router_scoring: str = "softmax"
+    router_scale: float = 1.0
+    # What the router reads: "attention" = the block's pre-attention
+    # normed input, "block" = the feed-forward's own normed input.
+    router_input: str = "attention"
+    # The gate's activation in every gated feed-forward (routed, shared,
+    # dense): "relu" or "silu".
+    expert_activation: str = "relu"
+    # Experts of ``expert_width`` that every token passes, beside the
+    # routed ones (one gated product of that many widths, held whole).
+    shared_experts: int = 0
+    # The layer kind per layer: the first ``dense_layers`` blocks of a
+    # routed model have a gated dense feed-forward of ``dense_width``
+    # instead (bias-free, ``expert_activation``).
+    dense_layers: int = 0
+    dense_width: int = 0
+    # Latent attention (``kv_lora_rank`` > 0): queries through a
+    # ``q_lora_rank`` latent and keys/values through a ``kv_lora_rank``
+    # latent, each with a norm inside; a head's query/key is
+    # ``qk_nope_head_dim`` columns from the latent and
+    # ``qk_rope_head_dim`` rotary columns, the key's rotary part ONE
+    # head shared by all query heads; values ``v_head_dim`` a head.
+    # ``head_dim`` is the two parts' sum (no ``head_dim_override``), and
+    # ``v_head_dim`` must equal it until the core takes unequal sizes.
+    # The heads are taken again from the latents in the backward pass.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Multi-token prediction: that many modules after the last block
+    # (one is built), each the shared embedding of the next token merged
+    # with the hidden state, one block of the last layer's kind, a norm
+    # and the shared head, predicting one token further; the objective
+    # is main + ``mtp_loss_weight`` x the modules' mean.
+    mtp_modules: int = 0
+    mtp_loss_weight: float = 0.3
     # Std of the normal initialiser of a token model's matrices (its
     # embedding rows start at N(0, 1): ``models/vit.py::TokenEmbedding``).
     init_std: float = 0.02
@@ -150,13 +193,41 @@ class ViTConfig:
                     f"{self.num_experts} experts of width "
                     f"{self.expert_width}, {held} held from "
                     f"{self.expert_offset}")
+        if self.router_scoring not in ("softmax", "sigmoid") \
+                or self.router_input not in ("attention", "block") \
+                or self.expert_activation not in ("relu", "silu"):
+            raise ValueError(
+                f"router_scoring {self.router_scoring!r}, router_input "
+                f"{self.router_input!r}, expert_activation "
+                f"{self.expert_activation!r}")
+        if (self.shared_experts or self.dense_layers) \
+                and not self.num_experts:
+            raise ValueError(
+                "shared_experts and dense_layers belong to a routed model")
+        if self.dense_layers and self.dense_width <= 0:
+            raise ValueError("dense_layers needs dense_width")
+        if self.kv_lora_rank and not (
+                self.vocab_size and self.q_lora_rank > 0
+                and self.qk_rope_head_dim % 2 == 0
+                and self.kv_heads == self.num_heads
+                and self.head_dim_override is None
+                and self.qk_nope_head_dim + self.qk_rope_head_dim
+                == self.v_head_dim):
+            raise ValueError(
+                "latent attention: a token model with q_lora_rank, equal "
+                "head counts, no head_dim_override (the head size is "
+                "qk_nope_head_dim + qk_rope_head_dim) and v_head_dim equal "
+                "to it (one head size for the attention core)")
+        if self.mtp_modules not in (0, 1) or (
+                self.mtp_modules and not self.vocab_size):
+            raise ValueError("mtp_modules: 0, or 1 on a token model")
         if self.image_size % self.patch_size != 0:
             # Reference asserts the same invariant at models/vit.py:25.
             raise ValueError(
                 f"image_size ({self.image_size}) must be divisible by "
                 f"patch_size ({self.patch_size})"
             )
-        if (self.head_dim_override is None
+        if (self.head_dim_override is None and not self.kv_lora_rank
                 and self.embedding_dim % self.num_heads != 0):
             raise ValueError(
                 f"embedding_dim ({self.embedding_dim}) must be divisible by "
@@ -194,6 +265,11 @@ class ViTConfig:
         return (self.num_experts if self.experts_held is None
                 else self.experts_held)
 
+    def layer_routed(self, layer: int) -> bool:
+        """Whether block ``layer``'s feed-forward is the routed one (the
+        multi-token-prediction module's block is block ``num_layers``)."""
+        return bool(self.num_experts) and layer >= self.dense_layers
+
     def layer_rope(self, layer: int) -> bool:
         """Whether block ``layer`` turns q and k by their positions."""
         lay = self.rope_layout
@@ -213,6 +289,8 @@ class ViTConfig:
     def head_dim(self) -> int:
         if self.head_dim_override is not None:
             return self.head_dim_override
+        if self.kv_lora_rank:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.embedding_dim // self.num_heads
 
     def replace(self, **kw) -> "ViTConfig":
@@ -286,6 +364,45 @@ def lm_tiny(**kw) -> ViTConfig:
         experts_held=4), **kw})
 
 
+def glm_47_flash_ep8(**kw) -> ViTConfig:
+    """GLM-4.7-Flash (huggingface.co/zai-org, ``glm4_moe_lite``), one
+    chip's share of a deployment in which 8 chips share each layer:
+    every published width (2048; latent attention 768 / 512 with 20
+    heads of 192 + 64 rotary and 256 value columns, theta 1e6; the
+    leading dense layer of 10240; experts of 1536, 4 of 64 by sigmoid
+    scores scaled 1.8, one shared), the leading dense layer and 4 of the
+    46 routed layers, the one multi-token-prediction module, experts 0-7
+    of 64 and rows 0-19,359 of the 154,880-row vocabulary, at 16,384 of
+    its 202,752 positions. What is assumed of the source is in
+    ``benchmark/configs/glm-4.7-flash-ep8.json``."""
+    base = dict(
+        vocab_size=19360, max_seq_len=16384, num_layers=5, num_heads=20,
+        embedding_dim=2048, norm="rmsnorm",
+        ln_epsilon=1e-5, attn_bias=False, rope_layout=(1,),
+        rope_theta=1e6, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        num_experts=64, experts_per_token=4, expert_width=1536,
+        experts_held=8, expert_offset=0, router_scoring="sigmoid",
+        router_scale=1.8, router_input="block", expert_activation="silu",
+        shared_experts=1, dense_layers=1, dense_width=10240,
+        mtp_modules=1, mtp_loss_weight=0.3, attn_dropout=0.0,
+        mlp_dropout=0.0, embedding_dropout=0.0)
+    return ViTConfig(**{**base, **kw})
+
+
+def mla_tiny(**kw) -> ViTConfig:
+    """The same blocks at a size for tests: a dense layer and 2 routed
+    ones with the module, width 64, latents 24 / 16, 4 heads of 8 + 8
+    and 16, dense 96, 8 experts of 32 of which 4 are held, top 2, one
+    shared, 256 rows, 64 positions."""
+    return glm_47_flash_ep8(**{**dict(
+        vocab_size=256, max_seq_len=64, num_layers=3, num_heads=4,
+        embedding_dim=64, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=8,
+        v_head_dim=16, num_experts=8, experts_per_token=2,
+        expert_width=32, experts_held=4, dense_width=96), **kw})
+
+
 PRESETS = {
     "ViT-Ti/16": vit_ti16,
     "ViT-S/16": vit_s16,
@@ -300,6 +417,8 @@ PRESETS = {
 LM_PRESETS = {
     "smallthinker-21b-a3b-ep4": smallthinker_21b_a3b_ep4,
     "lm-tiny": lm_tiny,
+    "glm-4.7-flash-ep8": glm_47_flash_ep8,
+    "mla-tiny": mla_tiny,
 }
 
 # The fields that make two configs the same *servable architecture*

@@ -140,7 +140,10 @@ def _moe_metrics(moe_stats) -> Dict[str, jax.Array]:
     kept = sum(leaves("kept")).astype(jnp.float32)
     routed = sum(leaves("routed")).astype(jnp.float32)
     passes = jnp.concatenate(leaves("passes"))
+    scores = leaves("score_sum")    # a sigmoid router's normaliser
     return {
+        **({"moe_score_sum_mean": jnp.mean(jnp.stack(scores))}
+           if scores else {}),
         "moe_pairs_per_expert_min": counts.min(),
         "moe_pairs_per_expert_mean": counts.mean(),
         "moe_pairs_per_expert_max": counts.max(),
@@ -158,16 +161,27 @@ def _token_loss(state, params, batch, dropout_rng):
     every position of ``batch["tokens"]`` against ``batch["label"]``,
     taken by the model's head in chunks (no ``[B, T, V]`` logits), and
     the metrics of the step: ``correct`` counts sequences' worth of
-    right positions, so that ``correct / count`` is the token accuracy."""
+    right positions, so that ``correct / count`` is the token accuracy.
+    A model with a multi-token-prediction module returns its two-term
+    objective and sows the terms (``lm_stats``: ``main_loss``,
+    ``mtp_loss``, ``mtp_top1_share``), which ride along as counters."""
     (loss, right), sown = state.apply_fn(
         {"params": params}, batch["tokens"], True, labels=batch["label"],
-        rngs={"dropout": dropout_rng}, mutable=["moe_stats"])
+        rngs={"dropout": dropout_rng}, mutable=["moe_stats", "lm_stats"])
     n, t = batch["label"].shape
-    metrics = {"loss_sum": loss * n, "correct": right / t,
-               "count": jnp.asarray(n, jnp.float32)}
-    if sown.get("moe_stats"):
-        metrics.update(_moe_metrics(sown["moe_stats"]))
+    with jax.named_scope("metrics"):
+        metrics = {"loss_sum": loss * n, "correct": right / t,
+                   "count": jnp.asarray(n, jnp.float32)}
+        if sown.get("moe_stats"):
+            metrics.update(_moe_metrics(sown["moe_stats"]))
+        metrics.update({key: value[0] for key, value in
+                        sown.get("lm_stats", {}).items()})
     return loss, metrics
+
+
+# A token model's step counters (beside ``moe_*``) that the loop hands to
+# the telemetry on barriered steps.
+LM_COUNTERS = ("main_loss", "mtp_loss", "mtp_top1_share")
 
 
 def _masked_metrics(losses, logits, labels, mask) -> Dict[str, jax.Array]:
@@ -570,7 +584,8 @@ def train(
                     # vitlint: hot-path-ok(sampled, on steps already barriered)
                     counters = {k: float(v) for k, v in jax.device_get(
                         {k: v for k, v in metrics.items()
-                         if k.startswith("moe_")}).items()}
+                         if k.startswith("moe_") or k in LM_COUNTERS}
+                    ).items()}
                 telemetry.step(
                     data_wait_s=data_wait,
                     exec_s=time.perf_counter() - t_step,

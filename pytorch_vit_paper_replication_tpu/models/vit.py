@@ -195,7 +195,9 @@ class MultiHeadSelfAttentionBlock(nn.Module):
                  with_normed: bool = False):
         cfg = self.config
         if cfg.vocab_size:
-            out, y = _token_attention(self, x, train)
+            attention = (_latent_attention if cfg.kv_lora_rank
+                         else _token_attention)
+            out, y = attention(self, x, train)
             return (out, y) if with_normed else out
         # Deliberately NOT Pallas-fused: a fused LN+QKV kernel (the
         # fused_mlp treatment applied here) measured a net LOSS — isolated
@@ -280,6 +282,67 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     out = dense(features=cfg.embedding_dim, axis=(-2, -1),
                 name="out")(attn)
     return out, y
+
+
+def _latent_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
+                      train: bool):
+    """(A free function for the reason :func:`_token_attention` is.)
+
+    Latent attention: with ``a = norm(x)``, queries ``norm_q(a W_qa)
+    W_qb`` and keys/values ``norm_kv((a W_kva)[:r]) W_kvb``, a head's
+    query/key being ``qk_nope_head_dim`` columns of those and
+    ``qk_rope_head_dim`` rotary columns — the key's rotary part the ONE
+    head ``(a W_kva)[r:]`` that every query head reads. k and v are made
+    whole per head (the absorbed form is for decoding and is not
+    built). Scopes: ``msa/qkv/{q_down,q_up,kv_down,kv_up}`` with their
+    inner norms, ``msa/rope``, ``msa/attn_core``, ``msa/out``. Returns
+    the output and the normed input, as :func:`_token_attention`."""
+    cfg = self.config
+    if self.tp_axis is not None:
+        raise ValueError("a token model has no manual tensor "
+                         "parallelism")
+    if train and cfg.attn_dropout > 0.0:
+        raise ValueError("latent attention has no attention dropout")
+    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    dense = functools.partial(
+        nn.DenseGeneral, use_bias=False, dtype=_dtype(cfg),
+        param_dtype=jnp.float32,
+        kernel_init=nn.initializers.normal(cfg.init_std))
+    y = _norm(cfg, "norm")(x)
+    with jax.named_scope("qkv"):
+        with jax.named_scope("q_down"):
+            c_q = _norm(cfg, "q_norm")(
+                dense(features=cfg.q_lora_rank, name="q_down")(y))
+        with jax.named_scope("kv_down"):
+            c = dense(features=rank + cfg.qk_rope_head_dim,
+                      name="kv_down")(y)
+            c_kv, k_rope = _norm(cfg, "kv_norm")(c[..., :rank]), c[..., rank:]
+        q = dense(features=(cfg.num_heads, cfg.head_dim), name="q_up")(c_q)
+        kv = dense(features=(cfg.num_heads, nope + cfg.v_head_dim),
+                   name="kv_up")(c_kv)
+    with jax.named_scope("rope"):
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta)], -1)
+        k_rope = rotary(k_rope[:, :, None, :], cfg.rope_theta)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_rope, kv.shape[:3] + k_rope.shape[3:])], -1)
+    kind, window = cfg.attention_kind(self.layer)
+    attn = dot_product_attention(
+        q, k, kv[..., nope:], impl=cfg.attention_impl, kind=kind,
+        window=window, deterministic=True, softmax=cfg.attention_softmax)
+    out = dense(features=cfg.embedding_dim, axis=(-2, -1), name="out")(attn)
+    return out, y
+
+
+# What a latent-attention block keeps of its attention for the backward
+# pass: the core's output and row statistic (named where they are made,
+# ``ops/attention.py`` / ``ops/flash_attention.py``), and not q, k and v
+# — three ``[T, H x head]`` arrays a layer, where the latents they are
+# taken from again are a sixth of one. The projections before the core
+# are computed twice (2.6% of GLM-4.7-Flash's step FLOPs); the core is
+# not.
+_KEEP_OF_LATENT_ATTENTION = jax.checkpoint_policies.save_only_these_names(
+    "attn_core_out", "attn_core_lse")
 
 
 def _flat_projections(cfg: ViTConfig, qkv_shape, train: bool) -> bool:
@@ -475,14 +538,54 @@ class MLPBlock(nn.Module):
         return y + x if self.include_residual else y
 
 
+class _GatedMLP(nn.Module):
+    """``(act(u W_gate) * (u W_up)) W_down``, bias-free, ``act`` the
+    configuration's ``expert_activation``: a token model's dense
+    feed-forward and the shared expert of a routed one (plain XLA GEMMs
+    over every token)."""
+
+    config: ViTConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, u: jax.Array) -> jax.Array:
+        from ..ops import moe
+        cfg = self.config
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=_dtype(cfg),
+            param_dtype=jnp.float32,
+            kernel_init=nn.initializers.normal(cfg.init_std))
+        hidden = moe.gated(dense(self.width, name="gate")(u),
+                           dense(self.width, name="up")(u),
+                           cfg.expert_activation)
+        return dense(cfg.embedding_dim, name="down")(hidden)
+
+
+class GatedMLPBlock(nn.Module):
+    """A token model's dense feed-forward, residual included: ``x +
+    gated(norm(x))`` at ``dense_width`` (:class:`_GatedMLP`; the ViT's
+    :class:`MLPBlock` is the GELU MLP with biases and its kernel)."""
+
+    config: ViTConfig
+
+    @nn.compact
+    def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
+        cfg = self.config
+        return x + _GatedMLP(cfg, cfg.dense_width, name="dense")(
+            _norm(cfg, "norm")(x))
+
+
 class RoutedMLPBlock(nn.Module):
     """Routed feed-forward in the MLP's place, residual included: ``x +
-    sum over a token's held experts e of p_e (relu(u W_gate,e) * (u
-    W_up,e)) W_down,e`` with ``u = norm(x)``.
+    shared(u) + sum over a token's held experts e of p_e (act(u
+    W_gate,e) * (u W_up,e)) W_down,e`` with ``u = norm(x)``, ``act`` the
+    configuration's ``expert_activation`` and ``shared`` one gated
+    product of ``shared_experts`` widths over every token (absent at 0).
 
-    The router reads ``routed_from`` — the block's *pre-attention* normed
-    input — and routes over all ``num_experts``; this chip holds experts
-    ``expert_offset .. + experts_held`` and adds their part only
+    The router reads what ``router_input`` names — ``routed_from``, the
+    block's pre-attention normed input, or ``u`` itself — and routes
+    over all ``num_experts`` by ``router_scoring``; this chip holds
+    experts ``expert_offset .. + experts_held`` and adds their part only
     (:mod:`..ops.moe`). The counters of the routing are sown into the
     collection ``moe_stats`` (kept when the caller makes it mutable).
     """
@@ -498,6 +601,8 @@ class RoutedMLPBlock(nn.Module):
                       cfg.num_experts_held)
         init = nn.initializers.normal(cfg.init_std)
         u = _norm(cfg, "norm")(x)
+        if cfg.router_input == "block":
+            routed_from = u
         with jax.named_scope("moe_router"):
             # float32 at full precision: the selection is a comparison,
             # and 64 outputs cost nothing.
@@ -506,15 +611,35 @@ class RoutedMLPBlock(nn.Module):
                 param_dtype=jnp.float32, kernel_init=init,
                 precision=jax.lax.Precision.HIGHEST, name="router")(
                 routed_from.astype(jnp.float32))
-        ids, probs = moe.route(logits, cfg.experts_per_token)
+        if cfg.router_scoring == "sigmoid":
+            # Zero, and no gradient reaches it (it only moves the
+            # selection); the balance rule that would update it between
+            # steps is not built (ROADMAP R-A).
+            bias = self.param("router_bias", nn.initializers.zeros,
+                              (cfg.num_experts,), jnp.float32)
+            ids, probs = moe.route(logits, cfg.experts_per_token,
+                                   scoring="sigmoid", bias=bias,
+                                   scale=cfg.router_scale)
+            with jax.named_scope("moe_router"):
+                # the normaliser: the selected scores' sum, a token
+                self.sow("moe_stats", "score_sum", jnp.mean(jnp.sum(
+                    jnp.take_along_axis(jax.nn.sigmoid(logits), ids, -1),
+                    axis=-1)))
+        else:
+            ids, probs = moe.route(logits, cfg.experts_per_token)
         gate = self.param("gate", init, (held, d, f), jnp.float32)
         up = self.param("up", init, (held, d, f), jnp.float32)
         down = self.param("down", init, (held, f, d), jnp.float32)
         y, stats = moe.moe_experts(u, ids, probs, gate, up, down,
                                    expert_offset=cfg.expert_offset,
-                                   num_experts=logits.shape[-1])
+                                   num_experts=logits.shape[-1],
+                                   activation=cfg.expert_activation)
         for key, value in stats.items():
             self.sow("moe_stats", key, value)
+        if cfg.shared_experts:
+            with jax.named_scope("moe_shared"):
+                y = y + _GatedMLP(cfg, cfg.shared_experts * f,
+                                  name="shared")(u)
         return x + y
 
 
@@ -532,15 +657,19 @@ class TransformerEncoderBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
-        if self.config.num_experts:
-            attn, normed = MultiHeadSelfAttentionBlock(
-                self.config, tp_axis=self.tp_axis, layer=self.layer,
-                name="msa")(x, train, with_normed=True)
+        msa = MultiHeadSelfAttentionBlock
+        if self.config.kv_lora_rank:
+            msa = nn.remat(msa, static_argnums=(2, 3),
+                           policy=_KEEP_OF_LATENT_ATTENTION)
+        msa = msa(self.config, tp_axis=self.tp_axis, layer=self.layer,
+                  name="msa")
+        if self.config.layer_routed(self.layer):
+            attn, normed = msa(x, train, True)
             return RoutedMLPBlock(self.config, name="mlp")(
                 attn + x, normed, train)
-        x = MultiHeadSelfAttentionBlock(self.config, tp_axis=self.tp_axis,
-                                        layer=self.layer,
-                                        name="msa")(x, train) + x
+        x = msa(x, train, False) + x
+        if self.config.dense_layers:
+            return GatedMLPBlock(self.config, name="mlp")(x, train)
         # The MLP half's residual is OWNED by MLPBlock (one owner on
         # every impl/backend; unlocks the full-half-block kernel).
         return MLPBlock(self.config, tp_axis=self.tp_axis,
@@ -559,13 +688,18 @@ class ViTFeatureExtractor(nn.Module):
     config: ViTConfig
 
     @nn.compact
-    def __call__(self, images: jax.Array, train: bool = False) -> jax.Array:
+    def __call__(self, images: jax.Array, train: bool = False,
+                 next_tokens: Optional[jax.Array] = None):
+        """``next_tokens [B, T]`` (each position's next token) asks a
+        model with a multi-token-prediction module for that module's
+        hidden states too: ``(tokens, module's tokens)``."""
         cfg = self.config
         if cfg.vocab_size:
             # ``images`` are token ids [B, T]. The scope is the name the
             # device trace's table has for the input embedding.
+            embed = TokenEmbedding(cfg, name="token_embedding")
             with jax.named_scope("patch_embedding"):
-                x = TokenEmbedding(cfg, name="token_embedding")(images)
+                x = embed(images)
         else:
             x = PatchEmbedding(cfg, name="patch_embedding")(images, train)
         block = TransformerEncoderBlock
@@ -573,7 +707,55 @@ class ViTFeatureExtractor(nn.Module):
             block = nn.remat(block, static_argnums=(2,))
         for i in range(cfg.num_layers):
             x = block(cfg, layer=i, name=f"encoder_block_{i}")(x, train)
-        return _norm(cfg, "encoder_norm")(x)
+        tokens = _norm(cfg, "encoder_norm")(x)
+        # (initialising makes the module's parameters, asked for or not)
+        if not cfg.mtp_modules or (next_tokens is None
+                                   and not self.is_initializing()):
+            return tokens
+        with jax.named_scope("mtp"), jax.named_scope("patch_embedding"), \
+                jax.named_scope("mtp_merge"):
+            ahead = embed(images if next_tokens is None else next_tokens)
+        drafted = MTPModule(cfg, name="mtp")(x, ahead, train)
+        return tokens if next_tokens is None else (tokens, drafted)
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3, section 2.2): at
+    position i the hidden state of the last block, before the final
+    norm, and the shared embedding of token i + 1, each normed, side by
+    side through ``eh_proj`` (``[embedding ; hidden]``, 2D -> D); one
+    block of the last layer's kind with weights of its own (block
+    ``num_layers``); a final norm of its own. The caller puts the
+    model's head on the result, against token i + 2. Scopes:
+    ``mtp/patch_embedding/mtp_merge`` (the module's input embedding: the
+    lookup, ``norm_e``, ``norm_h``, ``eh_proj``),
+    ``mtp/encoder_block_<n>``, ``mtp/norm_s``."""
+
+    config: ViTConfig
+
+    @nn.compact
+    def __call__(self, hidden: jax.Array, ahead: jax.Array,
+                 train: bool = False) -> jax.Array:
+        """``hidden``: the last block's output; ``ahead``: the shared
+        embedding's rows of each position's next token."""
+        cfg = self.config
+        with jax.named_scope("patch_embedding"), \
+                jax.named_scope("mtp_merge"):
+            merged = jnp.concatenate(
+                [_norm(cfg, "norm_e")(ahead),
+                 _norm(cfg, "norm_h")(hidden)], axis=-1)
+            x = nn.Dense(cfg.embedding_dim, use_bias=False,
+                         dtype=_dtype(cfg), param_dtype=jnp.float32,
+                         kernel_init=nn.initializers.normal(cfg.init_std),
+                         name="eh_proj")(merged)
+        n = cfg.num_layers
+        block = TransformerEncoderBlock
+        if cfg.remat:
+            block = nn.remat(block, static_argnums=(2,))
+        x = block(cfg, layer=n, name=f"encoder_block_{n}")(x, train)
+        # (named for the row of the device trace's table it belongs to)
+        with jax.named_scope("encoder_norm"):
+            return _norm(cfg, "norm_s")(x)
 
 
 class ViT(nn.Module):
@@ -594,6 +776,8 @@ class ViT(nn.Module):
     def __call__(self, images: jax.Array, train: bool = False,
                  labels: Optional[jax.Array] = None):
         cfg = self.config
+        if cfg.mtp_modules and labels is not None:
+            return _two_term_loss(self, images, labels, train)
         tokens = ViTFeatureExtractor(cfg, name="backbone")(images, train)
         if cfg.vocab_size:
             return LMHead(cfg, name="head")(tokens, labels)
@@ -607,17 +791,48 @@ class ViT(nn.Module):
         return logits
 
 
+def _two_term_loss(self: ViT, images: jax.Array, labels: jax.Array,
+                   train: bool):
+    """(Called from :class:`ViT`'s compact ``__call__``.) The objective
+    of a model with a multi-token-prediction module: ``main +
+    mtp_loss_weight x module``, both through the ONE head matrix (its
+    gradient is the sum of both terms'). The module's target at position
+    i is ``labels[i + 1]`` (token i + 2); a sequence's last position has
+    none and is left out of the mean. Returns ``(objective, main's
+    positions predicted right)`` as a one-term model does, and sows the
+    terms into ``lm_stats``: ``main_loss``, ``mtp_loss``,
+    ``mtp_top1_share`` (the module's positions whose largest logit is
+    the target: how often a drafted token would be accepted)."""
+    cfg = self.config
+    tokens, drafted = ViTFeatureExtractor(cfg, name="backbone")(
+        images, train, next_tokens=labels)
+    head = LMHead(cfg, name="head")
+    main, right = head(tokens, labels)
+    further = jnp.roll(labels, -1, axis=1)    # (the last wraps: left out)
+    counted = jnp.broadcast_to(
+        jnp.arange(labels.shape[1]) < labels.shape[1] - 1, labels.shape)
+    with jax.named_scope("mtp"):
+        module, drafted_right = head(drafted, further, counted)
+    self.sow("lm_stats", "main_loss", main)
+    self.sow("lm_stats", "mtp_loss", module)
+    self.sow("lm_stats", "mtp_top1_share",
+             drafted_right / jnp.sum(counted))
+    return main + cfg.mtp_loss_weight * module, right
+
+
 class LMHead(nn.Module):
     """A token model's untied head: float32 logits ``[B, T, V]`` at every
-    position or, given ``labels [B, T]`` (each position's next token),
-    ``(mean cross entropy, positions predicted right)`` without the
-    logits ever being whole (:mod:`..ops.lm_loss`)."""
+    position or, given ``labels [B, T]`` (each position's target),
+    ``(mean cross entropy, positions predicted right)`` over the
+    positions ``counted`` (all of them by default) without the logits
+    ever being whole (:mod:`..ops.lm_loss`)."""
 
     config: ViTConfig
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
-                 labels: Optional[jax.Array] = None):
+                 labels: Optional[jax.Array] = None,
+                 counted: Optional[jax.Array] = None):
         cfg = self.config
         kernel = self.param("kernel", nn.initializers.normal(cfg.init_std),
                             (cfg.embedding_dim, cfg.vocab_size), jnp.float32)
@@ -627,7 +842,8 @@ class LMHead(nn.Module):
         from ..ops.lm_loss import head_cross_entropy
         return head_cross_entropy(
             tokens.reshape(-1, cfg.embedding_dim), kernel,
-            labels.reshape(-1))
+            labels.reshape(-1),
+            counted=None if counted is None else counted.reshape(-1))
 
 
 def apply_tail(cfg: ViTConfig, params, tokens: jax.Array) -> jax.Array:

@@ -77,6 +77,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -108,9 +109,12 @@ def _vmem_limit(*resident, scratch=0):
     """Scoped-VMEM limit for a call that keeps ``resident`` whole
     operands or results (bytes each, double-buffered) and ``scratch``
     bytes of its own beside its blocks: the compiler's default (16 MiB
-    on the v5e) stops at T ~ 8k of bf16 k and v at head size 128."""
+    on the v5e) stops at T ~ 8k of bf16 k and v at head size 128. The
+    cap is what the backward asks at T = 16,384 and head size 256 (k, v,
+    dk and dv slabs of 8 MiB twice each, two float32 slabs of 16 MiB:
+    the compiler counts 100.3 MiB of the v5e's 128)."""
     need = 2 * sum(resident) + scratch + 16 * _MIB
-    return int(min(max(need, 32 * _MIB), 100 * _MIB))
+    return int(min(max(need, 32 * _MIB), 112 * _MIB))
 
 
 def _slab_bytes(x, head_dim):
@@ -589,6 +593,11 @@ def _flash_fwd(q, k, v, seed, mask3, threshold, block_q, block_k,
                     scale=scale,
                     block_q=block_q, block_k=block_k, threshold=threshold,
                     interpret=interpret, structure=structure)
+    # Named, so that a caller which takes q, k and v again in the
+    # backward pass (``jax.checkpoint`` with these names kept: the latent
+    # attention of ``models/vit.py``) does not take the core again too.
+    out = checkpoint_name(out, "attn_core_out")
+    lse = checkpoint_name(lse, "attn_core_lse")
     return out, (q, k, v, seed, mask3, out, lse)
 
 

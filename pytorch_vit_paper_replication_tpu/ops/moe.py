@@ -4,7 +4,7 @@ the result that the experts held on this chip give.
 The layer is told which experts it holds (``expert_offset`` and how many
 the weights have). It routes over every expert, keeps the token-expert
 pairs whose expert is held, sorts them by expert, runs the three grouped
-matrix products of a ReLU-gated expert over the ragged groups, and sums
+matrix products of a gated expert over the ragged groups, and sums
 each token's rows weighted by its router probabilities. What an absent
 expert would add is left out — that is the exchange-free share of an
 expert-parallel layer, and nothing here stands in for the other chips.
@@ -79,13 +79,31 @@ _VMEM_LIMIT = 96 * 1024 * 1024
 _FROM_ZERO, _TILE_0_GOES_ON, _ALL_GO_ON = 0, 1, 2
 
 
-def route(router_logits: jax.Array, k: int):
-    """The ``k`` largest of each row's router logits and the softmax over
-    those ``k`` (taken after the selection, so the weights of a token sum
-    to 1): ``(ids [N, k] int32, probs [N, k] float32)``."""
+def route(router_logits: jax.Array, k: int, *, scoring: str = "softmax",
+          bias: jax.Array | None = None, scale: float = 1.0):
+    """``(ids [N, k] int32, probs [N, k] float32)``: the ``k`` experts of
+    each row and their weights.
+
+    ``"softmax"``: the ``k`` largest router logits and the softmax over
+    those ``k`` (taken after the selection, so the weights of a token
+    sum to 1). ``"sigmoid"``: scores ``s = sigmoid(logits)``; the ``k``
+    largest of ``s + bias`` (the correction bias moves the selection
+    only, so no gradient reaches it); weights ``scale * s_e / (sum of
+    the selected s + 1e-20)``."""
     with jax.named_scope("moe_router"):
-        vals, ids = jax.lax.top_k(router_logits.astype(jnp.float32), k)
-        return ids.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+        logits = router_logits.astype(jnp.float32)
+        if scoring == "softmax":
+            vals, ids = jax.lax.top_k(logits, k)
+            return ids.astype(jnp.int32), jax.nn.softmax(vals, axis=-1)
+        if scoring != "sigmoid":
+            raise ValueError(f"unknown router scoring {scoring!r}")
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(
+            scores if bias is None
+            else scores + jax.lax.stop_gradient(bias), k)
+        picked = jnp.take_along_axis(scores, ids, axis=-1)
+        return ids.astype(jnp.int32), scale * picked / (
+            jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
 
 
 # ------------------------------------------------------------------ kernels
@@ -387,16 +405,40 @@ def _in_passes(one_pass, plan, rows, tile, start, later=False):
 
 
 # ------------------------------------------------------------- expert layer
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
-def _experts(u, probs, w_gate, w_up, w_down, plans, tile, rows, interpret):
+def _silu_slope(x):
+    s = jax.nn.sigmoid(x)
+    return s * (1.0 + x * (1.0 - s))
+
+
+# The gate's activation of a gated feed-forward ``act(gate) * up``,
+# stated once: its value, and the gate's cotangent from the hidden
+# state's ``dh`` (float32), which the hand-written backward pass needs.
+ACTIVATIONS = {
+    "relu": (jax.nn.relu,
+             lambda gate, dh, up: jnp.where(gate > 0, dh * up, 0.0)),
+    "silu": (jax.nn.silu, lambda gate, dh, up: dh * up * _silu_slope(
+        gate.astype(jnp.float32))),
+}
+
+
+def gated(gate, up, activation: str):
+    """``act(gate) * up``: the hidden state of a gated feed-forward."""
+    return ACTIVATIONS[activation][0](gate) * up
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _experts(u, probs, w_gate, w_up, w_down, plans, tile, rows, interpret,
+             activation):
     return _experts_fwd(u, probs, w_gate, w_up, w_down, plans, tile, rows,
-                        interpret)[0]
+                        interpret, activation)[0]
 
 
-def _hidden(gate_up):
-    """``relu(gate) * up`` from the two halves of the first product."""
+def _hidden(gate_up, activation):
+    """``act(gate) * up`` from the two halves of the first product (the
+    gate sliced and activated before ``up`` is sliced: the order the
+    lowered step has had, kept so that its text stays what it was)."""
     f = gate_up.shape[-1] // 2
-    return jax.nn.relu(gate_up[:, :f]) * gate_up[:, f:]
+    return ACTIVATIONS[activation][0](gate_up[:, :f]) * gate_up[:, f:]
 
 
 def _weights_in(w_gate, w_up, w_down, dt):
@@ -423,7 +465,7 @@ def _chunks(plans, *arrays):
 
 
 def _experts_fwd(u, probs, w_gate, w_up, w_down, plans, tile, rows,
-                 interpret):
+                 interpret, activation):
     k = probs.shape[1]
     w_in, w_out = _weights_in(w_gate, w_up, w_down, u.dtype)
     ys = []
@@ -434,7 +476,8 @@ def _experts_fwd(u, probs, w_gate, w_up, w_down, plans, tile, rows,
                                     interpret=interpret)
             _, gate_up = _first_product(u_c, w_in, window, gmm)
             with jax.named_scope("moe_experts"):
-                out = gmm(_hidden(gate_up), w_out, transpose_rhs=False)
+                out = gmm(_hidden(gate_up, activation), w_out,
+                          transpose_rhs=False)
             with jax.named_scope("moe_combine"):
                 return (_sum_pairs(out, window["pair_row"], u_c.shape[0],
                                    k, probs_c, start=carried[0]),)
@@ -448,8 +491,9 @@ def _experts_fwd(u, probs, w_gate, w_up, w_down, plans, tile, rows,
     return jnp.concatenate(ys), (u, probs, w_gate, w_up, w_down, plans)
 
 
-def _experts_bwd(tile, rows, interpret, res, dy):
+def _experts_bwd(tile, rows, interpret, activation, res, dy):
     u, probs, w_gate, w_up, w_down, plans = res
+    act, through_act = ACTIVATIONS[activation]
     # Without the barrier the compiler sees the rows and the first
     # product below as the forward pass's (same operands), merges the
     # two, and keeps the forward's alive until here: every layer's
@@ -488,12 +532,12 @@ def _experts_bwd(tile, rows, interpret, res, dy):
                 # ``out`` is not needed again.
                 dyw = gmm(dy_rows, w_out, transpose_rhs=True).astype(f32)
                 gate, up = gate_up[:, :f], gate_up[:, f:].astype(f32)
-                h = jax.nn.relu(gate).astype(f32) * up
+                h = act(gate).astype(f32) * up
                 dp_row = jnp.sum(dyw * h, axis=-1)
                 dh = p_row * dyw
                 d_gate_up = jnp.concatenate(
-                    [jnp.where(gate > 0, dh * up, 0.0),
-                     dh * jax.nn.relu(gate).astype(f32)], axis=-1
+                    [through_act(gate, dh, up),
+                     dh * act(gate).astype(f32)], axis=-1
                 ).astype(dt)
                 dw_down = gmm_dw((p_row * h).astype(dt), dy_rows,
                                  acc=dw_down)
@@ -528,7 +572,8 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 def moe_experts(u: jax.Array, ids: jax.Array, probs: jax.Array,
                 w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
                 expert_offset: int = 0, num_experts: int | None = None,
-                tile: int | None = None, interpret=None):
+                activation: str = "relu", tile: int | None = None,
+                interpret=None):
     """The held experts' part of the routed feed-forward.
 
     ``u`` ``[B, T, D]`` (the normed block input), ``ids`` / ``probs``
@@ -537,7 +582,8 @@ def moe_experts(u: jax.Array, ids: jax.Array, probs: jax.Array,
     given), ``w_gate`` / ``w_up`` ``[E_held, D, F]``, ``w_down``
     ``[E_held, F, D]``: experts ``expert_offset .. + E_held``. Returns
     ``(y [B, T, D], stats)``: ``y = sum over a token's held experts e of
-    p_e (relu(u W_gate,e) * (u W_up,e)) W_down,e`` and ``stats`` with
+    p_e (act(u W_gate,e) * (u W_up,e)) W_down,e`` (``activation``: a key
+    of :data:`ACTIVATIONS`) and ``stats`` with
     ``counts`` (pairs on each held expert), ``kept`` (rows computed),
     ``routed`` (pairs whose expert is held) and ``passes`` (of each
     chunk of tokens: :func:`buffer_rows`), all int32; ``routed - kept``
@@ -569,7 +615,7 @@ def moe_experts(u: jax.Array, ids: jax.Array, probs: jax.Array,
             passes = jnp.stack([passes_of(plan["active"], rows, rows_tile)
                                 for plan in plans])
         y = _experts(u.reshape(n, d), probs.reshape(n, k), w_gate, w_up,
-                     w_down, plans, rows_tile, rows, interpret)
+                     w_down, plans, rows_tile, rows, interpret, activation)
         return (y.reshape(b, t, d), counts[None], kept[None], routed[None],
                 passes)
 

@@ -92,9 +92,18 @@ LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
 # benchmark's frozen copy has it): the named scopes of the routed
 # feed-forward inside ``mlp`` (``ops/moe.py``), the rotary embedding
 # inside ``msa``, the token embedding and the head with its loss
-# (``ops/lm_loss.py``). A ViT's paths match none of them.
+# (``ops/lm_loss.py``). First a multi-token-prediction module's rows
+# (its merge, its block whole, its norm and its pass through the head),
+# then the latent attention's two paths and the shared expert. A ViT's
+# paths match none of them.
 TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
                      for name, pat in (
+    ("mtp_merge", r"mtp/(?:.*/)?mtp_merge"),
+    ("mtp_block", r"mtp/(?:.*/)?encoder_block_\d+"),
+    ("mtp_head", r"mtp"),            # the norm, the second head + loss
+    ("mla_q", r"msa/qkv/q_(?:down|up)"),
+    ("mla_kv", r"msa/qkv/kv_(?:down|up)"),
+    ("moe_shared", r"mlp/(?:.*/)?moe_shared"),
     ("moe_router", r"mlp/moe_router"),
     ("moe_dispatch", r"mlp/(?:.*/)?moe_dispatch"),
     ("moe_experts", r"mlp/(?:.*/)?moe_experts"),

@@ -66,28 +66,45 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
     """Analytic forward FLOPs of one sequence of a token model
     (:class:`..configs.ViTConfig` with ``vocab_size`` > 0): visible
     query-key pairs only (causal, and the window where the layer has
-    one), the expected pairs on the experts HELD (``experts_per_token x
-    held / num_experts`` a token: routing is counted as uniform), the
-    router over all experts, the head over the vocabulary rows held.
-    The embedding is a lookup and the rotary embedding elementwise:
-    neither is counted."""
+    one); by each layer's kind the dense feed-forward, or the router
+    over all experts, the expected pairs on the experts HELD
+    (``experts_per_token x held / num_experts`` a token: routing is
+    counted as uniform) and the shared experts over every token; the
+    head over the vocabulary rows held. A multi-token-prediction module
+    is its merge (``2D -> D``), one more block of the last kind and the
+    head a second time. The embedding is a lookup and the rotary
+    embedding elementwise: neither is counted."""
     t = seq_len or cfg.max_seq_len
     d, dh, hq, hkv = cfg.embedding_dim, cfg.head_dim, cfg.num_heads, \
         cfg.kv_heads
+    gated = lambda tokens, width: 3 * 2 * tokens * d * width
     total = 0.0
-    for layer in range(cfg.num_layers):
+    for layer in range(cfg.num_layers + cfg.mtp_modules):
         _, window = cfg.attention_kind(layer)
-        total += 2 * t * d * (hq + 2 * hkv) * dh        # q, k, v
+        if cfg.kv_lora_rank:
+            # queries and keys/values down to their latents and up again
+            total += 2 * t * (d * cfg.q_lora_rank
+                              + cfg.q_lora_rank * hq * dh)
+            total += 2 * t * (
+                d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+                + cfg.kv_lora_rank * hq
+                * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+        else:
+            total += 2 * t * d * (hq + 2 * hkv) * dh        # q, k, v
         total += 2 * 2 * visible_pairs(t, window) * hq * dh   # QK^T, PV
         total += 2 * t * hq * dh * d                    # out projection
-        if cfg.num_experts:
+        if cfg.layer_routed(layer):
             total += 2 * t * d * cfg.num_experts        # router
             pairs = t * cfg.experts_per_token * cfg.num_experts_held \
                 / cfg.num_experts
-            total += 3 * 2 * pairs * d * cfg.expert_width   # gate, up, down
+            total += gated(pairs, cfg.expert_width)     # gate, up, down
+            total += gated(t, cfg.shared_experts * cfg.expert_width)
+        elif cfg.num_experts:
+            total += gated(t, cfg.dense_width)
         else:
             total += 2 * 2 * t * d * cfg.mlp_size
-    return total + 2 * t * d * cfg.vocab_size           # head
+    total += cfg.mtp_modules * 2 * t * 2 * d * d        # [emb ; hidden]
+    return total + (1 + cfg.mtp_modules) * 2 * t * d * cfg.vocab_size
 
 
 def train_step_flops_per_sequence(cfg, seq_len: Optional[int] = None

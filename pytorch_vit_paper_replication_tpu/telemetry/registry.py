@@ -68,6 +68,11 @@ INSTRUMENTS: Dict[str, str] = {
     "tel_moe_dropped_pairs_total": "counter",
     "tel_moe_passes_max": "gauge",
     "tel_moe_one_pass_share": "gauge",
+    "tel_moe_score_sum_mean": "gauge",   # a sigmoid router's normaliser
+    # a model with a multi-token-prediction module: its objective's terms
+    "tel_main_loss": "gauge",
+    "tel_mtp_loss": "gauge",
+    "tel_mtp_top1_share": "gauge",
     "tel_goodput_pct": "gauge",         # step-exec share of wall time
     "tel_data_wait_frac": "gauge",      # data-wait share of wall time
     "tel_steps_total": "counter",
@@ -291,6 +296,15 @@ HELP_TEXT: Dict[str, str] = {
         "last sampled step",
     "tel_moe_one_pass_share":
         "Share of the routed layers' token chunks served in one pass",
+    "tel_moe_score_sum_mean":
+        "Sum of a token's selected sigmoid router scores before they are "
+        "normalised, mean over tokens and routed blocks",
+    "tel_main_loss": "Next-token loss of the main head, last sampled step",
+    "tel_mtp_loss":
+        "Loss of the multi-token-prediction module, last sampled step",
+    "tel_mtp_top1_share":
+        "Share of the module's positions whose largest logit is the "
+        "target (how often a drafted token would be accepted)",
     "tel_goodput_pct": "Step-exec share of epoch wall time, percent",
     "tel_data_wait_frac": "Data-wait share of epoch wall time",
     "tel_steps_total": "Train steps recorded",
