@@ -47,6 +47,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..configs import ViTConfig
 from ..ops import partition
@@ -294,44 +295,187 @@ def _latent_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     ``qk_rope_head_dim`` rotary columns — the key's rotary part the ONE
     head ``(a W_kva)[r:]`` that every query head reads. k and v are made
     whole per head (the absorbed form is for decoding and is not
-    built). Scopes: ``msa/qkv/{q_down,q_up,kv_down,kv_up}`` with their
-    inner norms, ``msa/rope``, ``msa/attn_core``, ``msa/out``. Returns
-    the output and the normed input, as :func:`_token_attention`."""
+    built), and every array between the latent products and the flash
+    kernels lies as the kernels read it, a head a column block of
+    ``[B, T, H x Dh]``: the up-projections and ``out`` are flat products
+    (:class:`_FlatProduct`), the query's rotary part is turned in place
+    (:func:`_flat_rotary`), the key is assembled by its own product
+    (:class:`_LatentKeyValueUp`). As ``[B, T, H, Dh]`` arrays XLA:TPU
+    lays the heads on sublanes and copies each for the kernels: ten
+    passes over HBM a block (PERF.md, PR 33). Scopes:
+    ``msa/qkv/{q_down,q_up,kv_down,kv_up}`` with their inner norms,
+    ``msa/rope``, ``msa/attn_core``, ``msa/out``. Returns the output and
+    the normed input, as :func:`_token_attention`."""
     cfg = self.config
     if self.tp_axis is not None:
         raise ValueError("a token model has no manual tensor "
                          "parallelism")
     if train and cfg.attn_dropout > 0.0:
         raise ValueError("latent attention has no attention dropout")
-    rank, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    rank, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    heads, dh = cfg.num_heads, cfg.head_dim
     dense = functools.partial(
         nn.DenseGeneral, use_bias=False, dtype=_dtype(cfg),
         param_dtype=jnp.float32,
         kernel_init=nn.initializers.normal(cfg.init_std))
+    flat = functools.partial(_FlatProduct, init_std=cfg.init_std,
+                             dtype=_dtype(cfg))
     y = _norm(cfg, "norm")(x)
     with jax.named_scope("qkv"):
         with jax.named_scope("q_down"):
             c_q = _norm(cfg, "q_norm")(
                 dense(features=cfg.q_lora_rank, name="q_down")(y))
         with jax.named_scope("kv_down"):
-            c = dense(features=rank + cfg.qk_rope_head_dim,
-                      name="kv_down")(y)
+            c = dense(features=rank + rope, name="kv_down")(y)
             c_kv, k_rope = _norm(cfg, "kv_norm")(c[..., :rank]), c[..., rank:]
-        q = dense(features=(cfg.num_heads, cfg.head_dim), name="q_up")(c_q)
-        kv = dense(features=(cfg.num_heads, nope + cfg.v_head_dim),
-                   name="kv_up")(c_kv)
+        q = flat((cfg.q_lora_rank, heads, dh), name="q_up")(c_q)
     with jax.named_scope("rope"):
-        q = jnp.concatenate(
-            [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta)], -1)
-        k_rope = rotary(k_rope[:, :, None, :], cfg.rope_theta)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-            k_rope, kv.shape[:3] + k_rope.shape[3:])], -1)
+        q = _flat_rotary(q, heads, rope, cfg.rope_theta)
+        k_rope = _flat_rotary(k_rope, 1, rope, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        k, v = _LatentKeyValueUp(cfg, name="kv_up")(c_kv, k_rope)
     kind, window = cfg.attention_kind(self.layer)
+    # (the dispatch takes [B, T, H, Dh]; the flash path's own reshape to
+    # [B, T, H x Dh] meets these and the pair cancels in the compiler)
     attn = dot_product_attention(
-        q, k, kv[..., nope:], impl=cfg.attention_impl, kind=kind,
-        window=window, deterministic=True, softmax=cfg.attention_softmax)
-    out = dense(features=cfg.embedding_dim, axis=(-2, -1), name="out")(attn)
+        *(a.reshape(a.shape[:2] + (heads, -1)) for a in (q, k, v)),
+        impl=cfg.attention_impl, kind=kind, window=window,
+        deterministic=True, softmax=cfg.attention_softmax)
+    out = flat((heads, cfg.v_head_dim, cfg.embedding_dim), n_in=2,
+               name="out")(attn.reshape(attn.shape[:2] + (-1,)))
     return out, y
+
+
+def _turn_heads(x, heads, rope, theta, sign):
+    """:func:`_flat_rotary`'s pass, by ``sign`` times the angle."""
+    b, t, width = x.shape
+    half = rope // 2
+    freq = theta ** (-jnp.arange(0, rope, 2, dtype=jnp.float32) / rope)
+    angle = freq[:, None] * jnp.arange(t, dtype=jnp.float32)[None, :]
+    cos, sin = jnp.cos(angle), sign * jnp.sin(angle)        # [half, T]
+    # tokens on the lanes: a head's columns are rows, ``half`` a group,
+    # and the turned ones its last two groups
+    groups = width // heads // half
+    xt = jnp.swapaxes(x, 1, 2).reshape(b, heads, groups, half, t)
+    x1, x2 = (xt[:, :, g].astype(jnp.float32)
+              for g in (groups - 2, groups - 1))
+    # ``rotary``'s ``x * cos + [-x2, x1] * sin``, half by half
+    y = jnp.stack([x1 * cos + (-x2) * sin, x2 * cos + x1 * sin], axis=2)
+    xt = jax.lax.dynamic_update_slice_in_dim(
+        xt, y.astype(x.dtype), groups - 2, axis=2)
+    return jnp.swapaxes(xt.reshape(b, width, t), 1, 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _flat_rotary(x: jax.Array, heads: int, rope: int,
+                 theta: float) -> jax.Array:
+    """:func:`rotary` of the last ``rope`` columns of every head of
+    ``x [B, T, heads x Dh]`` (a head's other columns stay), with that
+    function's arithmetic (float32, rounded once): bit-equal to
+    ``concatenate([x[..., :nope], rotary(x[..., nope:])])`` on
+    ``[B, T, heads, Dh]``, without that array.
+
+    Taken with the tokens on the lanes, ``[B, heads x Dh, T]``: there the
+    table is ``[rope / 2, T]`` and broadcasts over the heads as over any
+    major dimension, a column's partner is ``rope / 2`` ROWS away, and
+    the turned rows are updated where they lie. The product before it
+    writes that layout at no cost, and one transposing pass brings the
+    result to the kernels' (in the backward pass the cotangent takes the
+    same way back). What was tried on ``[T, heads x Dh]`` itself: one
+    elementwise pass with tables of period ``Dh`` has the compiler write
+    those tables out at full size, 320 MiB each, and a partner 32 lanes
+    away as shifted copies; head by head on ``[T, 64]`` windows it is 40
+    small ops a pass, each moving four times its bytes in lane padding
+    (PERF.md, PR 33). The transpose is the turn by the opposite angle:
+    the same pass over the cotangent."""
+    return _turn_heads(x, heads, rope, theta, 1.0)
+
+
+_flat_rotary.defvjp(
+    lambda x, *static: (_turn_heads(x, *static, 1.0), None),
+    lambda heads, rope, theta, _, g: (
+        _turn_heads(g, heads, rope, theta, -1.0),))
+
+
+def _drawn_flat(initializer, rows: int):
+    """``nn.DenseGeneral``'s way with a kernel's initialiser: drawn at
+    the flat shape ``[rows, columns]`` (fan-in and fan-out are the
+    GEMM's), then given the parameter's."""
+    def init(rng, shape, dtype=jnp.float32):
+        return initializer(
+            rng, (rows, int(np.prod(shape)) // rows), dtype).reshape(shape)
+    return init
+
+
+class _FlatProduct(nn.Module):
+    """A bias-free ``nn.DenseGeneral`` by its parameter (``kernel`` of
+    ``shape``: names, shapes and initial values of the tree are that
+    module's) whose product is taken flat and LEFT flat: ``[...,
+    prod(in)] x [prod(in), prod(out)]``, the first ``n_in`` dims of
+    ``shape`` contracted. Latent attention's heads are made and read
+    this way, as column blocks of ``[B, T, H x Dh]``, which is how the
+    flash kernels read them (:class:`_FlatDenseGeneral` has the why)."""
+
+    shape: Tuple[int, ...]
+    init_std: float
+    dtype: jnp.dtype
+    n_in: int = 1
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        rows = int(np.prod(self.shape[:self.n_in]))
+        kernel = self.param("kernel", _drawn_flat(
+            nn.initializers.normal(self.init_std), rows),
+                            self.shape, jnp.float32)
+        # Two things the compiler is told, both read from the step
+        # compiled for the v5e (PERF.md, PR 33). Without the barrier it
+        # folds the reshape into the weight-gradient product, whose other
+        # operand is then the activation as [H, Dh, T]: a transposed copy
+        # of 160 MiB a product. Without the layout it relays the float32
+        # parameter for the reshape, and with it its moments and update
+        # (270 MiB alive at the step's peak), where the converted kernel
+        # is 8 MB.
+        kernel = with_layout_constraint(
+            kernel.astype(self.dtype),
+            Layout(major_to_minor=tuple(range(kernel.ndim))))
+        return jnp.dot(x.astype(self.dtype), jax.lax.optimization_barrier(
+            kernel.reshape(rows, -1)))
+
+
+class _LatentKeyValueUp(nn.Module):
+    """``kv_up``: ``nn.DenseGeneral``'s kernel ``[rank, H, nope + v]``,
+    and from it k and v as two flat products, each whole where the flash
+    kernels read it. k's product places the ONE rotary head under every
+    head's own columns itself: ``k_rope`` stands beside the latent, and
+    under the kernel's k columns (zeros where the rotary part goes) stand
+    rows of 0 / 1 that copy it to each head. ``k_nope + 0`` and ``0 +
+    k_rope x 1`` are exact, so k is bit-equal to the concatenation; the
+    rotary head's gradient, the sum over the heads, is the same product
+    transposed."""
+
+    config: ViTConfig
+
+    @nn.compact
+    def __call__(self, c_kv: jax.Array, k_rope: jax.Array):
+        cfg, dt = self.config, _dtype(self.config)
+        rank, nope, rope = (cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+                            cfg.qk_rope_head_dim)
+        kernel = self.param(
+            "kernel",
+            _drawn_flat(nn.initializers.normal(cfg.init_std), rank),
+            (rank, cfg.num_heads, nope + cfg.v_head_dim),
+            jnp.float32).astype(dt)
+        place = jnp.pad(jnp.eye(rope, dtype=dt), ((0, 0), (nope, 0)))
+        # (the barriers: as in _FlatProduct)
+        k = jnp.dot(
+            jnp.concatenate([c_kv, k_rope], axis=-1).astype(dt),
+            jax.lax.optimization_barrier(jnp.concatenate([
+                jnp.pad(kernel[..., :nope], ((0, 0), (0, 0), (0, rope))
+                        ).reshape(rank, -1),
+                jnp.tile(place, (1, cfg.num_heads))])))
+        v = jnp.dot(c_kv.astype(dt), jax.lax.optimization_barrier(
+            kernel[..., nope:].reshape(rank, -1)))
+        return k, v
 
 
 # What a latent-attention block keeps of its attention for the backward
@@ -389,14 +533,9 @@ class _FlatDenseGeneral(nn.Module):
         lead, in_shape = x.shape[:x.ndim - n_in], x.shape[x.ndim - n_in:]
         flat = (int(np.prod(in_shape)), int(np.prod(out_shape)))
 
-        def kernel_init(rng, shape, dtype=jnp.float32):
-            # nn.DenseGeneral's: initialised flat, so that fan-in and
-            # fan-out are the GEMM's
-            return nn.initializers.lecun_normal()(
-                rng, flat, dtype).reshape(shape)
-
-        kernel = self.param("kernel", kernel_init, in_shape + out_shape,
-                            jnp.float32)
+        kernel = self.param(
+            "kernel", _drawn_flat(nn.initializers.lecun_normal(), flat[0]),
+            in_shape + out_shape, jnp.float32)
         bias = self.param("bias", nn.initializers.zeros, out_shape,
                           jnp.float32)
         y = jnp.dot(x.reshape(lead + flat[:1]).astype(self.dtype),

@@ -123,6 +123,130 @@ def test_the_block_keeps_the_cores_output_and_not_its_heads():
     assert "checkpoint" not in text and text.count("name=flash_fwd") == 1
 
 
+# ------------------------------------------- q, k, v on the flat layout
+def _four_d(cfg, c_q, c_kv, k_rope, wq, wkv):
+    """q, k, v as the block made them until PR 33: ``[B, T, H, Dh]``
+    products, :func:`rotary` on a slice, two concatenations and the
+    broadcast of the shared key head."""
+    import flax.linen as nn
+
+    nope, dt = cfg.qk_nope_head_dim, c_q.dtype
+    dense = lambda w, x: nn.DenseGeneral(
+        features=w.shape[1:], use_bias=False, dtype=dt).apply(
+            {"params": {"kernel": w}}, x)
+    q, kv = dense(wq, c_q), dense(wkv, c_kv)
+    q = jnp.concatenate([q[..., :nope], vit_module.rotary(
+        q[..., nope:], cfg.rope_theta)], -1)
+    k_rope = vit_module.rotary(k_rope[:, :, None, :], cfg.rope_theta)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_rope, kv.shape[:3] + k_rope.shape[3:])], -1)
+    return {"q": q, "k": k, "v": kv[..., nope:]}
+
+
+def _flat(cfg, c_q, c_kv, k_rope, wq, wkv):
+    """The same three on ``[B, T, H x Dh]``, by the block's own pieces."""
+    dt = c_q.dtype
+    q = vit_module._FlatProduct(wq.shape, cfg.init_std, dt).apply(
+        {"params": {"kernel": wq}}, c_q)
+    q = vit_module._flat_rotary(q, cfg.num_heads, cfg.qk_rope_head_dim,
+                                cfg.rope_theta)
+    k_rope = vit_module._flat_rotary(k_rope, 1, cfg.qk_rope_head_dim,
+                                     cfg.rope_theta)
+    k, v = vit_module._LatentKeyValueUp(cfg.replace(dtype=dt.name)).apply(
+        {"params": {"kernel": wkv}}, c_kv, k_rope)
+    return {"q": q, "k": k, "v": v}
+
+
+def _latents(cfg, dtype, seed=5):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    shapes = ((2, 24, cfg.q_lora_rank), (2, 24, cfg.kv_lora_rank),
+              (2, 24, cfg.qk_rope_head_dim),
+              (cfg.q_lora_rank, cfg.num_heads, cfg.head_dim),
+              (cfg.kv_lora_rank, cfg.num_heads,
+               cfg.qk_nope_head_dim + cfg.v_head_dim))
+    acts = [jax.random.normal(k, s).astype(dtype)
+            for k, s in zip(ks[:3], shapes[:3])]
+    return acts + [jax.random.normal(k, s) * 0.3      # float32 parameters
+                   for k, s in zip(ks[3:], shapes[3:])]
+
+
+@pytest.mark.parametrize("which", ["q", "k", "v"])
+def test_flat_q_k_v_equal_the_four_dimensional_ones(which):
+    """What the block hands the flash kernels, made on the flat layout
+    (a flat product, the rotary part turned in place with the tokens on
+    the lanes, the one rotary key head placed by k's own product): on
+    bfloat16 latents BIT-equal to the 4-D formulation it replaces, and
+    in float32 every gradient - the latents, the shared rotary key head,
+    both up-projection kernels - equal to 1e-6 of its size."""
+    cfg = _tiny()
+    args = _latents(cfg, jnp.bfloat16)
+    want = _four_d(cfg, *args)[which]
+    got = _flat(cfg, *args)[which]
+    assert got.dtype == want.dtype == jnp.bfloat16
+    assert got.shape == want.shape[:2] + (cfg.num_heads * want.shape[-1],)
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32)).reshape(want.shape),
+        np.asarray(want.astype(jnp.float32)))
+    # the turned columns are turned: k and q differ from the plain product
+    if which != "v":
+        plain = _four_d(cfg.replace(rope_theta=1e30), *args)[which]
+        assert not np.array_equal(np.asarray(plain, np.float32),
+                                  np.asarray(want, np.float32))
+    args = _latents(cfg, jnp.float32)
+    cot = jax.random.normal(jax.random.key(3), want.shape)
+    loss = lambda f: lambda *a: jnp.sum(
+        f(cfg, *a)[which].reshape(cot.shape) * cot)
+    g = jax.grad(loss(_flat), range(5))(*args)
+    w = jax.grad(loss(_four_d), range(5))(*args)
+    used = {"q": (0, 3), "k": (1, 2, 4), "v": (1, 4)}[which]
+    for i, name in enumerate(("c_q", "c_kv", "k_rope", "q_up", "kv_up")):
+        scale = float(jnp.abs(w[i]).max())
+        assert (scale > 0) == (i in used), name
+        np.testing.assert_allclose(g[i], w[i], rtol=0, atol=1e-6 * scale,
+                                   err_msg=name)
+
+
+def test_flat_products_keep_dense_generals_parameters():
+    """``q_up``, ``kv_up`` and ``out`` are ``nn.DenseGeneral``'s
+    parameters - name, shape, and the values its flat-shape
+    initialisation draws from the same key - so the tree, its count and
+    a checkpoint's leaves are what they were."""
+    import flax.linen as nn
+
+    cfg = _tiny()
+    x = jnp.zeros((1, 8, cfg.q_lora_rank))
+    shape = (cfg.q_lora_rank, cfg.num_heads, cfg.head_dim)
+    ours = vit_module._FlatProduct(shape, cfg.init_std, jnp.float32).init(
+        jax.random.key(4), x)["params"]
+    theirs = nn.DenseGeneral(
+        features=shape[1:], use_bias=False,
+        kernel_init=nn.initializers.normal(cfg.init_std)).init(
+            jax.random.key(4), x)["params"]
+    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
+    np.testing.assert_array_equal(ours["kernel"], theirs["kernel"])
+    # contracted over two leading dims, as ``out`` is
+    o = jnp.zeros((1, 8, cfg.num_heads * cfg.v_head_dim))
+    shape = (cfg.num_heads, cfg.v_head_dim, cfg.embedding_dim)
+    ours = vit_module._FlatProduct(shape, cfg.init_std, jnp.float32,
+                                   n_in=2).init(jax.random.key(4), o)
+    theirs = nn.DenseGeneral(
+        features=shape[2], axis=(-2, -1), use_bias=False,
+        kernel_init=nn.initializers.normal(cfg.init_std)).init(
+            jax.random.key(4), o.reshape(1, 8, *shape[:2]))
+    np.testing.assert_array_equal(ours["params"]["kernel"],
+                                  theirs["params"]["kernel"])
+    kv = vit_module._LatentKeyValueUp(cfg).init(
+        jax.random.key(4), jnp.zeros((1, 8, cfg.kv_lora_rank)),
+        jnp.zeros((1, 8, cfg.qk_rope_head_dim)))["params"]
+    shape = (cfg.kv_lora_rank, cfg.num_heads,
+             cfg.qk_nope_head_dim + cfg.v_head_dim)
+    theirs = nn.DenseGeneral(
+        features=shape[1:], use_bias=False,
+        kernel_init=nn.initializers.normal(cfg.init_std)).init(
+            jax.random.key(4), jnp.zeros((1, 8, shape[0])))["params"]
+    np.testing.assert_array_equal(kv["kernel"], theirs["kernel"])
+
+
 # ----------------------------------------------------- flash at head 256
 def _dense_causal(q, k, v):
     t, d = q.shape[1], q.shape[-1]

@@ -86,17 +86,24 @@ def dp4_step(v5e_2x2):
         return lowered.as_text(), lowered.compile().as_text()
 
 
-def _copies_beside_the_core(hlo, batch):
-    """Instructions of the entry computation that copy a ``[batch, 197,
-    ...]`` array under the scope ``attn_core``: a layout change between
-    a projection and the kernel that is a pass over HBM of its own (one
+def _entry(hlo):
+    """The entry computation's instructions (an op fused into another is
+    inside that fusion's computation, not here)."""
+    return hlo[hlo.index("\nENTRY "):].splitlines()
+
+
+def _copies_beside_the_core(hlo, batch, tokens=197, width=None):
+    """Instructions of the entry computation that copy a ``[batch,
+    tokens, ...]`` array (``[batch, tokens, width]`` where a width is
+    given) under the scope ``attn_core``: a layout change between a
+    projection and the kernel that is a pass over HBM of its own (one
     fused into a GEMM's operand is inside that fusion's computation,
     not here)."""
-    entry = hlo[hlo.index("\nENTRY "):]
-    return [line for line in entry.splitlines()
+    shape = r"= \w+\[%d,%d," % (batch, tokens) + (
+        "" if width is None else r"%d\]" % width)
+    return [line for line in _entry(hlo)
             if re.search(r" (copy|transpose)\(", line)
-            and "/attn_core/" in line
-            and re.search(r"= \w+\[%d,197," % batch, line)]
+            and "/attn_core/" in line and re.search(shape, line)]
 
 
 def test_dp4_train_step_compiles_with_per_shard_mosaic_calls(dp4_step):
@@ -397,8 +404,22 @@ def test_flash_kernels_compile_at_head_size_256(v5e_2x2):
     assert not re.search(r"bf16\[20,16384,256\]", hlo)
 
 
-def test_latent_attention_models_step_compiles_and_fits_one_chip(
-        v5e_2x2, monkeypatch):
+@pytest.fixture(scope="module")
+def mla_step(v5e_2x2):
+    """The cell ``glm47f_train_16k``'s step as the trainer builds it,
+    lowered and compiled once for the file: the lowered step and the
+    compiled one."""
+    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = LM_PRESETS["glm-4.7-flash-ep8"]()
+        lowered = _lower_lm_step(v5e_2x2[:1], cfg, dp=1, batch=1,
+                                 seq_len=cfg.max_seq_len)
+        return lowered, lowered.compile()
+
+
+def test_latent_attention_models_step_compiles_and_fits_one_chip(mla_step):
     """The cell ``glm47f_train_16k``'s step, as the trainer builds it: one
     16,384-token sequence through GLM-4.7-Flash's leading dense layer, 4
     routed layers and the multi-token-prediction module at every
@@ -409,17 +430,11 @@ def test_latent_attention_models_step_compiles_and_fits_one_chip(
     for the v5e and fits 15.75 GiB with that one recomputation and no
     other; every new scope is in the compiled step, and next to nothing
     falls outside the table's rows."""
-    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = LM_PRESETS["glm-4.7-flash-ep8"]()
-    lowered = _lower_lm_step(v5e_2x2[:1], cfg, dp=1, batch=1,
-                             seq_len=cfg.max_seq_len)
+    lowered, compiled = mla_step
     names = [name for name, _ in mosaic_calls(lowered.as_text())]
     assert {n: names.count(n) for n in set(names)} == {
         "flash_fwd": 6, "flash_bwd": 6,
         "moe_gmm_fwd": 30, "moe_gmm_dx": 20, "moe_gmm_dw": 20}
-    compiled = lowered.compile()
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes
             + m.output_size_in_bytes - m.alias_size_in_bytes)
@@ -439,3 +454,27 @@ def test_latent_attention_models_step_compiles_and_fits_one_chip(
                   "/mtp/head/head/", "/mtp/head/loss/"):
         assert any(scope in path for path in paths), scope
     assert layers.count("other") < 0.01 * len(layers)
+
+
+def test_latent_attention_hands_the_kernels_q_k_v_where_they_read_them(
+        mla_step):
+    """q, k, v and ``o`` lie as column blocks of ``[1, 16384, 20 x 256]``
+    from the latent products to the flash kernels and back, forward,
+    recomputed and as cotangents: the compiled step's entry computation
+    copies no such array under ``attn_core`` (PR 32's step held 60: q, k,
+    v before the forward call and ``o`` after it, q, k, v again in the
+    recomputation, dq, dk, dv after the backward call, six blocks), and
+    nothing under ``msa`` results in a ``[1, 16384, 20, 256]`` or
+    ``[1, 16384, 20, 448]`` array, whose heads XLA:TPU lays on sublanes
+    (PR 32's held 78: the 4-D products, the rotary concatenations, the
+    slices)."""
+    _, compiled = mla_step
+    hlo = compiled.as_text()
+    copies = _copies_beside_the_core(hlo, 1, 16384, 5120)
+    assert not copies, (len(copies), copies[0][:300])
+    four_d = [line for line in _entry(hlo) if "/msa/" in line and re.search(
+        r"= \(?\w+\[1,16384,20,(?:256|448)\]", line)]
+    assert not four_d, (len(four_d), four_d[0][:300])
+    # the kernels themselves read and write the flat layout
+    assert re.search(r"= \(bf16\[1,16384,5120\]\S*, f32\[20,1,16384\]\S*\) "
+                     r"custom-call\(", hlo)
