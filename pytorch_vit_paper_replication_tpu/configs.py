@@ -163,6 +163,24 @@ class ViTConfig:
     # is main + ``mtp_loss_weight`` x the modules' mean.
     mtp_modules: int = 0
     mtp_loss_weight: float = 0.3
+    # Sparse attention chosen by an indexer (``sa_topk`` > 0; DeepSeek
+    # sparse attention): every query attends to the ``sa_topk`` causal
+    # keys (all of them while it has no more) that a learned indexer
+    # scores highest: ``sa_index_heads`` query heads of
+    # ``sa_index_head_dim`` over ONE shared key head, read off the
+    # block's normed input with the gradient cut. The indexer learns
+    # from its own alignment loss alone (the KL from the main
+    # attention's head-mean probabilities over the selected keys), added
+    # to the objective at weight 1 a layer. ``sa_chunk``
+    # query rows are scored at a time (the ``[T, T]`` scores are never
+    # whole); it changes no result.
+    sa_topk: int = 0
+    sa_index_heads: int = 0
+    sa_index_head_dim: int = 0
+    sa_chunk: int = 512
+    # RMSNorm with a learned scale over the columns of each query and
+    # key head, before the rotary embedding (token models).
+    qk_norm: bool = False
     # Std of the normal initialiser of a token model's matrices (its
     # embedding rows start at N(0, 1): ``models/vit.py::TokenEmbedding``).
     init_std: float = 0.02
@@ -218,6 +236,16 @@ class ViTConfig:
                 "head counts, no head_dim_override (the head size is "
                 "qk_nope_head_dim + qk_rope_head_dim) and v_head_dim equal "
                 "to it (one head size for the attention core)")
+        if self.sa_topk and not (
+                self.vocab_size and not self.kv_lora_rank
+                and not any(self.sliding_window_layout)
+                and self.sa_index_heads > 0 and self.sa_chunk > 0
+                and self.sa_index_head_dim > 0
+                and self.sa_index_head_dim % 2 == 0):
+            raise ValueError(
+                "sparse attention: a token model without latent attention "
+                "or windows, with sa_index_heads of an even "
+                "sa_index_head_dim and sa_chunk > 0")
         if self.mtp_modules not in (0, 1) or (
                 self.mtp_modules and not self.vocab_size):
             raise ValueError("mtp_modules: 0, or 1 on a token model")
@@ -277,9 +305,12 @@ class ViTConfig:
 
     def attention_kind(self, layer: int):
         """Block ``layer``'s attention as structure: ``("full", 0)``
-        bidirectional, ``("causal", 0)`` or ``("causal_window", w)``."""
+        bidirectional, ``("causal", 0)``, ``("causal_window", w)`` or
+        ``("causal_topk", k)``: the k causal keys an indexer selects."""
         if not self.vocab_size:
             return ("full", 0)
+        if self.sa_topk:
+            return ("causal_topk", self.sa_topk)
         lay = self.sliding_window_layout
         if lay and lay[layer % len(lay)]:
             return ("causal_window", self.sliding_window)
@@ -403,6 +434,46 @@ def mla_tiny(**kw) -> ViTConfig:
         expert_width=32, experts_held=4, dense_width=96), **kw})
 
 
+def keye_vl_20_30b_a3b_ep8(**kw) -> ViTConfig:
+    """Keye-VL-2.0-30B-A3B's language model (huggingface.co/Kwai-Keye,
+    ``KeyeVL2``), one chip's share of a deployment in which 8 chips share
+    each layer: every published width (2048; 32 query / 4 key-value heads
+    of 128 with per-head q / k norms, theta 1e7; the sparse-attention
+    indexer of 16 heads of 64 over one key head selecting 2,048 keys a
+    query, scored 512 query rows at a time; experts of 768, 8 of 128 by
+    softmax over the selected, none shared), 6 of its 48 layers (every
+    one routed), experts 0-15 of 128 and rows 0-18,991 of the
+    151,936-row vocabulary, at 16,384 of its 262,144 positions. The
+    vision tower is not built: on text the three ``mrope`` position
+    streams are equal and the rotary embedding is the 1-D one. What is
+    assumed of the source is in
+    ``benchmark/configs/keye-vl-2.0-30b-a3b-ep8.json``."""
+    base = dict(
+        vocab_size=18992, max_seq_len=16384, num_layers=6, num_heads=32,
+        num_kv_heads=4, head_dim_override=128, embedding_dim=2048,
+        norm="rmsnorm", ln_epsilon=1e-6, attn_bias=False, qk_norm=True,
+        rope_layout=(1,), rope_theta=1e7, sa_topk=2048, sa_index_heads=16,
+        sa_index_head_dim=64, sa_chunk=512,
+        num_experts=128, experts_per_token=8, expert_width=768,
+        experts_held=16, expert_offset=0, router_input="block",
+        expert_activation="silu", attn_dropout=0.0, mlp_dropout=0.0,
+        embedding_dropout=0.0)
+    return ViTConfig(**{**base, **kw})
+
+
+def dsa_tiny(**kw) -> ViTConfig:
+    """The same blocks at a size for tests: 2 layers, width 64, 4 query /
+    2 key-value heads of 16, an indexer of 2 heads of 8 selecting 8 keys
+    a query 16 rows at a time, 8 experts of 32 of which 4 are held, top
+    2, 256 rows, 64 positions."""
+    return keye_vl_20_30b_a3b_ep8(**{**dict(
+        vocab_size=256, max_seq_len=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim_override=16, embedding_dim=64,
+        sa_topk=8, sa_index_heads=2, sa_index_head_dim=8, sa_chunk=16,
+        num_experts=8, experts_per_token=2, expert_width=32,
+        experts_held=4), **kw})
+
+
 PRESETS = {
     "ViT-Ti/16": vit_ti16,
     "ViT-S/16": vit_s16,
@@ -419,6 +490,8 @@ LM_PRESETS = {
     "lm-tiny": lm_tiny,
     "glm-4.7-flash-ep8": glm_47_flash_ep8,
     "mla-tiny": mla_tiny,
+    "keye-vl-2.0-30b-a3b-ep8": keye_vl_20_30b_a3b_ep8,
+    "dsa-tiny": dsa_tiny,
 }
 
 # The fields that make two configs the same *servable architecture*

@@ -156,6 +156,22 @@ def _moe_metrics(moe_stats) -> Dict[str, jax.Array]:
     }
 
 
+def _dsa_metrics(dsa_stats) -> Dict[str, jax.Array]:
+    """The counters of one step's indexed attention, from what the
+    blocks sowed (``dsa_stats``, per layer): the query-key pairs the
+    selection kept and the causal pairs it chose from, a sequence a
+    layer (the layers' mean), and the smallest over layers of the mean
+    over queries of ``pbar``'s mass on the selection (1 by
+    construction: it guards the normalisation)."""
+    from flax.traverse_util import flatten_dict
+    sown = flatten_dict(dsa_stats)
+    leaves = lambda key: jnp.stack([v for path, vs in sown.items()
+                                    if path[-1] == key for v in vs])
+    return {"dsa_selected_pairs": jnp.mean(leaves("selected_pairs")),
+            "dsa_causal_pairs": jnp.mean(leaves("causal_pairs")),
+            "dsa_pbar_mass_min": jnp.min(leaves("pbar_mass"))}
+
+
 def _token_loss(state, params, batch, dropout_rng):
     """A token model's objective: mean next-token cross entropy over
     every position of ``batch["tokens"]`` against ``batch["label"]``,
@@ -164,16 +180,21 @@ def _token_loss(state, params, batch, dropout_rng):
     right positions, so that ``correct / count`` is the token accuracy.
     A model with a multi-token-prediction module returns its two-term
     objective and sows the terms (``lm_stats``: ``main_loss``,
-    ``mtp_loss``, ``mtp_top1_share``), which ride along as counters."""
+    ``mtp_loss``, ``mtp_top1_share``), which ride along as counters; so
+    does a model whose attention an indexer selects (``main_loss``,
+    ``indexer_loss``, and :func:`_dsa_metrics`)."""
     (loss, right), sown = state.apply_fn(
         {"params": params}, batch["tokens"], True, labels=batch["label"],
-        rngs={"dropout": dropout_rng}, mutable=["moe_stats", "lm_stats"])
+        rngs={"dropout": dropout_rng},
+        mutable=["moe_stats", "lm_stats", "dsa_stats"])
     n, t = batch["label"].shape
     with jax.named_scope("metrics"):
         metrics = {"loss_sum": loss * n, "correct": right / t,
                    "count": jnp.asarray(n, jnp.float32)}
         if sown.get("moe_stats"):
             metrics.update(_moe_metrics(sown["moe_stats"]))
+        if sown.get("dsa_stats"):
+            metrics.update(_dsa_metrics(sown["dsa_stats"]))
         metrics.update({key: value[0] for key, value in
                         sown.get("lm_stats", {}).items()})
     return loss, metrics
@@ -181,7 +202,8 @@ def _token_loss(state, params, batch, dropout_rng):
 
 # A token model's step counters (beside ``moe_*``) that the loop hands to
 # the telemetry on barriered steps.
-LM_COUNTERS = ("main_loss", "mtp_loss", "mtp_top1_share")
+LM_COUNTERS = ("main_loss", "mtp_loss", "mtp_top1_share", "indexer_loss",
+               "dsa_selected_pairs", "dsa_causal_pairs", "dsa_pbar_mass_min")
 
 
 def _masked_metrics(losses, logits, labels, mask) -> Dict[str, jax.Array]:

@@ -269,6 +269,10 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
                 name="qkv")(y)               # [B, T, H + 2 Hkv, Dh]
     q, k, v = (qkv[:, :, :hq], qkv[:, :, hq:hq + hkv],
                qkv[:, :, hq + hkv:])
+    if cfg.qk_norm:
+        # over the columns of each head, one learned scale a projection
+        q = nn.RMSNorm(epsilon=cfg.ln_epsilon, dtype=dt, name="q_norm")(q)
+        k = nn.RMSNorm(epsilon=cfg.ln_epsilon, dtype=dt, name="k_norm")(k)
     if cfg.layer_rope(self.layer):
         with jax.named_scope("rope"):
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
@@ -276,13 +280,57 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     dropout_rng = None
     if train and cfg.attn_dropout > 0.0:
         dropout_rng = self.make_rng("dropout")
-    attn = dot_product_attention(
-        q, k, v, impl=cfg.attention_impl, kind=kind, window=window,
-        dropout_rate=cfg.attn_dropout, dropout_rng=dropout_rng,
-        deterministic=not train, softmax=cfg.attention_softmax)
+    if kind == "causal_topk":
+        if dropout_rng is not None:
+            raise ValueError("sparse attention has no attention dropout")
+        attn = _indexed_attention(self, y, q, k, v, dense)
+    else:
+        attn = dot_product_attention(
+            q, k, v, impl=cfg.attention_impl, kind=kind, window=window,
+            dropout_rate=cfg.attn_dropout, dropout_rng=dropout_rng,
+            deterministic=not train, softmax=cfg.attention_softmax)
     out = dense(features=cfg.embedding_dim, axis=(-2, -1),
                 name="out")(attn)
     return out, y
+
+
+def _indexed_attention(self: MultiHeadSelfAttentionBlock, y, q, k, v,
+                       dense):
+    """(Called from :func:`_token_attention`.) Sparse attention chosen
+    by an indexer (:mod:`..ops.sparse_attention`): ``index_q`` (``J``
+    heads of ``Di``), ``index_k`` (ONE head, LayerNorm'd) and
+    ``index_w`` (a weight a head, scaled ``J^-1/2 Di^-1/2``) read the
+    block's normed input with the gradient cut, q and k of the indexer
+    are turned by their positions over all ``Di`` columns, and every
+    query attends to the ``sa_topk`` causal keys of the largest score.
+    The indexer's alignment loss and the counters of the selection are
+    sown into ``dsa_stats`` (kept when the caller makes it mutable: the
+    objective adds the loss there, :func:`_with_indexer_loss`); the
+    selection itself into ``dsa_probe``. Scopes: ``msa/indexer/proj``,
+    ``msa/indexer/scores``, ``msa/indexer/select``, ``msa/attn_core``,
+    ``msa/indexer_loss``."""
+    from ..ops.sparse_attention import sparse_attention
+    cfg = self.config
+    heads, width = cfg.sa_index_heads, cfg.sa_index_head_dim
+    u = jax.lax.stop_gradient(y)
+    with jax.named_scope("indexer/proj"):
+        q_idx = dense(features=(heads, width), name="index_q")(u)
+        k_idx = nn.LayerNorm(epsilon=cfg.ln_epsilon, dtype=_dtype(cfg),
+                             name="index_k_norm")(
+            dense(features=width, name="index_k")(u))
+        # float32: the weights order the scores
+        w_idx = dense(features=heads, dtype=jnp.float32, name="index_w")(
+            u.astype(jnp.float32)) * (heads ** -0.5 * width ** -0.5)
+        q_idx = rotary(q_idx, cfg.rope_theta)
+        k_idx = rotary(k_idx[:, :, None], cfg.rope_theta)[:, :, 0]
+    attn, loss, stats = sparse_attention(
+        q, k, v, q_idx, k_idx, w_idx, topk=cfg.sa_topk, chunk=cfg.sa_chunk,
+        impl=cfg.attention_impl)
+    self.sow("dsa_probe", "mask", stats.pop("mask"))
+    self.sow("dsa_stats", "indexer_loss", loss)
+    for key, value in stats.items():
+        self.sow("dsa_stats", key, value)
+    return attn
 
 
 def _latent_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
@@ -487,6 +535,15 @@ class _LatentKeyValueUp(nn.Module):
 # not.
 _KEEP_OF_LATENT_ATTENTION = jax.checkpoint_policies.save_only_these_names(
     "attn_core_out", "attn_core_lse")
+
+
+# What a block whose attention an indexer selects keeps: the core's
+# output and row statistic as above, the selection (one int8 ``[T, T]`` a
+# layer: taking it again is the bisection again) and the alignment loss's
+# three gradients, which its forward pass already holds. The projections,
+# the norms and the rotary embedding are computed twice.
+_KEEP_OF_INDEXED_ATTENTION = jax.checkpoint_policies.save_only_these_names(
+    "attn_core_out", "attn_core_lse", "indexer_mask", "indexer_loss_grad")
 
 
 def _flat_projections(cfg: ViTConfig, qkv_shape, train: bool) -> bool:
@@ -800,6 +857,9 @@ class TransformerEncoderBlock(nn.Module):
         if self.config.kv_lora_rank:
             msa = nn.remat(msa, static_argnums=(2, 3),
                            policy=_KEEP_OF_LATENT_ATTENTION)
+        elif self.config.sa_topk:
+            msa = nn.remat(msa, static_argnums=(2, 3),
+                           policy=_KEEP_OF_INDEXED_ATTENTION)
         msa = msa(self.config, tp_axis=self.tp_axis, layer=self.layer,
                   name="msa")
         if self.config.layer_routed(self.layer):
@@ -918,6 +978,9 @@ class ViT(nn.Module):
         if cfg.mtp_modules and labels is not None:
             return _two_term_loss(self, images, labels, train)
         tokens = ViTFeatureExtractor(cfg, name="backbone")(images, train)
+        if cfg.sa_topk and labels is not None:
+            return _with_indexer_loss(
+                self, LMHead(cfg, name="head")(tokens, labels))
         if cfg.vocab_size:
             return LMHead(cfg, name="head")(tokens, labels)
         if cfg.pool == "cls":
@@ -957,6 +1020,28 @@ def _two_term_loss(self: ViT, images: jax.Array, labels: jax.Array,
     self.sow("lm_stats", "mtp_top1_share",
              drafted_right / jnp.sum(counted))
     return main + cfg.mtp_loss_weight * module, right
+
+
+def _with_indexer_loss(self: ViT, main_and_right):
+    """(Called from :class:`ViT`'s compact ``__call__``.) The objective
+    of a model whose attention an indexer selects: ``main +
+    sum over layers of L_I`` (weight 1 a layer), the alignment losses the
+    attention blocks sowed into ``dsa_stats``. The two terms' gradients
+    fall on disjoint parameters: the selection passes none, and the
+    indexer reads its input with the gradient cut. Where the caller did
+    not make ``dsa_stats`` mutable (evaluation) nothing was sown and the
+    objective is the main loss. Sows ``main_loss`` and ``indexer_loss``
+    (the layers' mean) into ``lm_stats``."""
+    from flax.traverse_util import flatten_dict
+    main, right = main_and_right
+    sown = flatten_dict(self.variables.get("dsa_stats", {}))
+    terms = [v for path, vs in sorted(sown.items())
+             if path[-1] == "indexer_loss" for v in vs]
+    if not terms:
+        return main, right
+    self.sow("lm_stats", "main_loss", main)
+    self.sow("lm_stats", "indexer_loss", sum(terms) / len(terms))
+    return main + sum(terms), right
 
 
 class LMHead(nn.Module):

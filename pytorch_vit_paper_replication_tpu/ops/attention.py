@@ -43,9 +43,12 @@ flash kernel run per shard on a data/model mesh (XLA will not partition a
 Mosaic call by itself).
 
 **Structure, not a mask.** ``kind`` says which keys a query sees:
-``"full"`` (every key: the ViT), ``"causal"`` (key j <= query i) or
-``"causal_window"`` (also ``i - j < window``). The flash kernels compute
-it from block positions and skip the blocks outside it; the XLA path —
+``"full"`` (every key: the ViT), ``"causal"`` (key j <= query i),
+``"causal_window"`` (also ``i - j < window``) or ``"causal_topk"`` (the
+causal keys an indexer selects for each query: a set that differs per
+row, which :mod:`.sparse_attention` makes and hands to the flash kernels
+as a mask, or to XLA; it asks :func:`choose` which). The flash kernels compute
+the first three from block positions and skip the blocks outside them; the XLA path —
 short sequences, and everything off the TPU — builds the ``[T, T]``
 boolean it stands for. k and v may have fewer heads than q
 (grouped-query attention): flash reads each key/value head where it
@@ -255,7 +258,7 @@ def choose(shape, dtype, k_shape=None, *, impl: str = "auto",
     """
     if impl not in ("xla", "flash", "auto"):
         raise ValueError(f"unknown attention impl {impl!r}")
-    if kind not in ("full", "causal", "causal_window"):
+    if kind not in ("full", "causal", "causal_window", "causal_topk"):
         raise ValueError(f"unknown attention kind {kind!r}")
     packed = len(shape) == 5
     (b, t), (h, dh) = shape[:2], shape[-2:]
@@ -378,6 +381,10 @@ def dot_product_attention(
     axes uses the XLA path (:func:`choose`, rule 1). Attention dropout
     rides the ring natively.
     """
+    if kind == "causal_topk":
+        raise ValueError("unknown attention kind 'causal_topk' here: the "
+                         "keys are an indexer's to select "
+                         "(ops.sparse_attention.sparse_attention)")
     served, reason = choose(
         q.shape, q.dtype, k.shape, impl=impl, kind=kind, mask=mask,
         dropout_rate=dropout_rate, deterministic=deterministic,

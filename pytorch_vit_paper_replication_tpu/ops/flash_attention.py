@@ -312,8 +312,17 @@ def _mask_block_rows(mask_ref, mask_info, ki, block_q, block_k):
     """[Bq|1, Bk] attend-mask tile for a (q-strip kernel, kv block ki)."""
     _, q_bcast = mask_info
     rows = 1 if q_bcast else block_q
-    return mask_ref[0, :, pl.ds(ki * block_k, block_k)].reshape(
-        rows, block_k)
+    return _attend(mask_ref[0, :, pl.ds(ki * block_k, block_k)].reshape(
+        rows, block_k))
+
+
+def _attend(tile):
+    """A mask tile as booleans. A caller's mask may be int8 (non-zero =
+    attend): a boolean array crosses a Mosaic kernel's boundary as 32-bit
+    words, an int8 one as bytes."""
+    if tile.dtype == jnp.bool_:
+        return tile
+    return tile.astype(jnp.int32) != 0
 
 
 # --------------------------------------------------------------------------
@@ -411,6 +420,13 @@ def _pad_mask(mask3, mask_info, block_q, block_k):
     return m
 
 
+def _strip_bytes(mask3, mask_info, block_q):
+    """Bytes of the mask strip one program holds: a query block's rows
+    (one where the mask broadcasts over queries) by every key."""
+    rows = 1 if mask_info[1] else block_q
+    return rows * mask3.shape[2] * mask3.dtype.itemsize
+
+
 def _pad_to_false(x, axis, multiple):
     size = x.shape[axis]
     pad = (-size) % multiple
@@ -442,11 +458,13 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
         pl.BlockSpec((1, vp.shape[1], head_dim), kv_whole),
     ]
     operands = [qp, kp, vp]
+    strip = ()
     if mask_info is not None:
         mask3 = _pad_mask(mask3, mask_info, block_q, block_k)
         in_specs.append(_mask_spec_rows(mask_info, heads[0],
                                         mask3.shape[2], block_q))
         operands.append(mask3)
+        strip = (_strip_bytes(mask3, mask_info, block_q),)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",
@@ -465,7 +483,8 @@ def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(_slab_bytes(kp, head_dim),
-                                         _slab_bytes(vp, head_dim))),
+                                         _slab_bytes(vp, head_dim),
+                                         *strip)),
         interpret=interpret,
     )(seed, *operands)
     return out[:, :q_len], lse[:, 0, :q_len]
@@ -536,7 +555,7 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                                          _visible(structure, row, col))
             p = jnp.where(keep_s, p, 0.0)
         if mask_info is not None:
-            p = jnp.where(mask_ref[0, keys, :], p, 0.0)  # [Bk, Bq|1]
+            p = jnp.where(_attend(mask_ref[0, keys, :]), p, 0.0)  # [Bk, Bq|1]
         if threshold:
             keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
                               shape, threshold, query_dim=1)
@@ -576,18 +595,21 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 # --------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, seed, mask3, threshold, block_q, block_k, interpret,
-           mask_info, heads, structure):
+           mask_info, heads, structure, with_lse=False):
+    """``out``, or ``(out, lse)`` with the row statistic ``[bh, T]`` (a
+    constant under the gradient: its cotangent is dropped)."""
     scale = _layout(structure, q)[1] ** -0.5
-    out, _ = _fwd(q, k, v, seed, mask3, mask_info, heads=heads, scale=scale,
-                  block_q=block_q, block_k=block_k, threshold=threshold,
-                  interpret=interpret, structure=structure)
-    return out
+    out, lse = _fwd(q, k, v, seed, mask3, mask_info, heads=heads,
+                    scale=scale,
+                    block_q=block_q, block_k=block_k, threshold=threshold,
+                    interpret=interpret, structure=structure)
+    return (out, lse) if with_lse else out
 
 
 def _flash_fwd(q, k, v, seed, mask3, threshold, block_q, block_k,
-               interpret, mask_info, heads, structure):
+               interpret, mask_info, heads, structure, with_lse=False):
     scale = _layout(structure, q)[1] ** -0.5
     out, lse = _fwd(q, k, v, seed, mask3, mask_info, heads=heads,
                     scale=scale,
@@ -598,12 +620,14 @@ def _flash_fwd(q, k, v, seed, mask3, threshold, block_q, block_k,
     # attention of ``models/vit.py``) does not take the core again too.
     out = checkpoint_name(out, "attn_core_out")
     lse = checkpoint_name(lse, "attn_core_lse")
-    return out, (q, k, v, seed, mask3, out, lse)
+    return (out, lse) if with_lse else out, (q, k, v, seed, mask3, out, lse)
 
 
 def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
-               structure, res, do):
+               structure, with_lse, res, do):
     q, k, v, seed, mask3, out, lse = res
+    if with_lse:
+        do = do[0]
     q_len, kv_len = q.shape[1], k.shape[1]
     group = structure[2]
     bh, head_dim, q_at, kv_at = _layout(structure, q)
@@ -635,10 +659,12 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
                 pl.BlockSpec((1, 1, block_q),
                              lambda n, g, i, *_: (n * group + g, 0, i))]
     operands = [qp, kp, vp, outp, dop, lsep]
+    strip = ()
     if mask_info is not None:
         # The kernel holds the block transposed: so is its mask.
-        mask_t = _pad_mask(mask3, mask_info, block_q, block_k
-                           ).transpose(0, 2, 1)
+        padded_mask = _pad_mask(mask3, mask_info, block_q, block_k)
+        strip = (_strip_bytes(padded_mask, mask_info, block_q),)
+        mask_t = padded_mask.transpose(0, 2, 1)
         bhi = _mask_bh_index(mask_info[0], heads[0])
         in_specs.append(pl.BlockSpec(
             (1, padded_kv, 1 if mask_info[1] else block_q),
@@ -668,7 +694,7 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(
-                slab, slab, slab, slab,
+                slab, slab, slab, slab, *strip,
                 scratch=2 * 4 * padded_kv * head_dim)),
         interpret=interpret,
     )(seed, *operands)
@@ -687,7 +713,7 @@ def flash_attention(q, k, v, *, kind: str = "full", window: int = 0,
                     dropout_rng=None, deterministic: bool = True,
                     block_q: int | None = None,
                     block_k: int | None = None,
-                    interpret=None) -> jax.Array:
+                    interpret=None, return_lse: bool = False):
     """Flash attention over ``[B, T, H, Dh]`` inputs, optional mask+dropout.
 
     ``dropout_rate``/``dropout_rng``/``deterministic`` follow the
@@ -695,8 +721,9 @@ def flash_attention(q, k, v, *, kind: str = "full", window: int = 0,
     is generated in-kernel (module docstring), so the O(T) memory property
     holds with dropout active.
 
-    ``mask``: optional boolean array broadcastable to ``[B, H, Tq, Tk]``
-    (True = attend), applied IN-KERNEL (round 4 — previously a silent XLA
+    ``mask``: optional boolean (True = attend) or int8 (non-zero = attend:
+    it crosses the kernels' boundary as bytes, a boolean one as 32-bit
+    words) array broadcastable to ``[B, H, Tq, Tk]``, applied IN-KERNEL (round 4 — previously a silent XLA
     fallback): broadcast batch/head/query dims are never materialized, so
     a key-padding mask ``[B, 1, 1, Tk]`` streams O(B·T); only a mask the
     caller already materialized at ``[B, H, Tq, Tk]`` costs O(T²) input —
@@ -713,6 +740,12 @@ def flash_attention(q, k, v, *, kind: str = "full", window: int = 0,
     ``"full"`` bidirectional, ``"causal"``, or ``"causal_window"`` over
     the last ``window`` keys. k and v may have fewer heads than q (a
     divisor of q's): grouped-query attention.
+
+    ``return_lse``: also return the row statistic, ``(out, lse [B, H,
+    Tq])``: the log of each query's sum of ``exp(q . k / sqrt(Dh))`` over
+    the keys it attends to, a constant under the gradient (what reads it,
+    the head-mean probabilities an indexer is aligned to, is one by
+    definition: :mod:`.sparse_attention`).
 
     ``interpret``: run the Pallas interpreter instead of Mosaic (default:
     auto — True off-TPU, so a forced ``impl="flash"`` works everywhere
@@ -762,11 +795,17 @@ def flash_attention(q, k, v, *, kind: str = "full", window: int = 0,
             flat = lambda x: x.reshape(x.shape[:2] + (-1,))
             out = _flash(flat(q), flat(k), flat(v), meta, mask3, threshold,
                          bq, bk, interpret, mask_info, (h, h_total),
-                         (kind != "full", int(window), group, h))
+                         (kind != "full", int(window), group, h),
+                         return_lse)
+            if return_lse:
+                return out[0].reshape(b, t, h, dh), out[1].reshape(b, h, t)
             return out.reshape(b, t, h, dh)
         out = _flash(_fold_heads(q), _fold_heads(k), _fold_heads(v), meta,
                      mask3, threshold, bq, bk, interpret, mask_info,
-                     (h, h_total), (kind != "full", int(window), group, 0))
+                     (h, h_total), (kind != "full", int(window), group, 0),
+                     return_lse)
+        if return_lse:
+            return _unfold_heads(out[0], b, h), out[1].reshape(b, h, t)
         return _unfold_heads(out, b, h)
 
     part = partition.current()
@@ -790,5 +829,6 @@ def flash_attention(q, k, v, *, kind: str = "full", window: int = 0,
     return part.shard_map(
         lambda q, k, v, seed, mask: local(
             q, k, v, seed, mask, part.index((data,)), part.index((model,))),
-        in_specs=(spec, spec, spec, P(), mask_spec), out_specs=spec,
+        in_specs=(spec, spec, spec, P(), mask_spec),
+        out_specs=(spec, P(data, model, None)) if return_lse else spec,
     )(q, k, v, seed, mask)

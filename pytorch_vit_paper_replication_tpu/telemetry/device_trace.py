@@ -94,8 +94,10 @@ LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
 # inside ``msa``, the token embedding and the head with its loss
 # (``ops/lm_loss.py``). First a multi-token-prediction module's rows
 # (its merge, its block whole, its norm and its pass through the head),
-# then the latent attention's two paths and the shared expert. A ViT's
-# paths match none of them.
+# then the latent attention's two paths and the shared expert, then the
+# sparse-attention indexer's four (matched without ``msa/`` in front: an
+# op inside the loops over chunks of query rows may carry the path from
+# the loop's body on). A ViT's paths match none of them.
 TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
                      for name, pat in (
     ("mtp_merge", r"mtp/(?:.*/)?mtp_merge"),
@@ -108,6 +110,10 @@ TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
     ("moe_dispatch", r"mlp/(?:.*/)?moe_dispatch"),
     ("moe_experts", r"mlp/(?:.*/)?moe_experts"),
     ("moe_combine", r"mlp/(?:.*/)?moe_combine"),
+    ("indexer/proj", r"indexer/proj"),
+    ("indexer/scores", r"indexer/scores"),
+    ("indexer/select", r"indexer/select"),
+    ("indexer_loss", r"indexer_loss"),
     ("rope", r"msa/rope"),
     ("token_embedding", r"token_embedding"),
     ("head_loss", r"head/(?:.*/)?loss"),

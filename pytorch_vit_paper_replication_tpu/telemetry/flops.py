@@ -70,7 +70,9 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
     over all experts, the expected pairs on the experts HELD
     (``experts_per_token x held / num_experts`` a token: routing is
     counted as uniform) and the shared experts over every token; the
-    head over the vocabulary rows held. A multi-token-prediction module
+    head over the vocabulary rows held. Attention that an indexer selects
+    counts the selected pairs for the core, and the indexer's projections
+    and its scores of every causal pair. A multi-token-prediction module
     is its merge (``2D -> D``), one more block of the last kind and the
     head a second time. The embedding is a lookup and the rotary
     embedding elementwise: neither is counted."""
@@ -91,7 +93,14 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
                 * (cfg.qk_nope_head_dim + cfg.v_head_dim))
         else:
             total += 2 * t * d * (hq + 2 * hkv) * dh        # q, k, v
-        total += 2 * 2 * visible_pairs(t, window) * hq * dh   # QK^T, PV
+        # QK^T, PV (a selection of ``topk`` keys counts as a window does)
+        total += 2 * 2 * visible_pairs(t, window) * hq * dh
+        if cfg.sa_topk:
+            # the indexer: its three projections, its scores of every
+            # causal pair
+            heads, width = cfg.sa_index_heads, cfg.sa_index_head_dim
+            total += 2 * t * d * (heads * width + width + heads)
+            total += 2 * visible_pairs(t) * heads * width
         total += 2 * t * hq * dh * d                    # out projection
         if cfg.layer_routed(layer):
             total += 2 * t * d * cfg.num_experts        # router
@@ -110,8 +119,21 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
 def train_step_flops_per_sequence(cfg, seq_len: Optional[int] = None
                                   ) -> float:
     """3 x forward (module docstring's convention): the token model's
-    ``flops_per_image``, a sequence being its "image"."""
-    return 3.0 * forward_flops_per_sequence(cfg, seq_len)
+    ``flops_per_image``, a sequence being its "image". A model whose
+    attention an indexer selects departs from it in the indexer alone,
+    whose loss is local to its layer: its scores' two backward products
+    run over the selected pairs, not over every causal pair, and the
+    head-mean probabilities it is aligned to cost one more ``q k^T``
+    over the selected pairs, forward only."""
+    total = 3.0 * forward_flops_per_sequence(cfg, seq_len)
+    if cfg.sa_topk:
+        t = seq_len or cfg.max_seq_len
+        score = 2 * cfg.sa_index_heads * cfg.sa_index_head_dim
+        selected = visible_pairs(t, cfg.sa_topk)
+        total += cfg.num_layers * (
+            2 * score * (selected - visible_pairs(t))
+            + 2 * cfg.num_heads * cfg.head_dim * selected)
+    return total
 
 
 def analytic_mfu(images_per_sec_per_chip: float, flops_per_image: float,

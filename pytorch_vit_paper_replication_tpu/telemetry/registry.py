@@ -73,6 +73,11 @@ INSTRUMENTS: Dict[str, str] = {
     "tel_main_loss": "gauge",
     "tel_mtp_loss": "gauge",
     "tel_mtp_top1_share": "gauge",
+    # a model whose attention an indexer selects (engine._dsa_metrics)
+    "tel_indexer_loss": "gauge",
+    "tel_dsa_selected_pairs": "gauge",
+    "tel_dsa_causal_pairs": "gauge",
+    "tel_dsa_pbar_mass_min": "gauge",
     "tel_goodput_pct": "gauge",         # step-exec share of wall time
     "tel_data_wait_frac": "gauge",      # data-wait share of wall time
     "tel_steps_total": "counter",
@@ -305,6 +310,17 @@ HELP_TEXT: Dict[str, str] = {
     "tel_mtp_top1_share":
         "Share of the module's positions whose largest logit is the "
         "target (how often a drafted token would be accepted)",
+    "tel_indexer_loss":
+        "The sparse-attention indexer's alignment loss, mean over layers, "
+        "last sampled step",
+    "tel_dsa_selected_pairs":
+        "Query-key pairs the indexer's selection kept, a sequence a layer",
+    "tel_dsa_causal_pairs":
+        "Causal query-key pairs the selection chose from, a sequence a "
+        "layer",
+    "tel_dsa_pbar_mass_min":
+        "Smallest over layers of the mean over queries of the head-mean "
+        "attention probabilities' mass on the selection (1 by construction)",
     "tel_goodput_pct": "Step-exec share of epoch wall time, percent",
     "tel_data_wait_frac": "Data-wait share of epoch wall time",
     "tel_steps_total": "Train steps recorded",

@@ -80,7 +80,15 @@ THE_CHOICE = [
           "flash", k_shape=(1, 16384, 4, 128), kind="causal"),
     _case("st21b_train_16k, a window layer", (1, 16384, 28, 128), "flash",
           k_shape=(1, 16384, 4, 128), kind="causal_window"),
+    _case("keye2_train_16k: the keys an indexer selects, a mask a row",
+          (1, 16384, 32, 128), "flash", k_shape=(1, 16384, 4, 128),
+          kind="causal_topk"),
     # --- other models the repo names ---
+    _case("dsa-tiny: T 64, Dh 16, selected keys", (8, 64, 4, 16), "xla",
+          k_shape=(8, 64, 2, 16), kind="causal_topk"),
+    _case("selected keys, forced flash", (8, 64, 4, 16), "flash",
+          cpu="flash", k_shape=(8, 64, 2, 16), kind="causal_topk",
+          impl="flash"),
     _case("lm-tiny: T 64, Dh 16", (8, 64, 4, 16), "xla",
           k_shape=(8, 64, 2, 16), kind="causal"),
     _case("H/14: T 257, Dh 80", (64, 257, 3, 16, 80), "xla"),
@@ -147,6 +155,9 @@ THE_CHOICE = [
           reason="shape (batch=7, tokens=196) not divisible by mesh axes"),
     _case("seq=2, causal", (8, 196, 12, 64), "xla", mesh=SEQ2,
           kind="causal", reason="bidirectional attention with equal head"),
+    _case("seq=2, selected keys", (8, 196, 12, 64), "xla", mesh=SEQ2,
+          k_shape=(8, 196, 12, 64), kind="causal_topk",
+          reason="bidirectional attention with equal head"),
     _case("seq=2, grouped heads", (8, 196, 12, 64), "xla", mesh=SEQ2,
           k_shape=(8, 196, 4, 64), reason="equal head counts only"),
 ]

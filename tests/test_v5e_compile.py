@@ -478,3 +478,91 @@ def test_latent_attention_hands_the_kernels_q_k_v_where_they_read_them(
     # the kernels themselves read and write the flat layout
     assert re.search(r"= \(bf16\[1,16384,5120\]\S*, f32\[20,1,16384\]\S*\) "
                      r"custom-call\(", hlo)
+
+
+# ------------------------------- attention an indexer selects (PR 34)
+# The cell ``keye2_train_16k``'s step held 12.15 GiB when PR 34 compiled
+# it (9.82 of them parameters, gradients and moments). Kept as one int8
+# ``[T, T]`` a layer the selection alone adds 1.5 GiB, and the step did
+# not fit at all before it was kept a bit a pair (17.1 GiB).
+DSA_STEP_GIB = 12.6
+
+
+@pytest.fixture(scope="module")
+def dsa_step(v5e_2x2):
+    """The cell ``keye2_train_16k``'s step as the trainer builds it,
+    lowered and compiled once for the file."""
+    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = LM_PRESETS["keye-vl-2.0-30b-a3b-ep8"]()
+        lowered = _lower_lm_step(v5e_2x2[:1], cfg, dp=1, batch=1,
+                                 seq_len=cfg.max_seq_len)
+        return lowered, lowered.compile()
+
+
+def test_indexed_attention_models_step_compiles_and_fits_one_chip(dsa_step):
+    """One 16,384-token sequence through 6 of Keye-VL-2.0-30B-A3B's
+    layers at every published width. Every layer names the flash kernels
+    once each way, reading the selection as an int8 strip a query block
+    (Mosaic takes it; the forward holds k, v and the strip in VMEM), and
+    the grouped products of its 16 held experts; the blocks take their
+    projections again in the backward pass and neither the selection,
+    nor the core's forward, nor the loss's pass. It compiles for the v5e
+    and fits 15.75 GiB with room; every new scope is in the compiled
+    step."""
+    lowered, compiled = dsa_step
+    names = [name for name, _ in mosaic_calls(lowered.as_text())]
+    assert {n: names.count(n) for n in set(names)} == {
+        "flash_fwd": 6, "flash_bwd": 6,
+        "moe_gmm_fwd": 18, "moe_gmm_dx": 12, "moe_gmm_dw": 12}
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert held < DSA_STEP_GIB * 2**30, held / 2**30
+    hlo = compiled.as_text()
+    paths = set(device_trace.parse_scopes(hlo)["scopes"].values())
+    layers = [device_trace.classify(path)[0] for path in paths
+              if path.startswith("jit(")]
+    for layer in ("indexer/proj", "indexer/scores", "indexer/select",
+                  "indexer_loss", "attn_core", "moe_router", "moe_experts"):
+        assert layer in layers, layer
+    for scope in ("/indexer/scores/", "/indexer/select/",
+                  "/msa/indexer_loss/", "/msa/attn_core/flash_fwd/",
+                  "/checkpoint/msa/attn_core/flash_bwd/",
+                  "checkpoint/rematted_computation/msa/qkv/"):
+        assert any(scope in path for path in paths), scope
+    # kept for the backward pass, not taken again
+    for scope in ("rematted_computation/msa/while",
+                  "rematted_computation/msa/indexer_loss",
+                  "rematted_computation/msa/attn_core/flash_fwd"):
+        assert not any(scope in path for path in paths), scope
+    assert layers.count("other") < 0.01 * len(layers)
+    # the selection reaches the kernels as bytes and is kept as bits; no
+    # 32-bit copy of it is made for them
+    assert re.search(r"s8\[1,16384,16384\]", hlo)
+    assert re.search(r"u8\[16384,2048\]", hlo)
+    assert not re.search(r"= [su]32\[1,16384,16384\]\S* (?:convert|copy)\(",
+                         hlo)
+
+
+def test_flash_kernels_compile_with_a_selection_a_row(v5e_2x2):
+    """T = 16,384, 32 query heads over 4 key/value heads of 128 and an
+    int8 ``[T, T]`` selection: both kernels compile with the strip of it
+    (8 MiB a query block, twice for the pipeline) beside k and v."""
+    from pytorch_vit_paper_replication_tpu.ops.flash_attention import (
+        flash_attention)
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16, sharding=one)
+    mask = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8, sharding=one)
+    compiled = jax.jit(jax.grad(lambda q, k, v, m: jnp.sum(
+        flash_attention(q, k, v, kind="causal", mask=m[:, None],
+                        interpret=False, return_lse=True)[0].astype(
+            jnp.float32)), argnums=(0, 1, 2))).lower(q, kv, kv,
+                                                     mask).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r"bf16\[32,16384,128\]", hlo)
