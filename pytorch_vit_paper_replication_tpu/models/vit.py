@@ -243,6 +243,50 @@ class MultiHeadSelfAttentionBlock(nn.Module):
         return (out, y) if with_normed else out
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _leave_together(lhs, rhs, cut_input):
+    """A product's operands as they are; their GRADIENTS leave together
+    (:func:`_tied_product`)."""
+    return lhs, rhs
+
+
+def _leave_together_bwd(cut_input, _, gradients):
+    d_lhs, d_rhs = gradients
+    if not cut_input:
+        return jax.lax.optimization_barrier((d_lhs, d_rhs))
+    # The input is a constant of this product: it gets zeros, one that
+    # waits for the kernel's gradient spread over its shape.
+    zero, d_rhs = jax.lax.optimization_barrier(
+        (jnp.zeros((), d_lhs.dtype), d_rhs))
+    return jnp.broadcast_to(zero, d_lhs.shape), d_rhs
+
+
+_leave_together.defvjp(lambda lhs, rhs, cut_input: ((lhs, rhs), None),
+                       _leave_together_bwd)
+
+
+def _tied_product(lhs, rhs, dimension_numbers, precision=None,
+                  preferred_element_type=None, *, cut_input=False):
+    """``lax.dot_general`` for the projections of a block whose attention
+    an indexer selects (``nn.DenseGeneral(dot_general=)``): in the
+    backward pass the input's gradient, which the block before waits for,
+    leaves with the kernel's, which only the optimizer reads (a barrier
+    on the two; the forward pass is the plain product's). Nothing else
+    orders the two, and the order the compiler takes for
+    ``keye2_train_16k``'s step computes every block's weight gradients
+    after the LAST block's backward pass, their operands held until then
+    (the core's output, dq after the q norm, the normed input, the loss's
+    gradients: 0.4 GiB a layer growing where this order frees 0.08;
+    13.94 GiB for the step: PERF.md section 6, PR 35). ``cut_input``: the
+    product reads its input as a constant (the indexer's projections),
+    and the zeros the input gets wait for the kernel's gradient all the
+    same."""
+    lhs, rhs = _leave_together(lhs, rhs, cut_input)
+    return jax.lax.dot_general(lhs, rhs, dimension_numbers,
+                               precision=precision,
+                               preferred_element_type=preferred_element_type)
+
+
 def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
                      train: bool):
     """(Called from the block's compact ``__call__``; a free function and
@@ -263,7 +307,9 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     dense = functools.partial(
         nn.DenseGeneral, use_bias=cfg.attn_bias, dtype=dt,
         param_dtype=jnp.float32,
-        kernel_init=nn.initializers.normal(cfg.init_std))
+        kernel_init=nn.initializers.normal(cfg.init_std),
+        # an indexed block's weight gradients are taken in place
+        **({"dot_general": _tied_product} if cfg.sa_topk else {}))
     y = _norm(cfg, "norm")(x)
     qkv = dense(features=(hq + 2 * hkv, cfg.head_dim), axis=-1,
                 name="qkv")(y)               # [B, T, H + 2 Hkv, Dh]
@@ -283,7 +329,7 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     if kind == "causal_topk":
         if dropout_rng is not None:
             raise ValueError("sparse attention has no attention dropout")
-        attn = _indexed_attention(self, y, q, k, v, dense)
+        attn, indexer_loss = _indexed_attention(self, y, q, k, v, dense)
     else:
         attn = dot_product_attention(
             q, k, v, impl=cfg.attention_impl, kind=kind, window=window,
@@ -291,6 +337,19 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
             deterministic=not train, softmax=cfg.attention_softmax)
     out = dense(features=cfg.embedding_dim, axis=(-2, -1),
                 name="out")(attn)
+    if kind == "causal_topk":
+        # The rest of the model waits for this layer's alignment loss.
+        # Nothing else orders the two, and the compiler puts what only
+        # the objective reads as late as it can: every layer's pass after
+        # the last layer's core, each layer's selection (256 MiB of
+        # bytes), q and k held until then. On the block's result and not
+        # inside ``sparse_attention`` on the core's, which the backward
+        # pass reads: there a checkpoint takes the barrier again (the
+        # loss has to be kept by name for it), and the out projection
+        # fuses with the residual and its norm and keeps its result in
+        # two layouts, 0.35 GiB more for ``keye2_train_16k``'s step
+        # (PERF.md section 6, PR 35, After the review).
+        out, _ = jax.lax.optimization_barrier((out, indexer_loss))
     return out, y
 
 
@@ -312,15 +371,18 @@ def _indexed_attention(self: MultiHeadSelfAttentionBlock, y, q, k, v,
     from ..ops.sparse_attention import sparse_attention
     cfg = self.config
     heads, width = cfg.sa_index_heads, cfg.sa_index_head_dim
-    u = jax.lax.stop_gradient(y)
+    # The indexer reads the block's normed input as a constant: the
+    # product cuts the gradient itself.
+    dense = functools.partial(
+        dense, dot_general=functools.partial(_tied_product, cut_input=True))
     with jax.named_scope("indexer/proj"):
-        q_idx = dense(features=(heads, width), name="index_q")(u)
+        q_idx = dense(features=(heads, width), name="index_q")(y)
         k_idx = nn.LayerNorm(epsilon=cfg.ln_epsilon, dtype=_dtype(cfg),
                              name="index_k_norm")(
-            dense(features=width, name="index_k")(u))
+            dense(features=width, name="index_k")(y))
         # float32: the weights order the scores
         w_idx = dense(features=heads, dtype=jnp.float32, name="index_w")(
-            u.astype(jnp.float32)) * (heads ** -0.5 * width ** -0.5)
+            y.astype(jnp.float32)) * (heads ** -0.5 * width ** -0.5)
         q_idx = rotary(q_idx, cfg.rope_theta)
         k_idx = rotary(k_idx[:, :, None], cfg.rope_theta)[:, :, 0]
     attn, loss, stats = sparse_attention(
@@ -330,7 +392,7 @@ def _indexed_attention(self: MultiHeadSelfAttentionBlock, y, q, k, v,
     self.sow("dsa_stats", "indexer_loss", loss)
     for key, value in stats.items():
         self.sow("dsa_stats", key, value)
-    return attn
+    return attn, loss
 
 
 def _latent_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,

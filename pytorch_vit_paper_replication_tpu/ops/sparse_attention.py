@@ -18,20 +18,29 @@ folded in), for a query t and a key s <= t:
   the core's own probabilities averaged over the query heads, a constant
   (:func:`indexer_loss`).
 
-**What the first version does with unselected keys: it visits them.**
-The ``[T, T]`` scores are taken ``chunk`` query rows at a time over every
-key (a loop of XLA products; at T = 16,384 the whole matrix would be
-1 GiB a layer), the selection leaves as one int8 ``[B, T, T]`` array, and
-the core is the causal flash kernel pair reading a strip of that array a
-query block (:func:`..flash_attention.flash_attention` with ``mask=`` and
-``return_lse``): every causal block is computed and the unselected pairs are masked inside it. The
-indexer's loss and its gradient are taken together in the forward pass, a
-chunk of query rows at a time (the gradient reaches the indexer's
-parameters only, so nothing of it waits for the backward pass), with one
-more ``q k^T`` over every key for ``pbar``. What is counted as the
-mechanism's work (``telemetry/flops.py``) is the selected pairs; the rest
-is this version's overhead, and a version that gathers or skips by block
-is read by the same count.
+**What this version does with unselected keys: it visits them, a block
+pair at a time.** The ``[T, T]`` scores of the SELECTION are taken
+``chunk`` query rows at a time over every key (a loop of XLA products; at
+T = 16,384 the whole matrix would be 1 GiB a layer), the selection leaves
+as one int8 ``[B, T, T]`` array, and the core is the causal flash kernel
+pair reading a strip of that array a query block
+(:func:`..flash_attention.flash_attention` with ``mask=`` and
+``return_lse``): every causal block is computed and the unselected pairs
+are masked inside it. The indexer's loss and its gradient are taken
+together in the forward pass (the gradient reaches the indexer's
+parameters only, so nothing of it waits for the backward pass). Where the
+flash kernels serve the core (:func:`..attention.choose`, asked as
+:func:`core` asks it) that pass is two more kernels over the same causal
+blocks (:mod:`.indexer_loss`, since PR 35): the head-mean probabilities,
+the indexer's scores once more, the KL and the three gradients a (query
+block, key block) pair at a time in VMEM, nothing above the diagonal
+touched and no ``[C, T]`` array in HBM. Everywhere else (the CPU, the
+tiny preset, a mesh) XLA takes it a chunk of query rows at a time over
+every key (:func:`_loss_pass`), with one more ``q k^T`` over every key
+for ``pbar`` and the scores through :func:`scores` again. What is counted
+as the mechanism's work (``telemetry/flops.py``) is the selected pairs;
+the rest is this version's overhead, and a version that gathers or skips
+by block is read by the same count.
 """
 
 from __future__ import annotations
@@ -43,6 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from . import indexer_loss as indexer_loss_kernels
 from .attention import choose
 
 _F32 = jnp.float32
@@ -251,21 +261,51 @@ def _loss_pass(q_idx, k_idx, w, mask, q, k, lse, chunk, with_gradients):
                  g_w.astype(w.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def indexer_loss(q_idx, k_idx, w, mask, q, k, lse, chunk=512):
+def _served_loss_pass(q_idx, k_idx, w, mask, q, k, lse, chunk, impl,
+                      with_gradients):
+    """The pass where the core goes: the kernel pair of
+    :mod:`.indexer_loss` where :func:`..attention.choose` gives the core
+    to the flash kernels, :func:`_loss_pass` everywhere else."""
+    served, _ = choose(q.shape, q.dtype, k.shape, impl=impl,
+                       kind="causal_topk")
+    if indexer_loss_kernels.serves(served, q.shape, q_idx.shape):
+        return indexer_loss_kernels.loss_pass(q_idx, k_idx, w, mask, q, k,
+                                              lse, with_gradients)
+    return _loss_pass(q_idx, k_idx, w, mask, q, k, lse, chunk,
+                      with_gradients)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def indexer_loss(q_idx, k_idx, w, mask, q, k, lse, chunk=512, impl="auto"):
     """``(L_I, pbar's mass)``: the mean over queries of ``KL(pbar_t ||
     softmax_{S_t} I[t, .])``, ``pbar[t, s] = mean_h exp(q[t, h] . k[s,
     g(h)] / sqrt(Dh) - lse[h, t])`` on the selection ``mask`` (the core's
     own probabilities: their mass is 1), and that mass's mean. Its
     gradient reaches ``q_idx``, ``k_idx`` and ``w`` only — ``mask``,
     ``q``, ``k`` and ``lse`` are constants — and is taken in the forward
-    pass with the loss, so the backward pass scales three small arrays."""
-    return _loss_pass(q_idx, k_idx, w, mask, q, k, lse, chunk, False)
+    pass with the loss, so the backward pass scales three small arrays.
+
+    ``impl`` is the core's (:func:`core`): where :func:`..attention.choose`
+    gives the core to the flash kernels, the pass is two Mosaic kernels
+    that take ``pbar``, the indexer's scores, the KL
+    (``indexer_loss_fwd``) and the three gradients (``indexer_loss_bwd``)
+    a (query block, key block) pair at a time in VMEM and visit no block
+    above the diagonal (:mod:`.indexer_loss`); everywhere else XLA takes
+    it ``chunk`` query rows at a time over every key, the scores through
+    :func:`scores` once more."""
+    return _served_loss_pass(q_idx, k_idx, w, mask, q, k, lse, chunk, impl,
+                             False)
 
 
-def _indexer_loss_fwd(q_idx, k_idx, w, mask, q, k, lse, chunk):
-    out, gradients = _loss_pass(q_idx, k_idx, w, mask, q, k, lse, chunk,
-                                True)
+def _indexer_loss_fwd(q_idx, k_idx, w, mask, q, k, lse, chunk, impl):
+    out, gradients = _served_loss_pass(q_idx, k_idx, w, mask, q, k, lse,
+                                       chunk, impl, True)
+    # The loss leaves with its gradients. Only the backward pass reads
+    # them, so nothing else keeps the compiler from taking every layer's
+    # gradients after the last layer's core, the pass's operands (the
+    # selection as bytes, 256 MiB a layer at T = 16,384, q, k) held until
+    # then.
+    out, gradients = jax.lax.optimization_barrier((out, gradients))
     # Named: a caller that takes the block again in the backward pass
     # (``jax.checkpoint`` keeping these names) does not take this pass
     # again.
@@ -274,8 +314,8 @@ def _indexer_loss_fwd(q_idx, k_idx, w, mask, q, k, lse, chunk):
     return out, (gradients, mask, q, k, lse)
 
 
-def _indexer_loss_bwd(chunk, res, cotangents):
-    del chunk
+def _indexer_loss_bwd(chunk, impl, res, cotangents):
+    del chunk, impl
     gradients, mask, q, k, lse = res
     scaled = tuple((cotangents[0] * g.astype(_F32)).astype(g.dtype)
                    for g in gradients)
@@ -310,7 +350,7 @@ def sparse_attention(q, k, v, q_idx, k_idx, w, *, topk: int, chunk: int = 512,
     with jax.named_scope("indexer_loss"):
         loss, mass = indexer_loss(
             q_idx, k_idx, w, mask, *(jax.lax.stop_gradient(x)
-                                     for x in (q, k, lse)), chunk)
+                                     for x in (q, k, lse)), chunk, impl)
     with jax.named_scope("indexer/select"):
         selected = jnp.sum(mask, dtype=jnp.int32).astype(_F32) / b
     return out, loss, {

@@ -334,6 +334,177 @@ def test_the_alignment_loss_and_its_gradient_against_autodiff():
     assert all(float(jnp.abs(g).max()) == 0 for g in others)
 
 
+def _plain_kl(q_i, k_i, w, chosen, q, k, lse):
+    """The alignment loss by its formula, for autodiff: ``pbar`` from the
+    core's own row statistic, a constant."""
+    group = q.shape[2] // k.shape[2]
+    f32 = lambda x: x.astype(jnp.float32)
+    total = jnp.einsum("btj,bjts->bts", f32(w), jax.nn.relu(
+        jnp.einsum("btjd,bsd->bjts", f32(q_i), f32(k_i))))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", f32(q), jnp.repeat(
+        f32(k), group, axis=2)) * q.shape[-1] ** -0.5
+    pbar = jnp.mean(jnp.where(chosen[:, None],
+                              jnp.exp(logits - lse[..., None]), 0.0), 1)
+    log_soft = jax.nn.log_softmax(jnp.where(chosen, total, -jnp.inf), -1)
+    cross = pbar * (jnp.log(jnp.where(pbar > 0, pbar, 1.0))
+                    - jnp.where(chosen, log_soft, 0.0))
+    return jnp.mean(jnp.sum(cross, -1)), jnp.mean(jnp.sum(pbar, -1))
+
+
+@pytest.mark.parametrize("case", [
+    dict(),                                   # 3 x 3 blocks of 16, group 2
+    dict(heads=8, kv_heads=1, index_heads=4),     # one group of 8
+    dict(heads=2, kv_heads=2),                    # groups of 1
+    dict(t=40),                     # padded rows and keys: nothing of them
+    dict(t=40, block_q=16, block_k=32),
+    dict(topk=64),                            # every causal key selected
+    dict(topk=1),                         # every row selects one key
+    dict(dtype="bfloat16"),
+    dict(dtype="bfloat16", heads=8, kv_heads=1, t=40),
+], ids=lambda case: "-".join(f"{k}{v}" for k, v in case.items()) or "plain")
+def test_the_loss_kernels_against_the_xla_pass_and_autodiff(case):
+    """``ops/indexer_loss.py`` under the Pallas interpreter: the loss,
+    ``pbar``'s mass and the three gradients equal the XLA pass's
+    (``_loss_pass``, which every CPU run and ``dsa-tiny`` keep) and
+    autodiff of the plain KL. Row 0 selects one key in every case (its
+    own: loss 0, gradient 0)."""
+    from pytorch_vit_paper_replication_tpu.ops import indexer_loss
+
+    case = dict(dict(t=T, heads=4, kv_heads=2, index_heads=2, topk=8,
+                     dtype="float32", block_q=16, block_k=16), **case)
+    t, dtype = case["t"], jnp.dtype(case["dtype"])
+    ks = jax.random.split(jax.random.key(11), 6)
+    cast = lambda x: x.astype(dtype)
+    q = cast(jax.random.normal(ks[0], (2, t, case["heads"], 16)))
+    k = cast(jax.random.normal(ks[1], (2, t, case["kv_heads"], 16)))
+    v = cast(jax.random.normal(ks[2], (2, t, case["kv_heads"], 16)))
+    q_i = cast(jax.random.normal(ks[3], (2, t, case["index_heads"], 8)))
+    k_i = cast(jax.random.normal(ks[4], (2, t, 8)))
+    w = jax.random.normal(ks[5], (2, t, case["index_heads"])) * 0.25
+    mask = sa.select(q_i, k_i, w, topk=case["topk"], chunk=8)
+    _, lse = sa.core(q, k, v, mask, impl="xla")
+
+    (loss, mass), got = indexer_loss.loss_pass(
+        q_i, k_i, w, mask, q, k, lse, True, block_q=case["block_q"],
+        block_k=case["block_k"], interpret=True)
+    alone = indexer_loss.loss_pass(
+        q_i, k_i, w, mask, q, k, lse, False, block_q=case["block_q"],
+        block_k=case["block_k"], interpret=True)
+    np.testing.assert_allclose(alone, (loss, mass), rtol=1e-6)
+    (xla_loss, xla_mass), xla = sa._loss_pass(q_i, k_i, w, mask, q, k, lse,
+                                              8, True)
+    (want, want_mass), by_autodiff = jax.value_and_grad(
+        _plain_kl, (0, 1, 2), has_aux=True)(q_i, k_i, w, mask != 0, q, k,
+                                            lse)
+    exact = dtype == jnp.float32
+    for other in (xla_loss, want):
+        np.testing.assert_allclose(loss, other, atol=1e-6,
+                                   rtol=1e-5 if exact else 2e-3)
+    np.testing.assert_allclose(mass, xla_mass, atol=1e-5)
+    np.testing.assert_allclose(mass, want_mass, atol=1e-5)
+    assert [g.dtype for g in got] == [g.dtype for g in xla]
+    for name, g, x, a in zip(("g_q", "g_k", "g_w"), got, xla, by_autodiff):
+        g, x, a = (np.asarray(y, np.float32) for y in (g, x, a))
+        # bf16: one rounding of d_act an element in both passes, the
+        # scores' products rounded in the XLA pass alone
+        for other in (x, a):
+            np.testing.assert_allclose(
+                g, other, err_msg=name,
+                atol=(1e-6 if exact else 0.03) * np.abs(a).max() + 1e-9)
+        # one key a row: softmax = pbar = 1, nothing to learn
+        assert (np.abs(g).max() > 1e-4) == (case["topk"] > 1), name
+    assert float(np.abs(np.asarray(got[0], np.float32)[:, 0]).max()) == 0
+    assert float(np.abs(np.asarray(got[2], np.float32)[:, 0]).max()) == 0
+
+
+def test_where_the_kernels_take_the_losss_pass(monkeypatch):
+    """The loss's pass goes where the core goes: the kernel pair where
+    :func:`attention.choose` gives the core to the flash kernels, at whole
+    lane blocks when compiled for the chip and on one device; the XLA
+    pass everywhere else."""
+    from pytorch_vit_paper_replication_tpu.ops import indexer_loss, partition
+
+    cell = ((1, 16384, 32, 128), (1, 16384, 16, 64))
+    small = ((2, 48, 4, 16), (2, 48, 2, 8))
+    assert indexer_loss.serves("flash", *small)       # the interpreter
+    with monkeypatch.context() as on_the_chip:
+        on_the_chip.setattr(jax, "default_backend", lambda: "tpu")
+        assert indexer_loss.serves("flash", *cell)
+        assert not indexer_loss.serves("xla", *cell)
+        # head size 64, or indexer heads that fill no lane block: XLA
+        assert not indexer_loss.serves("flash", *small)
+        assert not indexer_loss.serves("flash", (1, 512, 8, 64), cell[1])
+        assert not indexer_loss.serves("flash", cell[0], (1, 512, 3, 48))
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
+        with partition.on_mesh(mesh):
+            assert not indexer_loss.serves("flash", *cell)
+    # the CPU's verdict for the tiny preset, and a forced one
+    from pytorch_vit_paper_replication_tpu.ops import attention
+    q, k, _ = _core_inputs()
+    served = lambda impl: attention.choose(
+        q.shape, q.dtype, k.shape, impl=impl, kind="causal_topk")[0]
+    assert not indexer_loss.serves(served("auto"), q.shape, (2, T, 2, 8))
+    assert indexer_loss.serves(served("flash"), q.shape, (2, T, 2, 8))
+
+
+def test_the_forced_flash_path_is_the_kernels_and_trains_the_indexer():
+    """``impl="flash"`` through :func:`sparse_attention.sparse_attention`
+    (the interpreter here): the loss and the gradient that reaches the
+    indexer equal the XLA path's."""
+    q, k, v = _core_inputs()
+    q_i, k_i, w = _scores(2)
+
+    def objective(q_i, k_i, w, impl):
+        _, loss, stats = sa.sparse_attention(q, k, v, q_i, k_i, w, topk=8,
+                                             chunk=16, impl=impl)
+        return loss, stats["pbar_mass"]
+
+    (a, mass_a), ga = jax.value_and_grad(objective, (0, 1, 2), has_aux=True)(
+        q_i, k_i, w, "xla")
+    (b, mass_b), gb = jax.value_and_grad(objective, (0, 1, 2), has_aux=True)(
+        q_i, k_i, w, "flash")
+    np.testing.assert_allclose(a, b, rtol=1e-5)
+    np.testing.assert_allclose(mass_a, mass_b, atol=1e-5)
+    for x, y in zip(ga, gb):
+        np.testing.assert_allclose(x, y, atol=1e-6)
+    calls = str(jax.make_jaxpr(jax.grad(
+        lambda *a: objective(*a, "flash")[0], (0, 1, 2)))(q_i, k_i, w))
+    for kernel in ("flash_fwd", "indexer_loss_fwd", "indexer_loss_bwd"):
+        assert f"name={kernel}" in calls, kernel
+
+
+@pytest.mark.parametrize("cut_input", [False, True])
+def test_a_tied_products_gradients_are_the_plain_products(cut_input):
+    """The product an indexed block's projections take
+    (``models/vit.py::_tied_product``: the input's gradient leaves with
+    the kernel's, an order and not a value): the result and the kernel's
+    gradient are ``lax.dot_general``'s, the input's too, or zeros where
+    the product reads its input as a constant; ``jax.checkpoint`` takes
+    it again as it takes the plain one."""
+    from pytorch_vit_paper_replication_tpu.models.vit import _tied_product
+
+    x = jax.random.normal(jax.random.key(0), (2, 5, 8))
+    w = jax.random.normal(jax.random.key(1), (8, 3, 4))
+    dims = (((2,), (0,)), ((), ()))
+
+    def plain(x, w):
+        x = jax.lax.stop_gradient(x) if cut_input else x
+        return jnp.sum(jnp.sin(jax.lax.dot_general(x, w, dims)))
+
+    def tied(x, w):
+        return jnp.sum(jnp.sin(_tied_product(x, w, dims,
+                                             cut_input=cut_input)))
+
+    want, (want_x, want_w) = jax.value_and_grad(plain, (0, 1))(x, w)
+    for program in (tied, jax.checkpoint(tied)):
+        got, (got_x, got_w) = jax.jit(jax.value_and_grad(program, (0, 1)))(
+            x, w)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        np.testing.assert_allclose(got_w, want_w, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(got_x, want_x, rtol=1e-6, atol=1e-6)
+    assert bool(jnp.all(want_x == 0)) == cut_input
+
+
 def test_dot_product_attention_refuses_a_selection_without_an_indexer():
     from pytorch_vit_paper_replication_tpu.ops import attention
     with pytest.raises(ValueError, match="unknown attention kind"):

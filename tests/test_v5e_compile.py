@@ -484,7 +484,9 @@ def test_latent_attention_hands_the_kernels_q_k_v_where_they_read_them(
 # The cell ``keye2_train_16k``'s step held 12.15 GiB when PR 34 compiled
 # it (9.82 of them parameters, gradients and moments). Kept as one int8
 # ``[T, T]`` a layer the selection alone adds 1.5 GiB, and the step did
-# not fit at all before it was kept a bit a pair (17.1 GiB).
+# not fit at all before it was kept a bit a pair (17.1 GiB). Since PR 35
+# (the alignment loss's pass as two kernels, the loss tied to the block's
+# result and a block's weight gradients to its input's) it holds 11.74.
 DSA_STEP_GIB = 12.6
 
 
@@ -506,16 +508,17 @@ def test_indexed_attention_models_step_compiles_and_fits_one_chip(dsa_step):
     """One 16,384-token sequence through 6 of Keye-VL-2.0-30B-A3B's
     layers at every published width. Every layer names the flash kernels
     once each way, reading the selection as an int8 strip a query block
-    (Mosaic takes it; the forward holds k, v and the strip in VMEM), and
-    the grouped products of its 16 held experts; the blocks take their
-    projections again in the backward pass and neither the selection,
-    nor the core's forward, nor the loss's pass. It compiles for the v5e
-    and fits 15.75 GiB with room; every new scope is in the compiled
-    step."""
+    (Mosaic takes it; the forward holds k, v and the strip in VMEM), the
+    alignment loss's two kernels (PR 35) and the grouped products of its
+    16 held experts; the blocks take their projections again in the
+    backward pass and neither the selection, nor the core's forward, nor
+    the loss's pass. It compiles for the v5e and fits 15.75 GiB with
+    room; every new scope is in the compiled step."""
     lowered, compiled = dsa_step
     names = [name for name, _ in mosaic_calls(lowered.as_text())]
     assert {n: names.count(n) for n in set(names)} == {
         "flash_fwd": 6, "flash_bwd": 6,
+        "indexer_loss_fwd": 6, "indexer_loss_bwd": 6,
         "moe_gmm_fwd": 18, "moe_gmm_dx": 12, "moe_gmm_dw": 12}
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes
@@ -545,6 +548,32 @@ def test_indexed_attention_models_step_compiles_and_fits_one_chip(dsa_step):
     assert re.search(r"u8\[16384,2048\]", hlo)
     assert not re.search(r"= [su]32\[1,16384,16384\]\S* (?:convert|copy)\(",
                          hlo)
+
+
+def test_the_alignment_losss_pass_leaves_no_chunk_of_rows_in_hbm(dsa_step):
+    """The loss's pass is two kernels over the causal blocks (PR 35):
+    under ``/msa/indexer_loss/`` the compiled step holds neither the
+    head-mean probabilities' logits ``f32[1,8,512,16384]`` nor an array
+    of the indexer's heads ``[1,16,512,16384]`` (PR 34's step held both,
+    a chunk of 512 query rows over every key), the selection's own take
+    of the scores is where it was, and the step still fits."""
+    _, compiled = dsa_step
+    hlo = compiled.as_text()
+    of_the_loss = [line for line in hlo.splitlines()
+                   if "/msa/indexer_loss/" in line]
+    assert of_the_loss
+    wide = [line for line in of_the_loss if re.search(
+        r"f32\[1,8,512,16384\]|\w+\[1,16,512,16384\]", line)]
+    assert not wide, (len(wide), wide[0][:300])
+    assert sum("/msa/indexer_loss/indexer_loss_fwd" in line
+               or "/msa/indexer_loss/indexer_loss_bwd" in line
+               for line in of_the_loss) >= 12
+    assert "/indexer/scores/" in hlo
+    assert not any("/indexer/scores/" in line for line in of_the_loss)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert held < DSA_STEP_GIB * 2**30, held / 2**30
 
 
 def test_flash_kernels_compile_with_a_selection_a_row(v5e_2x2):
