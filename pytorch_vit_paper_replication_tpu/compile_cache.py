@@ -31,10 +31,26 @@ wiring it:
   program's ``fun_name`` and on the process's clock, so that
   ``time_to_first_step`` comes with its split
   (:meth:`CacheStats.stage_seconds`, ``snapshot()["programs"]``).
+  Since PR 36 a function traced inside a program's trace (a jitted
+  ``jnp`` helper: thousands in one token-model step) is a ``folded``
+  count on that program's row and no event of its own, so what is kept
+  is one event a stage for each top-level program and a long run cannot
+  push the set-up's rows out; the bound that remains counts what it
+  drops (``dropped``, ``compile_stage_events_dropped_total``).
 * :func:`seconds_since_process_start` — the denominator for the
   ``time_to_first_step`` / ``time_to_first_batch`` run-log fields
   (honest restart latency includes interpreter + import + backend init,
-  not just the compile the caller happens to time).
+  not just the compile the caller happens to time): ``CLOCK_BOOTTIME``
+  less the process's start ticks, so it counts from the same instant,
+  to the tick, as ``benchmark/lib/clock.py``.
+* :data:`STARTUP_STAGES` — ``imports``, ``mesh``, ``state``,
+  ``first_step``: the four stages of a trainer's start on that clock,
+  each closed once a process by the function where its work ends
+  (:func:`closes_startup_stage`, ``engine.train``'s first-step barrier)
+  and beginning where the one before it ended
+  (``snapshot()["startup"]``, gauges ``startup_<stage>_seconds``, the
+  trainer's ``[startup]`` line). ``time_to_first_step`` is the end of
+  ``first_step``.
 * :func:`warn_if_uncached` — one warning per process when a library
   caller reaches inference on a non-CPU backend without having called
   :func:`configure`; silent multi-minute warmups were the failure mode.
@@ -48,6 +64,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -85,40 +102,48 @@ _STAGES = {
         ("cache_read", "compile_cache_read_seconds_total"),
 }
 STAGE_NAMES = tuple(stage for stage, _ in _STAGES.values())
-# Bounds: a serve process compiles for as long as it lives.
-MAX_STAGE_EVENTS = 8192
+# Bounds: a serve process compiles for as long as it lives. What is kept
+# is one event a stage for each top-level program (tens in a training
+# run); the bound also has to hold the helpers of the one trace that is
+# open, which are kept until the event that encloses them arrives (some
+# thousands in a token model's step).
+MAX_STAGE_EVENTS = 65536
+# The stages of a trainer's start, in order, each with its registry
+# gauge: who closes each is in the module docstring.
+STARTUP_STAGES = {
+    "imports": "startup_imports_seconds",
+    "mesh": "startup_mesh_seconds",
+    "state": "startup_state_seconds",
+    "first_step": "startup_first_step_seconds",
+}
 
-_IMPORT_WALL_TIME = time.time()
 
-
-def _process_start_unix() -> float:
-    """Wall-clock time this PROCESS started (not this module's import).
+def _process_clock():
+    """``(clock, its reading when this PROCESS started)``.
 
     Linux: field 22 of /proc/self/stat is the start time in clock ticks
-    since boot; boot time is `btime` in /proc/stat. Falls back to this
-    module's import time elsewhere — a lower bound, clearly documented.
+    since boot, which is where ``CLOCK_BOOTTIME`` counts from. Elsewhere
+    the monotonic clock from this module's import — a lower bound,
+    clearly documented.
     """
     try:
         stat = Path("/proc/self/stat").read_text()
         # comm (field 2) may contain spaces/parens; split after the
         # closing paren. starttime is field 22 → index 19 post-comm.
         ticks = float(stat.rsplit(")", 1)[1].split()[19])
-        hz = os.sysconf("SC_CLK_TCK")
-        btime = next(
-            float(line.split()[1])
-            for line in Path("/proc/stat").read_text().splitlines()
-            if line.startswith("btime "))
-        return btime + ticks / hz
+        clock = functools.partial(time.clock_gettime, time.CLOCK_BOOTTIME)
+        clock()
+        return clock, ticks / os.sysconf("SC_CLK_TCK")
     except Exception:  # noqa: BLE001 — non-Linux / hardened /proc
-        return _IMPORT_WALL_TIME
+        return time.monotonic, time.monotonic()
 
 
-_PROCESS_START_UNIX = _process_start_unix()
+_CLOCK, _PROCESS_START = _process_clock()
 
 
 def seconds_since_process_start() -> float:
     """Seconds since the interpreter started — the time-to-first-X base."""
-    return time.time() - _PROCESS_START_UNIX
+    return _CLOCK() - _PROCESS_START
 
 
 class CacheStats:
@@ -130,10 +155,16 @@ class CacheStats:
         self.hits = 0
         self.saved_secs = 0.0
         self.cache_dir: Optional[str] = None
-        # (stage, fun_name, seconds, end: seconds since process start)
-        self._events: collections.deque = collections.deque(
-            maxlen=MAX_STAGE_EVENTS)
+        # Per thread, in order of arrival: (stage, fun_name, seconds,
+        # end: seconds since process start, folded: events that ended
+        # inside this one's interval and were dropped for it).
+        self._events: Dict[int, collections.deque] = \
+            collections.defaultdict(collections.deque)
+        self._kept_count = 0
+        self.dropped = 0
         self._unnamed_read = threading.local()
+        # (name, begin_s, end_s, own_s) of the start-up stages closed.
+        self._startup: list = []
 
     @property
     def misses(self) -> int:
@@ -175,7 +206,15 @@ class CacheStats:
         """Keep one stage of one program's first call. ``trace`` names
         the function bare and ``lower``/``backend`` as ``jit(<name>)``:
         one key for both. A cache read has no name until the
-        ``backend`` event that follows it on the same thread."""
+        ``backend`` event that follows it on the same thread.
+
+        jax reports a stage when it ends, so what ran inside it (the
+        jitted helpers a trace calls, each with a trace event of its
+        own) is already kept when the event arrives: this thread's
+        newest events that began after this one did. They are popped
+        and become its ``folded`` count, their seconds being part of
+        its own. Each event is popped at most once, and two threads'
+        events never meet."""
         name = fun_name or "(unnamed)"
         if name.startswith("jit(") and name.endswith(")"):
             name = name[4:-1]
@@ -184,43 +223,68 @@ class CacheStats:
             self._unnamed_read.event = (seconds, end)
             return
         read = getattr(self._unnamed_read, "event", None)
+        begin, folded, dropped = end - seconds, 0, 0
         with self._lock:
+            mine = self._events[threading.get_ident()]
+            before = len(mine)
+            while mine and mine[-1][3] - mine[-1][2] >= begin:
+                folded += 1 + mine.pop()[4]
             if stage == "backend" and read is not None:
                 self._unnamed_read.event = None
-                self._events.append(("cache_read", name, *read))
-            self._events.append((stage, name, seconds, end))
+                mine.append(("cache_read", name, *read, 0))
+            mine.append((stage, name, seconds, end, folded))
+            self._kept_count += len(mine) - before
+            while self._kept_count > MAX_STAGE_EVENTS:
+                tid = min((t for t, d in self._events.items() if d),
+                          key=lambda t: self._events[t][0][3])
+                self._events[tid].popleft()
+                if not self._events[tid]:
+                    del self._events[tid]
+                self._kept_count -= 1
+                dropped += 1
+            self.dropped += dropped
+        if dropped:
+            from .telemetry.registry import get_registry
+            get_registry().count("compile_stage_events_dropped_total",
+                                 dropped)
 
     def _kept(self, until_s: Optional[float]) -> list:
         with self._lock:
-            events = list(self._events)
-        return [e for e in events if until_s is None or e[3] <= until_s]
+            events = [e for d in self._events.values() for e in d]
+        return sorted((e for e in events
+                       if until_s is None or e[3] <= until_s),
+                      key=lambda e: e[3])
 
     def stage_seconds(self, until_s: Optional[float] = None
                       ) -> Dict[str, float]:
         """Seconds this process has spent in each stage, over the kept
         events that ended before ``until_s`` (seconds since process
         start; None = all). The union of the events' intervals, not
-        their sum: a function traced while another is being traced (a
-        jitted ``jnp`` helper inside the step) reports its own event
-        inside the outer one's, and counts once."""
+        their sum: a function traced while another is being traced is
+        folded into it, and where two threads trace at once each
+        instant still counts once."""
         from .telemetry.device_trace import covered
 
         spans: Dict[str, list] = {stage: [] for stage in STAGE_NAMES}
-        for stage, _, seconds, end in self._kept(until_s):
+        for stage, _, seconds, end, _ in self._kept(until_s):
             spans[stage].append((end - seconds, end))
         return {stage: covered(ivs) for stage, ivs in spans.items()}
 
     def programs(self, until_s: Optional[float] = None
                  ) -> Dict[str, Dict[str, float]]:
-        """Per ``fun_name``: ``count`` (first calls: backend events) and
-        the seconds of each stage, over the same events as
-        :meth:`stage_seconds` (here plain sums: a program's own cost)."""
+        """Per ``fun_name`` of a top-level program: ``count`` (first
+        calls: backend events), the seconds of each stage, over the
+        same events as :meth:`stage_seconds` (here plain sums: a
+        program's own cost), and ``folded``: the events of what ran
+        inside its stages (a trace's jitted helpers), which have no row
+        of their own."""
         out: Dict[str, Dict[str, float]] = {}
-        for stage, name, seconds, _ in self._kept(until_s):
-            row = out.setdefault(
-                name, {"count": 0, **dict.fromkeys(STAGE_NAMES, 0.0)})
+        for stage, name, seconds, _, folded in self._kept(until_s):
+            row = out.setdefault(name, {
+                "count": 0, **dict.fromkeys(STAGE_NAMES, 0.0), "folded": 0})
             row[stage] += seconds
             row["count"] += stage == "backend"
+            row["folded"] += folded
         return out
 
     def programs_line(self, until_s: Optional[float] = None,
@@ -233,12 +297,49 @@ class CacheStats:
                       key=lambda kv: -sum(kv[1][s] for s in cost))
         shown = [f"{name} x{r['count']} trace {r['trace']:.2f} lower "
                  f"{r['lower']:.2f} backend {r['backend']:.2f} (cache "
-                 f"read {r['cache_read']:.2f})" for name, r in rows[:top]]
+                 f"read {r['cache_read']:.2f}; {r['folded']} folded)"
+                 for name, r in rows[:top]]
         if rows[top:]:
             shown.append(f"{len(rows) - top} others " + " ".join(
                 f"{s} {sum(r[s] for _, r in rows[top:]):.2f}"
                 for s in cost))
-        return "; ".join(shown)
+        line = "; ".join(shown)
+        if self.dropped:
+            line += f" ({self.dropped} earlier events dropped)"
+        return line
+
+    def close_stage(self, name: str, entered_s: float) -> float:
+        """Close start-up stage ``name`` now, and return now. It began
+        where the last closed stage ended (0 for the first), and
+        ``entered_s`` is when the function that closes it was entered:
+        ``own_s`` tells the time inside that function from the time
+        before it (a caller's: the benchmark's harness). Once a process
+        and in the order of :data:`STARTUP_STAGES`: a stage that is
+        closed, or comes before one that is, stays as it is."""
+        order = list(STARTUP_STAGES)
+        end = seconds_since_process_start()
+        with self._lock:
+            last = self._startup[-1] if self._startup else None
+            if last and order.index(name) <= order.index(last[0]):
+                return end
+            begin = last[2] if last else 0.0
+            self._startup.append((name, begin, end, end - entered_s))
+        from .telemetry.registry import get_registry
+        get_registry().gauge(STARTUP_STAGES[name], round(end - begin, 3))
+        return end
+
+    def startup(self) -> Dict[str, Dict[str, float]]:
+        """The start-up stages closed so far, in order."""
+        with self._lock:
+            stages = list(self._startup)
+        return {name: {"begin_s": begin, "end_s": end,
+                       "seconds": end - begin, "own_s": own}
+                for name, begin, end, own in stages}
+
+    def startup_line(self) -> str:
+        """:meth:`startup` on one line: the trainer's ``[startup]``."""
+        return ", ".join(f"{name} {s['seconds']:.2f} (own {s['own_s']:.2f})"
+                         for name, s in self.startup().items())
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
@@ -248,14 +349,30 @@ class CacheStats:
                 "hits": self.hits,
                 "misses": self.requests - self.hits,
                 "compile_time_saved_s": round(self.saved_secs, 3),
+                "dropped": self.dropped,
             }
         snap["programs"] = self.programs()
+        snap["startup"] = self.startup()
         return snap
 
 
 STATS = CacheStats()
 _listeners_installed = False
 _warned_uncached = False
+
+
+def closes_startup_stage(name: str):
+    """Decorator: the function's return closes start-up stage ``name``
+    (:meth:`CacheStats.close_stage`; a call that raises closes nothing)."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def closing(*args, **kwargs):
+            entered = seconds_since_process_start()
+            out = fn(*args, **kwargs)
+            STATS.close_stage(name, entered)
+            return out
+        return closing
+    return decorate
 
 
 def _install_listeners() -> None:
@@ -371,6 +488,7 @@ def _install_atomic_cache_writes() -> None:
         LRUCache.put = atomic_put
 
 
+@closes_startup_stage("imports")
 def configure(cache_dir: Optional[str] = None) -> Optional[Path]:
     """Turn jax's persistent compilation cache on and say where it is.
 
@@ -379,7 +497,9 @@ def configure(cache_dir: Optional[str] = None) -> Optional[Path]:
     code path sets another directory. Unset: ``cache_dir`` (the
     ``--compile-cache-dir`` flag) or :data:`DEFAULT_CACHE_DIR`.
 
-    Returns the directory in force (also ``STATS.cache_dir``).
+    Returns the directory in force (also ``STATS.cache_dir``). Every
+    entry point's first call into the program: its return closes the
+    start-up stage ``imports``.
 
     The min-compile-time/entry-size thresholds are zeroed: jax's default
     of 1 s would silently skip every sub-second compile — exactly the

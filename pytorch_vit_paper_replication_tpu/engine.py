@@ -402,8 +402,9 @@ def evaluate(
 def _report_first_step(train_step, state, batch, stats) -> None:
     """Once per process, after the first step's barrier: what the device
     boundary actually did, printed instead of trusted. The persistent
-    cache's hit/miss counts and what the first calls cost by stage (the
-    split of ``time_to_first_step``); on a TPU the Mosaic calls found in the
+    cache's hit/miss counts, the start-up's stages and what the first
+    calls cost by stage (two splits of ``time_to_first_step``: by where
+    the process was, and by program); on a TPU the Mosaic calls found in the
     lowered step, by kernel name with the per-shard operand's shape (the
     MLP kernels' rows, the attention pair's packed qkv projection; the
     kernel dispatch reads ``jax.default_backend()`` and the call's shapes
@@ -415,6 +416,10 @@ def _report_first_step(train_step, state, batch, stats) -> None:
     cache = stats.snapshot()
     lines = [f"compile cache: {cache['hits']} hits, {cache['misses']} "
              f"misses ({cache['cache_dir']})"]
+    # Process start -> here, by stage: each stage's seconds, and of them
+    # the seconds inside the call that closed it.
+    lines.append(f"[startup] seconds by stage (own: inside the call "
+                 f"that closed it): {stats.startup_line()}")
     # What the first calls cost, by stage, costliest program first (the
     # train step), then every other program's together.
     lines.append("[programs] seconds in first calls (backend holds the "
@@ -534,6 +539,7 @@ def train(
     from .compile_cache import STATS as cache_stats
     from .compile_cache import seconds_since_process_start
 
+    entered = seconds_since_process_start()
     global_step = int(jax.device_get(state.step))
     time_to_first_step = None
 
@@ -584,7 +590,8 @@ def train(
                 # vitlint: hot-path-ok(one-off time-to-first-step barrier, first step only)
                 jax.block_until_ready(metrics["loss_sum"])
                 blocked = True
-                time_to_first_step = seconds_since_process_start()
+                time_to_first_step = cache_stats.close_stage(
+                    "first_step", entered)
                 if telemetry is not None:
                     telemetry.first_step(train_step, state, batch)
                 if verbose:
@@ -692,6 +699,8 @@ def train(
                 # vocabulary).
                 extra["time_to_first_step"] = round(time_to_first_step, 3)
                 cache = cache_stats.snapshot()
+                for name, stage in cache["startup"].items():
+                    extra[f"startup_{name}_s"] = round(stage["seconds"], 3)
                 if cache["requests"]:
                     extra["compile_cache_hits"] = cache["hits"]
                     extra["compile_cache_misses"] = cache["misses"]

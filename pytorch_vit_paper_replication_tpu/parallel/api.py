@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..compile_cache import closes_startup_stage
 from ..engine import TrainState, make_eval_step, make_train_step
 from ..ops.partition import traced_on_mesh
 from .sharding import pspec_for_path, shard_tree
@@ -59,6 +60,7 @@ def shard_batch(batch: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
     return {k: jax.device_put(v, sh) for k, v in batch.items()}
 
 
+@closes_startup_stage("state")
 def make_parallel_train_step(state: TrainState, mesh: Mesh, *,
                              label_smoothing: float = 0.0,
                              nan_guard: bool = False,
@@ -73,7 +75,9 @@ def make_parallel_train_step(state: TrainState, mesh: Mesh, *,
     picks the sequence-parallel strategy on seq>1 meshes ("ring" or
     "ulysses" — parallel/ulysses.py's table). ``distill_alpha``/
     ``distill_t`` select the knowledge-distillation objective
-    (:func:`..engine.distill_loss`).
+    (:func:`..engine.distill_loss`). Its return closes the start-up
+    stage ``state``: the state is made and laid out before this call,
+    and the step is built here.
     """
     step = make_train_step(label_smoothing, nan_guard=nan_guard,
                            distill_alpha=distill_alpha,

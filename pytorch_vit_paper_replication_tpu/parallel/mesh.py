@@ -23,14 +23,20 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..compile_cache import closes_startup_stage
 from ..configs import MeshConfig
 
 AXES = ("data", "model", "seq", "pipe")
 
 
+@closes_startup_stage("mesh")
 def make_mesh(config: Optional[MeshConfig] = None,
               devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
-    """Build the ('data','model','seq','pipe') mesh over the given devices."""
+    """Build the ('data','model','seq','pipe') mesh over the given devices.
+
+    Its return closes the start-up stage ``mesh`` (reaching the chip:
+    ``jax.devices()`` initialises the backend, here or in the caller
+    just before)."""
     config = config or MeshConfig()
     devices = list(devices) if devices is not None else jax.devices()
     shape = config.axis_sizes(len(devices))

@@ -130,6 +130,16 @@ INSTRUMENTS: Dict[str, str] = {
     "compile_lower_seconds_total": "counter",
     "compile_backend_seconds_total": "counter",
     "compile_cache_read_seconds_total": "counter",
+    # Kept stage events the bound pushed out (0 in a training run: what
+    # is kept is one event a stage for each top-level program).
+    "compile_stage_events_dropped_total": "counter",
+    # The stages of a trainer's start, seconds each (CacheStats.
+    # close_stage): process start -> configure() -> make_mesh() ->
+    # make_parallel_train_step() -> the first step applied.
+    "startup_imports_seconds": "gauge",
+    "startup_mesh_seconds": "gauge",
+    "startup_state_seconds": "gauge",
+    "startup_first_step_seconds": "gauge",
     # Serving fleet (serve/fleet/): the router's routing/admission
     # instruments, the rolling checkpoint hot-swap, and replica
     # membership. Per-replica replica_up_<rid> gauges are published
@@ -372,6 +382,20 @@ HELP_TEXT: Dict[str, str] = {
                                      "deserialise on a hit",
     "compile_cache_read_seconds_total": "Seconds reading persistent-"
                                         "cache entries (part of backend)",
+    "compile_stage_events_dropped_total": "First-call stage events the "
+                                          "record's bound pushed out "
+                                          "(oldest first)",
+    "startup_imports_seconds": "Process start to compile_cache."
+                               "configure() returning: interpreter, "
+                               "imports, argument parsing",
+    "startup_mesh_seconds": "From there to make_mesh() returning: the "
+                            "backend's initialisation, the device mesh",
+    "startup_state_seconds": "From there to make_parallel_train_step() "
+                             "returning: state made and laid out, step "
+                             "built",
+    "startup_first_step_seconds": "From there to the first train step "
+                                  "applied: first batch, trace, lower, "
+                                  "compile or cache read, execution",
     "profiler_last_step_device_ms": "Device ms per step in the last "
                                     "closed capture",
     "profiler_last_idle_pct": "Device idle share of the last closed "

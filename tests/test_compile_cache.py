@@ -132,7 +132,24 @@ def test_seconds_since_process_start_positive_and_monotonic():
 
 
 # ------------------------------ what a program's first call cost (PR 24)
-def test_first_call_leaves_its_stages_under_the_programs_name():
+@pytest.fixture
+def no_persistent_cache():
+    """No cache directory for this test, whatever an earlier test of the
+    same process (or the checkout's own ``.jax_compile_cache/``) left in
+    force: a first call here is a compile, never a read."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_first_call_leaves_its_stages_under_the_programs_name(
+        no_persistent_cache):
     import jax
     import jax.numpy as jnp
 
@@ -183,15 +200,93 @@ def test_stage_seconds_counts_nested_traces_once_and_stops_at_until_s(
         "trace": 3.0, "lower": 1.0, "backend": 2.0, "cache_read": 1.5}
     everything = stats.stage_seconds()
     assert everything["trace"] == 7.0 and everything["backend"] == 11.0
+    # the helper is a count on the row of the program whose trace it ran
+    # inside, and no row: its seconds are part of that trace's
     programs = stats.programs(until_s=20.0)
-    assert set(programs) == {"multiply", "train_step"}
+    assert set(programs) == {"train_step"}
     assert programs["train_step"] == {"count": 1, "trace": 3.0,
                                       "lower": 1.0, "backend": 2.0,
-                                      "cache_read": 1.5}
+                                      "cache_read": 1.5, "folded": 1}
     assert stats.programs()["eval_step"]["backend"] == 9.0
+    assert len(stats._kept(None)) == 7, "one event a stage a program"
     line = stats.programs_line(until_s=20.0, top=1)
-    assert line.startswith("train_step x1 trace 3.00 lower 1.00 backend "
-                           "2.00 (cache read 1.50); 1 others trace 0.50")
+    assert line == ("train_step x1 trace 3.00 lower 1.00 backend 2.00 "
+                    "(cache read 1.50; 1 folded)")
+    assert stats.programs_line(top=1).endswith(   # costliest first
+        "; 1 others trace 3.00 lower 1.00 backend 2.00")
+
+
+def _a_program(name, begin, helpers=0):
+    """The events of one program's first call from ``begin`` on: a trace
+    of 4 s with ``helpers`` helper traces inside it, each reported
+    before the trace that encloses it as jax reports them, then 1 s of
+    lowering and 2 s in the backend."""
+    inside = [("trace", "multiply", 1e-4, begin + (i + 1) * 1e-4)
+              for i in range(helpers)]
+    return inside + [("trace", name, 4.0, begin + 4.0),
+                     ("lower", f"jit({name})", 1.0, begin + 5.0),
+                     ("backend", f"jit({name})", 2.0, begin + 7.0)]
+
+
+def test_a_long_run_does_not_push_the_set_ups_stages_out(monkeypatch):
+    """PR 36: the ring of 8,192 events turned over on the helpers of the
+    later traces (4,955 in one gradient of a token model) and the
+    set-up's events, the oldest, were the ones dropped; the three set-up
+    metrics then read 0.0 (ledger, PR 35, ``keye2_train_16k``)."""
+    stats = _stats_with(monkeypatch, [
+        *_a_program("make_state", 10.0, helpers=300),
+        *_a_program("train_step", 20.0, helpers=20_000),
+        *_a_program("reference", 100.0, helpers=20_000),     # after the
+        *_a_program("eval_forward", 200.0, helpers=20_000)])  # window
+    assert stats.dropped == 0
+    assert len(stats._kept(None)) == 12
+    assert stats.stage_seconds(until_s=30.0) == {
+        "trace": 8.0, "lower": 2.0, "backend": 4.0, "cache_read": 0.0}
+    rows = stats.programs(until_s=30.0)
+    assert set(rows) == {"make_state", "train_step"}
+    assert rows["make_state"]["folded"] == 300
+    assert rows["train_step"] == {"count": 1, "trace": 4.0, "lower": 1.0,
+                                  "backend": 2.0, "cache_read": 0.0,
+                                  "folded": 20_000}
+    assert "train_step x1 trace 4.00 lower 1.00 backend 2.00" in \
+        stats.programs_line(until_s=30.0)
+    assert "dropped" not in stats.programs_line()
+
+
+def test_a_helper_of_a_helper_is_folded_with_it(monkeypatch):
+    stats = _stats_with(monkeypatch, [
+        ("trace", "square", 0.1, 10.3),        # inside norm's trace
+        ("trace", "norm", 0.5, 10.6),          # inside step's trace
+        ("trace", "add", 0.1, 10.9),
+        ("trace", "step", 2.0, 12.0)])
+    assert stats.programs() == {"step": {
+        "count": 0, "trace": 2.0, "lower": 0.0, "backend": 0.0,
+        "cache_read": 0.0, "folded": 3}}
+
+
+def test_two_threads_events_are_not_folded_together(monkeypatch):
+    """A warm-up thread's program that ends inside the interval of the
+    main thread's trace did not run inside that trace: both stay, and
+    the seconds they share count once."""
+    import threading
+
+    stats = compile_cache.CacheStats()
+
+    def feed(stage, name, seconds, end):
+        monkeypatch.setattr(compile_cache, "seconds_since_process_start",
+                            lambda: end)
+        stats._on_stage(stage, name, seconds)
+
+    feed("trace", "multiply", 0.5, 11.0)
+    other = threading.Thread(
+        target=feed, args=("trace", "rung_8", 1.0, 11.5))
+    other.start()
+    other.join()
+    feed("trace", "train_step", 3.0, 12.0)        # 9.0 .. 12.0
+    rows = stats.programs()
+    assert set(rows) == {"rung_8", "train_step"}
+    assert rows["train_step"]["folded"] == 1 and rows["rung_8"]["folded"] == 0
+    assert stats.stage_seconds()["trace"] == 3.0
 
 
 def test_kept_stage_events_are_bounded(monkeypatch):
@@ -201,6 +296,202 @@ def test_kept_stage_events_are_bounded(monkeypatch):
     assert len(stats._kept(None)) == 16
     assert stats.stage_seconds()["backend"] == 16.0
     assert set(stats.programs()) == {f"f{i}" for i in range(24, 40)}
+
+
+def test_a_drop_past_the_bound_is_counted_and_shown(monkeypatch):
+    from pytorch_vit_paper_replication_tpu.telemetry import (HELP_TEXT,
+                                                             INSTRUMENTS,
+                                                             get_registry)
+
+    name = "compile_stage_events_dropped_total"
+    assert INSTRUMENTS[name] == "counter" and name in HELP_TEXT
+    before = get_registry().snapshot()["counters"].get(name, 0)
+    monkeypatch.setattr(compile_cache, "MAX_STAGE_EVENTS", 16)
+    stats = _stats_with(monkeypatch, [
+        ("backend", f"jit(f{i})", 1.0, float(i)) for i in range(16)])
+    assert stats.dropped == 0 and stats.snapshot()["dropped"] == 0
+    assert "dropped" not in stats.programs_line()
+    for i in range(16, 40):
+        monkeypatch.setattr(compile_cache, "seconds_since_process_start",
+                            lambda i=i: float(i))
+        stats._on_stage("backend", f"jit(f{i})", 1.0)
+    assert stats.dropped == 24 and stats.snapshot()["dropped"] == 24
+    assert stats.programs_line().endswith(" (24 earlier events dropped)")
+    assert get_registry().snapshot()["counters"][name] == before + 24
+    # the oldest go first, whichever thread reported them
+    assert min(e[3] for e in stats._kept(None)) == 24.0
+
+
+# ------------------------------- the start-up's four stages (PR 36)
+def test_the_programs_clock_is_the_benchmarks_to_the_tick():
+    """Both count ``CLOCK_BOOTTIME`` from the process's start ticks: a
+    stage's end and the benchmark's ``setup_s`` are instants of one
+    clock (it was ``btime``, in whole seconds, before PR 36)."""
+    from benchmark.lib import clock
+
+    a = compile_cache.seconds_since_process_start()
+    b = clock.since_process_start()
+    c = compile_cache.seconds_since_process_start()
+    assert a <= b <= c and c - a < 0.05
+
+
+def test_startup_stages_close_once_and_in_order(monkeypatch):
+    from pytorch_vit_paper_replication_tpu.telemetry import (HELP_TEXT,
+                                                             INSTRUMENTS,
+                                                             get_registry)
+
+    assert list(compile_cache.STARTUP_STAGES) == [
+        "imports", "mesh", "state", "first_step"]
+    for gauge in compile_cache.STARTUP_STAGES.values():
+        assert INSTRUMENTS[gauge] == "gauge" and gauge in HELP_TEXT
+    stats = compile_cache.CacheStats()
+    monkeypatch.setattr(compile_cache, "STATS", stats)
+    now = [0.0]
+    monkeypatch.setattr(compile_cache, "seconds_since_process_start",
+                        lambda: now[0])
+
+    def close(name, at, entered):
+        now[0] = at
+        return stats.close_stage(name, entered)
+
+    assert stats.startup() == {} and stats.startup_line() == ""
+    assert close("imports", 6.0, 5.9) == 6.0
+    assert close("imports", 7.0, 6.5) == 7.0, "now, and nothing kept"
+    assert close("state", 20.0, 19.0) == 20.0     # no mesh: a stage a
+    assert close("mesh", 21.0, 20.5) == 21.0      # process never closed
+    assert close("first_step", 50.0, 22.0) == 50.0   # is absent
+    assert close("first_step", 90.0, 60.0) == 90.0
+    startup = stats.startup()
+    assert list(startup) == ["imports", "state", "first_step"]
+    assert startup["imports"] == {"begin_s": 0.0, "end_s": 6.0,
+                                  "seconds": 6.0,
+                                  "own_s": pytest.approx(0.1)}
+    assert startup["state"] == {"begin_s": 6.0, "end_s": 20.0,
+                                "seconds": 14.0, "own_s": 1.0}
+    assert startup["first_step"] == {"begin_s": 20.0, "end_s": 50.0,
+                                     "seconds": 30.0, "own_s": 28.0}
+    assert all(s["own_s"] <= s["seconds"] for s in startup.values())
+    assert stats.snapshot()["startup"] == startup
+    assert stats.startup_line() == ("imports 6.00 (own 0.10), state 14.00 "
+                                    "(own 1.00), first_step 30.00 (own "
+                                    "28.00)")
+    gauges = get_registry().snapshot()["gauges"]
+    assert gauges["startup_state_seconds"] == 14.0
+    assert gauges["startup_first_step_seconds"] == 30.0
+
+    # the decorator: the function's return closes the stage, a raise
+    # closes nothing, and the function stays what it was
+    fresh = compile_cache.CacheStats()
+    monkeypatch.setattr(compile_cache, "STATS", fresh)
+
+    @compile_cache.closes_startup_stage("mesh")
+    def make(fail=False):
+        """doc"""
+        now[0] += 2.0
+        if fail:
+            raise ValueError("no mesh")
+        return "made"
+
+    now[0] = 10.0
+    with pytest.raises(ValueError):
+        make(fail=True)
+    assert fresh.startup() == {}
+    assert make() == "made" and make.__name__ == "make"
+    assert fresh.startup() == {"mesh": {"begin_s": 0.0, "end_s": 14.0,
+                                        "seconds": 14.0, "own_s": 2.0}}
+
+
+_STARTUP = """
+import json, sys
+import jax, jax.numpy as jnp
+from pytorch_vit_paper_replication_tpu import compile_cache, engine, parallel
+from pytorch_vit_paper_replication_tpu.configs import (
+    MeshConfig, TrainConfig, ViTConfig)
+from pytorch_vit_paper_replication_tpu.metrics import MetricsLogger
+from pytorch_vit_paper_replication_tpu.models import ViT
+from pytorch_vit_paper_replication_tpu.optim import make_optimizer
+
+compile_cache.configure(sys.argv[1])
+mesh = parallel.make_mesh(MeshConfig())
+model = ViT(ViTConfig(image_size=32, patch_size=8, num_layers=2, num_heads=2,
+                      embedding_dim=32, mlp_size=64, num_classes=3,
+                      dtype="float32", attention_impl="xla"))
+tx = make_optimizer(TrainConfig(batch_size=8), 10)
+make_state = lambda key, rng: engine.TrainState.create(
+    apply_fn=model.apply, tx=tx, rng=rng,
+    params=model.init(key, jnp.zeros((1, 32, 32, 3)))["params"])
+state = jax.jit(make_state)(jax.random.key(0), jax.random.key(1))
+state = parallel.shard_train_state(state, mesh)
+step = parallel.make_parallel_train_step(state, mesh)
+batch = {"image": jnp.ones((8, 32, 32, 3)), "label": jnp.zeros(8, jnp.int32)}
+feed = lambda: (parallel.shard_batch(batch, mesh) for _ in range(2))
+with MetricsLogger(sys.argv[2]) as logger:
+    engine.train(state, feed, lambda: (), epochs=1, train_step=step,
+                 eval_step=lambda *a: None, verbose=True, logger=logger)
+from pytorch_vit_paper_replication_tpu.telemetry import get_registry
+print("SNAPSHOT " + json.dumps({
+    "cache": compile_cache.STATS.snapshot(),
+    "registry": get_registry().snapshot()}))
+"""
+
+
+def test_a_trainers_start_leaves_its_four_stages(tmp_path):
+    """``configure``, ``make_mesh``, ``make_parallel_train_step`` and two
+    steps of ``engine.train`` in a fresh process: the ``[startup]`` line
+    beside ``[programs]``, the four stages in ``snapshot()["startup"]``,
+    as gauges and in the first-epoch row, ``time_to_first_step`` the end
+    of ``first_step``, and no row of ``snapshot()["programs"]`` for a
+    helper traced inside a program."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO))
+    env.pop("XLA_FLAGS", None)
+    env.pop(compile_cache.ENV_CACHE_DIR, None)
+    jsonl = tmp_path / "m.jsonl"
+    out = subprocess.run(
+        [sys.executable, "-c", _STARTUP, str(tmp_path / "cc"), str(jsonl)],
+        capture_output=True, text=True, timeout=600, env=env, cwd=tmp_path)
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = out.stdout.splitlines()
+    shown = next(ln for ln in lines if ln.startswith("[startup] "))
+    assert lines.index(shown) + 1 == next(
+        i for i, ln in enumerate(lines) if ln.startswith("[programs] "))
+    assert [w for w in shown.replace(",", "").split()
+            if w in compile_cache.STARTUP_STAGES] == list(
+                compile_cache.STARTUP_STAGES)
+    snap = json.loads(next(ln for ln in lines if ln.startswith(
+        "SNAPSHOT ")).split(" ", 1)[1])
+    startup = snap["cache"]["startup"]
+    assert list(startup) == list(compile_cache.STARTUP_STAGES)
+    ends = [0.0] + [s["end_s"] for s in startup.values()]
+    assert [s["begin_s"] for s in startup.values()] == ends[:-1]
+    assert all(0 <= s["own_s"] <= s["seconds"] for s in startup.values())
+    # the step is traced, lowered and compiled inside engine.train
+    assert startup["first_step"]["own_s"] > 0.5 * \
+        startup["first_step"]["seconds"]
+    for name, gauge in compile_cache.STARTUP_STAGES.items():
+        assert snap["registry"]["gauges"][gauge] == pytest.approx(
+            startup[name]["seconds"], abs=1e-3)
+    row = json.loads(jsonl.read_text().splitlines()[0])
+    assert row["time_to_first_step"] == pytest.approx(
+        startup["first_step"]["end_s"], abs=1e-3)
+    assert f"time_to_first_step: {row['time_to_first_step']:.2f}s" in \
+        out.stdout
+    assert sum(row[f"startup_{name}_s"] for name in startup) == \
+        pytest.approx(row["time_to_first_step"], abs=5e-3)
+    # one row a top-level program: the helpers a trace called (jitted
+    # jnp functions, thousands in a step) are its ``folded`` count
+    programs = snap["cache"]["programs"]
+    assert programs["train_step"]["count"] == 1
+    assert programs["train_step"]["folded"] > 100
+    assert not {"multiply", "subtract", "_where", "true_divide"} \
+        & set(programs)
+    # (``add`` stays: ``engine._accumulate`` adds the steps' metrics
+    # eagerly, a program of its own.) A helper has a trace and no more.
+    assert all(r["lower"] > 0 and r["count"] >= 1 for r in programs.values())
+    assert snap["cache"]["dropped"] == 0
+    # (the costliest program comes first there: at this size the
+    # initialiser or the step)
+    assert " x1 trace " in next(
+        ln for ln in lines if ln.startswith("[programs] "))
 
 
 def test_setup_metrics_read_the_stages_up_to_the_window_with_a_second_of_slack(
@@ -216,7 +507,7 @@ def test_setup_metrics_read_the_stages_up_to_the_window_with_a_second_of_slack(
         ("lower", "jit(train_step)", 1.0, 21.0),
         ("cache_read", None, 0.25, 21.5),
         ("backend", "jit(train_step)", 2.0, 30.9),   # 0.9 s "late"
-        ("backend", "jit(eval_step)", 7.0, 31.5)])   # after the window
+        ("backend", "jit(eval_step)", 0.5, 31.5)])   # after the window
     monkeypatch.setattr(compile_cache, "STATS", stats)
     assert setup_trace_lower_s.read({"setup_s": 30.0}) == 4.0
     assert capsys.readouterr().out == "", "a rehearsal prints no time"
@@ -614,7 +905,8 @@ def test_compact_gates_line_stays_bounded():
         "time", "step", "epoch", "train_loss", "train_acc", "test_loss",
         "test_acc", "images_per_sec", "grad_norm", "skipped_steps", "lr",
         "time_to_first_step", "compile_cache_hits",
-        "compile_cache_misses",
+        "compile_cache_misses", "startup_imports_s", "startup_mesh_s",
+        "startup_state_s", "startup_first_step_s",
         # ServeStats.emit flattened rows
         "submitted", "completed", "rejected_queue_full", "expired",
         "batches", "padded_rows", "degraded_batches", "warmup_total_s",
