@@ -8,10 +8,19 @@ with an online-softmax accumulator, so HBM traffic stays O(T·D) instead of
 O(T²), and every matmul lands on the MXU with float32 accumulation.
 
 Layout: inputs are ``[B, T, H, Dh]``; internally folded to ``[B·H, T, Dh]``.
-The grid walks (batch·head, query-block); each program streams K/V blocks with
-``lax.fori_loop``. Sequence lengths that are not block-aligned are padded by
-the wrapper and masked inside the kernel, so 577-token (384px) ViT sequences
-work.
+The forward's grid walks (key/value head, query block, query head of the
+group); each program streams the visible K/V blocks with ``lax.fori_loop``,
+three interior blocks a step. Sequence lengths that are not block-aligned are
+padded by the wrapper and masked inside the kernel, so 577-token (384px) ViT
+sequences work.
+
+Both kernels hold a block transposed, ``[Bk, Bq]`` (``s^T = k q^T``: keys on
+sublanes, queries on lanes). The forward's statistics (running maximum, sum,
+correction) are then lane-dense ``[1, Bq]`` rows and its reductions over keys
+sums of vector registers; its accumulator is ``o^T`` and is turned once a
+program. A query row that no key attends is told apart in the forward's
+epilogue, not inside its loop (``_fwd_kernel``): zero output, ``lse =
+_NEG_INF``.
 
 The backward pass is the flash recomputation in ONE kernel
 (``flash_bwd``) that visits every visible (query block, key block) pair
@@ -96,12 +105,21 @@ from .dropout import positional_dropout_seed, positional_meta
 # The one-pass backward alone at the same shapes, a causal / a window
 # layer (PR 31, my chip run; the pair it replaced 50.3 / 27.6 at 512x512):
 # 512x512 28.4 / 14.5 ms, 512x1024 28.2 / 15.8, 1024x512 28.5 / 15.9,
-# 256x256 52.1 / 24.7.
+# 256x256 52.1 / 24.7. The transposed forward alone, a causal / a window
+# layer (PR 37, my chip runs, device time; the parent's forward 16.75 /
+# 9.38): a block a step 17.07 / 8.66, three interior blocks a step
+# 14.23 / 7.85 (two 14.73 / 8.04, four 14.62 / 8.24); a block a step at
+# 1024x512 15.48 / 8.77, 512x256 pairs 15.41 / 8.47, 256x512 pairs
+# 18.27 / 10.77.
+# Key blocks a step over the forward's interior (``_fwd_kernel``).
+FWD_STEP_BLOCKS = 3
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 LONG_SEQUENCE = 2048
 LONG_BLOCK = 512
 _NEG_INF = float(-1e30)
+_LOG2E = float(np.log2(np.e))
+_LN2 = float(np.log(2.0))
 _MIB = 1024 * 1024
 
 
@@ -182,11 +200,20 @@ def _kv_full_range(structure, qi, block_q, block_k, lo, hi):
     return full_lo, full_hi
 
 
-def _edges_and_interior(body, lo, full_lo, full_hi, hi, carry):
+def _edges_and_interior(body, lo, full_lo, full_hi, hi, carry,
+                        steps=None):
     """``body(i, carry, masked)`` over ``[lo, hi)``: with the structure's
-    mask on the edge blocks, without it on ``[full_lo, full_hi)``."""
+    mask on the edge blocks, without it on ``[full_lo, full_hi)``. Given
+    ``steps = (n, body_n)``, ``body_n(i, carry)`` takes the interior's
+    blocks ``i .. i + n - 1`` a step and ``body`` the ones left over."""
     edge = functools.partial(body, masked=True)
     carry = jax.lax.fori_loop(lo, full_lo, edge, carry)
+    if steps is not None:
+        n, body_n = steps
+        whole = (full_hi - full_lo) // n
+        carry = jax.lax.fori_loop(
+            0, whole, lambda j, c: body_n(full_lo + n * j, c), carry)
+        full_lo = full_lo + n * whole
     carry = jax.lax.fori_loop(full_lo, full_hi,
                               functools.partial(body, masked=False), carry)
     return jax.lax.fori_loop(full_hi, hi, edge, carry)
@@ -296,24 +323,29 @@ def _mask_bh_index(bh_mode, h):
     }[bh_mode]
 
 
-def _mask_spec_rows(mask_info, h, padded_kv, block_q):
-    """BlockSpec for kernels gridded over (bh, q-block): the q-row strip
-    [1, block_q|1, padded_kv]."""
+def _transposed_mask(mask3, mask_info, block_q, block_k):
+    """The mask as both kernels read it, their block being held
+    transposed: ``[G, Tk, Tq|1]`` padded with False, and the bytes of
+    the strip one program holds. The expression is the one
+    :func:`..indexer_loss.loss_pass` builds its selection with, so that
+    XLA takes the ``[T, T]`` transpose once for the two."""
+    padded = _pad_mask(mask3, mask_info, block_q, block_k)
+    return (jnp.swapaxes(padded, 1, 2),
+            _strip_bytes(padded, mask_info, block_q))
+
+
+def _mask_spec_cols(mask_info, h, padded_kv, block_q, head_and_block):
+    """BlockSpec of the transposed mask's strip ``[1, padded_kv,
+    block_q|1]`` for a grid whose step ``head_and_block(*step)`` gives
+    ``(batch·head, query block)``."""
     bh_mode, q_bcast = mask_info
     bhi = _mask_bh_index(bh_mode, h)
-    if q_bcast:
-        return pl.BlockSpec((1, 1, padded_kv),
-                            lambda b, i, *_: (bhi(b), 0, 0))
-    return pl.BlockSpec((1, block_q, padded_kv),
-                        lambda b, i, *_: (bhi(b), i, 0))
 
+    def index(*step):
+        b, i = head_and_block(*step[:3])
+        return bhi(b), 0, 0 if q_bcast else i
 
-def _mask_block_rows(mask_ref, mask_info, ki, block_q, block_k):
-    """[Bq|1, Bk] attend-mask tile for a (q-strip kernel, kv block ki)."""
-    _, q_bcast = mask_info
-    rows = 1 if q_bcast else block_q
-    return _attend(mask_ref[0, :, pl.ds(ki * block_k, block_k)].reshape(
-        rows, block_k))
+    return pl.BlockSpec((1, padded_kv, 1 if q_bcast else block_q), index)
 
 
 def _attend(tile):
@@ -331,93 +363,121 @@ def _attend(tile):
 
 def _fwd_kernel(meta_ref, q_ref, k_ref, v_ref, *rest, scale,
                 block_k, kv_len, threshold, mask_info, heads, structure):
-    """One (batch·head, q-block) program: online-softmax over K/V blocks."""
+    """One (key/value head, query block, query head of the group)
+    program: the online softmax over the visible key blocks.
+
+    The block is held transposed, ``[Bk, Bq]`` (``s^T = k q^T``), as
+    ``flash_bwd`` holds it: the running maximum ``m``, the sum ``l`` and
+    the correction are lane-dense ``[1, Bq]`` rows, the maximum and the
+    sum over keys are reductions over sublanes, and the accumulator is
+    ``o^T`` ``[Dh, Bq]`` (``v^T p^T``), which every correction scales
+    along sublanes as it lies; it is turned once, after the last block.
+    A logit is scaled by ``scale * log2 e`` as it leaves the MXU, so the
+    loop takes ``exp2`` of base-2 logits (the same numbers as ``exp`` of
+    the natural ones, to float32 rounding; folding the scale into a
+    bfloat16 query instead rounds the query's elements up by 3e-4 of
+    their size on average, a bias on every logit).
+
+    Over the interior (``_kv_full_range``) the loop takes
+    :data:`FWD_STEP_BLOCKS` key blocks a step, their logits first: the
+    MXU takes the next blocks' products while the vector unit takes the
+    softmax of one, where a block a step runs the two products and the
+    softmax between them one after the other.
+
+    A row that no key attends (a caller's mask; padded query rows)
+    needs no guard inside the loop: its ``m`` stays ``_NEG_INF``, where
+    ``exp2(s - m) = 1`` for each of its masked keys, so ``l`` and the
+    accumulator gather a finite sum of ones and of rows of ``v``. If a
+    key it attends arrives in a later block, ``m`` becomes finite and
+    that block's ``correction = exp2(_NEG_INF - m) = 0`` wipes both
+    exactly, and no masked key contributes again (``exp2(_NEG_INF -
+    m)`` is 0). If none arrives, ``m`` is still ``_NEG_INF`` after the
+    last block, and the epilogue writes a zero output and ``lse =
+    _NEG_INF``, as the backward reads it (every ``p`` of the row
+    masked, so zero gradients)."""
+    group = structure[2]
+    mask_ref = None
     if mask_info is not None:
-        mask_ref, o_ref, lse_ref = rest
-    else:
-        mask_ref, (o_ref, lse_ref) = None, rest
-    q = q_ref[0]                      # [Bq, Dh]
-    block_q, head_dim = q.shape
-    padded_kv = k_ref.shape[1]
-    num_kv = padded_kv // block_k
-    bh = _global_bh(meta_ref, heads)
-    qi = pl.program_id(1)
+        mask_ref, *rest = rest
+    o_ref, lse_ref = rest
     causal = structure[0]
+    q = q_ref[0]                       # [Bq, Dh]
+    block_q = q.shape[0]
+    num_kv = k_ref.shape[1] // block_k
+    qi = pl.program_id(1)
+    bh = _global_bh(meta_ref, heads, pl.program_id(0) * group
+                    + pl.program_id(2))
+    shape = (block_k, block_q)
+    padded = kv_len != k_ref.shape[1]  # static: a last block of padding
 
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, head_dim), jnp.float32)
-
-    padded = kv_len != padded_kv       # static: a last block of padding
-
-    def body(ki, carry, masked=True):
-        m, l, acc = carry
+    def logits(ki):
+        """``s^T`` of key block ``ki``, ``[Bk, Bq]``, base 2."""
         k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [Bq, Bk]
+        return jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * (scale * _LOG2E)
+
+    def update(ki, carry, s, masked):
+        m, l, acc = carry              # [1, Bq], [1, Bq], [Dh, Bq]
+        keys = pl.ds(ki * block_k, block_k)
         if padded or (causal and masked):
-            col = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+            col = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
             keep_s = col < kv_len
             if causal and masked:
                 row = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
+                    jnp.int32, shape, 1)
                 keep_s = jnp.logical_and(keep_s,
                                          _visible(structure, row, col))
             s = jnp.where(keep_s, s, _NEG_INF)
         if mask_info is not None:
-            attend = _mask_block_rows(mask_ref, mask_info, ki, block_q,
-                                      block_k)
-            s = jnp.where(attend, s, _NEG_INF)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                      # [Bq, Bk]
-        if mask_info is not None:
-            # A fully-masked row leaves m_new == _NEG_INF, where
-            # exp(s - m_new) = 1 for every masked column — the forward
-            # would silently produce uniform attention while the backward
-            # kernels zero p via the attend mask (ADVICE r4). Zero p
-            # wherever s carries the mask fill so l stays 0 for such rows
-            # and the l == 0 guard below yields a ZERO output, consistent
-            # with the zero gradients. (Without a caller mask the only
-            # _NEG_INF entries are kv padding and kv_len >= 1 keeps
-            # m_new finite, so the guard is unreachable — skip the op.)
-            p = jnp.where(s > 0.5 * _NEG_INF, p, 0.0)
-        correction = jnp.exp(m - m_new)             # [Bq, 1]
+            s = jnp.where(_attend(mask_ref[0, keys, :]), s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        correction = jnp.exp2(m - m_new)
         # The normalizer sums the UNDROPPED probabilities: dropout applies
         # to softmax(S), not to exp(S) pre-normalization.
-        l_new = l * correction + jnp.sum(p, axis=-1, keepdims=True)
+        l_new = l * correction + jnp.sum(p, axis=0, keepdims=True)
         if threshold:
             keep = _keep_mask(meta_ref[0], bh, qi * block_q, ki * block_k,
-                              (block_q, block_k), threshold)
+                              shape, threshold, query_dim=1)
             p = jnp.where(keep, p, 0.0)
-        acc_new = acc * correction + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        v = v_ref[0, keys, :]
+        acc_new = acc * correction + jax.lax.dot_general(
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    # Every query sees its own position, so a visited row's m is finite
-    # after its first block and the _NEG_INF entries underflow to 0.
+    def body(ki, carry, masked=True):
+        return update(ki, carry, logits(ki), masked)
+
+    def interior(ki, carry):
+        s = [logits(ki + u) for u in range(FWD_STEP_BLOCKS)]
+        for u in range(FWD_STEP_BLOCKS):
+            carry = update(ki + u, carry, s[u], False)
+        return carry
+
     lo, hi = _kv_block_range(structure, qi, block_q, block_k, num_kv)
     full = _kv_full_range(structure, qi, block_q, block_k, lo, hi)
-    m, l, acc = _edges_and_interior(body, lo, *full, hi, (m0, l0, acc0))
-    # Guard fully-masked rows (padded query rows): l == 0 there.
-    l_safe = jnp.where(l == 0.0, 1.0, l)
+    row = lambda fill: jnp.full((1, block_q), fill, jnp.float32)
+    m, l, acc = _edges_and_interior(
+        body, lo, *full, hi,
+        (row(_NEG_INF), row(0.0), jnp.zeros((q.shape[1], block_q),
+                                            jnp.float32)),
+        steps=(FWD_STEP_BLOCKS, interior))
+    dead = m == _NEG_INF               # no key attended: zero output
     keep_prob = 1.0 - threshold / 256.0  # quantized, like ops.dropout
-    o_ref[0] = (acc / (l_safe * keep_prob)).astype(o_ref.dtype)
+    inv = jnp.where(dead, 0.0, 1.0 / (l * keep_prob))
+    o_ref[0] = (acc * inv).T.astype(o_ref.dtype)
     # lse is carried as [bh, 1, T] so its (sublane, lane) block dims satisfy
     # the TPU (8, 128) tiling rule (sublane dim == full array dim 1).
-    lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
+    lse_ref[0] = jnp.where(dead, _NEG_INF, m * _LN2 + jnp.log(l))
 
 
 def _pad_mask(mask3, mask_info, block_q, block_k):
     """Pad the folded mask's real (non-broadcast) q/k dims with False."""
     _, q_bcast = mask_info
-    m = _pad_to_false(mask3, 2, block_k)
-    if not q_bcast:
-        m = _pad_to_false(m, 1, block_q)
-    return m
+    m = mask3 if q_bcast else _pad_to_false(mask3, 1, block_q)
+    return _pad_to_false(m, 2, block_k)
 
 
 def _strip_bytes(mask3, mask_info, block_q):
@@ -440,41 +500,51 @@ def _pad_to_false(x, axis, multiple):
 def _fwd(q, k, v, seed, mask3, mask_info, *, heads, scale, block_q,
          block_k, threshold, interpret, structure):
     q_len, kv_len = q.shape[1], k.shape[1]
+    group = structure[2]
     bh, head_dim, q_at, kv_at = _layout(structure, q)
     qp = _pad_to(q, 1, block_q)
     kp = _pad_to(k, 1, block_k)
     vp = _pad_to(v, 1, block_k)
-    grid = (bh, qp.shape[1] // block_q)
 
+    # Grid (key/value head n, query block i, query head g of its group):
+    # query head n * group + g. The group's heads are walked innermost,
+    # so k, v and a strip of the mask are fetched once for all of them.
+    def q_rows(n, i, g, *_):
+        at = q_at(n * group + g)
+        return at[0], i, at[1]
+
+    def kv_whole(n, i, g, *_):
+        at = kv_at(n * group)
+        return at[0], 0, at[1]
+
+    q_spec = pl.BlockSpec((1, block_q, head_dim), q_rows)
+    kv_spec = pl.BlockSpec((1, kp.shape[1], head_dim), kv_whole)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [qp, kp, vp]
+    strip = ()
+    if mask_info is not None:
+        mask_t, strip_bytes = _transposed_mask(mask3, mask_info, block_q,
+                                               block_k)
+        in_specs.append(_mask_spec_cols(
+            mask_info, heads[0], kp.shape[1], block_q,
+            lambda n, i, g: (n * group + g, i)))
+        operands.append(mask_t)
+        strip = (strip_bytes,)
     kernel = functools.partial(_fwd_kernel, scale=scale, block_k=block_k,
                                kv_len=kv_len, threshold=threshold,
                                mask_info=mask_info, heads=heads,
                                structure=structure)
-    q_rows = lambda b, i, *_: (q_at(b)[0], i, q_at(b)[1])
-    kv_whole = lambda b, i, *_: (kv_at(b)[0], 0, kv_at(b)[1])
-    in_specs = [
-        pl.BlockSpec((1, block_q, head_dim), q_rows),
-        pl.BlockSpec((1, kp.shape[1], head_dim), kv_whole),
-        pl.BlockSpec((1, vp.shape[1], head_dim), kv_whole),
-    ]
-    operands = [qp, kp, vp]
-    strip = ()
-    if mask_info is not None:
-        mask3 = _pad_mask(mask3, mask_info, block_q, block_k)
-        in_specs.append(_mask_spec_rows(mask_info, heads[0],
-                                        mask3.shape[2], block_q))
-        operands.append(mask3)
-        strip = (_strip_bytes(mask3, mask_info, block_q),)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(bh // group, qp.shape[1] // block_q, group),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, head_dim), q_rows),
-                pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i)),
+                q_spec,
+                pl.BlockSpec((1, 1, block_q),
+                             lambda n, i, g, *_: (n * group + g, 0, i)),
             ],
         ),
         out_shape=[
@@ -661,16 +731,13 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
     operands = [qp, kp, vp, outp, dop, lsep]
     strip = ()
     if mask_info is not None:
-        # The kernel holds the block transposed: so is its mask.
-        padded_mask = _pad_mask(mask3, mask_info, block_q, block_k)
-        strip = (_strip_bytes(padded_mask, mask_info, block_q),)
-        mask_t = padded_mask.transpose(0, 2, 1)
-        bhi = _mask_bh_index(mask_info[0], heads[0])
-        in_specs.append(pl.BlockSpec(
-            (1, padded_kv, 1 if mask_info[1] else block_q),
-            lambda n, g, i, *_: (bhi(n * group + g), 0,
-                                 0 if mask_info[1] else i)))
+        mask_t, strip_bytes = _transposed_mask(mask3, mask_info, block_q,
+                                               block_k)
+        in_specs.append(_mask_spec_cols(
+            mask_info, heads[0], padded_kv, block_q,
+            lambda n, g, i: (n * group + g, i)))
         operands.append(mask_t)
+        strip = (strip_bytes,)
     slab = _slab_bytes(kp, head_dim)
 
     dq, dk, dv = pl.pallas_call(

@@ -67,12 +67,47 @@ def _flash_against_dense(shape, kv_heads, kind, window, blocks):
     ("causal_window", 12, (16, 16)),
     ("causal_window", 40, (16, 16)),      # wider than a block
     ("causal_window", 12, (32, 8)),       # unequal blocks
+    ("causal", 0, (8, 8)),                # interior steps of 3 and left over
+    ("causal_window", 33, (8, 8)),
 ])
 def test_flash_kernels_compute_the_structure_from_positions(
         kind, window, blocks):
     """7 query heads to a key/value head, T = 50 (no multiple of a
-    block): the folded layout (Dh = 16)."""
+    block): the folded layout (Dh = 16). With blocks of 8 a query block
+    has up to 6 interior key blocks: the forward takes them three a step
+    and the rest one a step."""
     _flash_against_dense((2, 50, 7, 16), 1, kind, window, blocks)
+
+
+@pytest.mark.parametrize("shape,kv_heads,kind,window,blocks", [
+    ((2, 50, 7, 16), 1, "causal", 0, (8, 8)),            # folded, Dh 16
+    ((1, 70, 4, 64), 2, "causal_window", 21, (16, 8)),   # folded, Dh 64
+    ((1, 70, 4, 128), 2, "causal", 0, (16, 16)),         # flat, Dh 128
+    ((1, 40, 2, 256), 2, "causal", 0, (8, 8)),           # flat, Dh 256
+    ((2, 57, 3, 64), 3, "full", 0, (16, 8)),             # padded keys
+], ids=["folded16", "folded64-window", "flat128", "flat256", "full-padded"])
+def test_flash_lse_is_the_log_sum_exp_of_the_visible_logits(
+        shape, kv_heads, kind, window, blocks):
+    """The forward's row statistic, which leaves its loop in base 2
+    (``exp2`` of logits scaled by ``log2 e``) and is turned to natural
+    units in the epilogue, is ``logsumexp`` over the visible keys to
+    float32 rounding, as the parent's ``m + log l`` was."""
+    b, t, h, d = shape
+    ks = jax.random.split(jax.random.key(9), 3)
+    q = jax.random.normal(ks[0], shape)
+    k = jax.random.normal(ks[1], (b, t, kv_heads, d))
+    v = jax.random.normal(ks[2], (b, t, kv_heads, d))
+    _, lse = flash_attention(q, k, v, kind=kind, window=window,
+                             block_q=blocks[0], block_k=blocks[1],
+                             interpret=True, return_lse=True)
+    kk = jnp.repeat(k, h // kv_heads, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) * d ** -0.5
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None]
+    visible = (j <= i) if kind != "full" else jnp.ones((t, t), bool)
+    if window:
+        visible = visible & (i - j < window)
+    want = jax.nn.logsumexp(jnp.where(visible, s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(lse, want, rtol=2e-6, atol=2e-6)
 
 
 @pytest.mark.parametrize("kv_heads", [2, 1])

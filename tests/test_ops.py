@@ -444,24 +444,33 @@ def test_xla_saturating_softmax_semantics():
     np.testing.assert_array_equal(np.asarray(out[:, 3]), 0.0)
 
 
-def test_flash_mask_fully_masked_rows_zero_and_consistent():
+@pytest.mark.parametrize("blocks", [(None, None), (32, 16)], ids=str)
+def test_flash_mask_fully_masked_rows_zero_and_consistent(blocks):
     """ADVICE r4: a query row attending to NO key must have a DEFINED
     result — zero output with zero gradient, forward and backward
     agreeing (previously the forward degenerated to uniform attention
-    while the backward kernels zeroed p, so fwd and bwd disagreed)."""
+    while the backward kernels zeroed p, so fwd and bwd disagreed).
+    The forward tells such a row apart in its epilogue (its running
+    maximum never left ``_NEG_INF``): with blocks of 16 keys the row's
+    masked keys pass through eight blocks, interior steps of three among
+    them, before it does."""
     t = 128
     q, k, v = _qkv(15, 1, t, 2, 32)
     mask = jnp.ones((1, 1, t, t), bool).at[:, :, 5].set(False)
+    kw = dict(mask=mask, block_q=blocks[0], block_k=blocks[1],
+              interpret=True)
 
-    out = flash_attention(q, k, v, mask=mask, interpret=True)
+    out, lse = flash_attention(q, k, v, return_lse=True, **kw)
     np.testing.assert_array_equal(np.asarray(out[:, 5]), 0.0)
+    np.testing.assert_array_equal(np.asarray(lse[:, :, 5]), np.float32(-1e30))
+    out = flash_attention(q, k, v, **kw)
     # Other rows are untouched by the degenerate one.
     ref = _xla_masked(q, k, v, mask)
     np.testing.assert_allclose(np.asarray(out[:, :5]),
                                np.asarray(ref[:, :5]), **TOL)
 
     def loss(args):
-        return (flash_attention(*args, mask=mask, interpret=True) ** 2).sum()
+        return (flash_attention(*args, **kw) ** 2).sum()
 
     gq, gk, gv = jax.grad(loss)((q, k, v))
     assert bool(jnp.isfinite(gq).all() and jnp.isfinite(gk).all()
@@ -470,6 +479,37 @@ def test_flash_mask_fully_masked_rows_zero_and_consistent():
     # k/v gradients receive nothing FROM that row (checked via a probe:
     # perturbing row 5's query cannot change the loss).
     np.testing.assert_array_equal(np.asarray(gq[:, 5]), 0.0)
+
+
+@pytest.mark.parametrize("kind", ["full", "causal"])
+def test_flash_row_whose_first_blocks_select_nothing(kind):
+    """A row whose first visited key blocks hold none of its selected
+    keys (the forward's maximum stays ``_NEG_INF`` over them and every
+    masked key adds 1 to its sum) and whose later ones do: the blocks
+    before are wiped by the first correction, ``exp2(_NEG_INF - m) =
+    0``, so the row is the exact softmax of its selected keys (output
+    and ``lse``), whichever of the forward's loops saw them."""
+    t = 128
+    q, k, v = _qkv(17, 1, t, 2, 32)
+    i, j = jnp.arange(t)[:, None], jnp.arange(t)[None]
+    chosen = jax.random.bernoulli(jax.random.key(18), 0.5, (t, t))
+    # rows 100-103 select keys from 80 on only: blocks 0-4 of 16 are bare
+    late = (i >= 100) & (i < 104)
+    mask = jnp.where(late, chosen & (j >= 80), chosen) | (i == j)
+    if kind == "causal":
+        mask = mask & (j <= i)
+    out, lse = flash_attention(q, k, v, kind=kind, mask=mask[None, None],
+                               block_q=32, block_k=16, interpret=True,
+                               return_lse=True)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * 32 ** -0.5
+    s = jnp.where(mask, s, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+    np.testing.assert_allclose(out[:, 100:104], want[:, 100:104],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(lse[:, :, 100:104],
+                               jax.nn.logsumexp(s, axis=-1)[:, :, 100:104],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
 
 def test_flash_mask_bad_shape_raises():

@@ -576,6 +576,24 @@ def test_the_alignment_losss_pass_leaves_no_chunk_of_rows_in_hbm(dsa_step):
     assert held < DSA_STEP_GIB * 2**30, held / 2**30
 
 
+def test_the_selection_is_turned_once_a_layer_for_both_kernels(dsa_step):
+    """The forward flash kernel holds its block transposed since PR 37
+    and reads the selection as ``[keys, queries]`` strips, the
+    transposed array the alignment loss's kernels read: XLA takes it
+    once a layer for the two (the same expression, merged), and the
+    backward pass once more for ``flash_bwd``. The compiled step's entry
+    computation makes a full-size int8 ``[1, 16384, 16384]`` array 12
+    times; the parent's made it 18 times (6 copies into the row layout
+    its forward read, 6 transposes for the loss and 6 for the
+    backward)."""
+    _, compiled = dsa_step
+    made = [line for line in _entry(compiled.as_text()) if re.search(
+        r"= \(?[^=]*s8\[1,16384,16384\]\S* (?!bitcast|get-tuple-element|"
+        r"parameter|tuple|while|opt-barrier|custom-call)[\w-]+\(", line)]
+    assert len(made) <= 12, (len(made), made[0][:300])
+    assert not any("/msa/indexer_loss/transpose" in line for line in made)
+
+
 def test_flash_kernels_compile_with_a_selection_a_row(v5e_2x2):
     """T = 16,384, 32 query heads over 4 key/value heads of 128 and an
     int8 ``[T, T]`` selection: both kernels compile with the strip of it
