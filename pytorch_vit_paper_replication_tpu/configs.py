@@ -181,12 +181,25 @@ class ViTConfig:
     # RMSNorm with a learned scale over the columns of each query and
     # key head, before the rotary embedding (token models).
     qk_norm: bool = False
-    # Std of the normal initialiser of a token model's matrices (its
-    # embedding rows start at N(0, 1): ``models/vit.py::TokenEmbedding``).
+    # Per layer, 1 = a gated short convolution takes the attention's place
+    # (LFM2's mixer: ``[B | C | u] = norm(x) W_in``, ``C * conv(B * u)``,
+    # a depthwise causal convolution over the last ``conv_kernel``
+    # positions, ``W_out``; :mod:`..ops.short_conv`), 0 = attention. ()
+    # = attention everywhere. A layout shorter than the depth repeats.
+    # Token models only; a conv layer has no rotary positions or window.
+    mixer_layout: Tuple[int, ...] = ()
+    conv_kernel: int = 3
+    # The head reads the token embedding's table (``[V, D]``, as it lies)
+    # instead of a matrix of its own; the table's gradient is the sum of
+    # the lookup's and the head's.
+    tie_embedding: bool = False
+    # Std of the normal initialiser of a token model's matrices (and of
+    # a tied table's rows; an untied table's start at N(0, 1):
+    # ``models/vit.py::TokenEmbedding``).
     init_std: float = 0.02
 
     def __post_init__(self):
-        for name in ("rope_layout", "sliding_window_layout"):
+        for name in ("rope_layout", "sliding_window_layout", "mixer_layout"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"unknown norm {self.norm!r}")
@@ -249,6 +262,22 @@ class ViTConfig:
         if self.mtp_modules not in (0, 1) or (
                 self.mtp_modules and not self.vocab_size):
             raise ValueError("mtp_modules: 0, or 1 on a token model")
+        if any(self.mixer_layout) and not (
+                self.vocab_size and self.conv_kernel > 0
+                and not self.kv_lora_rank and not self.sa_topk
+                and not any(self.layer_mixer(i) == "conv"
+                            and (self.layer_rope(i)
+                                 or self.attention_kind(i)[1])
+                            for i in range(self.num_layers
+                                           + self.mtp_modules))):
+            raise ValueError(
+                "gated short convolutions: a token model with conv_kernel "
+                "> 0, no latent attention and no indexer, and no rotary "
+                "positions or window on a conv layer")
+        if self.tie_embedding and not (self.vocab_size
+                                       and not self.mtp_modules):
+            raise ValueError("tie_embedding: a token model without a "
+                             "multi-token-prediction module")
         if self.image_size % self.patch_size != 0:
             # Reference asserts the same invariant at models/vit.py:25.
             raise ValueError(
@@ -302,6 +331,12 @@ class ViTConfig:
         """Whether block ``layer`` turns q and k by their positions."""
         lay = self.rope_layout
         return bool(lay and lay[layer % len(lay)])
+
+    def layer_mixer(self, layer: int) -> str:
+        """Block ``layer``'s mixer: ``"conv"`` (a gated short
+        convolution) or ``"attention"``."""
+        lay = self.mixer_layout
+        return "conv" if lay and lay[layer % len(lay)] else "attention"
 
     def attention_kind(self, layer: int):
         """Block ``layer``'s attention as structure: ``("full", 0)``
@@ -474,6 +509,44 @@ def dsa_tiny(**kw) -> ViTConfig:
         experts_held=4), **kw})
 
 
+def lfm2_24b_a2b_ep8(**kw) -> ViTConfig:
+    """LFM2-24B-A2B (huggingface.co/LiquidAI, ``lfm2_moe``), one chip's
+    share of a deployment in which 8 chips share each layer: every
+    published width (2048; gated short convolutions of 3 taps beside
+    grouped-query attention of 32 query / 8 key-value heads of 64 with
+    per-head q / k norms, theta 1e6; the leading dense layer of 11,776;
+    experts of 1,536, 4 of 64 by sigmoid scores and an expert bias, none
+    shared; one table for the embedding and the head), the first leading
+    dense layer (a conv layer) and published layers 2-5 (attention, then
+    three conv layers: one period of the 38 routed layers), experts 0-7
+    of 64 and rows 0-8,191 of the 65,536-row vocabulary, at 8,192 of its
+    128,000 positions. What is assumed of the source is in
+    ``benchmark/configs/lfm2-24b-a2b-ep8.json``."""
+    base = dict(
+        vocab_size=8192, max_seq_len=8192, num_layers=5, num_heads=32,
+        num_kv_heads=8, embedding_dim=2048, norm="rmsnorm", ln_epsilon=1e-5,
+        attn_bias=False, qk_norm=True, rope_layout=(0, 1, 0, 0, 0),
+        rope_theta=1e6, mixer_layout=(1, 0, 1, 1, 1), conv_kernel=3,
+        num_experts=64, experts_per_token=4, expert_width=1536,
+        experts_held=8, expert_offset=0, router_scoring="sigmoid",
+        router_scale=1.0, router_input="block", expert_activation="silu",
+        dense_layers=1, dense_width=11776, tie_embedding=True,
+        attn_dropout=0.0, mlp_dropout=0.0, embedding_dropout=0.0)
+    return ViTConfig(**{**base, **kw})
+
+
+def conv_tiny(**kw) -> ViTConfig:
+    """The same blocks at a size for tests: a conv layer with the dense
+    feed-forward, an attention layer and a conv layer routed, width 64,
+    4 query / 2 key-value heads of 16, dense 96, 8 experts of 32 of which
+    4 are held, top 2, 256 rows, 64 positions."""
+    return lfm2_24b_a2b_ep8(**{**dict(
+        vocab_size=256, max_seq_len=64, num_layers=3, num_heads=4,
+        num_kv_heads=2, embedding_dim=64, num_experts=8,
+        experts_per_token=2, expert_width=32, experts_held=4,
+        dense_width=96), **kw})
+
+
 PRESETS = {
     "ViT-Ti/16": vit_ti16,
     "ViT-S/16": vit_s16,
@@ -492,6 +565,8 @@ LM_PRESETS = {
     "mla-tiny": mla_tiny,
     "keye-vl-2.0-30b-a3b-ep8": keye_vl_20_30b_a3b_ep8,
     "dsa-tiny": dsa_tiny,
+    "lfm2-24b-a2b-ep8": lfm2_24b_a2b_ep8,
+    "conv-tiny": conv_tiny,
 }
 
 # The fields that make two configs the same *servable architecture*

@@ -408,7 +408,9 @@ def _report_first_step(train_step, state, batch, stats) -> None:
     lowered step, by kernel name with the per-shard operand's shape (the
     MLP kernels' rows, the attention pair's packed qkv projection; the
     kernel dispatch reads ``jax.default_backend()`` and the call's shapes
-    and falls back silently) — costs one more lowering, so it is skipped
+    and falls back silently), and for a model whose layers mix
+    convolutions and attention each layer's mixer beside them — costs
+    one more lowering, so it is skipped
     elsewhere, where there is no Mosaic to find; and the memory each
     local device holds, where the backend reports it."""
     from .ops.partition import mosaic_calls
@@ -431,6 +433,12 @@ def _report_first_step(train_step, state, batch, stats) -> None:
             for (name, shape), n in sorted(Counter(calls).items()))
         lines.append(f"train step: {len(calls)} Mosaic kernel calls: "
                      f"{by_kernel}")
+        # which mixer each layer has, where the model mixes two kinds
+        cfg = getattr(getattr(state.apply_fn, "__self__", None), "config",
+                      None)
+        if getattr(cfg, "mixer_layout", ()):
+            lines[-1] += " | mixers by layer: " + ", ".join(
+                f"{i} {cfg.layer_mixer(i)}" for i in range(cfg.num_layers))
     stats = [d.memory_stats() or {} for d in jax.local_devices()]
     if all("bytes_in_use" in s for s in stats):
         # (peak_bytes_in_use adds nothing here: on the v5e it reads the

@@ -92,7 +92,9 @@ class TokenEmbedding(nn.Module):
     the residual stream a block's norms and routers read is then the
     token's own row. At 0.02 it is what the first position-free causal
     layer adds to every position alike, and an untrained router sends a
-    whole sequence to the same experts."""
+    whole sequence to the same experts. A head tied to the table reads
+    these rows as its weights, and at N(0, 1) its initial logits would
+    be sqrt(D) wide: a tied table's rows start at ``init_std``."""
 
     config: ViTConfig
 
@@ -102,8 +104,8 @@ class TokenEmbedding(nn.Module):
         if ids.shape[1] > cfg.max_seq_len:
             raise ValueError(f"{ids.shape[1]} tokens, max_seq_len "
                              f"{cfg.max_seq_len}")
-        table = self.param("embedding",
-                           nn.initializers.normal(1.0),
+        std = cfg.init_std if cfg.tie_embedding else 1.0
+        table = self.param("embedding", nn.initializers.normal(std),
                            (cfg.vocab_size, cfg.embedding_dim), jnp.float32)
         return jnp.take(table, ids, axis=0).astype(_dtype(cfg))
 
@@ -454,6 +456,48 @@ def _latent_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     out = flat((heads, cfg.v_head_dim, cfg.embedding_dim), n_in=2,
                name="out")(attn.reshape(attn.shape[:2] + (-1,)))
     return out, y
+
+
+class ShortConvBlock(nn.Module):
+    """A conv layer's mixer (LFM2's gated short convolution), in the
+    attention's place and with its wiring: pre-norm, the residual added
+    by :class:`TransformerEncoderBlock`, the normed input handed on to a
+    routed block's router. ``[B | C | u] = norm(x) W_in`` (``in_proj``,
+    ``D -> 3D``), ``C * conv(B * u)`` over the last ``conv_kernel``
+    positions with ``taps [K, D]`` (:func:`..ops.short_conv.short_conv`),
+    ``W_out`` (``out_proj``, ``D -> D``); no bias. Scopes
+    ``conv/in_proj``, ``conv/mix``, ``conv/out_proj``, inside the
+    block's mixer scope (the module is named ``msa``, as the attention
+    it replaces). The mixer's output is sown into ``conv_probe`` (kept
+    where the caller makes it mutable: the benchmark compares layer 0's
+    with its reference)."""
+
+    config: ViTConfig
+    tp_axis: Optional[str] = None
+    layer: int = 0
+
+    @nn.compact
+    def __call__(self, x: jax.Array, train: bool = False,
+                 with_normed: bool = False):
+        from ..ops.short_conv import short_conv
+        if self.tp_axis is not None:
+            raise ValueError("a token model has no manual tensor "
+                             "parallelism")
+        cfg = self.config
+        d = cfg.embedding_dim
+        init = nn.initializers.normal(cfg.init_std)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=_dtype(cfg),
+                                  param_dtype=jnp.float32, kernel_init=init)
+        y = _norm(cfg, "norm")(x)
+        with jax.named_scope("conv"):
+            bcu = dense(3 * d, name="in_proj")(y)        # [B, T, 3D]
+            taps = self.param("taps", init, (cfg.conv_kernel, d),
+                              jnp.float32)
+            with jax.named_scope("mix"):
+                mixed = short_conv(bcu, taps)
+            out = dense(d, name="out_proj")(mixed)
+        self.sow("conv_probe", "out", out)
+        return (out, y) if with_normed else out
 
 
 def _turn_heads(x, heads, rope, theta, sign):
@@ -905,8 +949,9 @@ class TransformerEncoderBlock(nn.Module):
     """Pre-norm residual encoder block: ``x = msa(x)+x; x = mlp(x)+x``.
 
     Reference: ``models/vit.py:133-169`` (residual wiring at :167-168).
-    ``layer`` is the block's index: a token model's rotary positions and
-    attention kind are chosen per layer (``configs.ViTConfig``).
+    ``layer`` is the block's index: a token model's mixer (attention or a
+    gated short convolution), rotary positions and attention kind are
+    chosen per layer (``configs.ViTConfig``).
     """
 
     config: ViTConfig
@@ -916,7 +961,9 @@ class TransformerEncoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         msa = MultiHeadSelfAttentionBlock
-        if self.config.kv_lora_rank:
+        if self.config.layer_mixer(self.layer) == "conv":
+            msa = ShortConvBlock
+        elif self.config.kv_lora_rank:
             msa = nn.remat(msa, static_argnums=(2, 3),
                            policy=_KEEP_OF_LATENT_ATTENTION)
         elif self.config.sa_topk:
@@ -1039,12 +1086,17 @@ class ViT(nn.Module):
         cfg = self.config
         if cfg.mtp_modules and labels is not None:
             return _two_term_loss(self, images, labels, train)
-        tokens = ViTFeatureExtractor(cfg, name="backbone")(images, train)
+        backbone = ViTFeatureExtractor(cfg, name="backbone")
+        tokens = backbone(images, train)
+        # a tied head reads the embedding's table (one parameter, whose
+        # gradient is the sum of both uses')
+        table = (backbone.variables["params"]["token_embedding"]["embedding"]
+                 if cfg.tie_embedding else None)
         if cfg.sa_topk and labels is not None:
             return _with_indexer_loss(
-                self, LMHead(cfg, name="head")(tokens, labels))
+                self, LMHead(cfg, name="head")(tokens, labels, table=table))
         if cfg.vocab_size:
-            return LMHead(cfg, name="head")(tokens, labels)
+            return LMHead(cfg, name="head")(tokens, labels, table=table)
         if cfg.pool == "cls":
             pooled = tokens[:, 0]
         else:
@@ -1107,26 +1159,38 @@ def _with_indexer_loss(self: ViT, main_and_right):
 
 
 class LMHead(nn.Module):
-    """A token model's untied head: float32 logits ``[B, T, V]`` at every
+    """A token model's head: float32 logits ``[B, T, V]`` at every
     position or, given ``labels [B, T]`` (each position's target),
     ``(mean cross entropy, positions predicted right)`` over the
     positions ``counted`` (all of them by default) without the logits
-    ever being whole (:mod:`..ops.lm_loss`)."""
+    ever being whole (:mod:`..ops.lm_loss`). Untied, a ``kernel [D, V]``
+    of its own; tied (``tie_embedding``), no parameter: ``table`` is the
+    token embedding's ``[V, D]``, read as it lies."""
 
     config: ViTConfig
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
                  labels: Optional[jax.Array] = None,
-                 counted: Optional[jax.Array] = None):
+                 counted: Optional[jax.Array] = None,
+                 table: Optional[jax.Array] = None):
+        from ..ops import lm_loss
         cfg = self.config
-        kernel = self.param("kernel", nn.initializers.normal(cfg.init_std),
-                            (cfg.embedding_dim, cfg.vocab_size), jnp.float32)
-        if labels is None:
-            return jnp.dot(tokens, kernel.astype(tokens.dtype),
-                           preferred_element_type=jnp.float32)
-        from ..ops.lm_loss import head_cross_entropy
-        return head_cross_entropy(
+        if cfg.tie_embedding:
+            if labels is None:
+                return jnp.einsum("btd,vd->btv", tokens,
+                                  table.astype(tokens.dtype),
+                                  preferred_element_type=jnp.float32)
+            loss, kernel = lm_loss.tied_head_cross_entropy, table
+        else:
+            kernel = self.param(
+                "kernel", nn.initializers.normal(cfg.init_std),
+                (cfg.embedding_dim, cfg.vocab_size), jnp.float32)
+            if labels is None:
+                return jnp.dot(tokens, kernel.astype(tokens.dtype),
+                               preferred_element_type=jnp.float32)
+            loss = lm_loss.head_cross_entropy
+        return loss(
             tokens.reshape(-1, cfg.embedding_dim), kernel,
             labels.reshape(-1),
             counted=None if counted is None else counted.reshape(-1))

@@ -20,7 +20,10 @@ A position can be left out (``counted``): it adds nothing to the loss,
 to the count or to either gradient, and the mean is over the positions
 that count. Two calls on one head matrix (a model with a second
 objective through the same head) give it the sum of both gradients, as
-any function of a shared argument does.
+any function of a shared argument does; so does a head tied to the
+token embedding (:func:`tied_head_cross_entropy`, which reads the
+embedding's ``[V, D]`` table as it lies), whose table gets the lookup's
+gradient and the head's.
 """
 
 from __future__ import annotations
@@ -44,12 +47,26 @@ def head_cross_entropy(hidden, kernel, labels, chunk: int = CHUNK,
     return _forward(hidden, kernel, labels, chunk, counted)[0]
 
 
-def _forward(hidden, kernel, labels, chunk, counted):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def tied_head_cross_entropy(hidden, table, labels, chunk: int = CHUNK,
+                            counted=None):
+    """:func:`head_cross_entropy` through a head tied to the token
+    embedding: ``table [V, D]`` as the embedding holds it (logits
+    ``hidden @ table^T``, the gradient ``[V, D]`` as well): no transposed
+    copy of it is made."""
+    return _forward(hidden, table, labels, chunk, counted, rows=True)[0]
+
+
+def _forward(hidden, kernel, labels, chunk, counted, rows=False):
     # ``keep`` weights a chunk's terms where positions are left out; the
-    # mean is then over the positions that count.
+    # mean is then over the positions that count. ``rows``: ``kernel`` is
+    # a ``[V, D]`` table, not a ``[D, V]`` matrix.
     n = hidden.shape[0] if counted is None else jnp.maximum(
         jnp.sum(counted.astype(jnp.float32)), 1.0)
     w = kernel.astype(hidden.dtype)
+    by = lambda a, b, lhs, rhs: jax.lax.dot_general(
+        a, b, (((lhs,), (rhs,)), ((), ())),
+        preferred_element_type=jnp.float32)
     loss_sum = jnp.zeros((), jnp.float32)
     correct = jnp.zeros((), jnp.float32)
     d_kernel = jnp.zeros(kernel.shape, jnp.float32)
@@ -60,7 +77,8 @@ def _forward(hidden, kernel, labels, chunk, counted):
             counted[lo:lo + chunk].astype(jnp.float32)
         kept = lambda terms: terms if keep is None else keep * terms
         with jax.named_scope("head"):
-            logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
+            logits = by(h, w, 1, 1) if rows else jnp.dot(
+                h, w, preferred_element_type=jnp.float32)
         with jax.named_scope("loss"):
             top = jnp.max(logits, axis=-1, keepdims=True)
             e = jnp.exp(logits - top)
@@ -76,17 +94,19 @@ def _forward(hidden, kernel, labels, chunk, counted):
                         else d_logits * (keep / n)[:, None]
                         ).astype(hidden.dtype)
         with jax.named_scope("head"):
-            d_hidden.append(jax.lax.dot_general(
-                d_logits, w, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32).astype(hidden.dtype))
-            d_kernel += jax.lax.dot_general(
-                h, d_logits, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            d_hidden.append(by(d_logits, w, 1, 0 if rows else 1).astype(
+                hidden.dtype))
+            d_kernel += by(d_logits, h, 0, 0) if rows else by(h, d_logits,
+                                                               0, 0)
     return (loss_sum / n, correct), (jnp.concatenate(d_hidden), d_kernel)
 
 
 def _fwd(hidden, kernel, labels, chunk, counted):
     return _forward(hidden, kernel, labels, chunk, counted)
+
+
+def _fwd_rows(hidden, table, labels, chunk, counted):
+    return _forward(hidden, table, labels, chunk, counted, rows=True)
 
 
 def _bwd(chunk, res, g):
@@ -97,3 +117,4 @@ def _bwd(chunk, res, g):
 
 
 head_cross_entropy.defvjp(_fwd, _bwd)
+tied_head_cross_entropy.defvjp(_fwd_rows, _bwd)

@@ -97,7 +97,10 @@ LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
 # then the latent attention's two paths and the shared expert, then the
 # sparse-attention indexer's four (matched without ``msa/`` in front: an
 # op inside the loops over chunks of query rows may carry the path from
-# the loop's body on). A ViT's paths match none of them.
+# the loop's body on), then a conv layer's mixer (its two projections and
+# the gate-conv-gate between them; the module keeps the name ``msa``, so
+# the frozen table counts it under ``msa_glue`` and its norm under
+# ``msa_norm``). A ViT's paths match none of them.
 TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
                      for name, pat in (
     ("mtp_merge", r"mtp/(?:.*/)?mtp_merge"),
@@ -114,6 +117,8 @@ TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
     ("indexer/scores", r"indexer/scores"),
     ("indexer/select", r"indexer/select"),
     ("indexer_loss", r"indexer_loss"),
+    ("conv_proj", r"msa/conv/(?:in|out)_proj"),
+    ("conv_mix", r"msa/conv/mix"),
     ("rope", r"msa/rope"),
     ("token_embedding", r"token_embedding"),
     ("head_loss", r"head/(?:.*/)?loss"),

@@ -62,6 +62,41 @@ def visible_pairs(tokens: int, window: int = 0) -> int:
     return window * (window + 1) // 2 + (tokens - window) * window
 
 
+def conv_mixer_flops(cfg, tokens: int) -> float:
+    """Forward FLOPs of a gated short convolution over ``tokens``
+    positions: its in (``D -> 3D``) and out (``D -> D``) projections and
+    the convolution's taps (a product a tap, channel and position); the
+    two gates are elementwise and not counted."""
+    d = cfg.embedding_dim
+    return 2 * tokens * d * 4 * d + 2 * tokens * cfg.conv_kernel * d
+
+
+def _attention_flops(cfg, layer: int, t: int) -> float:
+    """Forward FLOPs of block ``layer``'s attention over ``t``
+    positions (:func:`forward_flops_per_sequence`)."""
+    d, dh, hq, hkv = cfg.embedding_dim, cfg.head_dim, cfg.num_heads, \
+        cfg.kv_heads
+    _, window = cfg.attention_kind(layer)
+    total = 0.0
+    if cfg.kv_lora_rank:
+        # queries and keys/values down to their latents and up again
+        total += 2 * t * (d * cfg.q_lora_rank + cfg.q_lora_rank * hq * dh)
+        total += 2 * t * (
+            d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+            + cfg.kv_lora_rank * hq * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+    else:
+        total += 2 * t * d * (hq + 2 * hkv) * dh        # q, k, v
+    # QK^T, PV (a selection of ``topk`` keys counts as a window does)
+    total += 2 * 2 * visible_pairs(t, window) * hq * dh
+    if cfg.sa_topk:
+        # the indexer: its three projections, its scores of every causal
+        # pair
+        heads, width = cfg.sa_index_heads, cfg.sa_index_head_dim
+        total += 2 * t * d * (heads * width + width + heads)
+        total += 2 * visible_pairs(t) * heads * width
+    return total + 2 * t * hq * dh * d                  # out projection
+
+
 def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
     """Analytic forward FLOPs of one sequence of a token model
     (:class:`..configs.ViTConfig` with ``vocab_size`` > 0): visible
@@ -74,34 +109,18 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
     counts the selected pairs for the core, and the indexer's projections
     and its scores of every causal pair. A multi-token-prediction module
     is its merge (``2D -> D``), one more block of the last kind and the
-    head a second time. The embedding is a lookup and the rotary
-    embedding elementwise: neither is counted."""
+    head a second time. A conv layer counts its mixer
+    (:func:`conv_mixer_flops`) in the attention's place. The embedding is
+    a lookup and the rotary embedding elementwise: neither is counted."""
     t = seq_len or cfg.max_seq_len
-    d, dh, hq, hkv = cfg.embedding_dim, cfg.head_dim, cfg.num_heads, \
-        cfg.kv_heads
+    d = cfg.embedding_dim
     gated = lambda tokens, width: 3 * 2 * tokens * d * width
     total = 0.0
     for layer in range(cfg.num_layers + cfg.mtp_modules):
-        _, window = cfg.attention_kind(layer)
-        if cfg.kv_lora_rank:
-            # queries and keys/values down to their latents and up again
-            total += 2 * t * (d * cfg.q_lora_rank
-                              + cfg.q_lora_rank * hq * dh)
-            total += 2 * t * (
-                d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-                + cfg.kv_lora_rank * hq
-                * (cfg.qk_nope_head_dim + cfg.v_head_dim))
+        if cfg.layer_mixer(layer) == "conv":
+            total += conv_mixer_flops(cfg, t)
         else:
-            total += 2 * t * d * (hq + 2 * hkv) * dh        # q, k, v
-        # QK^T, PV (a selection of ``topk`` keys counts as a window does)
-        total += 2 * 2 * visible_pairs(t, window) * hq * dh
-        if cfg.sa_topk:
-            # the indexer: its three projections, its scores of every
-            # causal pair
-            heads, width = cfg.sa_index_heads, cfg.sa_index_head_dim
-            total += 2 * t * d * (heads * width + width + heads)
-            total += 2 * visible_pairs(t) * heads * width
-        total += 2 * t * hq * dh * d                    # out projection
+            total += _attention_flops(cfg, layer, t)
         if cfg.layer_routed(layer):
             total += 2 * t * d * cfg.num_experts        # router
             pairs = t * cfg.experts_per_token * cfg.num_experts_held \

@@ -1,22 +1,26 @@
 """The copies this repo keeps of the program inside the benchmark (a
 yardstick that neither side of a comparison can edit) for the token model
-whose attention an indexer selects, held equal to the program: the FLOP
-count (``benchmark/lib/flops_dsa.py``), the finer table of scopes
-(``benchmark/lib/scopes_dsa.py``) and the preset against its
-configuration file. (The older copies are held by
-``benchmark/tests/test_copies.py``, ``test_lm_files.py`` and
+whose attention an indexer selects and for the one whose layers mix gated
+short convolutions with attention, held equal to the program: the FLOP
+counts (``benchmark/lib/flops_dsa.py``, ``flops_conv.py``), the finer
+tables of scopes (``benchmark/lib/scopes_dsa.py``, ``scopes_conv.py``)
+and the presets against their configuration files. (The older copies are
+held by ``benchmark/tests/test_copies.py``, ``test_lm_files.py`` and
 ``test_mla_files.py``.)"""
 
 import json
 
 import pytest
 
-from benchmark.lib import flops_dsa, harness, scopes_dsa
+from benchmark.lib import flops_conv, flops_dsa, harness, scopes_conv, \
+    scopes_dsa
 from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
 from pytorch_vit_paper_replication_tpu.telemetry import device_trace, flops
 
 CONFIG = harness.BENCH / "configs" / "keye-vl-2.0-30b-a3b-ep8.json"
 CELL = "keye2_train_16k"
+CONV_CONFIG = harness.BENCH / "configs" / "lfm2-24b-a2b-ep8.json"
+CONV_CELL = "lfm2_train_8k"
 
 
 @pytest.mark.parametrize("tokens", [16384, 8192, 2048, 1000, 64])
@@ -58,6 +62,37 @@ def test_config_file_is_the_programs_preset(preset, rehearsal):
     assert json.loads(CONFIG.read_text())["name"] == config["name"]
 
 
+@pytest.mark.parametrize("tokens", [8192, 2048, 1000, 64])
+def test_conv_flop_count_equals_the_programs(tokens):
+    config = harness.load_json(CONV_CONFIG)
+    cfg, _ = harness.build_model(config)
+    assert flops_conv.train_step_flops_per_sequence(config["model"], tokens) \
+        == flops.train_step_flops_per_sequence(cfg, tokens)
+    assert flops_conv.forward_flops_per_sequence(config["model"], tokens) \
+        == flops.forward_flops_per_sequence(cfg, tokens)
+    tiny = harness.load_cell(CONV_CELL, rehearsal=True)[1]
+    assert flops_conv.train_step_flops_per_sequence(tiny["model"], tokens) \
+        == flops.train_step_flops_per_sequence(
+            harness.build_model(tiny)[0], tokens)
+
+
+def test_conv_finer_table_equals_the_programs_new_rows():
+    theirs = {n: p.pattern for n, p in device_trace.TOKEN_LAYERS}
+    mine = [(n, p.pattern) for n, p in scopes_conv.ROWS]
+    assert [n for n, _ in mine] == [
+        n for n, _ in device_trace.TOKEN_LAYERS if n.startswith("conv_")]
+    assert all(theirs[n] == pattern for n, pattern in mine)
+
+
+@pytest.mark.parametrize("preset,rehearsal", [
+    ("lfm2-24b-a2b-ep8", False), ("conv-tiny", True)])
+def test_conv_config_file_is_the_programs_preset(preset, rehearsal):
+    config = harness.load_cell(CONV_CELL, rehearsal=rehearsal)[1]
+    assert harness.build_model(config)[0] == LM_PRESETS[preset]()
+    assert config["program_preset"] == "lfm2-24b-a2b-ep8"
+    assert json.loads(CONV_CONFIG.read_text())["name"] == config["name"]
+
+
 def _window_of(driver: str) -> tuple:
     """The part of a token driver's ``run`` that IS the yardstick of
     ``train_img_s``: from the window's constants to the end of the
@@ -72,10 +107,11 @@ def _window_of(driver: str) -> tuple:
     return body, result
 
 
-@pytest.mark.parametrize("copy", ["train_mla", "train_dsa"])
+@pytest.mark.parametrize("copy", ["train_mla", "train_dsa", "train_conv"])
 def test_the_timed_window_is_one_text_in_every_token_driver(copy):
-    """``drivers/train_mla.py::run`` and ``drivers/train_dsa.py::run`` are
-    ``drivers/train_lm.py::run`` written again (a ``model_config`` PR may
+    """``drivers/train_mla.py::run``, ``drivers/train_dsa.py::run`` and
+    ``drivers/train_conv.py::run`` are ``drivers/train_lm.py::run``
+    written again (a ``model_config`` PR may
     edit no accepted benchmark file; PERF.md section 7 queues the one
     ``run`` that takes the cell's key, comparison and checks). Until
     then an edit to where the window opens or closes in one of them and

@@ -781,10 +781,13 @@ def test_every_other_preset_lowers_to_the_parents_text(name, example, sha):
 
 def test_other_presets_take_none_of_this_models_options():
     fields = ("sa_topk", "sa_index_heads", "sa_index_head_dim", "qk_norm")
+    # (LFM2's attention has per-head q / k norms too: tests/test_conv.py)
+    shares_qk_norm = ("lfm2-24b-a2b-ep8", "conv-tiny")
     for name, make in {**PRESETS, **LM_PRESETS}.items():
         if name in ("keye-vl-2.0-30b-a3b-ep8", "dsa-tiny"):
             continue
-        assert not any(getattr(make(), f) for f in fields), name
+        taken = fields[:3] if name in shares_qk_norm else fields
+        assert not any(getattr(make(), f) for f in taken), name
         assert make().attention_kind(0)[0] != "causal_topk", name
     assert LM_PRESETS["dsa-tiny"]().attention_kind(1) == ("causal_topk", 8)
 

@@ -83,6 +83,9 @@ THE_CHOICE = [
     _case("keye2_train_16k: the keys an indexer selects, a mask a row",
           (1, 16384, 32, 128), "flash", k_shape=(1, 16384, 4, 128),
           kind="causal_topk"),
+    _case("lfm2_train_8k: causal GQA at Dh 64, three sequences",
+          (3, 8192, 32, 64), "flash", k_shape=(3, 8192, 8, 64),
+          kind="causal"),
     # --- other models the repo names ---
     _case("dsa-tiny: T 64, Dh 16, selected keys", (8, 64, 4, 16), "xla",
           k_shape=(8, 64, 2, 16), kind="causal_topk"),

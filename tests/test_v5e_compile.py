@@ -613,3 +613,60 @@ def test_flash_kernels_compile_with_a_selection_a_row(v5e_2x2):
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     assert not re.search(r"bf16\[32,16384,128\]", hlo)
+
+
+# ------------------------------------------- gated short convolutions (LFM2)
+# The cell ``lfm2_train_8k``'s step held 12.40 GiB when it was first
+# compiled (three sequences of 8,192 tokens; 5.25 GiB of parameters and moments,
+# 7.16 of temporaries), no remat.
+CONV_STEP_GIB = 12.9
+
+
+@pytest.fixture(scope="module")
+def conv_step(v5e_2x2):
+    """The cell ``lfm2_train_8k``'s step as the trainer builds it,
+    lowered and compiled once for the file."""
+    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = LM_PRESETS["lfm2-24b-a2b-ep8"]()
+        lowered = _lower_lm_step(v5e_2x2[:1], cfg, dp=1, batch=3,
+                                 seq_len=cfg.max_seq_len)
+        return lowered, lowered.compile()
+
+
+def test_conv_models_step_compiles_and_fits_one_chip(conv_step):
+    """Three 8,192-token sequences through LFM2-24B-A2B's cut at every
+    published width: the one attention layer names the flash pair at head
+    size 64 (heads folded: 32 over 8 key/value heads), the four routed
+    blocks the grouped products of their 8 held experts (two chunks of
+    12,288 tokens a block, each a loop of passes lowered once; the first
+    product taken again in the backward pass), and the conv mixers no
+    kernel: their mix is XLA's. It compiles for the v5e and fits 15.75
+    GiB with room; the mixer's three scopes are in the compiled step,
+    under ``msa_glue`` by the frozen table and never ``other``."""
+    from benchmark.lib import scopes
+
+    lowered, compiled = conv_step
+    names = [name for name, _ in mosaic_calls(lowered.as_text())]
+    assert {n: names.count(n) for n in set(names)} == {
+        "flash_fwd": 1, "flash_bwd": 1,
+        "moe_gmm_fwd": 24, "moe_gmm_dx": 16, "moe_gmm_dw": 16}
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert held < CONV_STEP_GIB * 2**30, held / 2**30
+    hlo = compiled.as_text()
+    paths = set(device_trace.parse_scopes(hlo)["scopes"].values())
+    layers = [device_trace.classify(path)[0] for path in paths
+              if path.startswith("jit(")]
+    for layer in ("conv_proj", "conv_mix", "attn_core", "moe_router",
+                  "moe_experts", "head_loss"):
+        assert layer in layers, layer
+    mixer = [path for path in paths if "/msa/conv/" in path]
+    assert mixer and all(scopes.classify(path)[0] == "msa_glue"
+                         for path in mixer)
+    assert layers.count("other") < 0.01 * len(layers)
+    # the tied head reads the table as it lies: no transposed copy of it
+    assert not re.search(r"= \w+\[2048,8192\]\S* (?:copy|transpose)\(", hlo)
