@@ -160,16 +160,21 @@ def _dsa_metrics(dsa_stats) -> Dict[str, jax.Array]:
     """The counters of one step's indexed attention, from what the
     blocks sowed (``dsa_stats``, per layer): the query-key pairs the
     selection kept and the causal pairs it chose from, a sequence a
-    layer (the layers' mean), and the smallest over layers of the mean
+    layer (the layers' mean), the smallest over layers of the mean
     over queries of ``pbar``'s mass on the selection (1 by
-    construction: it guards the normalisation)."""
+    construction: it guards the normalisation), the layers' share whose
+    selection the kernel ``dsa_select`` searched, and the rows whose
+    threshold score was tied beyond what they take (the tie pass
+    decides them), a sequence, summed over layers."""
     from flax.traverse_util import flatten_dict
     sown = flatten_dict(dsa_stats)
     leaves = lambda key: jnp.stack([v for path, vs in sown.items()
                                     if path[-1] == key for v in vs])
     return {"dsa_selected_pairs": jnp.mean(leaves("selected_pairs")),
             "dsa_causal_pairs": jnp.mean(leaves("causal_pairs")),
-            "dsa_pbar_mass_min": jnp.min(leaves("pbar_mass"))}
+            "dsa_pbar_mass_min": jnp.min(leaves("pbar_mass")),
+            "dsa_select_served": jnp.mean(leaves("select_served")),
+            "dsa_select_tie_rows": jnp.sum(leaves("select_tie_rows"))}
 
 
 def _token_loss(state, params, batch, dropout_rng):
@@ -203,7 +208,8 @@ def _token_loss(state, params, batch, dropout_rng):
 # A token model's step counters (beside ``moe_*``) that the loop hands to
 # the telemetry on barriered steps.
 LM_COUNTERS = ("main_loss", "mtp_loss", "mtp_top1_share", "indexer_loss",
-               "dsa_selected_pairs", "dsa_causal_pairs", "dsa_pbar_mass_min")
+               "dsa_selected_pairs", "dsa_causal_pairs", "dsa_pbar_mass_min",
+               "dsa_select_served", "dsa_select_tie_rows")
 
 
 def _masked_metrics(losses, logits, labels, mask) -> Dict[str, jax.Array]:

@@ -147,13 +147,14 @@ def mosaic_calls(lowered_text: str) -> List[Tuple[str, Tuple[int, ...]]]:
     ``tpu_custom_call``. Under a mesh the operand shape is the
     per-shard one (rows = images per chip x tokens for the MLP
     kernels). Operand 0 is the kernels' scalar-prefetch vector, so the
-    shape reported is operand 1's."""
+    shape reported is operand 1's; a call with no operand (one that only
+    makes its result's buffer) reports ``()``."""
     calls = []
     for line in lowered_text.splitlines():
         m = _MOSAIC_CALL.search(line)
         if m is None:
             continue
-        operands = re.findall(r"tensor<([^>]+)>", m.group(2))
+        operands = re.findall(r"tensor<([^>]+)>", m.group(2)) or [""]
         dims = operands[min(1, len(operands) - 1)].split("x")[:-1]
         calls.append((m.group(1), tuple(int(d) for d in dims)))
     return calls

@@ -21,8 +21,11 @@ folded in), for a query t and a key s <= t:
 **What this version does with unselected keys: it visits them, a block
 pair at a time.** The ``[T, T]`` scores of the SELECTION are taken
 ``chunk`` query rows at a time over every key (a loop of XLA products; at
-T = 16,384 the whole matrix would be 1 GiB a layer), the selection leaves
-as one int8 ``[B, T, T]`` array, and the core is the causal flash kernel
+T = 16,384 the whole matrix would be 1 GiB a layer), each chunk searched
+by one more kernel over its causal keys where the flash kernels serve the
+core (:mod:`.indexer_select`) and by XLA over every key everywhere else
+(:func:`select_rows`), the selection leaves as one int8 ``[B, T, T]``
+array, and the core is the causal flash kernel
 pair reading a strip of that array a query block
 (:func:`..flash_attention.flash_attention` with ``mask=`` and
 ``return_lse``): every causal block is computed and the unselected pairs
@@ -53,6 +56,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from . import indexer_loss as indexer_loss_kernels
+from . import indexer_select
 from .attention import choose
 
 _F32 = jnp.float32
@@ -93,7 +97,14 @@ def select_rows(total, rows, topk: int):
     position — what ``lax.top_k`` over the causal scores selects, by
     bisection on the scores' bit patterns (32 counts over the row, then
     14 over positions only where the threshold value is tied: a sort of
-    16,384 scores a row is what no TPU does fast)."""
+    16,384 scores a row is what no TPU does fast). The reference of the
+    kernel :mod:`.indexer_select`."""
+    return _select_rows(total, rows, topk)[0]
+
+
+def _select_rows(total, rows, topk: int):
+    """:func:`select_rows`, and ``[B, C]`` True where a row had more keys
+    at its threshold than it takes."""
     t = total.shape[-1]
     cols = jnp.arange(t, dtype=jnp.int32)
     causal = cols[None, :] <= rows[:, None]                     # [C, T]
@@ -128,27 +139,62 @@ def select_rows(total, rows, topk: int):
                                  jnp.zeros(total.shape[:2], jnp.int32))
         return tied & (cols <= last[..., None])
 
-    tied = jax.lax.cond(jnp.any(count(tied) > short), lowest,
-                        lambda tied: tied, tied)
-    return ((above | tied) & causal[None]).astype(jnp.int8)
+    more = count(tied) > short
+    tied = jax.lax.cond(jnp.any(more), lowest, lambda tied: tied, tied)
+    return ((above | tied) & causal[None]).astype(jnp.int8), more
 
 
-def select(q_idx, k_idx, w, *, topk: int, chunk: int = 512):
-    """The selection of every query: int8 ``[B, T, T]`` (1 = attend),
-    ``chunk`` query rows at a time. No gradient passes."""
+def _select(q_idx, k_idx, w, topk, chunk, served):
+    """``(selection, rows tied beyond what they take, whether the kernel
+    searched)``: ``chunk`` query rows at a time, each chunk's scores by
+    :func:`scores` and its search by the kernel :mod:`.indexer_select`
+    where it serves (where the flash kernels serve the core: ``served``),
+    else by :func:`select_rows`."""
     q_idx, k_idx, w = (jax.lax.stop_gradient(x) for x in (q_idx, k_idx, w))
     b, t = q_idx.shape[:2]
     c = _chunks(t, chunk)
 
-    def rows_of(i):
-        rows = i * c + jnp.arange(c, dtype=jnp.int32)
+    def chunk_scores(i):
         take = lambda x: jax.lax.dynamic_slice_in_dim(x, i * c, c, axis=1)
-        total, _ = scores(take(q_idx), k_idx, take(w))
-        with jax.named_scope("indexer/select"):
-            return select_rows(total, rows, topk)
+        return scores(take(q_idx), k_idx, take(w))[0]
 
-    picked = jax.lax.map(rows_of, jnp.arange(t // c))      # [n, B, C, T]
-    return jnp.moveaxis(picked, 0, 1).reshape(b, t, t)
+    if indexer_select.serves(served, t, c):
+        def into(i, carry):
+            picked, tied = carry
+            total = chunk_scores(i)
+            with jax.named_scope("indexer/select"):
+                picked, more = indexer_select.select(picked, total, i * c,
+                                                     topk)
+            return picked, tied + jnp.sum(more)
+
+        with jax.named_scope("indexer/select"):
+            picked = indexer_select.empty(b, t)
+        # each chunk's queries written in place (a stacked result would
+        # be written by the kernel fused with the stacking, which the
+        # compiler cannot give its VMEM)
+        picked, tied = jax.lax.fori_loop(0, t // c, into,
+                                         (picked, jnp.int32(0)))
+        with jax.named_scope("indexer/select"):
+            return jnp.swapaxes(picked[:, :t], 1, 2), tied, True
+
+    def rows_of(i):
+        total = chunk_scores(i)
+        with jax.named_scope("indexer/select"):
+            picked, more = _select_rows(
+                total, i * c + jnp.arange(c, dtype=jnp.int32), topk)
+            return picked, jnp.sum(more, dtype=jnp.int32)
+
+    picked, tied = jax.lax.map(rows_of, jnp.arange(t // c))  # [n, B, C, T]
+    return jnp.moveaxis(picked, 0, 1).reshape(b, t, t), jnp.sum(tied), False
+
+
+def select(q_idx, k_idx, w, *, topk: int, chunk: int = 512):
+    """The selection of every query: int8 ``[B, T, T]`` (1 = attend),
+    ``chunk`` query rows at a time by :func:`select_rows`. No gradient
+    passes. (:func:`sparse_attention` searches by the kernel
+    ``dsa_select`` where the flash kernels serve the core: the same
+    selection.)"""
+    return _select(q_idx, k_idx, w, topk, chunk, "xla")[0]
 
 
 _BITS = 8
@@ -332,11 +378,16 @@ def sparse_attention(q, k, v, q_idx, k_idx, w, *, topk: int, chunk: int = 512,
                      impl: str = "auto"):
     """The whole mechanism: ``(o [B, T, H, Dh], L_I, stats)``. ``stats``:
     ``mask`` (int8 ``[B, T, T]``), ``selected_pairs`` and
-    ``causal_pairs`` (a sequence), ``pbar_mass`` (1 by construction).
+    ``causal_pairs`` (a sequence), ``pbar_mass`` (1 by construction),
+    ``select_served`` (1 where the kernel ``dsa_select`` took the
+    selection, 0 where :func:`select_rows` did) and ``select_tie_rows``
+    (rows with more keys at their threshold than they take, a sequence).
     Scopes: ``indexer/scores``, ``indexer/select``, ``attn_core``,
     ``indexer_loss``."""
     b, t = q.shape[:2]
-    mask = select(q_idx, k_idx, w, topk=topk, chunk=chunk)
+    served, _ = choose(q.shape, q.dtype, k.shape, impl=impl,
+                       kind="causal_topk")
+    mask, tie_rows, searched = _select(q_idx, k_idx, w, topk, chunk, served)
     if t % _BITS == 0:
         # Named a bit a pair: a caller that takes the block again in the
         # backward pass keeps that, and the bytes the kernels read are
@@ -356,4 +407,6 @@ def sparse_attention(q, k, v, q_idx, k_idx, w, *, topk: int, chunk: int = 512,
     return out, loss, {
         "mask": mask, "selected_pairs": selected,
         "causal_pairs": jnp.asarray(t * (t + 1) // 2, _F32),
-        "pbar_mass": jax.lax.stop_gradient(mass)}
+        "pbar_mass": jax.lax.stop_gradient(mass),
+        "select_served": jnp.asarray(float(searched), _F32),
+        "select_tie_rows": tie_rows.astype(_F32) / b}

@@ -78,6 +78,8 @@ INSTRUMENTS: Dict[str, str] = {
     "tel_dsa_selected_pairs": "gauge",
     "tel_dsa_causal_pairs": "gauge",
     "tel_dsa_pbar_mass_min": "gauge",
+    "tel_dsa_select_served": "gauge",
+    "tel_dsa_select_tie_rows": "gauge",
     "tel_goodput_pct": "gauge",         # step-exec share of wall time
     "tel_data_wait_frac": "gauge",      # data-wait share of wall time
     "tel_steps_total": "counter",
@@ -331,6 +333,12 @@ HELP_TEXT: Dict[str, str] = {
     "tel_dsa_pbar_mass_min":
         "Smallest over layers of the mean over queries of the head-mean "
         "attention probabilities' mass on the selection (1 by construction)",
+    "tel_dsa_select_served":
+        "Share of the indexed layers whose selection the dsa_select kernel "
+        "searched (0: the XLA bisection)",
+    "tel_dsa_select_tie_rows":
+        "Query rows whose threshold score was tied beyond what they take, "
+        "a sequence, summed over layers",
     "tel_goodput_pct": "Step-exec share of epoch wall time, percent",
     "tel_data_wait_frac": "Data-wait share of epoch wall time",
     "tel_steps_total": "Train steps recorded",

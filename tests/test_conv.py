@@ -459,7 +459,7 @@ IMAGES = {"x": jnp.zeros((1, 32, 32, 3)),
     ("glm-4.7-flash-ep8", TOKENS,
      "3cc9c473e34e317b59f3d8da94cb2ca562a9083ff792de3dd48dd378c3186fac"),
     ("keye-vl-2.0-30b-a3b-ep8", TOKENS,
-     "eaa99f295229e10dbb76ed9a478e40cce9fdd6ae0c80c50b4a54fea766403d6e"),
+     "6503ba31bac5594dcaab63ae53401e1adbf33ee970144b3e1aafeb923952b61e"),
 ])
 def test_every_accepted_preset_lowers_to_the_parents_text(name, example,
                                                           sha):
@@ -473,7 +473,10 @@ def test_every_accepted_preset_lowers_to_the_parents_text(name, example,
     The constants hold for that parent only: a later PR that changes one
     of these programs on purpose, or an upgrade of jax, deletes them with
     this test; ``test_other_presets_take_none_of_this_models_options``
-    is the check that stays."""
+    is the check that stays. Keye-VL's was recorded again when its
+    selection gained its two counters (``select_served``,
+    ``select_tie_rows``) and, where flash serves the core, its search
+    kernel: that program changed on purpose, the other four did not."""
     if name in LM_PRESETS:
         cfg = LM_PRESETS[name]()
     else:

@@ -261,6 +261,98 @@ def test_ties_go_to_the_lower_position(levels):
         assert got[:, -1, :8].all() and not got[:, -1, 8:].any()
 
 
+def _tied_rows(total, rows, topk, tied_rows):
+    """``total`` with, in each of ``tied_rows`` (positions among
+    ``rows``), its first ``want + 3`` keys at one value above every other
+    key of the row: three more keys at the threshold than the row takes,
+    so the tie pass decides it (ties to the lower position)."""
+    total = np.array(total)
+    for r in tied_rows:
+        want = min(int(rows[r]) + 1, topk)
+        total[:, r, :want + 3] = total[:, r].max() + 1.0
+    return jnp.asarray(total)
+
+
+@pytest.mark.parametrize("case", [
+    # random scores with negative values, -0.0 and +0.0 among them
+    dict(t=384, c=128, row0=128, topk=100),
+    # a chunk at a row offset, two query blocks of 64
+    dict(t=512, c=128, row0=256, topk=64, block_q=64),
+    # a block entirely under topk: every causal key, no search
+    dict(t=256, c=128, row0=0, topk=200),
+    # one block under topk and one that straddles it
+    dict(t=256, c=128, row0=0, topk=100, block_q=64),
+    # rows tied at their threshold beyond what they take
+    dict(t=384, c=128, row0=128, topk=100, tied=(0, 5, 127)),
+    # every score one of three values: ties in every row
+    dict(t=256, c=128, row0=128, topk=40, levels=3),
+    # causal widths that are no whole number of 128-key tiles, two key
+    # blocks
+    dict(t=200, c=40, row0=120, topk=50, block_k=128),
+], ids=["random", "offset", "under_topk", "straddles_topk", "tied",
+        "three_levels", "ragged_width"])
+def test_the_selection_kernel_equals_select_rows(case):
+    """``ops/indexer_select.py`` (``dsa_select``, the interpreter here)
+    writes a chunk's selection into the layer's buffer element for
+    element as ``select_rows`` selects it from the same float32 scores,
+    and flags exactly the rows the tie pass decides."""
+    from pytorch_vit_paper_replication_tpu.ops import indexer_select
+
+    t, c, row0, topk = case["t"], case["c"], case["row0"], case["topk"]
+    total = jax.random.normal(jax.random.key(t + row0), (2, c, t))
+    if "levels" in case:
+        total = jnp.round(total * (case["levels"] - 1) / 2)
+    if "tied" not in case:
+        total = total.at[:, :, 1].set(-0.0).at[:, :, 2].set(0.0)
+    rows = row0 + jnp.arange(c, dtype=jnp.int32)
+    total = _tied_rows(total, rows, topk, case.get("tied", ()))
+    want, more = sa._select_rows(total, rows, topk)
+    picked, tied = indexer_select.select(
+        indexer_select.empty(2, t), total, jnp.int32(row0), topk,
+        block_q=case.get("block_q"), block_k=case.get("block_k"))
+    got = np.swapaxes(np.asarray(picked)[:, :t], 1, 2)[:, row0:row0 + c]
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got, np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(tied), np.asarray(more))
+    # what lax.top_k over the causal scores selects, ties to the lower
+    # position
+    by_top_k = _by_top_k(jnp.pad(total, ((0, 0), (row0, t - row0 - c),
+                                         (0, 0))), topk)[:, row0:row0 + c]
+    np.testing.assert_array_equal(got != 0, by_top_k)
+    if case.get("tied"):
+        assert sorted(np.flatnonzero(np.asarray(tied)[0])) == list(
+            case["tied"])
+        for r in case["tied"]:
+            wanted = min(row0 + r + 1, topk)
+            assert got[:, r, :wanted].all() and not got[:, r, wanted:].any()
+    if row0 + c <= topk:
+        np.testing.assert_array_equal(
+            got, np.broadcast_to(np.tril(np.ones((c, t), np.int8)),
+                                 got.shape))
+
+
+@pytest.mark.parametrize("impl,served", [("flash", 1.0), ("xla", 0.0)])
+def test_the_selections_counters(impl, served):
+    """``select_served`` says which search took the layer's selection (the
+    kernel where flash serves the core, ``select_rows`` else) and
+    ``select_tie_rows`` counts, a sequence, the rows the tie pass
+    decided: the same number from both."""
+    q, k, v = _core_inputs()
+    q_i, k_i, w = _scores(3)
+    # products all positive: no score is 0 but those of 6 rows whose
+    # indexer queries are 0, which score every key alike
+    q_i, k_i = jnp.abs(q_i).at[:, 20:26].set(0.0), jnp.abs(k_i)
+    _, _, stats = sa.sparse_attention(q, k, v, q_i, k_i, w, topk=8,
+                                      chunk=16, impl=impl)
+    assert float(stats["select_served"]) == served
+    # rows 20-25 score every key 0 and take 8 of their 21-26: tied
+    assert float(stats["select_tie_rows"]) == 6.0
+    total = jnp.einsum("btj,bjts->bts", w, jax.nn.relu(
+        jnp.einsum("btjd,bsd->bjts", q_i, k_i)))
+    np.testing.assert_array_equal(np.asarray(stats["mask"]) != 0,
+                                  _by_top_k(total, 8))
+
+
 def test_the_kept_selection_is_a_bit_a_pair():
     mask = (jax.random.uniform(jax.random.key(0), (2, T, T)) > 0.5).astype(
         jnp.int8)
@@ -469,7 +561,8 @@ def test_the_forced_flash_path_is_the_kernels_and_trains_the_indexer():
         np.testing.assert_allclose(x, y, atol=1e-6)
     calls = str(jax.make_jaxpr(jax.grad(
         lambda *a: objective(*a, "flash")[0], (0, 1, 2)))(q_i, k_i, w))
-    for kernel in ("flash_fwd", "indexer_loss_fwd", "indexer_loss_bwd"):
+    for kernel in ("flash_fwd", "indexer_loss_fwd", "indexer_loss_bwd",
+                   "dsa_select_buffer", "dsa_select"):
         assert f"name={kernel}" in calls, kernel
 
 
@@ -533,6 +626,9 @@ def test_train_step_learns_and_counts(tiny):
     assert float(m["dsa_selected_pairs"]) == 36 + (T - 8) * 8
     assert float(m["dsa_causal_pairs"]) == T * (T + 1) / 2
     assert float(m["dsa_pbar_mass_min"]) == pytest.approx(1.0, abs=1e-5)
+    # the CPU's core is XLA's, and so is the search
+    assert float(m["dsa_select_served"]) == 0.0
+    assert float(m["dsa_select_tie_rows"]) >= 0.0
     assert float(m["moe_dropped_pairs"]) == 0.0
     assert float(m["moe_pairs_kept_share"]) == 1.0
     ev = jax.jit(engine.make_eval_step())(state, batch)
@@ -559,13 +655,15 @@ def test_counters_reach_step_telemetry_and_the_registry():
              counters={"main_loss": 9.5, "indexer_loss": 0.25,
                        "dsa_selected_pairs": 31458304.0,
                        "dsa_causal_pairs": 134225920.0,
-                       "dsa_pbar_mass_min": 1.0})
+                       "dsa_pbar_mass_min": 1.0, "dsa_select_served": 1.0,
+                       "dsa_select_tie_rows": 12.0})
     gauges = reg.snapshot()["gauges"]
     assert (gauges["tel_main_loss"], gauges["tel_indexer_loss"],
             gauges["tel_dsa_selected_pairs"],
             gauges["tel_dsa_causal_pairs"],
-            gauges["tel_dsa_pbar_mass_min"]) == (
-        9.5, 0.25, 31458304.0, 134225920.0, 1.0)
+            gauges["tel_dsa_pbar_mass_min"], gauges["tel_dsa_select_served"],
+            gauges["tel_dsa_select_tie_rows"]) == (
+        9.5, 0.25, 31458304.0, 134225920.0, 1.0, 1.0, 12.0)
     for name in engine.LM_COUNTERS:
         assert f"tel_{name}" in INSTRUMENTS and f"tel_{name}" in HELP_TEXT
 

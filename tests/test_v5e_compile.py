@@ -509,16 +509,20 @@ def test_indexed_attention_models_step_compiles_and_fits_one_chip(dsa_step):
     layers at every published width. Every layer names the flash kernels
     once each way, reading the selection as an int8 strip a query block
     (Mosaic takes it; the forward holds k, v and the strip in VMEM), the
-    alignment loss's two kernels (PR 35) and the grouped products of its
-    16 held experts; the blocks take their projections again in the
-    backward pass and neither the selection, nor the core's forward, nor
-    the loss's pass. It compiles for the v5e and fits 15.75 GiB with
-    room; every new scope is in the compiled step."""
+    alignment loss's two kernels, the selection's search
+    (``dsa_select``, once in the body of the loop over chunks of rows,
+    and the kernel that makes the buffer it writes, before the loop) and
+    the grouped products of its 16 held experts; the blocks take
+    their projections again in the backward pass and neither the
+    selection, nor the core's forward, nor the loss's pass. It compiles
+    for the v5e and fits 15.75 GiB with room; every new scope is in the
+    compiled step."""
     lowered, compiled = dsa_step
     names = [name for name, _ in mosaic_calls(lowered.as_text())]
     assert {n: names.count(n) for n in set(names)} == {
         "flash_fwd": 6, "flash_bwd": 6,
-        "indexer_loss_fwd": 6, "indexer_loss_bwd": 6,
+        "indexer_loss_fwd": 6, "indexer_loss_bwd": 6, "dsa_select": 6,
+        "dsa_select_buffer": 6,
         "moe_gmm_fwd": 18, "moe_gmm_dx": 12, "moe_gmm_dw": 12}
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.temp_size_in_bytes
@@ -592,6 +596,35 @@ def test_the_selection_is_turned_once_a_layer_for_both_kernels(dsa_step):
         r"parameter|tuple|while|opt-barrier|custom-call)[\w-]+\(", line)]
     assert len(made) <= 12, (len(made), made[0][:300])
     assert not any("/msa/indexer_loss/transpose" in line for line in made)
+
+
+def test_the_selections_search_compiles_at_the_cells_shape(v5e_2x2):
+    """``dsa_select`` over one chunk of the cell (512 query rows of T =
+    16,384 scores, ``topk`` 2,048): the keys of a whole query block in
+    VMEM as int32 (32 MiB), the int8 selection block (8 MiB, twice) and
+    two key blocks of scores beside them, inside the scoped limit it asks
+    (72 MiB) and the 112 MiB cap; the scores come in as XLA lays them
+    (``[keys, queries]``) and the selection is written so, in place, into
+    the one buffer of the layer's selection (which a kernel that writes
+    nothing makes: no zeros are written first)."""
+    from pytorch_vit_paper_replication_tpu.ops import indexer_select
+
+    one = SingleDeviceSharding(v5e_2x2[0])
+    total = jax.ShapeDtypeStruct((1, 512, 16384), jnp.float32, sharding=one)
+    row0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    lowered = jax.jit(lambda x, r: indexer_select.select(
+        indexer_select.empty(1, 16384, interpret=False), x, r, 2048,
+        interpret=False)).lower(total, row0)
+    assert mosaic_calls(lowered.as_text()) == [
+        ("dsa_select_buffer", ()), ("dsa_select", (1, 16384, 16384))]
+    asked = [int(n) for n in re.findall(r'\\22size\\22: (\d+)',
+                                        lowered.as_text())]
+    assert asked == [72 * 2**20], asked
+    hlo = lowered.compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    # the selection written where it lies, keys major and queries minor
+    assert re.search(r"= \(s8\[1,16384,16384\]\{2,1,0\S*, s32\[1,1,512\]\S*\) "
+                     r"custom-call\(.*output_to_operand_aliasing", hlo)
 
 
 def test_flash_kernels_compile_with_a_selection_a_row(v5e_2x2):
