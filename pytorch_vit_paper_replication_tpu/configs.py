@@ -184,11 +184,34 @@ class ViTConfig:
     # Per layer, 1 = a gated short convolution takes the attention's place
     # (LFM2's mixer: ``[B | C | u] = norm(x) W_in``, ``C * conv(B * u)``,
     # a depthwise causal convolution over the last ``conv_kernel``
-    # positions, ``W_out``; :mod:`..ops.short_conv`), 0 = attention. ()
-    # = attention everywhere. A layout shorter than the depth repeats.
-    # Token models only; a conv layer has no rotary positions or window.
+    # positions, ``W_out``; :mod:`..ops.short_conv`), 2 = a Mamba-2
+    # state-space layer (``models/vit.py::MambaBlock``), 0 = attention.
+    # () = attention everywhere. A layout shorter than the depth repeats.
+    # Token models only; a conv or state-space layer has no rotary
+    # positions or window.
     mixer_layout: Tuple[int, ...] = ()
     conv_kernel: int = 3
+    # A Mamba-2 layer: ``ssm_heads`` heads of ``ssm_head_dim`` columns,
+    # each with a ``[ssm_head_dim, ssm_state]`` state; ``ssm_groups``
+    # groups of heads share their B and C; a depthwise causal convolution
+    # of ``ssm_conv_kernel`` taps (with a bias) over ``[x | B | C]``; the
+    # scan taken in chunks of ``ssm_chunk`` positions (:mod:`..ops.ssd`).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 256
+    # Granite's multipliers (token models): the embedding's rows times
+    # ``embedding_multiplier``; each mixer's and feed-forward's output
+    # times ``residual_multiplier`` before its residual add; the logits
+    # divided by ``logits_scaling``. ``attn_scale``: the softmax scale of
+    # attention (None = ``head_dim ** -0.5``), its ratio to the default
+    # folded into q before the core.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attn_scale: float | None = None
     # The head reads the token embedding's table (``[V, D]``, as it lies)
     # instead of a matrix of its own; the table's gradient is the sum of
     # the lookup's and the head's.
@@ -231,10 +254,13 @@ class ViTConfig:
                 f"router_scoring {self.router_scoring!r}, router_input "
                 f"{self.router_input!r}, expert_activation "
                 f"{self.expert_activation!r}")
-        if (self.shared_experts or self.dense_layers) \
-                and not self.num_experts:
+        if self.shared_experts and not self.num_experts:
+            raise ValueError("shared_experts belong to a routed model")
+        if self.dense_layers and not self.num_experts \
+                and self.dense_layers != self.num_layers:
             raise ValueError(
-                "shared_experts and dense_layers belong to a routed model")
+                "dense_layers: the leading layers of a routed model, or "
+                "every layer (num_layers) of one without experts")
         if self.dense_layers and self.dense_width <= 0:
             raise ValueError("dense_layers needs dense_width")
         if self.kv_lora_rank and not (
@@ -262,18 +288,42 @@ class ViTConfig:
         if self.mtp_modules not in (0, 1) or (
                 self.mtp_modules and not self.vocab_size):
             raise ValueError("mtp_modules: 0, or 1 on a token model")
+        layers = range(self.num_layers + self.mtp_modules)
+        if not set(self.mixer_layout) <= {0, 1, 2}:
+            raise ValueError(f"mixer_layout {self.mixer_layout}: 0 "
+                             "attention, 1 conv, 2 ssm")
         if any(self.mixer_layout) and not (
                 self.vocab_size and self.conv_kernel > 0
                 and not self.kv_lora_rank and not self.sa_topk
-                and not any(self.layer_mixer(i) == "conv"
+                and not any(self.layer_mixer(i) != "attention"
                             and (self.layer_rope(i)
                                  or self.attention_kind(i)[1])
-                            for i in range(self.num_layers
-                                           + self.mtp_modules))):
+                            for i in layers)):
             raise ValueError(
-                "gated short convolutions: a token model with conv_kernel "
-                "> 0, no latent attention and no indexer, and no rotary "
-                "positions or window on a conv layer")
+                "gated short convolutions and state-space layers: a token "
+                "model with conv_kernel > 0, no latent attention and no "
+                "indexer, and no rotary positions or window on such a layer")
+        if any(self.layer_mixer(i) == "ssm" for i in layers) and not (
+                self.ssm_heads > 0 and self.ssm_head_dim > 0
+                and self.ssm_state > 0 and self.ssm_groups > 0
+                and self.ssm_heads % self.ssm_groups == 0
+                and self.ssm_conv_kernel > 0 and self.ssm_chunk > 0):
+            raise ValueError(
+                "state-space layers: ssm_heads, ssm_head_dim, ssm_state, "
+                "ssm_conv_kernel and ssm_chunk > 0, ssm_heads a multiple "
+                "of ssm_groups")
+        if (self.embedding_multiplier, self.residual_multiplier,
+                self.logits_scaling, self.attn_scale) \
+                != (1.0, 1.0, 1.0, None) and not (
+                    self.vocab_size and not self.kv_lora_rank
+                    and self.logits_scaling > 0
+                    and (self.attn_scale is None or self.attn_scale > 0)
+                    and (self.residual_multiplier == 1.0
+                         or self.dense_layers == self.num_layers)):
+            raise ValueError(
+                "multipliers: a token model without latent attention, "
+                "logits_scaling and attn_scale > 0, and a residual_"
+                "multiplier only where every feed-forward is dense")
         if self.tie_embedding and not (self.vocab_size
                                        and not self.mtp_modules):
             raise ValueError("tie_embedding: a token model without a "
@@ -334,9 +384,11 @@ class ViTConfig:
 
     def layer_mixer(self, layer: int) -> str:
         """Block ``layer``'s mixer: ``"conv"`` (a gated short
-        convolution) or ``"attention"``."""
+        convolution), ``"ssm"`` (a Mamba-2 state-space layer) or
+        ``"attention"``."""
         lay = self.mixer_layout
-        return "conv" if lay and lay[layer % len(lay)] else "attention"
+        kind = lay[layer % len(lay)] if lay else 0
+        return ("attention", "conv", "ssm")[kind]
 
     def attention_kind(self, layer: int):
         """Block ``layer``'s attention as structure: ``("full", 0)``
@@ -547,6 +599,49 @@ def conv_tiny(**kw) -> ViTConfig:
         dense_width=96), **kw})
 
 
+def granite_40_h_micro_pp4(**kw) -> ViTConfig:
+    """granite-4.0-h-micro (huggingface.co/ibm-granite,
+    ``granitemoehybrid``), one chip's part of the first of 4 pipeline
+    stages: every published width (2048; Mamba-2 layers of 64 heads of 64
+    with a 128-wide state, one group, a 4-tap convolution with a bias,
+    chunks of 256, expansion 2; grouped-query attention of 32 query / 8
+    key-value heads of 64 with no positions, softmax scale 1/64; a SiLU-
+    gated feed-forward of 8,192 in every layer; one table for the
+    embedding and the head; multipliers 12 / 0.22 / 8), published
+    layers 0-9 (one whole period: nine Mamba-2 layers, attention at layer
+    5) and rows 0-12,543 of the 100,352-row vocabulary, at 16,384 of its
+    131,072 positions; each block taken again in the backward pass.
+    What is assumed of the source is in
+    ``benchmark/configs/granite-4.0-h-micro-pp4.json``."""
+    base = dict(
+        vocab_size=12544, max_seq_len=16384, num_layers=10, num_heads=32,
+        num_kv_heads=8, embedding_dim=2048, norm="rmsnorm", ln_epsilon=1e-5,
+        attn_bias=False, mixer_layout=(2, 2, 2, 2, 2, 0, 2, 2, 2, 2),
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_conv_kernel=4, ssm_chunk=256, dense_layers=10, dense_width=8192,
+        expert_activation="silu", tie_embedding=True,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=8.0, attn_scale=0.015625, remat=True,
+        attn_dropout=0.0, mlp_dropout=0.0, embedding_dropout=0.0)
+    return ViTConfig(**{**base, **kw})
+
+
+def ssm_tiny(**kw) -> ViTConfig:
+    """The same blocks at a size for tests: a Mamba-2 layer, an attention
+    layer and a Mamba-2 layer, width 64, 4 query / 2 key-value heads of
+    16, 8 state-space heads of 16 with a 16-wide state, chunks of 16,
+    dense 96, 256 rows, 64 positions; float32 compute (at this width
+    bf16's rounding of a step's update, after the hundreds of steps a
+    rehearsal takes, reaches the cell's limits, which are set for the
+    chip's widths)."""
+    return granite_40_h_micro_pp4(**{**dict(
+        vocab_size=256, max_seq_len=64, num_layers=3, num_heads=4,
+        num_kv_heads=2, embedding_dim=64, mixer_layout=(2, 0, 2),
+        ssm_heads=8, ssm_head_dim=16, ssm_state=16, ssm_chunk=16,
+        dense_layers=3, dense_width=96, attn_scale=1 / 32,
+        dtype="float32"), **kw})
+
+
 PRESETS = {
     "ViT-Ti/16": vit_ti16,
     "ViT-S/16": vit_s16,
@@ -567,6 +662,8 @@ LM_PRESETS = {
     "dsa-tiny": dsa_tiny,
     "lfm2-24b-a2b-ep8": lfm2_24b_a2b_ep8,
     "conv-tiny": conv_tiny,
+    "granite-4.0-h-micro-pp4": granite_40_h_micro_pp4,
+    "ssm-tiny": ssm_tiny,
 }
 
 # The fields that make two configs the same *servable architecture*

@@ -187,11 +187,13 @@ def _token_loss(state, params, batch, dropout_rng):
     objective and sows the terms (``lm_stats``: ``main_loss``,
     ``mtp_loss``, ``mtp_top1_share``), which ride along as counters; so
     does a model whose attention an indexer selects (``main_loss``,
-    ``indexer_loss``, and :func:`_dsa_metrics`)."""
+    ``indexer_loss``, and :func:`_dsa_metrics`), and a model with
+    state-space layers its ``ssm_state_carry`` (the layers' mean of
+    :func:`.ops.ssd.state_carry`)."""
     (loss, right), sown = state.apply_fn(
         {"params": params}, batch["tokens"], True, labels=batch["label"],
         rngs={"dropout": dropout_rng},
-        mutable=["moe_stats", "lm_stats", "dsa_stats"])
+        mutable=["moe_stats", "lm_stats", "dsa_stats", "ssm_stats"])
     n, t = batch["label"].shape
     with jax.named_scope("metrics"):
         metrics = {"loss_sum": loss * n, "correct": right / t,
@@ -200,6 +202,10 @@ def _token_loss(state, params, batch, dropout_rng):
             metrics.update(_moe_metrics(sown["moe_stats"]))
         if sown.get("dsa_stats"):
             metrics.update(_dsa_metrics(sown["dsa_stats"]))
+        if sown.get("ssm_stats"):
+            # the state-space layers' carry between chunks, their mean
+            metrics["ssm_state_carry"] = jnp.mean(jnp.stack(
+                jax.tree.leaves(sown["ssm_stats"])))
         metrics.update({key: value[0] for key, value in
                         sown.get("lm_stats", {}).items()})
     return loss, metrics
@@ -209,7 +215,7 @@ def _token_loss(state, params, batch, dropout_rng):
 # the telemetry on barriered steps.
 LM_COUNTERS = ("main_loss", "mtp_loss", "mtp_top1_share", "indexer_loss",
                "dsa_selected_pairs", "dsa_causal_pairs", "dsa_pbar_mass_min",
-               "dsa_select_served", "dsa_select_tie_rows")
+               "dsa_select_served", "dsa_select_tie_rows", "ssm_state_carry")
 
 
 def _masked_metrics(losses, logits, labels, mask) -> Dict[str, jax.Array]:
@@ -415,7 +421,8 @@ def _report_first_step(train_step, state, batch, stats) -> None:
     MLP kernels' rows, the attention pair's packed qkv projection; the
     kernel dispatch reads ``jax.default_backend()`` and the call's shapes
     and falls back silently), and for a model whose layers mix
-    convolutions and attention each layer's mixer beside them — costs
+    convolutions or state-space layers with attention each layer's mixer
+    beside them — costs
     one more lowering, so it is skipped
     elsewhere, where there is no Mosaic to find; and the memory each
     local device holds, where the backend reports it."""
@@ -623,7 +630,8 @@ def train(
                 # steps only: the step has finished there, so the fetch
                 # waits for nothing.
                 counters = None
-                if blocked and "moe_dropped_pairs" in metrics:
+                if blocked and any(k.startswith("moe_")
+                                   or k in LM_COUNTERS for k in metrics):
                     # vitlint: hot-path-ok(sampled, on steps already barriered)
                     counters = {k: float(v) for k, v in jax.device_get(
                         {k: v for k, v in metrics.items()

@@ -107,7 +107,10 @@ class TokenEmbedding(nn.Module):
         std = cfg.init_std if cfg.tie_embedding else 1.0
         table = self.param("embedding", nn.initializers.normal(std),
                            (cfg.vocab_size, cfg.embedding_dim), jnp.float32)
-        return jnp.take(table, ids, axis=0).astype(_dtype(cfg))
+        rows = jnp.take(table, ids, axis=0)
+        if cfg.embedding_multiplier != 1.0:
+            rows = rows * cfg.embedding_multiplier
+        return rows.astype(_dtype(cfg))
 
 
 class _PatchConv(nn.Module):
@@ -324,6 +327,10 @@ def _token_attention(self: MultiHeadSelfAttentionBlock, x: jax.Array,
     if cfg.layer_rope(self.layer):
         with jax.named_scope("rope"):
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
+    if cfg.attn_scale is not None:
+        # the core scales by head_dim ** -0.5: q carries the rest (2^-3
+        # for Granite's 1/64 at head size 64, exact in bf16)
+        q = q * (cfg.attn_scale * cfg.head_dim ** 0.5)
     kind, window = cfg.attention_kind(self.layer)
     dropout_rng = None
     if train and cfg.attn_dropout > 0.0:
@@ -500,6 +507,104 @@ class ShortConvBlock(nn.Module):
         return (out, y) if with_normed else out
 
 
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's ``A = -exp(A_log)`` with ``-A`` drawn from U[1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """Mamba-2's step bias: ``dt`` log-uniform in [0.001, 0.1], floored at
+    1e-4, then the inverse of the softplus, so that ``softplus(dt_bias)``
+    is that ``dt``."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, jnp.log(1e-3),
+                                    jnp.log(1e-1)))
+    dt = jnp.maximum(dt, 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _symmetric(bound: float):
+    """U[-bound, bound] (torch's default for a convolution's weight and
+    bias: ``bound = fan_in ** -0.5``)."""
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, dtype, -bound, bound)
+
+
+class MambaBlock(nn.Module):
+    """A state-space layer's mixer (Mamba-2, as granite-4.0-h's
+    ``GraniteMoeHybridMambaLayer``), in the attention's place and with
+    its wiring, as :class:`ShortConvBlock` is: ``u = norm(x)``, ``[z |
+    xBC | dt] = u W_in`` (``in_proj``), ``xBC = silu(conv(xBC) + b)``
+    over the last ``ssm_conv_kernel`` positions, ``[x | B | C] = xBC``
+    (``ssm_heads`` x ``ssm_head_dim``, then ``ssm_groups`` x
+    ``ssm_state`` twice), ``dt = softplus(dt + dt_bias)``, ``A =
+    -exp(A_log)``, the scan (:func:`..ops.ssd.ssd`, chunks of
+    ``ssm_chunk``), ``y * silu(z)`` RMS-normed over each group's columns
+    with a learned scale, ``W_out`` (``out_proj``); no bias on either
+    projection. Scopes ``ssm/in_proj``, ``ssm/conv``, ``ssm/scan``,
+    ``ssm/gate_norm``, ``ssm/out_proj`` inside the block's mixer scope
+    ``msa``. Sown: the mixer's output into ``ssm_probe`` (the benchmark
+    compares layer 0's with its reference) and ``state_carry``
+    (:func:`..ops.ssd.state_carry`) into ``ssm_stats``."""
+
+    config: ViTConfig
+    tp_axis: Optional[str] = None
+    layer: int = 0
+
+    @nn.compact
+    def __call__(self, x: jax.Array, train: bool = False,
+                 with_normed: bool = False):
+        from ..ops import ssd
+        if self.tp_axis is not None:
+            raise ValueError("a token model has no manual tensor "
+                             "parallelism")
+        cfg = self.config
+        h, p, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        inner, width = h * p, h * p + 2 * g * n
+        f32 = jnp.float32
+        dense = functools.partial(
+            nn.Dense, use_bias=False, dtype=_dtype(cfg), param_dtype=f32,
+            kernel_init=nn.initializers.normal(cfg.init_std))
+        y = _norm(cfg, "norm")(x)
+        b, t, _ = y.shape
+        with jax.named_scope("ssm"):
+            zxdt = dense(inner + width + h, name="in_proj")(y)
+            bound = cfg.ssm_conv_kernel ** -0.5
+            taps = self.param("conv_kernel", _symmetric(bound),
+                              (cfg.ssm_conv_kernel, width), f32)
+            conv_bias = self.param("conv_bias", _symmetric(bound), (width,),
+                                   f32)
+            a_log = self.param("A_log", _a_log_init, (h,), f32)
+            dt_bias = self.param("dt_bias", _dt_bias_init, (h,), f32)
+            skip = self.param("D", nn.initializers.ones, (h,), f32)
+            scale = self.param("gate_norm_scale", nn.initializers.ones,
+                               (inner,), f32)
+            with jax.named_scope("conv"):
+                xbc = ssd.causal_conv(zxdt[..., inner:inner + width], taps,
+                                      conv_bias)
+            with jax.named_scope("scan"):
+                dt = jax.nn.softplus(
+                    zxdt[..., inner + width:].astype(f32) + dt_bias)
+                a = -jnp.exp(a_log)
+                self.sow("ssm_stats", "state_carry",
+                         ssd.state_carry(dt, a, cfg.ssm_chunk))
+                ys = ssd.ssd(
+                    xbc[..., :inner].reshape(b, t, h, p), dt, a,
+                    xbc[..., inner:inner + g * n].reshape(b, t, g, n),
+                    xbc[..., inner + g * n:].reshape(b, t, g, n), skip,
+                    cfg.ssm_chunk)
+            with jax.named_scope("gate_norm"):
+                v = (ys.reshape(b, t, g, inner // g).astype(f32)
+                     * jax.nn.silu(zxdt[..., :inner].astype(f32)).reshape(
+                         b, t, g, inner // g))
+                v = v * jax.lax.rsqrt(jnp.mean(v * v, axis=-1, keepdims=True)
+                                      + cfg.ln_epsilon)
+                v = (v.reshape(b, t, inner) * scale).astype(_dtype(cfg))
+            out = dense(cfg.embedding_dim, name="out_proj")(v)
+        self.sow("ssm_probe", "out", out)
+        return (out, y) if with_normed else out
+
+
 def _turn_heads(x, heads, rope, theta, sign):
     """:func:`_flat_rotary`'s pass, by ``sign`` times the angle."""
     b, t, width = x.shape
@@ -638,8 +743,8 @@ class _LatentKeyValueUp(nn.Module):
 # — three ``[T, H x head]`` arrays a layer, where the latents they are
 # taken from again are a sixth of one. The projections before the core
 # are computed twice (2.6% of GLM-4.7-Flash's step FLOPs); the core is
-# not.
-_KEEP_OF_LATENT_ATTENTION = jax.checkpoint_policies.save_only_these_names(
+# not. A token model's whole blocks under ``remat`` keep the same two.
+_KEEP_OF_ATTENTION_CORE = jax.checkpoint_policies.save_only_these_names(
     "attn_core_out", "attn_core_lse")
 
 
@@ -873,8 +978,11 @@ class GatedMLPBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         cfg = self.config
-        return x + _GatedMLP(cfg, cfg.dense_width, name="dense")(
+        y = _GatedMLP(cfg, cfg.dense_width, name="dense")(
             _norm(cfg, "norm")(x))
+        if cfg.residual_multiplier != 1.0:
+            y = y * cfg.residual_multiplier
+        return x + y
 
 
 class RoutedMLPBlock(nn.Module):
@@ -949,9 +1057,11 @@ class TransformerEncoderBlock(nn.Module):
     """Pre-norm residual encoder block: ``x = msa(x)+x; x = mlp(x)+x``.
 
     Reference: ``models/vit.py:133-169`` (residual wiring at :167-168).
-    ``layer`` is the block's index: a token model's mixer (attention or a
-    gated short convolution), rotary positions and attention kind are
-    chosen per layer (``configs.ViTConfig``).
+    ``layer`` is the block's index: a token model's mixer (attention, a
+    gated short convolution or a Mamba-2 state-space layer), rotary
+    positions and attention kind are chosen per layer
+    (``configs.ViTConfig``); ``residual_multiplier`` scales the mixer's
+    output before its residual add (and the dense feed-forward's).
     """
 
     config: ViTConfig
@@ -961,11 +1071,14 @@ class TransformerEncoderBlock(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = False) -> jax.Array:
         msa = MultiHeadSelfAttentionBlock
-        if self.config.layer_mixer(self.layer) == "conv":
+        mixer = self.config.layer_mixer(self.layer)
+        if mixer == "conv":
             msa = ShortConvBlock
+        elif mixer == "ssm":
+            msa = MambaBlock
         elif self.config.kv_lora_rank:
             msa = nn.remat(msa, static_argnums=(2, 3),
-                           policy=_KEEP_OF_LATENT_ATTENTION)
+                           policy=_KEEP_OF_ATTENTION_CORE)
         elif self.config.sa_topk:
             msa = nn.remat(msa, static_argnums=(2, 3),
                            policy=_KEEP_OF_INDEXED_ATTENTION)
@@ -975,7 +1088,10 @@ class TransformerEncoderBlock(nn.Module):
             attn, normed = msa(x, train, True)
             return RoutedMLPBlock(self.config, name="mlp")(
                 attn + x, normed, train)
-        x = msa(x, train, False) + x
+        mixed = msa(x, train, False)
+        if self.config.residual_multiplier != 1.0:
+            mixed = mixed * self.config.residual_multiplier
+        x = mixed + x
         if self.config.dense_layers:
             return GatedMLPBlock(self.config, name="mlp")(x, train)
         # The MLP half's residual is OWNED by MLPBlock (one owner on
@@ -1012,7 +1128,9 @@ class ViTFeatureExtractor(nn.Module):
             x = PatchEmbedding(cfg, name="patch_embedding")(images, train)
         block = TransformerEncoderBlock
         if cfg.remat:
-            block = nn.remat(block, static_argnums=(2,))
+            # a token model's attention core is not taken again
+            block = nn.remat(block, static_argnums=(2,), policy=(
+                _KEEP_OF_ATTENTION_CORE if cfg.vocab_size else None))
         for i in range(cfg.num_layers):
             x = block(cfg, layer=i, name=f"encoder_block_{i}")(x, train)
         tokens = _norm(cfg, "encoder_norm")(x)
@@ -1165,7 +1283,8 @@ class LMHead(nn.Module):
     positions ``counted`` (all of them by default) without the logits
     ever being whole (:mod:`..ops.lm_loss`). Untied, a ``kernel [D, V]``
     of its own; tied (``tie_embedding``), no parameter: ``table`` is the
-    token embedding's ``[V, D]``, read as it lies."""
+    token embedding's ``[V, D]``, read as it lies. The logits are
+    divided by ``logits_scaling``."""
 
     config: ViTConfig
 
@@ -1176,6 +1295,9 @@ class LMHead(nn.Module):
                  table: Optional[jax.Array] = None):
         from ..ops import lm_loss
         cfg = self.config
+        if cfg.logits_scaling != 1.0:
+            # logits / s = (hidden / s) W: the head's products as they are
+            tokens = tokens / cfg.logits_scaling
         if cfg.tie_embedding:
             if labels is None:
                 return jnp.einsum("btd,vd->btv", tokens,

@@ -135,9 +135,17 @@ def _vmem_limit(*resident, scratch=0):
     return int(min(max(need, 32 * _MIB), 112 * _MIB))
 
 
+def _lanes(width: int) -> int:
+    """Columns a row of ``width`` takes in VMEM: whole 128-lane tiles."""
+    return -(-width // 128) * 128
+
+
 def _slab_bytes(x, head_dim):
-    """Bytes of one head's ``[T, Dh]`` slab of a ``[.., T, ..]`` array."""
-    return x.shape[1] * head_dim * x.dtype.itemsize
+    """Bytes of one head's ``[T, Dh]`` slab of a ``[.., T, ..]`` array as
+    VMEM holds it (a head of 64 columns takes 128 lanes: at T = 16,384
+    the backward's four slabs and two float32 slabs take 49 MiB, which a
+    count of 64 columns puts at 40)."""
+    return x.shape[1] * _lanes(head_dim) * x.dtype.itemsize
 
 
 def _layout(structure, x):
@@ -762,7 +770,7 @@ def _flash_bwd(threshold, block_q, block_k, interpret, mask_info, heads,
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(
                 slab, slab, slab, slab, *strip,
-                scratch=2 * 4 * padded_kv * head_dim)),
+                scratch=2 * 4 * padded_kv * _lanes(head_dim))),
         interpret=interpret,
     )(seed, *operands)
     seed_zero = np.zeros(seed.shape, dtype=jax.dtypes.float0)

@@ -47,7 +47,10 @@ def _parts(bcu: jax.Array):
     return f32(bcu[..., :d]), f32(bcu[..., d:2 * d]), f32(bcu[..., 2 * d:])
 
 
-def _conv(v: jax.Array, taps: jax.Array) -> jax.Array:
+def depthwise_causal_conv(v: jax.Array, taps: jax.Array) -> jax.Array:
+    """``sum_k taps[k] * v`` moved ``K - 1 - k`` positions later, for
+    ``v [batch, T, C]`` and ``taps [K, C]``: each channel convolved over
+    its own last K positions, 0 before a sequence's first."""
     k = taps.shape[0]
     return sum(taps[i] * _shift(v, k - 1 - i) for i in range(k))
 
@@ -58,7 +61,8 @@ def short_conv(bcu: jax.Array, taps: jax.Array) -> jax.Array:
     with ``taps [K, D]`` (float32): ``[batch, T, D]`` in ``bcu``'s
     dtype (module docstring)."""
     b, c, u = _parts(bcu)
-    return (c * _conv(b * u, taps.astype(jnp.float32))).astype(bcu.dtype)
+    return (c * depthwise_causal_conv(
+        b * u, taps.astype(jnp.float32))).astype(bcu.dtype)
 
 
 def _fwd(bcu, taps):
@@ -77,7 +81,8 @@ def _bwd(res, dy):
     dv = sum(w[i] * _shift(d_conv, -(k - 1 - i)) for i in range(k))
     d_taps = jnp.stack([jnp.sum(d_conv * _shift(v, k - 1 - i), axis=(0, 1))
                         for i in range(k)])
-    d_bcu = jnp.concatenate([dv * u, g * _conv(v, w), dv * b], axis=-1)
+    d_bcu = jnp.concatenate(
+        [dv * u, g * depthwise_causal_conv(v, w), dv * b], axis=-1)
     return d_bcu.astype(bcu.dtype), d_taps.astype(taps.dtype)
 
 

@@ -100,7 +100,9 @@ LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
 # the loop's body on), then a conv layer's mixer (its two projections and
 # the gate-conv-gate between them; the module keeps the name ``msa``, so
 # the frozen table counts it under ``msa_glue`` and its norm under
-# ``msa_norm``). A ViT's paths match none of them.
+# ``msa_norm``), then a Mamba-2 layer's mixer, under ``msa`` likewise (its
+# two projections, its convolution, its chunked scan and the gated norm
+# after it). A ViT's paths match none of them.
 TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
                      for name, pat in (
     ("mtp_merge", r"mtp/(?:.*/)?mtp_merge"),
@@ -119,6 +121,10 @@ TOKEN_LAYERS = tuple((name, re.compile(rf"(?:^|/)(?:{pat})(?:/|$)"))
     ("indexer_loss", r"indexer_loss"),
     ("conv_proj", r"msa/conv/(?:in|out)_proj"),
     ("conv_mix", r"msa/conv/mix"),
+    ("ssm_proj", r"msa/ssm/(?:in|out)_proj"),
+    ("ssm_conv", r"msa/ssm/conv"),
+    ("ssm_scan", r"msa/ssm/scan"),
+    ("ssm_norm", r"msa/ssm/gate_norm"),
     ("rope", r"msa/rope"),
     ("token_embedding", r"token_embedding"),
     ("head_loss", r"head/(?:.*/)?loss"),

@@ -71,6 +71,31 @@ def conv_mixer_flops(cfg, tokens: int) -> float:
     return 2 * tokens * d * 4 * d + 2 * tokens * cfg.conv_kernel * d
 
 
+def chunk_pairs(tokens: int, chunk: int) -> int:
+    """Causal position pairs inside the chunks of ``chunk`` positions of
+    a sequence of ``tokens`` (the last chunk as long as what is left)."""
+    whole, rest = divmod(tokens, chunk)
+    return whole * chunk * (chunk + 1) // 2 + rest * (rest + 1) // 2
+
+
+def ssm_mixer_flops(cfg, tokens: int) -> float:
+    """Forward FLOPs of a Mamba-2 mixer over ``tokens`` positions: its in
+    (``D -> 2 H P + 2 G N + H``) and out (``H P -> D``) projections, the
+    convolution's taps over ``H P + 2 G N`` channels, and the chunked
+    scan's products at ``ssm_chunk``: ``C B^T`` a group and the blocks'
+    product with ``dt x`` a head over the causal pairs inside each chunk,
+    each position's part of its chunk's state and its read of the state
+    entering the chunk (``P x N`` a head each). The gates, the norm and
+    the decays are elementwise and not counted."""
+    d, h, p, g, n = (cfg.embedding_dim, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_groups, cfg.ssm_state)
+    width = h * p + 2 * g * n
+    pairs = chunk_pairs(tokens, cfg.ssm_chunk)
+    return (2 * tokens * d * (h * p + width + h) + 2 * tokens * h * p * d
+            + 2 * tokens * cfg.ssm_conv_kernel * width
+            + 2 * pairs * (g * n + h * p) + 2 * 2 * tokens * h * p * n)
+
+
 def _attention_flops(cfg, layer: int, t: int) -> float:
     """Forward FLOPs of block ``layer``'s attention over ``t``
     positions (:func:`forward_flops_per_sequence`)."""
@@ -109,16 +134,22 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
     counts the selected pairs for the core, and the indexer's projections
     and its scores of every causal pair. A multi-token-prediction module
     is its merge (``2D -> D``), one more block of the last kind and the
-    head a second time. A conv layer counts its mixer
-    (:func:`conv_mixer_flops`) in the attention's place. The embedding is
-    a lookup and the rotary embedding elementwise: neither is counted."""
+    head a second time. A conv or state-space layer counts its mixer
+    (:func:`conv_mixer_flops`, :func:`ssm_mixer_flops`) in the
+    attention's place; a model without experts whose layers are all
+    dense counts the gated feed-forward of ``dense_width``. The embedding
+    is a lookup and the rotary embedding elementwise: neither is
+    counted."""
     t = seq_len or cfg.max_seq_len
     d = cfg.embedding_dim
     gated = lambda tokens, width: 3 * 2 * tokens * d * width
     total = 0.0
     for layer in range(cfg.num_layers + cfg.mtp_modules):
-        if cfg.layer_mixer(layer) == "conv":
+        mixer = cfg.layer_mixer(layer)
+        if mixer == "conv":
             total += conv_mixer_flops(cfg, t)
+        elif mixer == "ssm":
+            total += ssm_mixer_flops(cfg, t)
         else:
             total += _attention_flops(cfg, layer, t)
         if cfg.layer_routed(layer):
@@ -127,7 +158,7 @@ def forward_flops_per_sequence(cfg, seq_len: Optional[int] = None) -> float:
                 / cfg.num_experts
             total += gated(pairs, cfg.expert_width)     # gate, up, down
             total += gated(t, cfg.shared_experts * cfg.expert_width)
-        elif cfg.num_experts:
+        elif cfg.num_experts or cfg.dense_layers:
             total += gated(t, cfg.dense_width)
         else:
             total += 2 * 2 * t * d * cfg.mlp_size

@@ -80,6 +80,8 @@ INSTRUMENTS: Dict[str, str] = {
     "tel_dsa_pbar_mass_min": "gauge",
     "tel_dsa_select_served": "gauge",
     "tel_dsa_select_tie_rows": "gauge",
+    # a model with state-space layers (engine._token_loss)
+    "tel_ssm_state_carry": "gauge",
     "tel_goodput_pct": "gauge",         # step-exec share of wall time
     "tel_data_wait_frac": "gauge",      # data-wait share of wall time
     "tel_steps_total": "counter",
@@ -339,6 +341,9 @@ HELP_TEXT: Dict[str, str] = {
     "tel_dsa_select_tie_rows":
         "Query rows whose threshold score was tied beyond what they take, "
         "a sequence, summed over layers",
+    "tel_ssm_state_carry":
+        "Share of the state entering a chunk of the scan that reaches its "
+        "end, mean over state-space layers, heads and chunks",
     "tel_goodput_pct": "Step-exec share of epoch wall time, percent",
     "tel_data_wait_frac": "Data-wait share of epoch wall time",
     "tel_steps_total": "Train steps recorded",

@@ -612,9 +612,11 @@ def main(argv=None) -> dict:
                 "--distill-from/--label-smoothing/--mesh-pipe/--elastic "
                 "do not apply")
         from .data.tokens import TokenLoader, TokenSource
+        # (--remat adds recomputation; a preset that has it keeps it)
         cfg = LM_PRESETS[args.preset](
             dtype=args.dtype, attention_impl=args.attention,
-            attention_softmax=args.attention_softmax, remat=args.remat)
+            attention_softmax=args.attention_softmax,
+            **({"remat": True} if args.remat else {}))
         source = TokenSource(args.seed, cfg.vocab_size, cfg.seq_len)
         train_dl = TokenLoader(source, loader_kwargs["batch_size"],
                                args.steps_per_epoch,

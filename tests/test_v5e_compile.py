@@ -703,3 +703,60 @@ def test_conv_models_step_compiles_and_fits_one_chip(conv_step):
     assert layers.count("other") < 0.01 * len(layers)
     # the tied head reads the table as it lies: no transposed copy of it
     assert not re.search(r"= \w+\[2048,8192\]\S* (?:copy|transpose)\(", hlo)
+
+
+# ------------------------------------ Mamba-2 state-space layers (Granite)
+# The cell ``granite4h_train_16k``'s step held 14.21 GiB when it was first
+# compiled (one sequence of 16,384 tokens; 8.63 GiB of parameters and
+# moments, 5.58 of temporaries), every block taken again in the backward
+# pass; the cell asks no more than 15.3 GiB of the v5e's 15.75.
+SSM_STEP_GIB = 15.3
+
+
+@pytest.fixture(scope="module")
+def ssm_step(v5e_2x2):
+    """The cell ``granite4h_train_16k``'s step as the trainer builds it,
+    lowered and compiled once for the file."""
+    from pytorch_vit_paper_replication_tpu.configs import LM_PRESETS
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = LM_PRESETS["granite-4.0-h-micro-pp4"]()
+        lowered = _lower_lm_step(v5e_2x2[:1], cfg, dp=1, batch=1,
+                                 seq_len=cfg.max_seq_len)
+        return lowered, lowered.compile()
+
+
+def test_ssm_models_step_compiles_and_fits_one_chip(ssm_step):
+    """One 16,384-token sequence through granite-4.0-h-micro's cut at
+    every published width: the one attention layer names the flash pair
+    once at head size 64 (its output kept through the block's
+    recomputation: the forward kernel is not run again), the nine Mamba
+    mixers no kernel (their scan is XLA's). It compiles for the v5e and
+    fits under the cell's 15.3 GiB; the scan's in-chunk blocks are never
+    whole for every head (no ``f32[64, 64, 256, 256]``); the mixer's five
+    scopes are in the compiled step, under ``msa_glue`` by the frozen
+    table and never ``other``."""
+    from benchmark.lib import scopes
+
+    lowered, compiled = ssm_step
+    names = [name for name, _ in mosaic_calls(lowered.as_text())]
+    assert {n: names.count(n) for n in set(names)} == {
+        "flash_fwd": 1, "flash_bwd": 1}
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.temp_size_in_bytes
+            + m.output_size_in_bytes - m.alias_size_in_bytes)
+    assert held < SSM_STEP_GIB * 2**30, held / 2**30
+    hlo = compiled.as_text()
+    assert not re.search(r"f32\[(?:1,)?64,64,256,256\]", hlo)
+    assert re.search(r"f32\[(?:1,)?64,8,256,256\]", hlo)
+    paths = set(device_trace.parse_scopes(hlo)["scopes"].values())
+    layers = [device_trace.classify(path)[0] for path in paths
+              if path.startswith("jit(")]
+    for layer in ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_norm",
+                  "attn_core", "head_loss"):
+        assert layer in layers, layer
+    mixer = [path for path in paths if "/msa/ssm/" in path]
+    assert mixer and all(scopes.classify(path)[0] == "msa_glue"
+                         for path in mixer)
+    assert layers.count("other") < 0.01 * len(layers)
