@@ -1,14 +1,16 @@
 """The token model whose layers mix Mamba-2 state-space layers with
 attention (``--preset ssm-tiny``: granite-4.0-h-micro's blocks at a size
-for tests) on the CPU: the chunked scan (``ops/ssd.py``) and its written-
-out gradient against a plain token-by-token recurrence, the model against
-its plain reference (``benchmark/lib/reference_ssm.py``, which takes the
-scan in its quadratic form), a planted fault in the carry between chunks,
-the state-carry counter, Granite's multipliers, and the parameter and FLOP
+for tests) on the CPU: the chunked scan's kernel pair (``ops/ssd.py``,
+``ssd_fwd`` / ``ssd_bwd`` under the Pallas interpreter) and its gradient
+against a plain token-by-token recurrence, the model against its plain
+reference (``benchmark/lib/reference_ssm.py``, which takes the scan in its
+quadratic form), a planted fault in the carry between chunks, the
+state-carry counter, Granite's multipliers, and the parameter and FLOP
 counts of the cut against hand counts.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -66,28 +68,38 @@ def _through(scan, x, raw, a_log, bb, cc, d, dt_bias):
     return scan(x, jax.nn.softplus(raw + dt_bias), -jnp.exp(a_log), bb, cc, d)
 
 
+def _pulled(scan, args, gy):
+    """``(y, the gradient of sum(gy * y) at every input)``, one program."""
+    y, pull = jax.vjp(lambda a: _through(scan, **a), args)
+    return y, pull(gy)[0]
+
+
 @pytest.mark.parametrize("b,t,h,g,chunk,slow", [
     (1, 10, 2, 1, 16, False),     # T < chunk
     (2, 32, 4, 1, 8, False),      # T a multiple of the chunk
-    (2, 37, 4, 1, 8, True),       # T not a multiple
-    (1, 40, 16, 1, 16, True),     # several heads over one group, 2 blocks
-    (2, 40, 16, 2, 16, False),    # two groups of 8 heads
+    (2, 37, 4, 1, 8, True),       # T not a multiple: a partial last chunk
+    (1, 40, 16, 1, 16, True),     # one group, two head blocks of 8
+    (2, 40, 16, 2, 16, False),    # two groups of 8 heads, a block each
     (1, 64, 8, 1, 16, True),      # slow decays: the carry does the work
+    (1, 21, 32, 2, 8, True),      # two groups of two blocks each
 ])
 def test_the_chunked_scan_and_its_gradient_equal_the_recurrence(
         b, t, h, g, chunk, slow):
     """Outputs and the seven gradients (``x``, the raw step through the
     softplus, ``A_log`` through ``A``, ``B``, ``C``, ``D`` and ``dt_bias``)
-    of the chunked scan equal those of the plain recurrence."""
+    of the kernels equal those of the plain recurrence: every one of the
+    scan's six cotangents reaches them. A head block never straddles two
+    groups (``head_block`` takes a divisor of a group's heads), so the
+    cases split groups into blocks, never heads across them."""
     args, gy = _scan_inputs(b, t, h, 4, g, 5, slow)
     chunked = lambda *v: ssd.ssd(*v, chunk)
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(lambda a: _through(chunked, **a))(args)
-        want = jax.jit(lambda a: _through(_recurrence, **a))(args)
-        np.testing.assert_allclose(got, want, atol=2e-5 * float(
-            jnp.abs(want).max()))
-        grads = [jax.jit(jax.grad(lambda a: jnp.sum(gy * _through(s, **a))))(
-            args) for s in (chunked, _recurrence)]
+        (got, got_g), (want, want_g) = (
+            jax.jit(functools.partial(_pulled, s))(args, gy)
+            for s in (chunked, _recurrence))
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(
+        jnp.abs(want).max()))
+    grads = (got_g, want_g)
     assert len(grads[0]) == 7
     for name in args:
         w = grads[1][name]
@@ -96,17 +108,72 @@ def test_the_chunked_scan_and_its_gradient_equal_the_recurrence(
             jnp.abs(w).max()), err_msg=name)
 
 
+def _one_of(args, i):
+    """Sequence ``i`` of a batch's inputs (the per-head ones shared)."""
+    return {k: (v[i:i + 1] if v.ndim > 1 else v) for k, v in args.items()}
+
+
 def test_the_sequences_of_a_batch_share_no_state():
     """Two sequences scanned together give what each gives alone, and
-    each state starts at 0 at its first position."""
-    args, _ = _scan_inputs(2, 40, 4, 4, 1, 5, slow=True, seed=3)
-    run = jax.jit(lambda a: _through(lambda *v: ssd.ssd(*v, 16), **a))
-    both = run(args)
+    each state starts at 0 at its first position: outputs, the per-
+    sequence gradients (``x``, the raw step, ``B``, ``C``) and the shared
+    ones (``A_log``, ``D``, ``dt_bias``: the sum of the two alone)."""
+    args, gy = _scan_inputs(2, 40, 4, 4, 1, 5, slow=True, seed=3)
+    run = jax.jit(functools.partial(_pulled, lambda *v: ssd.ssd(*v, 16)))
+    both, both_g = run(args, gy)
+    alone = [run(_one_of(args, i), gy[i:i + 1]) for i in range(2)]
     for i in range(2):
-        alone = run({k: (v[i:i + 1] if v.ndim > 1 else v)
-                     for k, v in args.items()})
-        np.testing.assert_allclose(both[i:i + 1], alone, rtol=1e-6,
+        np.testing.assert_allclose(both[i:i + 1], alone[i][0], rtol=1e-6,
                                    atol=1e-6)
+    for name, g in both_g.items():
+        want = (jnp.concatenate([a[1][name] for a in alone]) if g.ndim > 1
+                else alone[0][1][name] + alone[1][1][name])
+        np.testing.assert_allclose(g, want, rtol=1e-5, atol=1e-5 * float(
+            jnp.abs(want).max()), err_msg=name)
+
+
+def test_the_kernels_run_per_shard_on_a_data_mesh(devices):
+    """Traced under a two-device data mesh, the scan runs per shard (XLA
+    partitions no Mosaic call; ``tests/test_v5e_compile.py`` compiles it
+    so for the chip): the output and every gradient are the plain
+    recurrence's, ``A``'s and ``D``'s summed over the shards."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_vit_paper_replication_tpu.ops import partition
+
+    args, gy = _scan_inputs(2, 24, 4, 4, 1, 5, slow=True, seed=4)
+    loss = lambda scan, a: jnp.sum(gy * _through(scan, **a))
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.jit(jax.value_and_grad(
+            functools.partial(loss, _recurrence)))(args)
+    mesh = jax.sharding.Mesh(np.array(devices[:2]), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    placed = {k: jax.device_put(v, rows if v.ndim > 1 else
+                                NamedSharding(mesh, P()))
+              for k, v in args.items()}
+    with partition.on_mesh(mesh), jax.default_matmul_precision("highest"):
+        got, got_g = jax.jit(jax.value_and_grad(functools.partial(
+            loss, lambda *v: ssd.ssd(*v, 8))))(placed)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for name in args:
+        w = want_g[name]
+        np.testing.assert_allclose(got_g[name], w, atol=2e-5 * float(
+            jnp.abs(w).max()), err_msg=name)
+
+
+def test_the_head_block_adapts_to_the_shapes():
+    """A program's heads: 8 of Granite's 64 heads of 64 (512 lane-dense
+    columns); never more than a group holds, and whole lanes where the
+    head size allows them."""
+    assert ssd.head_block(64, 64, 1) == 8
+    assert ssd.head_block(8, 16, 1) == 8         # ssm-tiny
+    assert ssd.head_block(16, 64, 2) == 8
+    assert ssd.head_block(12, 64, 1) == 6        # 384 columns
+    assert ssd.head_block(6, 64, 3) == 2         # a group of two
+    assert ssd.head_block(64, 128, 1) == 4
+    assert ssd.head_block(64, 256, 1) == 2
+    assert ssd.head_block(4, 1024, 1) == 1       # a head wider than 512
+    assert ssd.head_block(10, 48, 1) == 5        # no lane-dense divisor
 
 
 def test_the_convolution_is_causal_and_its_gradient_autodiffs():
@@ -154,8 +221,10 @@ def test_the_state_carry_is_its_definition():
 
 # ---------------------------------------------------------- the model
 def _tiny(**kw):
-    # float32 compute: the comparison is of the mathematics
-    return LM_PRESETS["ssm-tiny"](dtype="float32", **kw)
+    # float32 compute: the comparison is of the mathematics; four heads
+    # of 16 (one kernel block), so that the interpreter's kernels stay
+    # small
+    return LM_PRESETS["ssm-tiny"](dtype="float32", ssm_heads=4, **kw)
 
 
 def _params(model, cfg, key=1):
@@ -270,7 +339,8 @@ def _start_rms(got, want, chunk):
 def test_a_state_dropped_between_chunks_shows_at_the_chunks_starts(
         tiny, monkeypatch):
     """The planted fault: the program reads no state back at a chunk's
-    start (what crossed from the chunk before is lost). The mixer
+    start (what crossed from the chunk before is lost: ``ssd_fwd``'s
+    state scratch zeroed before each of its programs). The mixer
     comparison the benchmark's driver makes at the first positions of
     each chunk after the first tells it, where the scan's own reading is
     float32's rounding. (Decays slowed, so that states outlive a chunk as
@@ -287,12 +357,25 @@ def test_a_state_dropped_between_chunks_shows_at_the_chunks_starts(
     honest = _probed(model, slow, tokens)[1]["ssm_probe"]["backbone"][
         "encoder_block_0"]["msa"]["out"][0]
     assert _start_rms(honest, want, cfg.ssm_chunk) < 1e-4
-    monkeypatch.setattr(ssd, "_from_states",
-                        lambda cs, entering, acs, h: jnp.zeros(
-                            acs.shape + (entering.shape[3],)))
-    faulty = ViT(cfg).apply({"params": slow}, tokens, False,
-                            mutable=["ssm_probe"])[1]["ssm_probe"][
-        "backbone"]["encoder_block_0"]["msa"]["out"][0]
+    real = ssd._fwd_kernel
+
+    def no_carry(*refs, **kw):
+        # the state scratch, the kernel's last ref, zeroed before every
+        # program: no chunk reads what the one before it left
+        refs[-1][...] = jnp.zeros(refs[-1].shape, refs[-1].dtype)
+        real(*refs, **kw)
+
+    monkeypatch.setattr(ssd, "_fwd_kernel", no_carry)
+    # the kernels' calls are traced once a shape: trace them again with
+    # the fault, and again without it for the tests after this one
+    jax.clear_caches()
+    try:
+        faulty = ViT(cfg).apply({"params": slow}, tokens, False,
+                                mutable=["ssm_probe"])[1]["ssm_probe"][
+            "backbone"]["encoder_block_0"]["msa"]["out"][0]
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
     assert _start_rms(faulty, want, cfg.ssm_chunk) > 2 * SSM_RMS_TOLERANCE
     # the first chunk has nothing to carry: the fault leaves it alone
     np.testing.assert_allclose(faulty[:, :cfg.ssm_chunk],
@@ -305,22 +388,25 @@ def test_train_step_learns_and_counts(tiny):
     tx = make_optimizer(TrainConfig(batch_size=2), 100)
     state = engine.TrainState.create(apply_fn=model.apply, params=params,
                                      tx=tx, rng=jax.random.key(2))
-    step = jax.jit(engine.make_train_step())
     batch = {"tokens": tokens, "label": labels}
+    # lowered once: the text is read below, the compiled step is run
+    lowered = jax.jit(engine.make_train_step()).lower(state, batch)
+    step = lowered.compile()
     seen = []
     for _ in range(5):
         state, m = step(state, batch)
         seen.append(m)
     assert float(seen[-1]["loss_sum"]) < float(seen[0]["loss_sum"])
     # the step's counter is the layers' mean of the sown carries
-    sown = model.apply({"params": params}, tokens, True, labels=labels,
-                       mutable=["ssm_stats"])[1]["ssm_stats"]
+    sown = jax.jit(lambda p: model.apply(
+        {"params": p}, tokens, True, labels=labels,
+        mutable=["ssm_stats"])[1]["ssm_stats"])(params)
     carries = [float(v[0]) for v in jax.tree.leaves(
         sown, is_leaf=lambda v: isinstance(v, tuple))]
     assert len(carries) == 2 and all(0 < c < 1 for c in carries)
     assert float(seen[0]["ssm_state_carry"]) == pytest.approx(
         np.mean(carries), rel=1e-5)
-    text = step.lower(state, batch).as_text(debug_info=True)
+    text = lowered.as_text(debug_info=True)
     for scope in ("/msa/ssm/in_proj/", "/msa/ssm/conv/", "/msa/ssm/scan/",
                   "/msa/ssm/gate_norm/", "/msa/ssm/out_proj/", "/msa/norm/",
                   "/msa/attn_core/", "/mlp/dense/"):
